@@ -1,0 +1,441 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, one chip, two phases, every check fatal:
+
+1. **The served device path, end to end** at the repo's flagship size
+   (BASELINE config 3: incremental PageRank, 100 000 nodes / 1 000 000
+   edges, ``WebGraph.random(seed=7)``, tol 1e-4). A
+   ``DurableScheduler(get_executor("tpu"), fsync="tick",
+   committer="thread")`` loads the graph, then serves 1 % churn through
+   ``RemoteProducer`` -> ``RpcIngestServer`` (loopback transport, threads)
+   -> ``IngestFrontend(depth=2)`` -> WAL -> fused 16-tick windows. It
+   answers: every ticket ``applied``; ranks within tol / (1 - damping) of
+   ``pagerank.reference_ranks`` (the bound two tol-converged fixpoints can
+   differ by — ``__graft_entry__._dryrun_body``); the fused linear
+   fixpoint engine ran, in fused windows, with zero fallbacks. Then the
+   guarantee: the scheduler is dropped and a fresh one on a fresh executor
+   ``recover()``s from the same WAL — an acked write is read back through
+   the device path (same tick horizon, every acked batch id, ranks inside
+   the same bound). That second build of the same programs also shows the
+   persistent compile cache working.
+2. **Kernels that compile**: k-NN at BASELINE config 4 widths (Q 256,
+   dim 768, k 16, scan chunk 8192, int8 corpus of 2^20 slots preloaded on
+   device), one insert tick and one retraction tick with the Pallas top-k
+   in the tick program, its result equal to ``jax.lax.top_k`` on the same
+   scores.
+
+The default invocation needs a TPU and never finishes on anything else.
+``--tiny`` is the small CPU form tier-1 drives; it is reached only by
+asking for it AND stating ``JAX_PLATFORMS=cpu``, never by finding no chip.
+
+stdout: progress lines, one JSON summary (``"claim": null`` — this script
+measures nothing it would defend as a performance number), and as the
+LAST line ``{"ok": true, "device": {"platform", "kind", "count"}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+
+FULL = {
+    "nodes": 100_000, "edges": 1_000_000, "churn": 0.01, "tol": 1e-4,
+    "window_ticks": 16, "steady_windows": 2,
+    "knn": {"Q": 256, "D": 1 << 20, "dim": 768, "k": 16, "chunk": 8192,
+            "preload_rows": 1 << 16, "preload_chunks": 15,
+            "insert_rows": 8192, "retract_rows": 1024},
+}
+TINY = {
+    "nodes": 256, "edges": 2048, "churn": 0.01, "tol": 1e-4,
+    "window_ticks": 4, "steady_windows": 2,
+    "knn": {"Q": 16, "D": 2048, "dim": 32, "k": 4, "chunk": 512,
+            "preload_rows": 512, "preload_chunks": 3,
+            "insert_rows": 256, "retract_rows": 64},
+}
+
+#: generous wall bounds on each blocking wait, so a wedged pump or link
+#: fails the smoke instead of hanging the chip
+WAIT_S = 600.0
+
+
+def say(msg: str) -> None:
+    print(f"chip_smoke: {msg}", flush=True)
+
+
+def require(cond, msg: str) -> None:
+    """A failed check ends the run: non-zero exit, no result line."""
+    if not cond:
+        raise SystemExit(f"chip_smoke: FAILED: {msg}")
+
+
+def require_device(tiny: bool):
+    """The device this run is allowed to finish on — checked before any
+    work. Default: a TPU, whatever the environment says. ``--tiny``: the
+    CPU, and only because the caller said so."""
+    if tiny:
+        require(os.environ.get("JAX_PLATFORMS") == "cpu",
+                "--tiny is the CPU form: state JAX_PLATFORMS=cpu")
+    import jax
+
+    dev = jax.devices()[0]
+    want = "cpu" if tiny else "tpu"
+    require(dev.platform == want,
+            f"needs platform {want!r}: JAX resolved {dev.platform!r} "
+            f"(kind {dev.device_kind!r}); there is no fallback")
+    return dev
+
+
+def require_resident(states, devices, what: str) -> None:
+    """Every state leaf lives on ``devices``, and together they use all
+    of them — nothing strayed to another device (or stayed on device 0
+    of a mesh)."""
+    import jax
+
+    leaves = [x for x in jax.tree.leaves(states) if isinstance(x, jax.Array)]
+    require(leaves, f"{what}: no device state was built")
+    used = set().union(*(x.devices() for x in leaves))
+    require(used == set(devices),
+            f"{what}: state leaves live on {sorted(map(str, used))}, "
+            f"want exactly {sorted(map(str, devices))}")
+
+
+def rel_err(got, ref) -> float:
+    """max |got - ref| / max(|ref|, 1): ``__graft_entry__``'s measure."""
+    import numpy as np
+
+    return float((np.abs(got - ref) / np.maximum(np.abs(ref), 1.0)).max())
+
+
+# -- phase 1: served PageRank + recovery from the WAL ----------------------
+
+def pagerank_phase(cfg: dict, devices, root: str, *, make_executor=None,
+                   shards: int = 1) -> dict:
+    """``devices`` is where the executor's state must live: the one chip
+    here; ``tools/chip_checks.py mesh`` passes a 4-chip mesh's devices
+    with a ``ShardedTpuExecutor`` factory and ``shards=4``."""
+    import jax
+
+    from bench import _build_pagerank     # arena sized as the bench does
+    from bench_configs import _pad_batch
+    from reflow_tpu.executors import get_executor
+    from reflow_tpu.net import LoopbackTransport
+    from reflow_tpu.serve import (APPLIED, CoalesceWindow, IngestFrontend,
+                                  RemoteProducer, RpcIngestServer)
+    from reflow_tpu.wal import DurableScheduler, recover
+    from reflow_tpu.workloads import pagerank
+
+    n, e, tol, k = cfg["nodes"], cfg["edges"], cfg["tol"], cfg["window_ticks"]
+    bound = tol / (1.0 - pagerank.DAMPING)
+    n_churn = 2 * max(1, int(cfg["churn"] * e))
+    wal_dir = os.path.join(root, "wal")
+
+    # every batch is minted up front (WebGraph.churn mutates its edge
+    # set), padded to ONE capacity bucket; afterwards `web` IS the final
+    # graph, so reference_ranks(web) is what the served state must equal
+    if make_executor is None:
+        make_executor = lambda: get_executor("tpu")     # noqa: E731
+    pr, web = _build_pagerank(n, e, cfg["churn"], tol, seed=7, shards=shards)
+    init = web.initial_batch()
+    waves = [[_pad_batch(web.churn(cfg["churn"]), n_churn)
+              for _ in range(w * k)]
+             for w in (1, cfg["steady_windows"])]   # warm (compiles), steady
+    ref = pagerank.reference_ranks(web)
+
+    # -- load: the first build of every program (compile included) -----
+    t0 = time.perf_counter()
+    ex = make_executor()
+    sched = DurableScheduler(pr.graph, ex, wal_dir=wal_dir, fsync="tick",
+                             committer="thread")
+    require_resident(ex.states, devices, "pagerank bind")
+    sched.push(pr.teleport, pagerank.teleport_batch(n), batch_id="load/tp")
+    sched.push(pr.edges, init, batch_id="load/edges")
+    built = sched.tick()
+    setup_s = time.perf_counter() - t0
+    require(built.quiesced, "initial build did not quiesce")
+    require(ex.fixpoint_engine == "LinearFixpointProgram",
+            f"build ran {ex.fixpoint_engine}, not the fused linear loop")
+    say(f"pagerank load {n} nodes / {e} edges: {setup_s:.2f}s "
+        f"(build tick {built.wall_s:.2f}s, engine {ex.fixpoint_engine})")
+
+    # -- serve: churn through producer -> rpc -> frontend -> WAL -> windows
+    fe = IngestFrontend(
+        sched, max_bytes=1 << 30, depth=2,
+        window=CoalesceWindow(max_rows=n_churn, max_ticks=k,
+                              max_latency_s=0.005))
+    srv = prod = None
+    acked = []
+    try:
+        lt = LoopbackTransport()
+        srv = RpcIngestServer(fe, lt).start()
+        prod = RemoteProducer(lt, srv.address, name="smoke")
+        walls = []
+        for batches in waves:
+            # pause -> submit -> resume: the wave drains as ONE backlog,
+            # so its windows stage back to back and depth 2 can pipeline
+            fe.pause()
+            tickets = [prod.submit(pr.edges, b) for b in batches]
+            t0 = time.perf_counter()
+            fe.resume()
+            prod.flush(timeout=WAIT_S)
+            fe.flush(timeout=WAIT_S)
+            jax.block_until_ready(ex.states)
+            walls.append(time.perf_counter() - t0)
+            for t in tickets:
+                res = t.result(timeout=WAIT_S)
+                require(res.status == APPLIED,
+                        f"ticket {t.batch_id} resolved {res.status!r}")
+                acked.append(t.batch_id)
+        warm_s, steady_s = walls
+        steady_ticks = cfg["steady_windows"] * k
+        say(f"served {len(acked)} churn batches of {n_churn} rows: warm "
+            f"window {warm_s:.2f}s (compiles), {steady_ticks} steady ticks "
+            f"{steady_s:.3f}s")
+
+        sched.executor.check_errors()
+        require(all(r.block().quiesced for r in sched.history),
+                "a churn window did not quiesce")
+        counters = {
+            "fixpoint_engine": ex.fixpoint_engine,
+            "megatick_windows": sched.megatick_windows,
+            "megatick_fallbacks": sched.megatick_fallbacks,
+            "windows_staged": fe.windows_staged,
+            "windows_pipelined": fe.windows_pipelined,
+            "forced_syncs": sched.forced_syncs,
+            "wal_log_readbacks": sched.log_readbacks,
+        }
+        require(ex.fixpoint_engine == "LinearFixpointProgram",
+                f"churn ran {ex.fixpoint_engine}, not the fused linear loop")
+        require(sched.megatick_windows >= 1 + cfg["steady_windows"],
+                f"only {sched.megatick_windows} fused windows")
+        require(sched.megatick_fallbacks == 0,
+                f"{sched.megatick_fallbacks} windows fell back per-tick")
+        require(fe.windows_pipelined >= 1, "depth 2 never pipelined")
+        require_resident(ex.states, devices, "pagerank after churn")
+
+        ranks = pagerank.ranks_to_array(sched.read_table(pr.new_rank), n)
+        err = rel_err(ranks, ref)
+        require(err < bound, f"served ranks off the reference by {err:.3e} "
+                             f"(bound {bound:.3e})")
+        horizon = sched._tick
+        say(f"ranks vs reference_ranks: max rel err {err:.3e} < "
+            f"{bound:.3e}; horizon tick {horizon}; {counters}")
+    finally:
+        if prod is not None:
+            prod.close()
+        if srv is not None:
+            srv.close()
+        fe.close()
+        sched.close()
+    del sched, ex, fe
+
+    # -- the guarantee: recover a fresh scheduler from the same WAL ----
+    pr2, _ = _build_pagerank(n, e, cfg["churn"], tol, seed=7, shards=shards)
+    t0 = time.perf_counter()
+    ex2 = make_executor()
+    sched2 = DurableScheduler(pr2.graph, ex2, wal_dir=wal_dir, fsync="tick",
+                              committer="thread")
+    try:
+        report = recover(sched2, wal_dir)
+        jax.block_until_ready(ex2.states)
+        recover_s = time.perf_counter() - t0
+        rebuilt_s = sched2.history[0].wall_s
+        require(sched2._tick == horizon,
+                f"recovered horizon {sched2._tick} != {horizon}")
+        lost = [b for b in acked if b not in sched2._seen_batch_ids]
+        require(not lost, f"{len(lost)} acked batches missing after "
+                          f"recovery (first: {lost[:3]})")
+        require(ex2.fixpoint_engine == "LinearFixpointProgram",
+                f"recovery ran {ex2.fixpoint_engine}")
+        require_resident(ex2.states, devices, "pagerank recovered")
+        ranks2 = pagerank.ranks_to_array(
+            sched2.read_table(pr2.new_rank), n)
+        err2 = rel_err(ranks2, ref)
+        drift = rel_err(ranks2, ranks)
+        require(err2 < bound, f"recovered ranks off the reference by "
+                              f"{err2:.3e} (bound {bound:.3e})")
+        require(drift < bound, f"recovered ranks differ from the served "
+                               f"ones by {drift:.3e} (bound {bound:.3e})")
+    finally:
+        sched2.close()
+    say(f"recovered from the WAL in {recover_s:.2f}s: {report.replayed_pushes}"
+        f" pushes, {report.replayed_ticks} ticks, horizon {sched2._tick}, "
+        f"rel err {err2:.3e}, vs served {drift:.3e}; build tick "
+        f"{rebuilt_s:.2f}s (first build {built.wall_s:.2f}s)")
+
+    return {
+        "nodes": n, "edges": e, "churn_rows_per_batch": n_churn,
+        "window_ticks": k, "tickets_applied": len(acked),
+        "max_rel_err": err, "rel_err_bound": bound,
+        **counters,
+        "setup_s": round(setup_s, 3),
+        "build_tick_s": round(built.wall_s, 3),
+        "warm_window_s": round(warm_s, 3),
+        "steady_s": round(steady_s, 4), "steady_ticks": steady_ticks,
+        "recover_s": round(recover_s, 3),
+        "recovered_build_tick_s": round(rebuilt_s, 3),
+        "recovered_horizon": sched2._tick,
+        "recovered_max_rel_err": err2,
+        "recovered_vs_served_rel": drift,
+        "replayed_pushes": report.replayed_pushes,
+        "replayed_ticks": report.replayed_ticks,
+    }
+
+
+# -- phase 2: k-NN with the compiled Pallas top-k --------------------------
+
+def knn_phase(cfg: dict, dev) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench_configs import knn_preload_chunk
+    from reflow_tpu.delta import DeltaBatch
+    from reflow_tpu.executors import get_executor
+    from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
+                                         score_form, topk)
+    from reflow_tpu.scheduler import DirtyScheduler
+    from reflow_tpu.workloads import knn
+
+    c = cfg["knn"]
+    Q, D, dim, k, chunk = c["Q"], c["D"], c["dim"], c["k"], c["chunk"]
+    kg = knn.build_graph(Q, D, dim, k, scan_chunk=chunk, dtype=jnp.bfloat16,
+                         doc_dtype=jnp.int8, precision="default")
+    ex = get_executor("tpu")
+    sched = DirtyScheduler(kg.graph, ex)
+    require_resident(ex.states, [dev], "knn bind")
+
+    t0 = time.perf_counter()
+    store = knn.EmbeddingStore.create(dim, seed=3)
+    sched.push(kg.queries, DeltaBatch(
+        np.arange(Q, dtype=np.int64), store._random(Q),
+        np.ones(Q, np.int64)))
+    gen = knn_preload_chunk(c["preload_rows"], dim, D, jnp.int8)
+    next_id = 0
+    for ix in range(c["preload_chunks"]):       # corpus made on the device
+        sched.push(kg.docs, gen(np.int32(ix), np.int32(next_id)))
+        sched.tick(sync=False)
+        next_id += c["preload_rows"]
+    jax.block_until_ready(ex.states)
+    preload_s = time.perf_counter() - t0
+
+    # one insert tick (host int8 rows, the real ingest boundary) ...
+    t0 = time.perf_counter()
+    ins_ids = np.arange(next_id, next_id + c["insert_rows"])
+    sched.push(kg.docs, store.insert_batch(ins_ids, quantize=True))
+    sched.tick()
+    insert_s = time.perf_counter() - t0
+    # ... and one retraction tick: forces the chunked full-corpus rescan,
+    # i.e. the kernel at [Q, k + chunk]. A device retraction only clears
+    # the id's live bit, so zero rows stand in for the device-made vectors.
+    t0 = time.perf_counter()
+    ret_ids = np.arange(c["retract_rows"], dtype=np.int64)
+    sched.push(kg.docs, DeltaBatch(
+        ret_ids, np.zeros((len(ret_ids), dim), np.float32),
+        -np.ones(len(ret_ids), np.int64)))
+    sched.tick()
+    rescan_s = time.perf_counter() - t0
+
+    st = ex.states[kg.index.id]
+    live = int(np.asarray(st["dlive"]).sum())
+    want_live = next_id + c["insert_rows"] - c["retract_rows"]
+    require(live == want_live, f"{live} live corpus rows, want {want_live}")
+    table = sched.read_table(kg.index)
+    require(len(table) == Q, f"{len(table)} query rows, want {Q}")
+    rows = np.stack([table[q] for q in range(Q)])          # [Q, k, 2]
+    require(rows.shape == (Q, k, 2) and np.isfinite(rows).all(),
+            f"top-k table shape {rows.shape} / non-finite entries")
+    got_ids, got_vals = rows[:, :, 0].astype(np.int64), rows[:, :, 1]
+    require((got_ids >= c["retract_rows"]).all() and (got_ids < D).all(),
+            "a retracted or out-of-range doc id is in a query's top-k")
+
+    # the served table (Pallas inside the tick program on a TPU) against
+    # the same rescan selected by lax.top_k, from the executor's own state
+    prec = jax.lax.Precision.DEFAULT
+    ref_vals, ref_ids = jax.jit(
+        lambda q, d, l: chunked_corpus_topk(q, d, l, k, chunk,
+                                            use_pallas=False,
+                                            precision=prec)
+    )(st["qvec"], st["dvec"], st["dlive"])
+    require(np.array_equal(got_ids, np.asarray(ref_ids))
+            and np.array_equal(got_vals, np.asarray(ref_vals)),
+            "served top-k table != lax.top_k rescan of the same state")
+
+    # the kernel alone, on literally the same scores: one [Q, k + chunk]
+    # candidate matrix as the rescan builds it, Pallas vs lax.top_k
+    @jax.jit
+    def scores(q, d, l):
+        s = jnp.dot(score_form(q), score_form(d[:chunk]).T,
+                    preferred_element_type=jnp.float32, precision=prec)
+        s = jnp.where(l[None, :chunk], s, NEG)
+        return jnp.concatenate([jnp.full((Q, k), NEG, jnp.float32), s], 1)
+
+    s = scores(st["qvec"], st["dvec"], st["dlive"])
+    pv, pi = jax.jit(lambda x: topk(x, k, use_pallas=True))(s)
+    lv, li = jax.lax.top_k(s, k)
+    require(np.array_equal(np.asarray(pi), np.asarray(li))
+            and np.array_equal(np.asarray(pv), np.asarray(lv)),
+            f"Pallas top-k != lax.top_k on the same {s.shape} scores")
+    pallas_in_tick = jax.default_backend() == "tpu"
+    say(f"knn Q {Q} dim {dim} k {k} chunk {chunk}, {live} live of {D} "
+        f"slots (cut: {D - next_id - c['insert_rows']} slots left empty): "
+        f"preload {preload_s:.2f}s, insert tick {insert_s:.3f}s, "
+        f"retraction rescan {rescan_s:.3f}s; Pallas kernel "
+        f"{'compiled' if pallas_in_tick else 'interpreted'} at {s.shape} "
+        f"== lax.top_k; served table == lax.top_k rescan")
+    return {
+        "Q": Q, "dim": dim, "k": k, "scan_chunk": chunk,
+        "corpus_slots": D, "corpus_live": live,
+        "pallas_compiled": pallas_in_tick,
+        "kernel_scores_shape": list(s.shape),
+        "preload_s": round(preload_s, 3),
+        "insert_tick_s": round(insert_s, 4),
+        "rescan_tick_s": round(rescan_s, 4),
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="small CPU form (tier-1); needs JAX_PLATFORMS=cpu")
+    args = ap.parse_args(argv)
+    cfg = TINY if args.tiny else FULL
+
+    t_start = time.perf_counter()
+    dev = require_device(args.tiny)
+
+    from reflow_tpu.utils.runtime import device_record, place_compile_cache
+
+    device = device_record()
+    say(f"platform={device['platform']} device_kind={device['kind']} "
+        f"devices={device['count']}")
+    cache_dir = place_compile_cache()
+    cached = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    say(f"compile cache {cache_dir} ({cached} entries at start)")
+
+    root = tempfile.mkdtemp(prefix="reflow-chip-smoke-")
+    try:
+        pr = pagerank_phase(cfg, [dev], root)
+        kn = knn_phase(cfg, dev)
+    finally:
+        shutil.rmtree(root, ignore_errors=True)
+
+    print(json.dumps({
+        "schema": "reflow.chip_smoke/1", "form": "tiny" if args.tiny
+        else "full", "device": device,
+        "compile_cache": {"dir": cache_dir, "entries_at_start": cached},
+        "pagerank": pr, "knn": kn,
+        "total_s": round(time.perf_counter() - t_start, 2),
+        "claim": None,
+    }), flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

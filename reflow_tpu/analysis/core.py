@@ -31,7 +31,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 #: directories the walker never descends into
 SKIP_DIRS = {"__pycache__", ".git", ".claude", "node_modules", ".venv",
-             "venv", "build", "dist", ".pytest_cache"}
+             "venv", "build", "dist", ".pytest_cache",
+             # git-ignored run-time directories: the compile cache, the
+             # chip tool's output, a second checkout unpacked for a chip run
+             ".jax_cache", "chiprun_out", ".chip_tree"}
 
 _WAIVE_RE = re.compile(
     r"#\s*reflow-lint:\s*waive\s+([A-Za-z0-9_,-]+)(?:\s*--\s*(.*))?")
@@ -158,9 +161,9 @@ def run(root: str, *, passes: Optional[List[str]] = None,
     but counted; a waiver missing its reason is always a finding."""
     # passes self-register at import; import here so `import
     # reflow_tpu.analysis.core` alone stays side-effect-light
-    from reflow_tpu.analysis import (constants, envknobs,  # noqa: F401
-                                     exceptions, locks, metrics_pass,
-                                     seams, sockets, spans)
+    from reflow_tpu.analysis import (envknobs, exceptions,  # noqa: F401
+                                     locks, metrics_pass, seams,
+                                     sockets, spans)
 
     corpus = Corpus(root)
     findings: List[Finding] = []

@@ -96,27 +96,6 @@ def _write_slot(bufs: DeviceDelta, t, keys, values, weights) -> DeviceDelta:
 # devices) share the compilation instead of re-jitting per queue
 _WRITER = jax.jit(_write_slot, donate_argnums=0)
 
-#: does this backend COPY host numpy arguments when they enter a
-#: computation? jaxlib's CPU client can zero-copy aligned host buffers
-#: in some versions, in which case a reused scratch array would alias
-#: live device data and mutating it between slot writes would corrupt
-#: an in-flight window. Probed once, lazily.
-_SCRATCH_REUSE_SAFE: Optional[bool] = None
-
-
-def _scratch_reuse_safe() -> bool:
-    global _SCRATCH_REUSE_SAFE
-    if _SCRATCH_REUSE_SAFE is None:
-        import jax.numpy as jnp
-
-        probe = np.arange(32, dtype=np.int32)
-        dev = jnp.asarray(probe)
-        probe[:] = -1
-        dev.block_until_ready()
-        _SCRATCH_REUSE_SAFE = not bool((np.asarray(dev) < 0).any())
-    return _SCRATCH_REUSE_SAFE
-
-
 class DeviceIngressQueue:
     """Per-source [K, cap] delta buffers plus their jitted slot writer.
 
@@ -163,11 +142,6 @@ class DeviceIngressQueue:
         self._inflight: List[int] = []
         self._staging: Optional[int] = None
         self._alloc_gen()  # generation 0, eagerly — same memory as before
-        #: host-side padded staging arrays, one set per source, reused
-        #: across every slot write (kills the three-np.zeros-per-slot
-        #: churn); only when the backend copies host args at dispatch
-        self._scratch: Dict[int, tuple] = {}
-        self._scratch_rows: Dict[int, int] = {}
         self._writer = _WRITER
 
     def _alloc_gen(self) -> int:
@@ -306,33 +280,19 @@ class DeviceIngressQueue:
         self.writes += 1
 
     def _pad_host(self, nid: int, n: int, cap: int, bkeys, batch):
-        """Capacity-padded host images of one batch's columns. Reuses a
-        per-source preallocated scratch set (zeroing only the tail the
-        previous fill dirtied) when the backend copies host args at
-        dispatch; falls back to fresh allocations on an aliasing
-        backend, where a reused array could be mutated under an
-        in-flight transfer."""
+        """Capacity-padded host images of one batch's columns — FRESH
+        arrays every call, never a reused scratch set. The slot writer's
+        dispatch is asynchronous and the runtime may read a host argument
+        after the call returns (the CPU client aliases a suitably aligned
+        buffer outright; an accelerator copies it when its transfer
+        runs), so an array handed to it must never be written again:
+        refilling a shared scratch for slot t+1 rewrote slot t's rows
+        under the in-flight write."""
         spec = self._specs[nid]
         vshape = tuple(spec.value_shape)
-        if _scratch_reuse_safe():
-            sc = self._scratch.get(nid)
-            if sc is None:
-                sc = self._scratch[nid] = (
-                    np.zeros(cap, np.int32),
-                    np.zeros((cap,) + vshape, spec.value_dtype),
-                    np.zeros(cap, np.int32))
-                self._scratch_rows[nid] = 0
-            keys, values, weights = sc
-            prev = self._scratch_rows[nid]
-            if prev > n:
-                keys[n:prev] = 0
-                values[n:prev] = 0
-                weights[n:prev] = 0
-            self._scratch_rows[nid] = n
-        else:
-            keys = np.zeros(cap, np.int32)
-            values = np.zeros((cap,) + vshape, spec.value_dtype)
-            weights = np.zeros(cap, np.int32)
+        keys = np.zeros(cap, np.int32)
+        values = np.zeros((cap,) + vshape, spec.value_dtype)
+        weights = np.zeros(cap, np.int32)
         keys[:n] = bkeys
         weights[:n] = batch.weights
         values[:n] = np.asarray(batch.values).reshape((n,) + vshape)

@@ -771,9 +771,7 @@ class LinearFixpointProgram(_MacroTickMixin):
             out_specs = (jspec, rspec, cspec, PS(), PS(), PS())
             if resid is not None:
                 out_specs = out_specs + (PS(axis),)
-            from reflow_tpu.parallel.shard import shard_map
-
-            fn = shard_map(
+            fn = jax.shard_map(
                 loop_region, mesh=mesh,
                 in_specs=(jspec, rspec, cspec, dspec, PS(axis), rs_in),
                 out_specs=out_specs, check_vma=False)

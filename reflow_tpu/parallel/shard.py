@@ -31,31 +31,7 @@ from reflow_tpu.graph import FlowGraph, GraphError, Node
 from reflow_tpu.parallel.mesh import make_mesh, replicate
 from reflow_tpu.parallel.shard_lowerings import lower_node_sharded
 
-__all__ = ["ShardedTpuExecutor", "shard_map"]
-
-
-def _resolve_shard_map():
-    """Version-tolerant ``shard_map``: newer jax exposes ``jax.shard_map``
-    (replication check kwarg ``check_vma``); the pinned older releases
-    only have ``jax.experimental.shard_map.shard_map`` (kwarg
-    ``check_rep``). Resolve whichever exists and normalize the kwarg so
-    every call site can use the modern spelling."""
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    check_kw = ("check_vma" if "check_vma" in inspect.signature(fn).parameters
-                else "check_rep")
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **{check_kw: check_vma})
-
-    return _shard_map
-
-
-shard_map = _resolve_shard_map()
+__all__ = ["ShardedTpuExecutor"]
 
 
 class ShardedTpuExecutor(TpuExecutor):
@@ -294,7 +270,7 @@ class ShardedTpuExecutor(TpuExecutor):
             sspec = self._state_tree_specs(
                 {node.id: self.states[node.id]})[node.id]
             dspec = DeviceDelta(P2(axis), P2(axis), P2(axis))
-            fn = self._cache[sig] = jax.jit(shard_map(
+            fn = self._cache[sig] = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=(sspec, dspec),
                 out_specs=sspec, check_vma=False), donate_argnums=0)
         self.states[node.id] = fn(self.states[node.id], d)
@@ -338,7 +314,7 @@ class ShardedTpuExecutor(TpuExecutor):
             in_specs = (state_specs, {nid: dspec for nid in ingress})
             out_specs = (state_specs, {eid: dspec
                                        for eid in _egress_ids(ingress)})
-            fn = shard_map(local_pass, mesh=mesh, in_specs=in_specs,
+            fn = jax.shard_map(local_pass, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
             return fn(states, ingress)
 

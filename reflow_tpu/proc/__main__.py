@@ -19,6 +19,7 @@ for checkout-relative invocation.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 
 
@@ -71,6 +72,14 @@ def main(argv=None) -> int:
         ap.error(f"--role {args.role} requires --root")
     if args.role == "producer" and not args.connect:
         ap.error("--role producer requires --connect")
+
+    # Every role runs the CPU oracle executor, so the process belongs on
+    # the CPU whatever it inherited — set before anything imports JAX.
+    # An accelerator is held by ONE process: a leader or replica that
+    # checkpoints calls jax.process_count(), which initialises a backend,
+    # and on a machine with a chip the first such child would take it
+    # from the process that actually runs the device path.
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     from reflow_tpu.proc import worker
 

@@ -8,8 +8,8 @@ forked *processes*), speaks a line protocol with the parent, and
 returns a status dict the CLI prints as its exit JSON:
 
 - **stdout**: one JSON object per line. The first is the ready line
-  (``{"event": "ready", "name", "pid", addresses...}``) — the parent
-  learns the OS-assigned ports from it. The last is the exit status.
+  (``{"event": "ready", "name", "pid", "jax_platforms", addresses...}``)
+  — the parent learns the OS-assigned ports from it. The last is the exit status.
 - **stdin**: JSON commands — ``{"cmd": "stop"}`` everywhere;
   ``{"cmd": "attach", "replicas": [[name, [host, port]], ...]}`` on a
   leader; ``{"cmd": "connect", "address": [host, port]}`` retargets a
@@ -92,6 +92,12 @@ def _stdin_commands() -> "queue.Queue[Optional[dict]]":
 
     threading.Thread(target=read, name="proc-stdin", daemon=True).start()
     return q
+
+
+def _jax_platforms() -> Optional[str]:
+    """The ready line's ``jax_platforms``: what this process lets JAX
+    initialise (``proc/__main__`` pins every role to the CPU)."""
+    return os.environ.get("JAX_PLATFORMS")
 
 
 def _graph(workload: str):
@@ -285,7 +291,8 @@ def run_replica(opts: dict) -> dict:
     _obs_install(opts, opts["name"])
     telemetry = _telemetry(opts, opts["name"])
     emit({"event": "ready", "role": "replica", "name": node.name,
-          "pid": os.getpid(), "addr": list(node.server.address),
+          "pid": os.getpid(), "jax_platforms": _jax_platforms(),
+          "addr": list(node.server.address),
           "subs": list(node.subs_address)})
     cmds = _stdin_commands()
     try:
@@ -328,7 +335,8 @@ def run_leader(opts: dict) -> dict:
     _obs_install(opts, name)
     telemetry = _telemetry(opts, name)
     emit({"event": "ready", "role": "leader", "name": name,
-          "pid": os.getpid(), "ingest": list(ingest.address),
+          "pid": os.getpid(), "jax_platforms": _jax_platforms(),
+          "ingest": list(ingest.address),
           "wal_dir": wal_dir, "ckpt_dir": ckpt_dir})
     cmds = _stdin_commands()
     attached: List[str] = []
@@ -379,7 +387,8 @@ def run_producer(opts: dict) -> dict:
     _obs_install(opts, name)
     telemetry = _telemetry(opts, name)
     emit({"event": "ready", "role": "producer", "name": name,
-          "pid": os.getpid(), "connect": list(opts["connect"])})
+          "pid": os.getpid(), "jax_platforms": _jax_platforms(),
+          "connect": list(opts["connect"])})
     cmds = _stdin_commands()
     acked: List[List] = []          # [seq, status]
     stop = False

@@ -360,28 +360,34 @@ class RpcIngestServer:
     def _op_resolve(self, req: TicketResolve) -> Dict[str, tuple]:
         wait_s = min(max(req.wait_s, 0.0), self._resolve_cap)
         deadline = time.perf_counter() + wait_s
+        # long-poll until every named ticket is decided (or the wait is
+        # up), THEN report — once: ``_ack_of`` drops a resolved ticket
+        # from the table as it reports it, so reporting inside the loop
+        # made the next slice read it as "unknown" and the producer
+        # resubmit an applied batch
         while True:
-            out, pending = {}, []
             with self._lock:
                 tickets = {b: self._tickets.get(b)
                            for b in req.batch_ids}
-            for bid, t in tickets.items():
-                if t is None:
-                    out[bid] = _trim(tuple(SubmitAck(
-                        bid, "unknown",
-                        reason="no ticket on this server; resubmit")))
-                elif t.done():
-                    out[bid] = _trim(tuple(self._ack_of(t)))
-                else:
-                    pending.append(t)
-                    out[bid] = _trim(tuple(SubmitAck(
-                        bid, "pending", cause=_ticket_cause(t))))
+            pending = [t for t in tickets.values()
+                       if t is not None and not t.done()]
             remaining = deadline - time.perf_counter()
             if not pending or remaining <= 0 or self._stop.is_set():
-                return out
-            # long-poll one slice on the first undecided ticket; loop
-            # re-reads them all (another may have resolved meanwhile)
+                break
+            # one slice on the first undecided ticket; the loop re-reads
+            # them all (another may have resolved meanwhile)
             pending[0]._event.wait(min(remaining, _POLL_S))
+        out = {}
+        for bid, t in tickets.items():
+            if t is None:
+                ack = SubmitAck(bid, "unknown",
+                                reason="no ticket on this server; resubmit")
+            elif t.done():
+                ack = self._ack_of(t)
+            else:
+                ack = SubmitAck(bid, "pending", cause=_ticket_cause(t))
+            out[bid] = _trim(tuple(ack))
+        return out
 
     def close(self) -> None:
         self._stop.set()

@@ -143,9 +143,6 @@ declare("REFLOW_MEGATICK_WASTE", "float", 0.5,
         "max padded-slot fraction before a fused window falls back")
 declare("REFLOW_MEGATICK_MAX_ROWS", "int", 1 << 16,
         "max rows per fused mega-tick window before fallback")
-declare("REFLOW_TOPK_PALLAS", "str", None,
-        "force the Pallas top-k kernel on (1) or off (0); unset = "
-        "auto-detect")
 declare("REFLOW_LOCKCHECK", "flag", False,
         "wrap named locks with the runtime lock-order detector; a "
         "held-before cycle raises LockOrderError (docs/guide.md "
@@ -193,8 +190,6 @@ declare("REFLOW_BENCH_IMG_PER_TICK", "int", 256,
         "image_embed bench: images folded per tick")
 declare("REFLOW_BENCH_KNN_DTYPE", "str", "int8",
         "knn bench wire dtype for document uploads")
-declare("REFLOW_BENCH_KNN_SETTLE", "int", 60,
-        "knn bench settle ticks before measuring")
 declare("REFLOW_BENCH_KNN_PRELOAD", "int", None,
         "knn bench preloaded document count cap")
 declare("REFLOW_BENCH_RECOVERY", "flag", False,

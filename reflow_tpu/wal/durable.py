@@ -29,8 +29,8 @@ recognized, so end-to-end exactly-once requires caller-supplied ids.
 
 Device-resident batches and pre-images (ROADMAP: "log device-resident
 batches without a forced sync"): durability needs the host bytes, but a
-readback of a device batch is a forced sync — on a tunnel runtime the
-degrading first-sync. The fix is **ingest-time pre-image logging**:
+readback of a device batch is a forced sync that stalls the pipelined
+device stream. The fix is **ingest-time pre-image logging**:
 whoever uploaded the batch had the host payload first; hand it to
 :meth:`DurableScheduler.push_preimage` (the serve frontend does this
 automatically from ``submit(..., preimage=...)``) and the WAL logs that
@@ -278,7 +278,7 @@ class DurableScheduler(DirtyScheduler):
         ``_log_window_feeds``."""
         ids_seq = feed_ids if feed_ids is not None else [{}] * len(feeds)
         logged, records = [], []
-        for feed, ids_map in zip(feeds, ids_seq):
+        for t, (feed, ids_map) in enumerate(zip(feeds, ids_seq)):
             entry = {}
             for src, b in feed.items():
                 ids = list(ids_map.get(src, ())) or [self._mint_auto_id(src)]
@@ -298,6 +298,13 @@ class DurableScheduler(DirtyScheduler):
                     # several micro-batches coalesced into this one feed
                     # batch: their ids commit (and replay) atomically
                     rec["batch_ids"] = ids
+                if t:
+                    # which of the window's ticks folds this batch
+                    # (``tick`` is the window's start): replay ticks up
+                    # to it first instead of merging the whole window
+                    # into one oversized tick — a device executor's
+                    # arenas and queues are sized for one tick's delta
+                    rec["feed"] = t
                 causes = self._record_causes(ids)
                 if causes:
                     rec["cause"] = causes[0]

@@ -88,8 +88,27 @@ def replay_records(sched, records) -> tuple:
     ``DurableScheduler`` caller must suspend its own re-logging around
     this (``recover`` does; replicas run a plain scheduler). Returns
     ``(replayed_pushes, deduped_pushes, replayed_ticks, skipped_ticks)``.
+
+    A fused K-tick window is logged as its K feeds' push records and
+    then K tick markers. Feed ``t > 0``'s records carry ``feed: t``, and
+    the replay ticks up to ``tick + feed`` before folding one — so the
+    window re-runs as the K ticks the leader ran, never as one tick K
+    times the size (which overflows join arenas sized for one tick's
+    delta). Those early ticks count as replayed; the markers they
+    pre-empted then count as skipped. Records without the key (plain
+    pushes, older logs, compacted folds) merge into the next marker's
+    tick as before.
     """
     replayed = deduped = ticks_done = ticks_skipped = 0
+
+    def catch_up(rec) -> int:
+        ran = 0
+        if "feed" in rec:
+            while sched._tick < rec["tick"] + rec["feed"]:
+                sched.tick()
+                ran += 1
+        return ran
+
     for _pos, rec in records:
         kind = rec.get("kind")
         if kind == "push":
@@ -98,10 +117,12 @@ def replay_records(sched, records) -> tuple:
             node = _resolve_source(sched, rec)
             ids = rec.get("batch_ids")
             if ids is None:
-                if sched.push(node, batch, batch_id=rec["batch_id"]):
-                    replayed += 1
-                else:
+                if rec["batch_id"] in sched._seen_batch_ids:
                     deduped += 1
+                else:
+                    ticks_done += catch_up(rec)
+                    sched.push(node, batch, batch_id=rec["batch_id"])
+                    replayed += 1
             elif any(b in sched._seen_batch_ids for b in ids):
                 # a coalesced frontend feed batch: its micro-batch
                 # ids committed atomically with the macro-tick, so
@@ -127,6 +148,7 @@ def replay_records(sched, records) -> tuple:
                         f"from the checkpoint anchor instead")
                 deduped += 1
             else:
+                ticks_done += catch_up(rec)
                 for b in ids:
                     sched._register_batch_id(b)
                 sched.push(node, batch)

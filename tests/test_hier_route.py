@@ -16,7 +16,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from reflow_tpu.executors.device_delta import DeviceDelta
 from reflow_tpu.parallel import make_mesh
-from reflow_tpu.parallel.shard import shard_map
 from reflow_tpu.parallel.shard_lowerings import deliver_to_owner
 
 N, N_DCN, N_ICI = 8, 2, 4
@@ -43,7 +42,7 @@ def _delta(mesh, seed=0):
 def _routed(mesh, d):
     dspec = DeviceDelta(P(("dcn", "delta")), P(("dcn", "delta")),
                         P(("dcn", "delta")))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda dd: deliver_to_owner(dd, ("dcn", "delta"), N, KL,
                                     sizes=(N_DCN, N_ICI)),
         mesh=mesh, in_specs=(dspec,),
@@ -119,7 +118,7 @@ def test_flat_mesh_unchanged_single_leg():
         jax.device_put(jnp.asarray(rng.standard_normal(C), np.float32), sh),
         jax.device_put(jnp.asarray(np.ones(C, np.int32)), sh))
     dspec = DeviceDelta(P("delta"), P("delta"), P("delta"))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda dd: deliver_to_owner(dd, "delta", N, KL),
         mesh=mesh, in_specs=(dspec,), out_specs=(dspec, P()),
         check_vma=False)

@@ -242,3 +242,26 @@ def test_int8_embeddings_high_recall():
         hits += len(set(table[q]) & set(ref_ids[q]))
         total += K
     assert hits / total >= 0.95, f"int8 recall {hits/total:.3f}"
+
+
+@pytest.mark.parametrize("q,n,k", [(256, 8208, 16), (16, 300, 4)])
+def test_pallas_topk_interpreted_equals_lax_top_k(q, n, k):
+    """The Pallas kernel's logic, interpreted (what ``use_pallas=True``
+    means off-TPU), against ``jax.lax.top_k`` on the same scores — at
+    the config-4 rescan shape ``[256, 16 + 8192]`` (lane-padded to 8320
+    inside) and at a ragged one. Values and ids exactly: ties (planted
+    here) go to the first index on both. The compiled kernel gets the
+    same comparison on the chip in ``chip_smoke.py``."""
+    import jax
+    import jax.numpy as jnp
+
+    from reflow_tpu.kernels.topk import topk
+
+    rng = np.random.default_rng(7)
+    s = rng.normal(size=(q, n)).astype(np.float32)
+    s[:, ::7] = np.round(s[:, ::7], 1)          # plant exact ties
+    s = jnp.asarray(s)
+    vals, ids = topk(s, k, use_pallas=True)
+    ref_vals, ref_ids = jax.lax.top_k(s, k)
+    assert np.array_equal(np.asarray(ids), np.asarray(ref_ids))
+    assert np.array_equal(np.asarray(vals), np.asarray(ref_vals))

@@ -283,6 +283,26 @@ def test_zero_padding_overwrites_stale_slot():
     assert q.zero_writes == 1
 
 
+def test_slot_writes_never_share_a_host_buffer():
+    """The slot writer dispatches asynchronously and the runtime may read
+    a host argument after the call returns (the CPU client aliases an
+    aligned buffer outright), so every write hands it arrays nothing
+    writes again. A scratch set reused across writes let slot t+1's fill
+    rewrite slot t's rows — wrong PageRank state in roughly one served
+    run in three on the CPU backend, whenever numpy's allocation happened
+    to be aligned."""
+    from reflow_tpu.executors.ingress_queue import DeviceIngressQueue
+
+    spec = Spec((), np.float32, key_space=8)
+    q = DeviceIngressQueue({0: spec}, {0: 64}, 2)
+    a, b = _batch([(1, 2.0, 3)]), _batch([(2, 1.0, 1)])
+    first = q._pad_host(0, len(a), 64, np.asarray(a.keys), a)
+    second = q._pad_host(0, len(b), 64, np.asarray(b.keys), b)
+    for x, y in zip(first, second):
+        assert not np.shares_memory(x, y)
+    assert first[0][0] == 1 and second[0][0] == 2
+
+
 def test_queue_rejects_over_capacity_rows():
     from reflow_tpu.executors.ingress_queue import DeviceIngressQueue
 

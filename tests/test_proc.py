@@ -150,12 +150,17 @@ def test_child_kill9_respawn_rejoins_the_barrier(tmp_path):
     child dies by SIGKILL mid-stream, comes back over the same state
     directory, and rejoins the fleet at a consistent horizon while a
     paced producer keeps writing."""
+    # the children inherit what a machine with a chip exports; every
+    # role runs the CPU oracle and must pin itself to the CPU anyway
+    # (one process per chip — the first child to checkpoint would
+    # otherwise take it)
     h = ProcHarness(str(tmp_path), fleet=False,
-                    child_env={"JAX_PLATFORMS": "cpu"})
+                    child_env={"JAX_PLATFORMS": "tpu,cpu"})
     try:
         ready = h.spawn_leader()
         assert ready["ingest"][1] > 0        # OS-assigned, reported
-        h.spawn_replica("r0")
+        assert ready["jax_platforms"] == "cpu"
+        assert h.spawn_replica("r0")["jax_platforms"] == "cpu"
         assert h.replica_address("r0")[1] > 0
         h.attach_replicas()
         h.spawn_producer("p0", index=0, pace_s=0.02)

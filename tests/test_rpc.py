@@ -77,6 +77,33 @@ def test_unknown_source_rejects_deterministically(tmp_path):
         sched.wal.close()
 
 
+def test_long_poll_keeps_a_resolved_ticket_while_waiting_on_another(
+        tmp_path):
+    """One resolve long-poll over a finished ticket AND a pending one:
+    the server reports the finished one and drops it from its table in
+    the first slice of the poll; a later slice must not look it up
+    again, read "unknown", and make the producer resubmit an applied
+    batch (which then came back DEDUPED — with its full payload resent).
+    """
+    sched, fe, lt, srv, src, sink = make_stack(tmp_path)
+    prod = RemoteProducer(lt, srv.address, name="p0")
+    try:
+        t0 = prod.submit(src, batch("aa"), batch_id="b0")
+        fe.flush()                # b0 applied server-side, not yet polled
+        fe.pause()
+        t1 = prod.submit(src, batch("bb"), batch_id="b1")   # stays pending
+        res = t0.result(10)       # polls (b0, b1) together
+        assert res.status == APPLIED
+        assert t0.submits == 1 and prod.resubmits_total == 0
+        fe.resume()
+        assert t1.result(10).status == APPLIED
+    finally:
+        prod.close()
+        srv.close()
+        fe.close()
+        sched.wal.close()
+
+
 def test_resubmit_after_producer_death_exactly_once(tmp_path):
     """The reconnect-dedup satellite: producer dies mid-submit, the
     respawned producer resubmits the same batch_id — the hello

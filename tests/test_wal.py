@@ -518,6 +518,34 @@ def test_coalesced_batch_ids_replay_all_or_nothing(tmp_path):
     assert report2.deduped_pushes >= 1
 
 
+def test_window_replays_as_its_own_ticks_not_one_merged_tick(tmp_path):
+    """A K-tick window's push records carry their feed index, and replay
+    ticks up to it before folding one: the window re-runs as the K ticks
+    the leader ran. Merged into one tick it would be K times the size —
+    past what a device executor's arenas are sized for (the recover()
+    arena overflow chip_smoke.py hit at 16 x 20 000-row churn)."""
+    g, src, sink = wordcount.build_graph()
+    sched = DurableScheduler(g, wal_dir=str(tmp_path / "wal"))
+    lines = [["a b"], ["b c d"], ["a"]]
+    sched.tick_many([{src: wordcount.ingest_lines(ln)} for ln in lines],
+                    feed_ids=[{src: [f"m{t}"]} for t in range(3)])
+    want = dict(sched.view(sink.name))
+    sched.close()
+
+    records, _ = scan_wal(str(tmp_path / "wal"))
+    pushes = [r for _p, r in records if r["kind"] == "push"]
+    assert [r.get("feed", 0) for r in pushes] == [0, 1, 2]
+    assert len({r["tick"] for r in pushes}) == 1     # the window's start
+
+    g2, src2, sink2 = wordcount.build_graph()
+    fresh = DirtyScheduler(g2)
+    report = recover(fresh, str(tmp_path / "wal"))
+    assert dict(fresh.view(sink2.name)) == want and fresh._tick == 3
+    # one feed per tick, in order — not 6 rows in tick 1 and two no-ops
+    assert [r.deltas_in for r in fresh.history] == [2, 3, 1]
+    assert report.replayed_ticks == 3 and report.skipped_ticks == 2
+
+
 # -- asynchronous committer pipeline ---------------------------------------
 
 PIPELINE_SEAMS = ["wal_enqueue", "wal_before_write", "wal_after_write",

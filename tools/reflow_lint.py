@@ -7,7 +7,6 @@ Usage::
     python tools/reflow_lint.py --json           # reflow.lint/1 report
     python tools/reflow_lint.py --passes locks,seams
     python tools/reflow_lint.py --rules bare-assert
-    python tools/reflow_lint.py --hlo            # + slow HLO audit
     python tools/reflow_lint.py --list-rules
 
 Exit codes: 0 clean, 1 findings, 2 usage/internal error. Waive a
@@ -40,17 +39,11 @@ def main() -> int:
                     help="comma-separated pass subset (default: all)")
     ap.add_argument("--rules", default=None,
                     help="comma-separated rule filter (default: all)")
-    ap.add_argument("--hlo", action="store_true",
-                    help="also run the slow HLO constant audit "
-                         "(executes workloads; tens of seconds each)")
-    ap.add_argument("--hlo-workloads", default=None,
-                    help="workload subset for --hlo")
     ap.add_argument("--list-rules", action="store_true",
                     help="print the rule catalog and exit")
     args = ap.parse_args()
 
     from reflow_tpu.analysis import core, run
-    from reflow_tpu.analysis import constants as hlo
 
     if args.list_rules:
         # import the passes so every rule is registered
@@ -69,14 +62,6 @@ def main() -> int:
     except KeyError as e:
         print(f"reflow_lint: {e}", file=sys.stderr)
         return 2
-
-    if args.hlo:
-        wl = args.hlo_workloads.split(",") if args.hlo_workloads else None
-        extra = hlo.hlo_pass(root, wl)
-        report["findings"].extend(f.to_dict() for f in extra)
-        for f in extra:
-            report["counts"][f.rule] = report["counts"].get(f.rule, 0) + 1
-        report["passes"] = list(report["passes"]) + ["hlo"]
 
     if args.as_json:
         print(json.dumps(report, indent=2))
