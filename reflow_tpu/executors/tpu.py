@@ -20,7 +20,9 @@ serving tier — trace their window program once (``megatick_cache_hits``).
 
 from __future__ import annotations
 
+import contextlib
 import os
+import queue as _queue
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -61,6 +63,125 @@ _SHARED_WINDOW_LOCK = named_lock("executors.window_cache")
 
 class _Unshareable(Exception):
     pass
+
+
+#: stands in for a profiler annotation a dispatch does not enter
+_NO_NOTE = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def _dispatch_notes(K: int, window: bool, traced: bool):
+    """The profiler annotations around one macro-tick dispatch:
+    ``reflow.window[K]`` on the mega-tick path, and under tracing the
+    clock anchor ``reflow.clock[<perf_counter_ns>]`` inside it."""
+    with (jax.profiler.TraceAnnotation(f"reflow.window[{K}]")
+          if window else _NO_NOTE), \
+         (jax.profiler.TraceAnnotation(_trace.clock_anchor_name())
+          if traced else _NO_NOTE):
+        yield
+
+
+def _completion_token(states):
+    """One scalar OUTPUT of a loop-free window program that nobody
+    donates, for the device watcher to wait on: every other output of
+    that program is state (donated to the next window) or the fresh
+    ingress stack (re-adopted by the queue and donated by a later
+    window), so none of them may be touched once the pump has moved on.
+    It reads one element of every state leaf's final value, so it
+    cannot be ready before the window's last update is. Only the
+    program a *traced* dispatch builds has it: with the token in every
+    program the untraced paced TF-IDF cell read p50 97-102 ms for the
+    parent's 93-96 on the v5e (PERF.md, PR 24), so an untraced leader
+    runs the program it always ran."""
+    import jax.numpy as jnp
+
+    tok = jnp.zeros((), jnp.float32)
+    for x in jax.tree.leaves(states):
+        if getattr(x, "size", 0):
+            tok = tok + x[(0,) * x.ndim].astype(jnp.float32)
+    return tok
+
+
+class _DeviceWatch:
+    """Traced runs only: ONE thread that waits, in dispatch order, on a
+    completion token of each dispatched window and records its
+    ``window_device`` span on the ``device/<label>`` track —
+    ``[max(launch returned, previous window done), done]`` — so the
+    program itself says when the device finished a window. The token is
+    an output of the window program, never a second dispatch, and never
+    an array a later dispatch may donate. With tracing off the executor
+    builds none of this."""
+
+    def __init__(self, executor: "TpuExecutor"):
+        self._ex = executor
+        label = executor.device_label or "default"
+        self.track = f"device/{label}"
+        #: a SimpleQueue: the hand-over costs the dispatching thread no
+        #: Python-level lock, and it runs on every traced window
+        self._q: "_queue.SimpleQueue[Optional[tuple]]" = _queue.SimpleQueue()
+        self.handed = 0          # windows handed over (dispatching thread)
+        self.seen = 0            # windows waited out (watcher thread)
+        self._thread = threading.Thread(
+            target=self._run, name=f"reflow-device-watch/{label}",
+            daemon=True)
+        self._thread.start()
+
+    def put(self, token, t_launch0: float, t_launch: float, ticks: int,
+            kind: str) -> None:
+        self.handed += 1
+        self._q.put((token, t_launch0, t_launch, _trace.current_window(),
+                     ticks, kind))
+
+    def _run(self) -> None:
+        ex = self._ex
+        prev_done = 0.0
+        while True:
+            item = self._q.get()
+            if item is None:
+                return
+            token, t_launch0, t_launch, win, ticks, kind = item
+            try:
+                jax.block_until_ready(token)
+            except Exception as e:  # noqa: BLE001 - the program's
+                # failure reaches its caller through check_errors /
+                # block(); the watcher only loses this window's span
+                ex.device_watch_error = e
+                self.seen += 1
+                continue
+            done = time.perf_counter()
+            start = max(t_launch, prev_done)
+            prev_done = done
+            ex.device_busy_s += done - start
+            ex.windows_done += 1
+            # the device may start while the launch call is still
+            # returning (on a busy host that takes milliseconds), so
+            # the span is a lower bound of the window's device time
+            # and ``dur + launch_s`` (launch entered -> done, when
+            # nothing was queued ahead) an upper one
+            args = {"ticks": ticks, "kind": kind,
+                    "queued_s": start - t_launch,
+                    "launch_s": t_launch - t_launch0}
+            if win is not None:
+                args["win"] = win
+            _trace.evt("window_device", start, done - start,
+                       track=self.track, args=args)
+            self.seen += 1
+
+    def drain(self, timeout_s: float = 60.0) -> None:
+        """Block until every window handed over so far is recorded
+        (tests and exporters; raises if the device does not get there
+        in ``timeout_s``)."""
+        deadline = time.monotonic() + timeout_s
+        while self.seen < self.handed:
+            if time.monotonic() > deadline:
+                raise TimeoutError(
+                    f"device watcher saw {self.seen} of {self.handed} "
+                    f"windows in {timeout_s}s")
+            time.sleep(0.001)
+
+    def close(self) -> None:
+        self._q.put(None)
+        self._thread.join(timeout=30.0)
 
 
 class StagedWindow:
@@ -194,6 +315,15 @@ class TpuExecutor(Executor):
         #: window programs adopted from the process-wide plan-signature
         #: cache instead of traced locally (surfaced as a scheduler gauge)
         self.megatick_cache_hits = 0
+        #: device completion, as the program's own ``window_device``
+        #: spans see it: their summed seconds and their count (scheduler
+        #: gauges; zero while tracing is off, when nothing watches), the
+        #: watcher itself (built at the first traced dispatch), and the
+        #: last error a watched token raised
+        self.device_busy_s = 0.0
+        self.windows_done = 0
+        self._watch: Optional[_DeviceWatch] = None
+        self.device_watch_error: Optional[BaseException] = None
 
     #: subclasses whose traced programs close over executor-specific
     #: context (e.g. the sharded executor's mesh/axis in ``_lower``) must
@@ -225,6 +355,20 @@ class TpuExecutor(Executor):
         if d is None:
             return None
         return f"{getattr(d, 'platform', 'dev')}:{getattr(d, 'id', '?')}"
+
+    def drain_device_watch(self) -> None:
+        """Wait until the ``window_device`` span of every window
+        dispatched so far under tracing is recorded (a no-op when
+        nothing was)."""
+        if self._watch is not None:
+            self._watch.drain()
+
+    def close(self) -> None:
+        """Stop the device watcher, if tracing ever started one. The
+        executor stays usable: a later traced dispatch starts another."""
+        watch, self._watch = self._watch, None
+        if watch is not None:
+            watch.close()
 
     def _ingress_placement(self):
         """Placement handed to ingress buffers (queue slots, stacked
@@ -430,8 +574,9 @@ class TpuExecutor(Executor):
         if _trace.ENABLED:
             _trace.evt("device_dispatch", t_d0,
                        time.perf_counter() - t_d0,
-                       args={"kind": "fixpoint",
-                             "device": self.device_label})
+                       args=_trace.with_win(
+                           {"kind": "fixpoint",
+                            "device": self.device_label}))
         self.states = new_states
         exit_passes = 1 if st.exit_plan else 0
         leftover = {}
@@ -509,7 +654,7 @@ class TpuExecutor(Executor):
         stack, caps = self._stack_feeds(feeds)
         if _trace.ENABLED:
             _trace.evt("stack_feeds", t_h0, time.perf_counter() - t_h0,
-                       args={"ticks": K})
+                       args=_trace.with_win({"ticks": K}))
         return self._dispatch_many(plan, stack, caps, K, max_iters)
 
     def supports_window(self) -> bool:
@@ -629,14 +774,21 @@ class TpuExecutor(Executor):
                 caps, K, placement=self._ingress_placement())
             self._cache[qsig] = queue
 
-        t_h0 = time.perf_counter() if _trace.ENABLED else 0.0
+        tr = _trace.ENABLED
+        t_h0 = time.perf_counter() if tr else 0.0
+        c_h0 = time.thread_time() if tr else 0.0
         for t, f in enumerate(feeds):
             for nid in node_ids:
                 queue.write(t, nid, f[nid])
-        if _trace.ENABLED:
-            _trace.evt("queue_write", t_h0, time.perf_counter() - t_h0,
-                       args={"ticks": K, "slots": K * len(node_ids),
-                             "inflight": queue.in_flight})
+        if tr:
+            # dur - cpu_s: the slot writes waiting for the device (they
+            # queue behind the running window's program)
+            dur = time.perf_counter() - t_h0
+            _trace.evt("queue_write", t_h0, dur,
+                       args=_trace.with_win(
+                           {"ticks": K, "slots": K * len(node_ids),
+                            "inflight": queue.in_flight,
+                            "cpu_s": _trace.cpu_s(c_h0, dur)}))
         stack = queue.stacked()
         gen = queue.seal()
         return StagedWindow(plan, caps, K, max_iters, queue, gen, stack,
@@ -708,20 +860,28 @@ class TpuExecutor(Executor):
         being re-adopted inline — the queue and the window never hold
         two live copies either way. ``window=True`` tags the dispatch
         span as the mega-tick path and wraps it in a ``jax.profiler``
-        annotation so Perfetto lines host stages up against device
-        occupancy."""
-        from reflow_tpu.utils.metrics import profile_annotation
-
+        annotation (``reflow.window[K]``) so Perfetto lines host stages
+        up against device occupancy. Under tracing the dispatch also
+        enters a ``reflow.clock[<perf_counter_ns>]`` annotation — one
+        reading of the offset between the span clock and the profiler's
+        — and hands a completion token (an output of the program) to
+        the device watcher. Both annotations are entered directly: a
+        ``TraceMe`` is a no-op while no profiler runs, and a profiler
+        that cannot annotate must fail loudly, not thin out a trace."""
         if not self.graph.loops:
             # loop-free sink-free graph (e.g. streaming TF-IDF): scan the
             # PLAIN pass program over the K stacked feeds — one device
             # execution for K ticks, zero per-tick egress by construction
+            tr = _trace.ENABLED
+            # a traced dispatch runs a twin of the window program that
+            # also outputs the watcher's completion token
             sig = ("pass_many", tuple(n.id for n in plan),
-                   tuple(sorted(caps.items())))
+                   tuple(sorted(caps.items()))) + (("token",) if tr else ())
             prog = self._cache.get(sig)
             if prog is None:
                 shared_sig = self._window_signature(plan, caps)
                 if shared_sig is not None:
+                    shared_sig += sig[3:]
                     with _SHARED_WINDOW_LOCK:
                         prog = _SHARED_WINDOW_PROGRAMS.get(shared_sig)
                 if prog is not None:
@@ -731,6 +891,7 @@ class TpuExecutor(Executor):
                     self.megatick_cache_hits += 1
                 else:
                     pass_fn = self.build_pass_fn(list(plan))
+                    with_token = tr
 
                     def scan_fn(op_states, ing_stack):
                         def body(states, ing):
@@ -746,8 +907,11 @@ class TpuExecutor(Executor):
                         # input) lets XLA alias the donated memory while
                         # giving the ingress queue valid buffers to adopt
                         import jax.numpy as jnp
-                        return states, jax.tree.map(jnp.zeros_like,
-                                                    ing_stack)
+                        out = (states,
+                               jax.tree.map(jnp.zeros_like, ing_stack))
+                        if with_token:
+                            out += (_completion_token(states),)
+                        return out
 
                     prog = jax.jit(scan_fn, donate_argnums=(0, 1))
                     if shared_sig is not None:
@@ -757,16 +921,15 @@ class TpuExecutor(Executor):
                 self._cache[sig] = prog
             self._track_arena(plan, caps)
             kind = "window" if window else "pass_many"
-            t_d0 = time.perf_counter() if _trace.ENABLED else 0.0
-            with profile_annotation(f"reflow.window[{K}]", enabled=window):
-                self.states, fresh = prog(dict(self.states), stack)
+            t_d0 = time.perf_counter() if tr else 0.0
+            c_d0 = time.thread_time() if tr else 0.0
+            with _dispatch_notes(K, window, tr):
+                out = prog(dict(self.states), stack)
+            self.states, fresh = out[0], out[1]
             if staged is not None:
                 staged.fresh = fresh
-            if _trace.ENABLED:
-                _trace.evt("device_dispatch", t_d0,
-                           time.perf_counter() - t_d0,
-                           args={"kind": kind, "ticks": K,
-                                 "device": self.device_label})
+            if tr:
+                self._dispatched(out[2], t_d0, c_d0, K, kind)
             return K, 0, 0, True, set()
 
         sig = ("fx", tuple(n.id for n in plan),
@@ -788,21 +951,37 @@ class TpuExecutor(Executor):
                 {n.id: 2 * n.inputs[0].spec.key_space for n in st.boundary})
 
         kind = "window" if window else "fixpoint_many"
-        t_d0 = time.perf_counter() if _trace.ENABLED else 0.0
-        with profile_annotation(f"reflow.window[{K}]", enabled=window):
+        tr = _trace.ENABLED
+        t_d0 = time.perf_counter() if tr else 0.0
+        c_d0 = time.thread_time() if tr else 0.0
+        with _dispatch_notes(K, window, tr):
             new_states, (iters, rows, conv), fresh = prog.call_many(
                 dict(self.states), stack, K)
         if staged is not None:
             staged.fresh = fresh
-        if _trace.ENABLED:
-            _trace.evt("device_dispatch", t_d0,
-                       time.perf_counter() - t_d0,
-                       args={"kind": kind, "ticks": K,
-                             "device": self.device_label})
+        if tr:
+            # ``conv`` is the token: a program output the scheduler only
+            # ever reads (TickResult.quiesced), never donates
+            self._dispatched(conv, t_d0, c_d0, K, kind)
         self.states = new_states
         extra_dirty = set(st.region_ids) | {n.id for n in st.exit_plan}
         passes_base = K * (1 + (1 if st.exit_plan else 0))
         return passes_base, iters, rows, conv, extra_dirty
+
+    def _dispatched(self, token, t_d0: float, c_d0: float, K: int,
+                    kind: str) -> None:
+        """Traced tail of a macro-tick dispatch: the ``device_dispatch``
+        span (launch wall and CPU on the dispatching thread) and the
+        completion token's hand-over to the device watcher."""
+        t_d1 = time.perf_counter()
+        _trace.evt("device_dispatch", t_d0, t_d1 - t_d0,
+                   args=_trace.with_win(
+                       {"kind": kind, "ticks": K,
+                        "device": self.device_label,
+                        "cpu_s": _trace.cpu_s(c_d0, t_d1 - t_d0)}))
+        if self._watch is None:
+            self._watch = _DeviceWatch(self)
+        self._watch.put(token, t_d0, t_d1, K, kind)
 
     def _stack_feeds(self, feeds):
         """Host-side [K, C] stacking of K per-tick ingress dicts: ONE

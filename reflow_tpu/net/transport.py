@@ -35,6 +35,7 @@ from reflow_tpu.net.framing import (HEADER, MAGIC, FrameError,
                                     TransportError, WireTimeout,
                                     decode_frame, encode_frame,
                                     frame_size)
+from reflow_tpu.obs import trace as _trace
 from reflow_tpu.utils.config import env_float
 from reflow_tpu.utils.runtime import named_lock
 
@@ -54,6 +55,23 @@ class Conn:
     ``recv_msg`` blocks up to ``timeout_s`` for one whole frame. Both
     raise :class:`TransportError` on link death and ``recv_msg`` raises
     :class:`FrameError` (a subclass) on an unsyncable stream."""
+
+    #: traced runs: ``(t_received, payload_bytes, decode_s)`` of the
+    #: frame the last ``recv_msg`` returned — ``t_received`` is the
+    #: ``perf_counter()`` at which its last byte was in hand, before the
+    #: payload was checked and unpickled. The ingest server's
+    #: ``rpc_serve`` span starts there. None while tracing is off.
+    last_rx: Optional[Tuple[float, int, float]] = None
+
+    def _decode(self, hdr: bytes, payload: bytes) -> Any:
+        """``decode_frame`` that, under tracing, stamps ``last_rx``."""
+        if not _trace.ENABLED:
+            self.last_rx = None
+            return decode_frame(hdr, payload)
+        t_rx = time.perf_counter()
+        msg = decode_frame(hdr, payload)
+        self.last_rx = (t_rx, len(payload), time.perf_counter() - t_rx)
+        return msg
 
     def send_msg(self, obj: Any, timeout_s: Optional[float] = None) -> int:
         raise NotImplementedError
@@ -155,7 +173,7 @@ class _LoopbackEnd(Conn):
         hdr = bytes(self._rx[:_HDR])
         payload = bytes(self._rx[_HDR:_HDR + length])
         del self._rx[:_HDR + length]
-        return (decode_frame(hdr, payload),)
+        return (self._decode(hdr, payload),)
 
     def close(self) -> None:
         for end in (self, self.peer):
@@ -310,7 +328,7 @@ class _TcpConn(Conn):
             hdr = self._read_exact(_HDR, deadline, idle_ok=True)
             length = frame_size(hdr)  # FrameError propagates: reset
             payload = self._read_exact(length, deadline)
-        return decode_frame(hdr, payload)
+        return self._decode(hdr, payload)
 
     def close(self) -> None:
         self._closed = True

@@ -112,7 +112,10 @@ def ticket_timelines(events: List[Dict[str, Any]]
     """Reconstruct per-ticket stage timelines from a chrome event list:
     ``{batch_id: {"stages": {name: dur_us}, "e2e_us": .., "sum_us": ..}}``
     where ``e2e_us`` spans the earliest start to the latest end of the
-    ticket's events and ``sum_us`` totals its stage durations."""
+    ticket's events and ``sum_us`` totals its stage durations. Only the
+    six ``trace.STAGES`` tile; a ticket track's other spans nest inside
+    one of them (``wire_wait`` in ``fsync``, ``admit_lock_wait`` in
+    ``admission``) and are kept apart under ``"sub"``."""
     names: Dict[int, str] = {}
     for ev in events:
         if ev.get("ph") == "M" and ev.get("name") == "thread_name":
@@ -125,10 +128,12 @@ def ticket_timelines(events: List[Dict[str, Any]]
         if not track.startswith("ticket/"):
             continue
         bid = track[len("ticket/"):]
-        t = out.setdefault(bid, {"stages": {}, "_t0": None, "_t1": None})
+        t = out.setdefault(bid, {"stages": {}, "sub": {},
+                                 "_t0": None, "_t1": None})
         dur = float(ev.get("dur", 0.0))
         name = ev.get("name", "?")
-        t["stages"][name] = t["stages"].get(name, 0.0) + dur
+        into = t["stages"] if name in trace.STAGES else t["sub"]
+        into[name] = into.get(name, 0.0) + dur
         s = float(ev.get("ts", 0.0))
         t["_t0"] = s if t["_t0"] is None else min(t["_t0"], s)
         t["_t1"] = (s + dur if t["_t1"] is None

@@ -32,6 +32,20 @@ stage is the *durability wait*: the gap between the execute finishing
 (``t_dur``) — near-zero when the committer's fsync fully overlapped the
 execute, the exposed disk latency when it didn't. The committer's own
 ``wal_fsync`` spans land on the ``wal-committer`` track.
+
+Joining and splitting what is recorded (docs/guide.md "Span catalog"):
+spans of one serving window share ``args["win"]`` — the pump sets a
+thread-local current window (:func:`set_window`) that the emit sites
+below it read (:func:`with_win`), so no signature carries it down;
+``wal_fsync`` carries the ``lsn`` it covered and ``pump_execute`` its
+window's, so window → fsync joins by LSN. Pump-thread and committer
+spans carry ``cpu_s`` (``time.thread_time()`` over the span): ``dur -
+cpu_s`` is time the thread was not running. And every traced window
+dispatch enters a ``jax.profiler.TraceAnnotation`` named
+``reflow.clock[<perf_counter_ns>]`` (:func:`clock_anchor_name`): each
+one found in a profiler trace is one reading of the offset between
+this module's clock and the trace's, which puts these spans on the
+device trace's time axis.
 """
 
 from __future__ import annotations
@@ -44,8 +58,8 @@ from typing import Any, Dict, List, Optional, Tuple
 __all__ = ["ENABLED", "RING_CAPACITY", "SAMPLE_EVERY", "STAGES",
            "TraceCtx", "enable", "disable", "enabled", "reset", "evt",
            "mint", "mint_cause", "sample", "set_flight_hook",
-           "ticket_stages", "wal_accum_reset", "wal_accum_add",
-           "wal_accum_take"]
+           "ticket_stages", "set_window", "current_window", "with_win",
+           "cpu_s", "clock_anchor_name"]
 
 #: hot-path gate — read directly (``if trace.ENABLED:``) at every
 #: instrumentation site; never wrapped in a function call
@@ -178,6 +192,52 @@ def evt(name: str, ts: float, dur: float, track: Optional[str] = None,
         _flight_hook(name, ts, dur, track, args)
 
 
+def set_window(win: Optional[int]) -> None:
+    """Set (or clear, with None) the calling thread's *current window*:
+    the serving pump numbers its windows and sets this before it stages
+    or ticks one, so the emit sites below it (scheduler, executor) can
+    put the same ``win`` in their span ``args`` without a signature
+    carrying it down. Call under ENABLED."""
+    _tls.win = win
+
+
+def current_window() -> Optional[int]:
+    """The calling thread's current window id (None outside a pump's
+    window, or when tracing was off as it began)."""
+    return getattr(_tls, "win", None)
+
+
+def with_win(args: Dict[str, Any]) -> Dict[str, Any]:
+    """``args`` with the calling thread's current window id under
+    ``win``, when there is one (emit sites below the pump)."""
+    win = getattr(_tls, "win", None)
+    if win is not None:
+        args["win"] = win
+    return args
+
+
+def cpu_s(c0: float, dur: float, c1: Optional[float] = None) -> float:
+    """The ``cpu_s`` arg of a span that took ``dur`` seconds of wall:
+    the recording thread's CPU seconds since ``c0`` (a
+    ``time.thread_time()`` taken at the span's start; ``c1`` if its end
+    was read earlier), held inside ``[0, dur]`` — the two clocks tick
+    apart by nanoseconds. ``dur - cpu_s`` is then time the thread was
+    not running: waiting for the interpreter lock, a lock, or the
+    device inside a slot write."""
+    if c1 is None:
+        c1 = time.thread_time()
+    return max(0.0, min(c1 - c0, dur))
+
+
+def clock_anchor_name() -> str:
+    """``reflow.clock[<perf_counter_ns now>]``: the name of the
+    ``jax.profiler.TraceAnnotation`` a traced window dispatch enters,
+    made at the instant it is entered — so each one found in a profiler
+    trace is one reading of the offset between the program's span clock
+    and the trace's clock."""
+    return f"reflow.clock[{time.perf_counter_ns()}]"
+
+
 def mint(batch_id: str, t0: float) -> TraceCtx:
     """Mint the trace context for one submission (call under ENABLED)."""
     return TraceCtx(batch_id, t0,
@@ -207,13 +267,21 @@ def mint_cause(origin: str, epoch: int) -> str:
 
 def ticket_stages(ctx: TraceCtx, *, t_adm: float, t_ready: float,
                   t_exec0: float, t_exec1: float, t_dur: float,
-                  t_res: float) -> None:
+                  t_res: float, win: Optional[int] = None,
+                  t_wired: Optional[float] = None) -> None:
     """Emit the six-stage end-to-end timeline of one sampled ticket onto
     its own ``ticket/<batch_id>`` track. ``t_dur`` is the durability
     point — when the ticket's LSN passed ``wal.wait_durable`` (equal to
     ``t_exec1`` on a non-durable scheduler, so the fsync stage collapses
     to zero). Boundaries are clamped into pipeline order so the stages
-    tile ``[ctx.t0, t_res]`` exactly."""
+    tile ``[ctx.t0, t_res]`` exactly.
+
+    ``win`` (the window that executed the ticket) rides every stage's
+    args. ``t_wired`` — when the window's tickets were handed to the
+    durable watermark — adds the sub-span ``wire_wait``
+    ``[t_exec1, t_wired]`` *inside* ``fsync`` (not a stage: the six
+    still tile alone): the part of the durability wait spent before
+    anyone was waiting for the disk."""
     if not ENABLED:
         return
     track = f"ticket/{ctx.batch_id}"
@@ -230,29 +298,13 @@ def ticket_stages(ctx: TraceCtx, *, t_adm: float, t_ready: float,
     args: Dict[str, Any] = {"batch_id": ctx.batch_id}
     if ctx.cause:
         args["cause"] = ctx.cause
+    if win is not None:
+        args["win"] = win
     for name, s, e in spans:
         evt(name, s, e - s, track=track, args=args)
-
-
-# -- WAL time accumulator (legacy) -------------------------------------------
-# Pre-pipeline tiling carved WAL append+fsync wall time out of the
-# execute span via this thread-local; with the asynchronous committer
-# the fsync stage is measured directly as the durability wait
-# ([t_exec1, t_dur]), so the frontend no longer feeds it. Kept for
-# external instrumentation that still accumulates per-thread WAL time.
-
-def wal_accum_reset() -> None:
-    _tls.wal_s = 0.0
-
-
-def wal_accum_add(s: float) -> None:
-    _tls.wal_s = getattr(_tls, "wal_s", 0.0) + s
-
-
-def wal_accum_take() -> float:
-    s = getattr(_tls, "wal_s", 0.0)
-    _tls.wal_s = 0.0
-    return s
+    if t_wired is not None:
+        evt("wire_wait", t_exec1, max(t_exec1, min(t_wired, d)) - t_exec1,
+            track=track, args=args)
 
 
 if env_flag("REFLOW_TRACE"):

@@ -500,7 +500,8 @@ class DirtyScheduler:
             )
             if _trace.ENABLED:
                 _trace.evt("tick_many", t0, agg.wall_s,
-                           args={"ticks": len(feeds), "fused": False})
+                           args=_trace.with_win(
+                               {"ticks": len(feeds), "fused": False}))
             self.history.append(agg)
             return agg
 
@@ -523,7 +524,7 @@ class DirtyScheduler:
         )
         if _trace.ENABLED:
             _trace.evt("tick_many", t0, result.wall_s,
-                       args={"ticks": K, "fused": True})
+                       args=_trace.with_win({"ticks": K, "fused": True}))
         self.history.append(result)
         return result
 
@@ -700,7 +701,8 @@ class DirtyScheduler:
         )
         if _trace.ENABLED:
             _trace.evt("tick_many", t0, result.wall_s,
-                       args={"ticks": K, "fused": True, "staged": True})
+                       args=_trace.with_win(
+                           {"ticks": K, "fused": True, "staged": True}))
         self.history.append(result)
         return result
 
@@ -730,6 +732,13 @@ class DirtyScheduler:
                   lambda: self.megatick_fallbacks)
         reg.gauge(f"{key}.megatick_cache_hits",
                   lambda: getattr(self.executor, "megatick_cache_hits", 0))
+        # "is the chip busy": sums of the executor's ``window_device``
+        # spans (device completion of each window); zero while tracing
+        # is off — nothing watches the device then
+        reg.gauge(f"{key}.device_busy_s",
+                  lambda: getattr(self.executor, "device_busy_s", 0.0))
+        reg.gauge(f"{key}.windows_done",
+                  lambda: getattr(self.executor, "windows_done", 0))
         self._metric_keys.append((reg, key))
         return key
 
@@ -815,6 +824,14 @@ class DirtyScheduler:
         for reg, key in self._metric_keys:
             reg.unregister_prefix(f"{key}.")
         self._metric_keys = []
+        self._close_executor()
+
+    def _close_executor(self) -> None:
+        """Stop what the executor runs beside the ticks (the traced
+        device watcher); executors without a ``close`` have nothing."""
+        closefn = getattr(self.executor, "close", None)
+        if closefn is not None:
+            closefn()
 
     # -- host boundary out -------------------------------------------------
 

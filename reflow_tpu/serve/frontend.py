@@ -101,6 +101,33 @@ class _ResBlock:
     t_ready: float
     t_exec0: float
     t_exec1: float
+    #: the pump's window id (joins this block's spans to the window's)
+    win: int = 0
+    #: traced runs: when ``_wire_block`` handed the block to the durable
+    #: watermark — ``[t_exec1, t_wired]`` is the ticket span
+    #: ``wire_wait``, the part of ``fsync`` no disk was waited for
+    t_wired: Optional[float] = None
+
+
+class _PumpClock:
+    """Traced private pumps only: the instant up to which the pump
+    thread's wall is under a span. Every top-level span the pump
+    records goes through ``IngestFrontend._pump_span``, which first
+    books the stretch since the previous one as ``pump_turn`` (loop
+    bookkeeping: lock waits, the fire check, the window take, budget
+    release, block wiring) — so the pump's spans tile its wall by
+    construction and nothing it does is unlabelled. The ``w_*`` fields
+    hold an idle episode still open (``wakes`` > 0): every admission
+    wakes the pump, and the wake-ups that only re-check the triggers and
+    wait again are one ``pump_wait`` span, not one each."""
+
+    __slots__ = ("t", "c", "w_t0", "w_c0", "w_t1", "w_c1", "wakes",
+                 "notified")
+
+    def __init__(self) -> None:
+        self.t = time.perf_counter()
+        self.c = time.thread_time()
+        self.wakes = 0
 
 
 @dataclasses.dataclass
@@ -236,6 +263,11 @@ class IngestFrontend:
         # the current window's ready/take stamps (trace spans)
         self._metric_keys: List = []
         self._win_t_ready: Optional[float] = None
+        #: windows numbered as they are staged (or, unfused, ticked):
+        #: the ``win`` every span of one window carries
+        self._win_seq = 0
+        #: the private pump's tiling clock while tracing is on
+        self._clk: Optional[_PumpClock] = None
         self._thread: Optional[threading.Thread] = None
         if start:
             self._thread = threading.Thread(
@@ -282,6 +314,7 @@ class IngestFrontend:
         t0 = time.perf_counter()
         deadline = None if timeout is None else t0 + timeout
         with self._lock:
+            t_lock = time.perf_counter() if _trace.ENABLED else t0
             self._crash_point("producer_submit")
             self.submitted += 1
             if self._state != "running":
@@ -302,6 +335,12 @@ class IngestFrontend:
                     from reflow_tpu.obs.wire import node_id
                     ticket.trace.cause = _trace.mint_cause(
                         node_id(), getattr(self.sched, "epoch", 0))
+                if ticket.trace.sampled:
+                    # inside ``admission``: how long this producer stood
+                    # at the frontend lock before admission could begin
+                    _trace.evt("admit_lock_wait", t0, t_lock - t0,
+                               track=f"ticket/{batch_id}",
+                               args={"batch_id": batch_id})
             if batch_id in self._admitted:
                 self.deduped += 1
                 ticket._resolve(TicketResult(
@@ -715,6 +754,11 @@ class IngestFrontend:
         try:
             while True:
                 drained = None
+                # the tiling clock lives exactly while tracing is on
+                if not _trace.ENABLED:
+                    self._clk = None
+                elif self._clk is None:
+                    self._clk = _PumpClock()
                 with self._lock:
                     while True:
                         if self._state == "closing" and (
@@ -736,17 +780,65 @@ class IngestFrontend:
                             # (latched, so pause/close wait it out)
                             self._begin_settle()
                             break
-                        self._work.wait(timeout=wait_t)
+                        if self._clk is not None:
+                            self._traced_wait(wait_t)
+                        else:
+                            self._work.wait(timeout=wait_t)
+                if self._clk is not None and self._clk.wakes:
+                    self._end_wait()
                 if drained is None:
                     self._settle_all()
-                    with self._lock:
-                        self._finish_window()
-                    continue
-                self._run_window(drained)
+                else:
+                    self._run_window(drained)
                 with self._lock:
                     self._finish_window()
         except BaseException as e:  # noqa: BLE001 - incl. CrashPoint kills
             self._on_pump_crash(e)
+        finally:
+            self._clk = None
+
+    def _traced_wait(self, wait_t: Optional[float]) -> None:
+        """The pump's idle wait under tracing (caller holds the lock):
+        extends the open idle episode, which ``_end_wait`` records once
+        the loop has something to do."""
+        clk = self._clk
+        if not clk.wakes:
+            clk.w_t0, clk.w_c0 = time.perf_counter(), time.thread_time()
+        clk.notified = self._work.wait(timeout=wait_t)
+        clk.w_t1, clk.w_c1 = time.perf_counter(), time.thread_time()
+        clk.wakes += 1
+
+    def _end_wait(self) -> None:
+        """Record the idle episode that just ended as one ``pump_wait``
+        span. ``woke`` says what ended it: ``timeout`` (the latency
+        trigger came due) or ``notify`` (an admission, flush, resume or
+        close); ``wakes`` counts the wake-ups inside it, all but the
+        last of which found nothing to fire."""
+        clk = self._clk
+        wakes, clk.wakes = clk.wakes, 0
+        self._pump_span("pump_wait", clk.w_t0, clk.w_c0, clk.w_t1, {
+            "woke": "notify" if clk.notified else "timeout",
+            "wakes": wakes}, c1=clk.w_c1)
+
+    def _pump_span(self, name: str, t0: float, c0: float, t1: float,
+                   args: dict, c1: Optional[float] = None) -> None:
+        """Record one top-level span of the pumping thread, ``[t0, t1]``
+        with ``cpu_s`` (``c0`` / ``c1``: ``time.thread_time()`` at its
+        ends; ``c1`` defaults to now). On the private pump the stretch
+        since the previous span is booked first, as ``pump_turn``
+        (:class:`_PumpClock`). Call under ``_trace.ENABLED``."""
+        if c1 is None:
+            c1 = time.thread_time()
+        clk = self._clk
+        if clk is not None:
+            if t0 > clk.t:
+                _trace.evt("pump_turn", clk.t, t0 - clk.t, args={
+                    "graph": self.name or "frontend",
+                    "cpu_s": _trace.cpu_s(clk.c, t0 - clk.t, c0)})
+            clk.t, clk.c = t1, c1
+        args["graph"] = self.name or "frontend"
+        args["cpu_s"] = _trace.cpu_s(c0, t1 - t0, c1)
+        _trace.evt(name, t0, t1 - t0, args=args)
 
     def _exit_pump_locked(self) -> None:
         # caller holds the lock; fail whatever close(flush=False) strands
@@ -771,12 +863,18 @@ class IngestFrontend:
         self._window_entries = drained  # crash path fails their tickets
         tr = _trace.ENABLED
         t_w0 = time.perf_counter()
+        c_w0 = time.thread_time() if tr else 0.0
         t_ready = self._win_t_ready or t_w0
         feeds = build_feeds(drained, self.window.max_rows)
+        k = self.window.max_ticks
+        # one drained set becomes ceil(feeds / k) windows: the spans
+        # that cover all of them carry the first and the last id
+        win_first = self._win_seq + 1
+        win_last = self._win_seq + max(1, -(-len(feeds) // k))
         if tr:
-            _trace.evt("host_merge", t_w0, time.perf_counter() - t_w0,
-                       args={"graph": self.name or "frontend",
-                             "feeds": len(feeds)})
+            self._pump_span("host_merge", t_w0, c_w0, time.perf_counter(),
+                            {"feeds": len(feeds), "win": win_first,
+                             "win_last": win_last})
         self._crash_point("pump_coalesce")
         wal = getattr(self.sched, "wal", None)
         push_pre = getattr(self.sched, "push_preimage", None)
@@ -801,7 +899,6 @@ class IngestFrontend:
                         ctx = e.ticket.trace
                         if ctx is not None and ctx.cause:
                             push_cause(e.batch_id, ctx.cause)
-        k = self.window.max_ticks
         for i in range(0, len(feeds), k):
             chunk = feeds[i:i + k]
             # bound the pipeline: at most depth dispatched windows may
@@ -811,9 +908,16 @@ class IngestFrontend:
             while len(self._inflight) > self.depth - 1:
                 self._settle_one()
             self._crash_point("pump_before_tick")
+            self._win_seq += 1
+            win = self._win_seq
+            if tr:
+                # the emit sites below the pump (scheduler, executor)
+                # read the window id from here
+                _trace.set_window(win)
             handle = None
             if self.depth > 1:
                 t_s0 = time.perf_counter()
+                c_s0 = time.thread_time() if tr else 0.0
                 inflight0 = len(self._inflight)
                 handle = self.sched.stage_window(
                     [f.batches for f in chunk],
@@ -826,9 +930,8 @@ class IngestFrontend:
                         self.windows_pipelined += 1
                         self.stage_overlap_s += t_s1 - t_s0
                     if tr:
-                        _trace.evt("window_stage", t_s0, t_s1 - t_s0,
-                                   args={"graph": self.name or "frontend",
-                                         "ticks": len(chunk),
+                        self._pump_span("window_stage", t_s0, c_s0, t_s1,
+                                        {"win": win, "ticks": len(chunk),
                                          "inflight": inflight0,
                                          "device": self._device_label()})
                     # stage-complete budget release: the chunk's rows now
@@ -844,19 +947,20 @@ class IngestFrontend:
             if handle is not None:
                 tick0 = self.sched._tick
                 t_exec0 = time.perf_counter()
+                c_e0 = time.thread_time() if tr else 0.0
                 self.sched.dispatch_staged(handle)
                 lsn = wal.last_lsn() if wal is not None else 0
                 t_exec1 = time.perf_counter()
                 if tr:
-                    _trace.evt("pump_execute", t_exec0, t_exec1 - t_exec0,
-                               args={"graph": self.name or "frontend",
-                                     "ticks": len(chunk), "lsn": lsn,
-                                     "megatick": True,
+                    self._pump_span("pump_execute", t_exec0, c_e0, t_exec1,
+                                    {"win": win, "ticks": len(chunk),
+                                     "lsn": lsn, "megatick": True,
                                      "depth": len(self._inflight) + 1,
                                      "device": self._device_label()})
                 self._crash_point("pump_after_tick")
                 block = _ResBlock(self._chunk_items(chunk, tick0), lsn,
-                                  len(chunk), t_ready, t_exec0, t_exec1)
+                                  len(chunk), t_ready, t_exec0, t_exec1,
+                                  win)
                 with self._lock:
                     self._pending_res += 1
                 self._inflight.append(_InflightWindow(handle, block))
@@ -868,6 +972,7 @@ class IngestFrontend:
             self._settle_all()
             tick0 = self.sched._tick
             t_exec0 = time.perf_counter()
+            c_e0 = time.thread_time() if tr else 0.0
             if wal is not None:
                 self.sched.tick_many([f.batches for f in chunk],
                                      feed_ids=[f.ids for f in chunk],
@@ -879,22 +984,25 @@ class IngestFrontend:
                 lsn = 0
             t_exec1 = time.perf_counter()
             if tr:
-                _trace.evt("pump_execute", t_exec0, t_exec1 - t_exec0,
-                           args={"graph": self.name or "frontend",
-                                 "ticks": len(chunk), "lsn": lsn,
-                                 "megatick": self.megatick,
+                self._pump_span("pump_execute", t_exec0, c_e0, t_exec1,
+                                {"win": win, "ticks": len(chunk),
+                                 "lsn": lsn, "megatick": self.megatick,
                                  "depth": 1,
                                  "device": self._device_label()})
             self._crash_point("pump_after_tick")
             block = _ResBlock(self._chunk_items(chunk, tick0), lsn,
-                              len(chunk), t_ready, t_exec0, t_exec1)
+                              len(chunk), t_ready, t_exec0, t_exec1, win)
             with self._lock:
                 self._pending_res += 1
             self._wire_block(block)
         if tr:
+            _trace.set_window(None)
+            # the umbrella over everything above: it overlaps the tiling
+            # spans, so it goes past the pump clock
             _trace.evt("window", t_w0, time.perf_counter() - t_w0,
                        args={"graph": self.name or "frontend",
-                             "feeds": len(feeds),
+                             "feeds": len(feeds), "win": win_first,
+                             "win_last": win_last,
                              "device": self._device_label()})
         self._win_t_ready = None
         with self._lock:
@@ -928,10 +1036,12 @@ class IngestFrontend:
         iw = self._inflight.popleft()
         tr = _trace.ENABLED
         t_r0 = time.perf_counter() if tr else 0.0
+        c_r0 = time.thread_time() if tr else 0.0
         self.sched.retire_staged(iw.handle)
         if tr:
-            _trace.evt("window_retire", t_r0, time.perf_counter() - t_r0,
-                       args={"graph": self.name or "frontend",
+            self._pump_span("window_retire", t_r0, c_r0,
+                            time.perf_counter(),
+                            {"win": iw.block.win,
                              "ticks": iw.block.nticks})
         self._wire_block(iw.block)
 
@@ -946,6 +1056,8 @@ class IngestFrontend:
         fsync) may still be in flight — ``when_durable`` fires on the
         committer once the window's LSN is covered, so the pump overlaps
         the disk latency instead of serializing behind it."""
+        if _trace.ENABLED:
+            block.t_wired = time.perf_counter()
         wal = getattr(self.sched, "wal", None)
         if wal is None:
             self._complete_block(block, None)
@@ -988,6 +1100,7 @@ class IngestFrontend:
             return
         tr = _trace.ENABLED
         t_dur = time.perf_counter()
+        c_dur = time.thread_time() if tr else 0.0
         applied = 0
         for e, tick, co in block.items:
             if e.ticket.done():
@@ -1001,7 +1114,8 @@ class IngestFrontend:
                 _trace.ticket_stages(
                     ctx, t_adm=e.t_admitted, t_ready=block.t_ready,
                     t_exec0=block.t_exec0, t_exec1=block.t_exec1,
-                    t_dur=t_dur, t_res=time.perf_counter())
+                    t_dur=t_dur, t_res=time.perf_counter(),
+                    win=block.win, t_wired=block.t_wired)
                 if ctx.cause:
                     # the write's durability boundary on the shared
                     # chain: execute end -> durable watermark passed
@@ -1015,6 +1129,15 @@ class IngestFrontend:
             self.ticks += block.nticks
             self.applied += applied
             self._idle.notify_all()
+        if tr:
+            # on the WAL committer's thread (under the WAL's lock) when
+            # the fsync overlapped later work, inline on the pump when
+            # the LSN was already durable
+            dur = time.perf_counter() - t_dur
+            _trace.evt("resolve_block", t_dur, dur,
+                       args={"graph": self.name or "frontend",
+                             "win": block.win, "tickets": applied,
+                             "cpu_s": _trace.cpu_s(c_dur, dur)})
 
     def _on_pump_crash(self, error: BaseException,
                        window: Optional[Dict[int, List[Entry]]] = None,

@@ -69,7 +69,9 @@ class SubmitReq(NamedTuple):
     ``cause`` is the optional causality token minted at the producer
     (``obs.trace.mint_cause``); its presence IS the sampling decision —
     the server adopts it instead of re-rolling, so every process
-    records the same 1-in-N writes. Trailing + defaulted and trimmed
+    records the same 1-in-N writes (only a connection that has never
+    carried one, an untraced producer's, gets the server's own
+    1-in-N). Trailing + defaulted and trimmed
     when None (:func:`_trim`) so untraced requests stay byte-identical
     to the pre-trace wire protocol."""
 
@@ -161,6 +163,11 @@ class RpcIngestServer:
         self._conns: list = []
         self._handlers: list = []
         self._tickets: "OrderedDict[str, Any]" = OrderedDict()
+        #: per handler thread (one a connection): the sampled ticket,
+        #: if any, that the request being served produced (``ctx``, for
+        #: the ``rpc_serve`` span), and whether the peer has ever sent a
+        #: causality token (``peer_samples``)
+        self._served = threading.local()
         self.connections_total = 0
         self.requests_total = 0
         self.submits_total = 0
@@ -219,6 +226,9 @@ class RpcIngestServer:
                     continue
                 except TransportError:
                     return
+                rx = conn.last_rx if _trace.ENABLED else None
+                if rx is not None:
+                    self._served.ctx = None
                 try:
                     reply = self._dispatch(msg)
                 except TransportError:
@@ -226,10 +236,13 @@ class RpcIngestServer:
                 except Exception as e:  # noqa: BLE001 - a poisoned
                     # request must not kill the endpoint for the others
                     reply = ("err", f"{type(e).__name__}: {e}")
+                t_reply = time.perf_counter() if rx is not None else 0.0
                 try:
                     conn.send_msg(reply)
                 except TransportError:
                     return
+                if rx is not None and self._served.ctx is not None:
+                    self._trace_served(self._served.ctx, rx, t_reply)
         finally:
             conn.close()
             with self._lock:
@@ -304,15 +317,25 @@ class RpcIngestServer:
         timeout = self._submit_cap
         if req.timeout_s is not None:
             timeout = min(timeout, req.timeout_s)
+        # the wire decision rides the token: a present ``cause`` means
+        # the producer sampled this write, so the frontend adopts it
+        # (and its sampling bit) instead of re-rolling — every process
+        # then records the same writes. A connection that has carried a
+        # token belongs to a producer that samples: its bare requests
+        # are writes it chose not to follow. One that never has belongs
+        # to a producer that does not trace at all, and the leader's own
+        # 1-in-N decides, or a traced leader behind untraced clients
+        # would follow no ticket
+        if req.cause is not None:
+            self._served.peer_samples = sampled = True
+        elif getattr(self._served, "peer_samples", False):
+            sampled = False
+        else:
+            sampled = None
         try:
-            # the wire decision rides the token: a present ``cause``
-            # means the producer sampled this write, so the frontend
-            # adopts it (and its sampling bit) instead of re-rolling —
-            # every process then records the same writes
             ticket = self.frontend.submit(
                 source, req.payload, batch_id=req.batch_id,
-                timeout=timeout, cause=req.cause,
-                sampled=(req.cause is not None))
+                timeout=timeout, cause=req.cause, sampled=sampled)
         except FrontendClosed as e:
             # closed OR pump crashed: either way the producer holds the
             # payload and the mirror holds the truth — tell it to retry
@@ -324,7 +347,31 @@ class RpcIngestServer:
                        track="rpc-server",
                        args={"batch_id": req.batch_id,
                              "cause": req.cause})
+        ctx = ticket.trace
+        if ctx is not None and ctx.sampled:
+            # decided only now, from the ticket: an unsampled request
+            # records nothing on this server
+            self._served.ctx = (ctx, req.cause)
         return self._ack_of(ticket)
+
+    @staticmethod
+    def _trace_served(served, rx, t_reply: float) -> None:
+        """``rpc_serve``: this server's whole handling of one sampled
+        submit, on the handler thread's own track — from the request
+        frame's last byte in hand (``net`` stamps it, before the
+        unpickle) to the reply written to the socket. Unlike
+        ``rpc_admit`` (a link of the cross-process chain, recorded only
+        for a write whose *producer* sampled it and covering only the
+        frontend admit) it needs no wire ``cause`` and covers decode,
+        dispatch, admission and the reply."""
+        ctx, wire_cause = served
+        t_rx, nbytes, decode_s = rx
+        t1 = time.perf_counter()
+        args = {"batch_id": ctx.batch_id, "bytes": nbytes,
+                "decode_s": decode_s, "reply_s": t1 - t_reply}
+        if wire_cause is not None:
+            args["cause"] = wire_cause
+        _trace.evt("rpc_serve", t_rx, t1 - t_rx, args=args)
 
     def _ack_of(self, ticket) -> SubmitAck:
         cause = _ticket_cause(ticket)

@@ -152,7 +152,7 @@ declare("REFLOW_LOCKCHECK", "flag", False,
 
 declare("REFLOW_TRACE", "flag", False,
         "enable per-ticket trace spans at import time (obs.enable())")
-declare("REFLOW_TRACE_RING", "int", 65536,
+declare("REFLOW_TRACE_RING", "int", 262144,
         "per-thread trace ring-buffer capacity (spans)")
 declare("REFLOW_TRACE_SAMPLE", "int", 16,
         "ticket sampling stride: 1-in-N tickets get a span timeline")

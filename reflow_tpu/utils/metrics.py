@@ -370,39 +370,3 @@ def profile_trace(log_dir: str):
         yield
     finally:
         stop()
-
-
-#: warn once, then stay silent: dispatch-path annotation failures must
-#: not spam a log line per window
-_annotation_warned = False
-
-
-@contextlib.contextmanager
-def profile_annotation(name: str, *, enabled: bool = True):
-    """Label a block with a ``jax.profiler.TraceAnnotation`` so a device
-    trace (``profile_trace`` / xprof) lines it up against host spans —
-    one annotation per mega-tick window dispatch correlates the obs
-    ``device_dispatch`` span with device occupancy in Perfetto.
-
-    ``enabled=False`` (and any profiler failure) degrades to running the
-    block unannotated; like :func:`profile_trace`, annotation is
-    observability, never correctness. Failures warn once per process.
-    """
-    global _annotation_warned
-    if not enabled:
-        yield
-        return
-    try:
-        import jax
-
-        ctx = jax.profiler.TraceAnnotation(name)
-    except Exception as e:  # noqa: BLE001 - degrade to a no-op label
-        if not _annotation_warned:
-            _annotation_warned = True
-            warnings.warn(
-                f"jax.profiler unavailable ({e!r}); profile_annotation "
-                f"is a no-op", RuntimeWarning, stacklevel=3)
-        yield
-        return
-    with ctx:
-        yield

@@ -354,3 +354,4 @@ class DurableScheduler(DirtyScheduler):
         self._preimages.clear()
         self._causes.clear()
         self.wal.close()
+        self._close_executor()

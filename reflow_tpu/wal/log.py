@@ -737,20 +737,27 @@ class WriteAheadLog:
                             self._crash_point("wal_after_fsync")
                 if not do_sync:
                     continue
+                tr = _trace.ENABLED
+                c0 = time.thread_time() if tr else 0.0
                 t0 = time.perf_counter()
                 with self._sync_lock:
                     if not f.closed:
                         # reflow-lint: waive lock-blocking-call -- the committer's durability fsync; wal.sync is the fsync-serializing leaf
                         os.fsync(f.fileno())
                 dur = time.perf_counter() - t0
+                cpu = _trace.cpu_s(c0, dur) if tr else 0.0
                 with self._lock:
                     self.fsyncs += 1
                     self.fsync_s.append(dur)
-                    if _trace.ENABLED:
+                    if tr:
+                        # ``lsn``: the watermark this fsync covers — a
+                        # window's ``pump_execute`` carries its own, so
+                        # window -> fsync joins by LSN
                         _trace.evt("wal_fsync", t0, dur,
                                    track="wal-committer",
-                                   args={"covered": n,
-                                         "queue_depth": len(self._io_q)})
+                                   args={"covered": n, "lsn": cover,
+                                         "queue_depth": len(self._io_q),
+                                         "cpu_s": cpu})
                     if n:
                         self.group_sizes.append(n)
                     if cover > self._synced_lsn:
@@ -846,6 +853,7 @@ class WriteAheadLog:
             _trace.evt("wal_fsync", t0, time.perf_counter() - t0,
                        track="wal",
                        args={"covered": self._unsynced_appends,
+                             "lsn": self._written_lsn,
                              "queue_depth": len(self._io_q)})
         if self._unsynced_appends:
             self.group_sizes.append(self._unsynced_appends)

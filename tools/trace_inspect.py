@@ -28,6 +28,15 @@ has two halves:
   decomposition deviation (stage sums are tiled, so this should sit at
   ~0%; large values mean a clock or export bug).
 
+A trace of a serving pump carries the **pump cycle**: the pump
+thread's top-level spans (``pump_wait``, ``pump_turn``, ``host_merge``,
+``window_stage``, ``pump_execute``, ``window_retire``) tile its wall,
+so the report gives the time by span with the share of it the thread
+spent off the CPU (``dur - cpu_s``: waiting for the interpreter lock, a
+lock, or the device inside a slot write), what no span covers, the
+cycle (gap between ``window_stage`` starts), and the device's busy
+share from the executor's own ``window_device`` completion spans.
+
 A trace recorded under WAL shipping (``wal/ship.py`` +
 ``serve/replica.py``) carries ``ship_segment`` spans on the
 ``wal-shipper`` track and ``replica_replay`` spans on per-replica
@@ -104,6 +113,83 @@ FRESHNESS_STAGES = ("admission", "durability", "ship", "apply",
 #: would mix cut points from different writes and break the tiling.
 FRESHNESS_SPANS = ("producer_submit", "rpc_admit", "wal_append",
                    "replica_replay", "sub_fanout", "sub_deliver")
+
+
+#: the spans that tile a pump thread's wall (the umbrella ``window``
+#: overlaps them all and is left out)
+PUMP_SPANS = ("pump_wait", "pump_turn", "host_merge", "window_stage",
+              "pump_execute", "window_retire")
+
+
+def _pump_cycle(events, tid_names):
+    """The pump-cycle section: per pump track (one that recorded
+    ``pump_execute``) the time by top-level span with its CPU seconds,
+    the off-CPU share of the working spans, the untiled share of the
+    wall and the cycle; and the device's busy share from
+    ``window_device``. None when the trace holds no pump."""
+    by_track: dict = defaultdict(list)
+    device = []
+    for ev in events:
+        if ev.get("ph") != "X":
+            continue
+        name = ev.get("name")
+        if name in PUMP_SPANS:
+            by_track[ev.get("tid")].append(ev)
+        elif name == "window_device":
+            device.append(ev)
+    by_track = {t: evs for t, evs in by_track.items()
+                if any(e["name"] == "pump_execute" for e in evs)}
+    if not by_track and not device:
+        return None
+    by_span: dict = defaultdict(lambda: {"count": 0, "ms": 0.0,
+                                         "cpu_ms": 0.0})
+    wall = covered = 0.0
+    cycles = []
+    for evs in by_track.values():
+        evs.sort(key=lambda e: e["ts"])
+        wall += max(e["ts"] + e["dur"] for e in evs) - evs[0]["ts"]
+        edge = evs[0]["ts"]
+        for e in evs:
+            end = e["ts"] + e["dur"]
+            if end > edge:
+                covered += end - max(e["ts"], edge)
+                edge = end
+            d = by_span[e["name"]]
+            d["count"] += 1
+            d["ms"] += e["dur"] / 1e3
+            d["cpu_ms"] += 1e3 * float(
+                (e.get("args") or {}).get("cpu_s", 0.0))
+        starts = [e["ts"] for e in evs if e["name"] == "window_stage"]
+        cycles += [b - a for a, b in zip(starts, starts[1:])]
+    work_ms = sum(d["ms"] for n, d in by_span.items() if n != "pump_wait")
+    work_cpu = sum(d["cpu_ms"] for n, d in by_span.items()
+                   if n != "pump_wait")
+    out = {
+        "tracks": sorted(str(tid_names.get(t, t)) for t in by_track),
+        "wall_ms": round(wall / 1e3, 3),
+        "untiled_frac": round(1.0 - covered / wall, 6) if wall else 0.0,
+        "offcpu_frac": (round(1.0 - work_cpu / work_ms, 4)
+                        if work_ms else 0.0),
+        "cycle_p50_us": round(percentile(cycles, 50), 3),
+        "by_span": {
+            n: {"count": d["count"], "ms": round(d["ms"], 3),
+                "cpu_ms": round(d["cpu_ms"], 3),
+                "share": round(1e3 * d["ms"] / wall, 4) if wall else 0.0}
+            for n, d in sorted(by_span.items(),
+                               key=lambda kv: -kv[1]["ms"])},
+        "device": None,
+    }
+    if device:
+        t0 = min(e["ts"] for e in device)
+        t1 = max(e["ts"] + e["dur"] for e in device)
+        busy = sum(e["dur"] for e in device)
+        out["device"] = {
+            "windows": len(device),
+            "busy_ms": round(busy / 1e3, 3),
+            "window_p50_us": round(
+                percentile([e["dur"] for e in device], 50), 3),
+            "busy_frac": round(busy / (t1 - t0), 4) if t1 > t0 else 0.0}
+    return out
 
 
 def load_events(path: str) -> list:
@@ -679,6 +765,7 @@ def inspect(path, require_chain=None) -> dict:
         "window_dispatch_frac": window_dispatch_frac,
         "stage_overlap_frac": stage_overlap_frac,
         "dispatch_by_depth": dispatch_by_depth,
+        "pump_cycle": _pump_cycle(events, tid_names),
         "per_device": per_device,
         "replication": replication,
         "tiles": tiles,
@@ -720,6 +807,25 @@ def _print_human(s: dict) -> None:
         occ = ", ".join(f"depth {d}: {n}"
                         for d, n in s["dispatch_by_depth"].items())
         print(f"dispatch occupancy: {occ}")
+    pc = s.get("pump_cycle")
+    if pc:
+        if pc["by_span"]:
+            print(f"pump cycle ({', '.join(pc['tracks'])}): "
+                  f"{pc['wall_ms']:.2f}ms wall, cycle p50 "
+                  f"{pc['cycle_p50_us'] / 1e3:.3f}ms, "
+                  f"{pc['offcpu_frac']:.0%} of the working spans off "
+                  f"the CPU, {pc['untiled_frac']:.2%} under no span")
+            print(f"  {'span':<14} {'count':>7} {'ms':>10} {'cpu_ms':>10} "
+                  f"{'share':>7}")
+            for name, d in pc["by_span"].items():
+                print(f"  {name:<14} {d['count']:>7} {d['ms']:>10.2f} "
+                      f"{d['cpu_ms']:>10.2f} {100 * d['share']:>6.1f}%")
+        dv = pc["device"]
+        if dv:
+            print(f"  device: {dv['windows']} window(s) completed, busy "
+                  f"{dv['busy_ms']:.2f}ms = {dv['busy_frac']:.1%} of "
+                  f"first launch to last completion, window p50 "
+                  f"{dv['window_p50_us'] / 1e3:.3f}ms")
     if s.get("per_device"):
         print(f"{'device':<12} {'dispatches':>11} {'busy_ms':>10} "
               f"{'share':>8}")
