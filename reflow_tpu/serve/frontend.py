@@ -46,7 +46,10 @@ records are written+flushed; ticket resolution is deferred into a
 fires when the committer thread's fsync passes the window's LSN — so
 window N's disk latency overlaps window N+1's merge and dispatch, while
 commit-before-resolve holds (a crash between execute and fsync leaves
-the tickets unresolved; upstream re-sends, replay dedups). Device
+the tickets unresolved; upstream re-sends, replay dedups). The block is
+registered as soon as the window's dispatch has returned, at any
+pipeline depth: a pipelined window's retire (the hand-back of its
+ingress buffers) comes later and no ticket waits for it. Device
 batches submitted with ``preimage=`` log the host pre-image instead of
 paying a device readback (``DurableScheduler.push_preimage``).
 
@@ -55,8 +58,10 @@ Crash seams (``utils.faults.CrashInjector``): ``producer_submit`` /
 ``pump_before_tick`` / ``pump_after_tick`` on the pump. A named
 frontend (tier-managed) scopes its seams as ``<seam>@<name>`` so one
 graph of a pool can be killed in isolation. A pump kill fails every
-undecided ticket with :class:`PumpCrashed` and releases blocked
-producers; a durable scheduler's WAL then carries exactly-once across
+undecided ticket of the drained set it was working on and of the
+backlog with :class:`PumpCrashed` and releases blocked producers
+(windows dispatched earlier are on the durable watermark, which decides
+them); a durable scheduler's WAL then carries exactly-once across
 ``recover()`` + upstream re-send.
 """
 
@@ -103,6 +108,9 @@ class _ResBlock:
     t_exec1: float
     #: the pump's window id (joins this block's spans to the window's)
     win: int = 0
+    #: the chunk went through the staged lifecycle, so its window has a
+    #: retire of its own (which this block does not wait for)
+    staged: bool = False
     #: traced runs: when ``_wire_block`` handed the block to the durable
     #: watermark — ``[t_exec1, t_wired]`` is the ticket span
     #: ``wire_wait``, the part of ``fsync`` no disk was waited for
@@ -134,12 +142,14 @@ class _PumpClock:
 class _InflightWindow:
     """One dispatched-but-unretired pipelined window: the scheduler's
     staged handle (whose retire re-adopts the donated queue generation)
-    plus the :class:`_ResBlock` whose durability wiring happens at the
-    retire step — both deliberately OFF the stage→dispatch critical
-    path."""
+    and what the ``window_retire`` span says of it. Its tickets are not
+    here: their :class:`_ResBlock` went onto the durable watermark when
+    the dispatch returned, and a pump crash reads nothing of an entry —
+    it drops them unretired."""
 
     handle: object               # scheduler _StagedTicks
-    block: _ResBlock
+    win: int
+    nticks: int
 
 
 #: per-sample metric retention: percentile summaries only need a recent
@@ -249,6 +259,10 @@ class IngestFrontend:
         #: (``stage_overlap_frac`` is the overlapped fraction)
         self.windows_staged = 0
         self.windows_pipelined = 0
+        #: staged windows whose tickets resolved (at their durability
+        #: point) while the window was still dispatched-but-unretired:
+        #: how often resolution did not wait for the retire
+        self.blocks_resolved_before_retire = 0
         self.stage_s_total = 0.0
         self.stage_overlap_s = 0.0
         #: times a failed frontend was re-armed (:meth:`revive`)
@@ -266,6 +280,10 @@ class IngestFrontend:
         #: windows numbered as they are staged (or, unfused, ticked):
         #: the ``win`` every span of one window carries
         self._win_seq = 0
+        #: id of the newest staged window handed back by its retire
+        #: (windows retire oldest first, so every staged window up to
+        #: this one is retired); written by the pump only
+        self._win_retired = 0
         #: the private pump's tiling clock while tracing is on
         self._clk: Optional[_PumpClock] = None
         self._thread: Optional[threading.Thread] = None
@@ -960,15 +978,22 @@ class IngestFrontend:
                 self._crash_point("pump_after_tick")
                 block = _ResBlock(self._chunk_items(chunk, tick0), lsn,
                                   len(chunk), t_ready, t_exec0, t_exec1,
-                                  win)
+                                  win, staged=True)
                 with self._lock:
                     self._pending_res += 1
-                self._inflight.append(_InflightWindow(handle, block))
+                self._inflight.append(
+                    _InflightWindow(handle, win, len(chunk)))
+                # the window's records and its durability request are in
+                # the WAL and ``lsn`` covers them: its tickets resolve
+                # when the watermark passes it, whenever the retire comes
+                self._wire_block(block)
                 continue
-            # unfused (or depth-1) chunk: settle the pipeline first so
-            # ticket wiring stays LSN-ordered, then run today's serial
-            # tick_many path verbatim (it re-checks the window fit and
-            # counts any fallback exactly once)
+            # unfused (or depth-1) chunk: retire the pipeline first (the
+            # serial path below re-uses the ingress queue; every earlier
+            # block is already on the watermark, in dispatch = LSN
+            # order), then run today's serial tick_many path verbatim
+            # (it re-checks the window fit and counts any fallback
+            # exactly once)
             self._settle_all()
             tick0 = self.sched._tick
             t_exec0 = time.perf_counter()
@@ -1029,21 +1054,21 @@ class IngestFrontend:
 
     def _settle_one(self) -> None:
         """Retire the OLDEST dispatched window (lock NOT held): re-adopt
-        its donated queue generation, then wire its tickets onto the
-        durable watermark. Runs off the stage→dispatch critical path —
-        under pipelining this executes while the next window is already
-        on the device."""
+        its donated queue generation, and nothing else — the window's
+        tickets went onto the durable watermark at its dispatch and
+        resolve without waiting for this. Runs off the stage→dispatch
+        critical path — under pipelining this executes while the next
+        window is already on the device."""
         iw = self._inflight.popleft()
         tr = _trace.ENABLED
         t_r0 = time.perf_counter() if tr else 0.0
         c_r0 = time.thread_time() if tr else 0.0
         self.sched.retire_staged(iw.handle)
+        self._win_retired = iw.win
         if tr:
             self._pump_span("window_retire", t_r0, c_r0,
                             time.perf_counter(),
-                            {"win": iw.block.win,
-                             "ticks": iw.block.nticks})
-        self._wire_block(iw.block)
+                            {"win": iw.win, "ticks": iw.nticks})
 
     def _settle_all(self) -> None:
         while self._inflight:
@@ -1051,11 +1076,16 @@ class IngestFrontend:
 
     def _wire_block(self, block: _ResBlock) -> None:
         """Park one executed chunk's tickets on the durable watermark
-        (``_pending_res`` was already taken at dispatch). Pipelined
+        (``_pending_res`` was already taken). Called by the pump as soon
+        as the chunk's dispatch (staged) or ``tick_many`` (serial) has
+        returned and ``block.lsn`` has been read, so blocks are wired in
+        LSN order and a window's retire has no part in it. Pipelined
         resolution: commit-before-resolve holds, but the commit (the
         fsync) may still be in flight — ``when_durable`` fires on the
         committer once the window's LSN is covered, so the pump overlaps
-        the disk latency instead of serializing behind it."""
+        the disk latency instead of serializing behind it. A
+        non-durable scheduler has nothing to wait for: its tickets
+        resolve here."""
         if _trace.ENABLED:
             block.t_wired = time.perf_counter()
         wal = getattr(self.sched, "wal", None)
@@ -1065,7 +1095,8 @@ class IngestFrontend:
         try:
             deferred = wal.when_durable(
                 block.lsn,
-                lambda err, b=block: self._complete_block(b, err))
+                lambda err, b=block: self._complete_block(
+                    b, err, "committer"))
         except BaseException:
             with self._lock:
                 self._pending_res -= 1
@@ -1074,14 +1105,19 @@ class IngestFrontend:
             self._complete_block(block, None)
 
     def _complete_block(self, block: _ResBlock,
-                        err: Optional[BaseException]) -> None:
+                        err: Optional[BaseException],
+                        where: str = "pump") -> None:
         """Resolve one executed chunk's tickets at its durability point.
         Runs inline on the pump (LSN already durable / non-durable
-        scheduler) or on the WAL committer thread via ``when_durable``
-        (pipelined fsync). ``err`` is the committer's death cause — the
-        chunk's records may never become durable, so its undecided
-        tickets fail with :class:`PumpCrashed` instead (the upstream
-        re-sends; replay after ``recover()`` dedups)."""
+        scheduler) or, ``where="committer"``, as the ``when_durable``
+        continuation on the thread that advanced the watermark — the
+        WAL committer's (pipelined fsync), under the WAL's lock. A
+        staged window may still be dispatched-but-unretired either way
+        (``blocks_resolved_before_retire``), or already handed back:
+        nothing here depends on it. ``err`` is the committer's death
+        cause — the chunk's records may never become durable, so its
+        undecided tickets fail with :class:`PumpCrashed` instead (the
+        upstream re-sends; replay after ``recover()`` dedups)."""
         if err is not None:
             crash = PumpCrashed(
                 f"wal committer died before the window's records were "
@@ -1128,42 +1164,55 @@ class IngestFrontend:
             self._pending_res -= 1
             self.ticks += block.nticks
             self.applied += applied
+            if block.staged and block.win > self._win_retired:
+                self.blocks_resolved_before_retire += 1
             self._idle.notify_all()
         if tr:
-            # on the WAL committer's thread (under the WAL's lock) when
-            # the fsync overlapped later work, inline on the pump when
-            # the LSN was already durable
+            # ``where``: on the WAL committer's thread (under the WAL's
+            # lock) when the fsync overlapped later work, inline on the
+            # pump when the LSN was already durable; ``inflight``:
+            # windows dispatched and unretired right now (the pump owns
+            # the deque; another thread may only take its length)
+            inflight = len(self._inflight)
             dur = time.perf_counter() - t_dur
             _trace.evt("resolve_block", t_dur, dur,
                        args={"graph": self.name or "frontend",
                              "win": block.win, "tickets": applied,
+                             "where": where, "inflight": inflight,
                              "cpu_s": _trace.cpu_s(c_dur, dur)})
 
     def _on_pump_crash(self, error: BaseException,
                        window: Optional[Dict[int, List[Entry]]] = None,
                        ) -> None:
         """Fail the frontend after its pump died: every undecided ticket
-        of the in-flight window and the stranded backlog resolves with
-        :class:`PumpCrashed`, blocked producers are released, and the
-        graph's budget bytes return to the pool. On a tier, only THIS
-        graph fails — the pool thread survives and keeps serving
-        siblings (``window`` carries the drained entries when the crash
-        fired before ``_run_window`` stamped them)."""
+        of the drained set the pump was working on and of the stranded
+        backlog resolves with :class:`PumpCrashed`, blocked producers
+        are released, and the graph's budget bytes return to the pool.
+        On a tier, only THIS graph fails — the pool thread survives and
+        keeps serving siblings (``window`` carries the drained entries
+        when the crash fired before ``_run_window`` stamped them).
+
+        A chunk whose dispatch (or ``tick_many``) had returned is on the
+        durable watermark already and keeps its own fate: a ticket the
+        watermark decided stays APPLIED (its records are durable and
+        ``recover()`` replays them), and one it has not decided yet is
+        decided by it — or by the committer's death, as
+        :class:`PumpCrashed` — unless it belongs to the drained set
+        above, whose undecided tickets fail here and now. Either way the
+        block's ``_pending_res`` unit comes back through
+        ``_complete_block``, never from here."""
         with self._lock:
             self._state = "failed"
             self.pump_error = error
             self._executing = False
             # dispatched-but-unretired pipelined windows die with the
-            # pump: their device work may or may not have completed, so
-            # treat them like the in-flight window — tickets fail (the
-            # upstream re-sends; durable replay dedups what actually
-            # applied) and their ids STAY in the dedup mirror. Their
-            # queue generations are never retired; the executor's
-            # use-after-donate guard already dropped the queue on a
-            # dispatch crash, and a fresh one is allocated next window.
-            inflight = list(self._inflight)
+            # pump unretired: their device work may or may not have
+            # completed, and their ids STAY in the dedup mirror (a
+            # re-send dedups). Their queue generations are never handed
+            # back; the executor's use-after-donate guard already
+            # dropped the queue on a dispatch crash, and a fresh one is
+            # allocated next window.
             self._inflight.clear()
-            self._pending_res -= len(inflight)
             stranded = self._queues.drain_all()
             self._queues.commit_executing()
             # the stranded backlog never reached the scheduler: drop its
@@ -1183,10 +1232,6 @@ class IngestFrontend:
         crash.__cause__ = error
         if window is None:
             window = getattr(self, "_window_entries", None) or {}
-        for iw in inflight:
-            for e, _tick, _co in iw.block.items:
-                if not e.ticket.done():
-                    e.ticket._fail(crash)
         for entries in list(window.values()) + list(stranded.values()):
             for e in entries:
                 if not e.ticket.done():

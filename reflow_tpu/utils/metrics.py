@@ -201,11 +201,14 @@ class ServeMetrics:
     #: that took the stage/dispatch/retire path, how many of those
     #: staged while a previous window was still in flight, and the
     #: fraction of host staging wall that overlapped device compute
-    #: (0.0 at depth 1 — staging and execution strictly alternate)
+    #: (0.0 at depth 1 — staging and execution strictly alternate);
+    #: ``blocks_resolved_before_retire``: staged windows whose tickets
+    #: resolved (at durability) while the window was still unretired
     window_depth: int = 1
     windows_staged: int = 0
     windows_pipelined: int = 0
     stage_overlap_frac: float = 0.0
+    blocks_resolved_before_retire: int = 0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -240,6 +243,8 @@ def summarize_serve(frontend) -> ServeMetrics:
         windows_staged=getattr(frontend, "windows_staged", 0),
         windows_pipelined=getattr(frontend, "windows_pipelined", 0),
         stage_overlap_frac=getattr(frontend, "stage_overlap_frac", 0.0),
+        blocks_resolved_before_retire=getattr(
+            frontend, "blocks_resolved_before_retire", 0),
     )
 
 

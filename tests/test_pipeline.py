@@ -10,6 +10,11 @@ work happens, never WHAT is computed —
 - staging window N+1 never writes a buffer set an in-flight window
   program owns (generation rotation), including when the pump crashes
   with windows dispatched but unretired — every ticket still resolves;
+- a window's tickets resolve at its durability point — as soon as its
+  dispatch has returned and the WAL watermark has passed its LSN, in
+  LSN order, never before the fsync — and a window's retire (the hand-
+  back of its ingress buffers) has no part in it, nor has a pump crash
+  after the dispatch;
 - a producer blocked on the admission budget wakes at STAGE-complete
   (the chunk's rows live in the device queue, their host bytes no
   longer occupy the frontend), not at retire;
@@ -17,6 +22,7 @@ work happens, never WHAT is computed —
   instead of silently wrapping them.
 """
 
+import os
 import threading
 import time
 
@@ -30,6 +36,7 @@ from reflow_tpu.executors.device_delta import DeviceDelta
 from reflow_tpu.executors.ingress_queue import DeviceIngressQueue, slot_nbytes
 from reflow_tpu.serve import CoalesceWindow, IngestFrontend, PumpCrashed
 from reflow_tpu.utils.faults import CrashInjector, DeliveryError
+from reflow_tpu.wal import DurableScheduler, recover
 
 K_SPACE = 32
 ROWS = 6
@@ -184,11 +191,14 @@ def test_depth1_pingpong_reuses_generation_zero():
     assert q.in_flight == 0
 
 
-def test_crash_with_window_in_flight_fails_every_ticket():
+def test_crash_with_window_in_flight_keeps_dispatched_tickets():
     """Kill the pump between chunk dispatches (chunk 1 dispatched and
-    unretired, chunk 2 about to stage): the crash path must fail BOTH
-    chunks' tickets — the in-flight window's ids stay in the dedup
-    mirror, so a replay after recovery dedups instead of double-folding."""
+    unretired, chunk 2 about to stage) on a NON-durable scheduler: chunk
+    1's tickets resolved APPLIED when its dispatch returned, as at depth
+    1 (there is no durability point to wait for), and stay so; chunk 2
+    never reached the scheduler and fails. The dispatched window dies
+    unretired, and its ids stay in the dedup mirror, so a re-send
+    dedups instead of double-folding."""
     g, s, _r = _graph()
     sched = DirtyScheduler(g, get_executor("tpu"))
     crash = CrashInjector(2, only="pump_before_tick")
@@ -199,15 +209,250 @@ def test_crash_with_window_in_flight_fails_every_ticket():
     tks = [fe.submit(s, b, batch_id=f"b{i}")
            for i, b in enumerate(_mk_batches(3, n=4))]
     fe.resume()
-    for t in tks:
+    for i, t in enumerate(tks[:2]):
+        res = t.result(timeout=10)
+        assert res.applied and res.tick == i + 1
+    for t in tks[2:]:
         with pytest.raises(PumpCrashed):
             t.result(timeout=10)
     assert crash.fired
     assert not fe._inflight
     assert fe._pending_res == 0
-    # executed-but-unresolved ids stay admitted: a resend dedups
+    assert fe.applied == 2
+    # dispatched ids stay admitted, and so do the crashed chunk's (it
+    # may have executed): a resend dedups
     assert "b0" in fe._admitted and "b3" in fe._admitted
     fe.close()
+
+
+# -- tickets resolve at the durability point, not at the retire -------------
+
+COMMITTER = "reflow-wal-committer"
+
+
+class _FsyncGate:
+    """Holds the WAL committer's fsync (and nobody else's) until
+    ``release()``; ``release(fail=True)`` makes that fsync raise, which
+    kills the committer."""
+
+    def __init__(self, monkeypatch):
+        self._real = os.fsync
+        self._open = threading.Event()
+        self._fail = False
+        self.held = threading.Event()
+        monkeypatch.setattr(os, "fsync", self._fsync)
+
+    def _fsync(self, fd):
+        if threading.current_thread().name == COMMITTER:
+            self.held.set()
+            assert self._open.wait(30)
+            if self._fail:
+                raise OSError("injected: the disk is gone")
+        return self._real(fd)
+
+    def release(self, fail=False):
+        self._fail = fail
+        self._open.set()
+
+
+def _durable_frontend(tmp_path, *, depth=2, crash=None):
+    """An externally pumped frontend over a DurableScheduler whose
+    end-of-window settle is stubbed out: a dispatched window stays in
+    ``_inflight`` until the test retires it (the loop in ``_run_window``
+    still retires the oldest when the pipeline is full)."""
+    g, s, r = _graph()
+    sched = DurableScheduler(g, get_executor("tpu"),
+                             wal_dir=str(tmp_path / "wal"), fsync="tick",
+                             committer="thread")
+    fe = IngestFrontend(sched, start=False, depth=depth, crash=crash,
+                        window=CoalesceWindow(max_rows=ROWS, max_ticks=2,
+                                              max_latency_s=0.001))
+    fe._real_settle_all = fe._settle_all
+    fe._settle_all = lambda: None
+    return fe, sched, s, r
+
+
+def _pump_once(fe):
+    """One pool-style pump iteration: take the backlog, run it, unlatch."""
+    with fe._lock:
+        drained = fe._take_window()
+    try:
+        fe._run_window(drained)
+    except BaseException as e:  # noqa: BLE001 - what the tier's pool does
+        fe._on_pump_crash(e, window=drained)
+        return e
+    with fe._lock:
+        fe._finish_window()
+    return None
+
+
+def _wait_for(cond, timeout=10.0):
+    deadline = time.perf_counter() + timeout
+    while not cond():
+        assert time.perf_counter() < deadline, "condition never held"
+        time.sleep(0.002)
+
+
+@pytest.mark.parametrize("case", ["while_inflight", "fsync_held",
+                                  "lsn_order"])
+def test_tickets_resolve_at_durability_not_at_retire(case, tmp_path,
+                                                     monkeypatch):
+    """(while_inflight) a dispatched window's tickets resolve while it
+    still sits in ``_inflight``, unretired; (fsync_held) with the
+    committer's fsync held they do NOT, however long the window has been
+    dispatched and even once it has been retired, and do when the fsync
+    is let through; (lsn_order) three windows parked behind one held
+    fsync resolve in dispatch = LSN order, on the committer."""
+    nwin = 3 if case == "lsn_order" else 1
+    gate = _FsyncGate(monkeypatch) if case != "while_inflight" else None
+    fe, sched, s, _r = _durable_frontend(tmp_path, depth=4)
+    order = []
+    real_complete = fe._complete_block
+
+    def spy(block, err, where="pump"):
+        order.append((block.win, block.lsn, where))
+        real_complete(block, err, where)
+
+    fe._complete_block = spy
+    tks = [fe.submit(s, b, batch_id=f"b{i}")
+           for i, b in enumerate(_mk_batches(11, n=2 * nwin))]
+    assert _pump_once(fe) is None
+    assert len(fe._inflight) == nwin       # dispatched, NOT retired
+    if gate is not None:
+        assert gate.held.wait(10)
+        time.sleep(0.1)
+        assert not any(t.done() for t in tks)
+        assert fe._pending_res == nwin
+        if case == "fsync_held":
+            # the retire changes nothing: durability is what is missing
+            fe._real_settle_all()
+            assert not fe._inflight
+            time.sleep(0.05)
+            assert not any(t.done() for t in tks)
+        gate.release()
+    for i, t in enumerate(tks):
+        res = t.result(timeout=10)
+        assert res.applied and res.tick == i + 1
+    _wait_for(lambda: fe._pending_res == 0)
+    assert [w for w, _, _ in order] == list(range(1, nwin + 1))
+    assert [lsn for _, lsn, _ in order] == sorted(
+        lsn for _, lsn, _ in order)
+    assert len({lsn for _, lsn, _ in order}) == nwin
+    assert [t.result().lsn for t in tks] == [
+        lsn for _, lsn, _ in order for _ in range(2)]
+    if gate is not None:
+        assert all(where == "committer" for _, _, where in order)
+    if case == "fsync_held":
+        assert fe.blocks_resolved_before_retire == 0
+    else:
+        assert len(fe._inflight) == nwin   # still dispatched, unretired
+        assert fe.blocks_resolved_before_retire == nwin
+    fe._real_settle_all()
+    assert not fe._inflight
+    fe._settle_all = fe._real_settle_all
+    fe.close()
+
+
+@pytest.mark.parametrize("case", ["lsn_durable", "committer_dead"])
+def test_pump_crash_after_dispatch_leaves_tickets_to_the_watermark(
+        case, tmp_path, monkeypatch):
+    """A pump that dies with a window dispatched (and wired) does not
+    decide that window's tickets. (lsn_durable) the watermark had passed
+    the LSN: they are APPLIED and stay so, and ``recover()`` reads the
+    batches back; the drained set the pump died in fails. (committer_
+    dead) the fsync never came and the committer died: they fail
+    ``PumpCrashed``. ``_pending_res`` ends at 0 either way — the block's
+    unit comes back through its continuation, once."""
+    gate = _FsyncGate(monkeypatch) if case == "committer_dead" else None
+    crash = CrashInjector(2, only="pump_before_tick")
+    fe, sched, s, r = _durable_frontend(tmp_path, crash=crash)
+    batches = _mk_batches(12, n=4)
+    first = [fe.submit(s, b, batch_id=f"b{i}")
+             for i, b in enumerate(batches[:2])]
+    assert _pump_once(fe) is None          # window 1: dispatched, wired
+    assert len(fe._inflight) == 1 and not crash.fired
+    if gate is None:
+        for t in first:
+            assert t.result(timeout=10).applied
+        _wait_for(lambda: fe._pending_res == 0)
+    else:
+        assert gate.held.wait(10)
+        assert fe._pending_res == 1
+    second = [fe.submit(s, b, batch_id=f"b{i + 2}")
+              for i, b in enumerate(batches[2:])]
+    assert _pump_once(fe) is not None      # dies before window 2 stages
+    assert crash.fired and fe._state == "failed"
+    assert not fe._inflight
+    for t in second:
+        with pytest.raises(PumpCrashed):
+            t.result(timeout=10)
+    if gate is None:
+        assert all(t.result().applied for t in first)
+        assert fe._pending_res == 0
+        fe.close()
+        g2, s2, r2 = _graph()
+        fresh = DurableScheduler(g2, get_executor("cpu"),
+                                 wal_dir=str(tmp_path / "wal"))
+        recover(fresh, str(tmp_path / "wal"))
+        assert {"b0", "b1"} <= set(fresh._seen_batch_ids)
+        assert "b2" not in fresh._seen_batch_ids
+        assert _table(fresh, r2, nd=3) == _oracle(batches[:2])
+        fresh.close()
+    else:
+        # undecided, and not the crashed pump's to decide
+        assert not any(t.done() for t in first)
+        assert fe._pending_res == 1
+        gate.release(fail=True)
+        for t in first:
+            with pytest.raises(PumpCrashed):
+                t.result(timeout=10)
+        _wait_for(lambda: fe._pending_res == 0)
+        assert sched.wal.committer_error is not None
+        assert "b0" in fe._admitted
+        fe.close()
+
+
+@pytest.mark.parametrize("mode", ["depth1", "unfused"])
+def test_serial_paths_resolve_as_before(mode, tmp_path):
+    """Depth 1 and the unfused fallback (a chunk ``stage_window``
+    refuses at depth 2) wire a chunk's tickets right after ``tick_many``
+    returns, as they always did: every ticket's tick, LSN and
+    coalescing — and the tables — are those of the staged depth-2 drive
+    of the same batches, no window is staged, none resolves 'before its
+    retire'."""
+    batches = _mk_batches(13, n=6)
+
+    def drive(sub, depth, refuse_stage):
+        g, s, r = _graph()
+        sched = DurableScheduler(g, get_executor("tpu"),
+                                 wal_dir=str(tmp_path / sub), fsync="tick",
+                                 committer="thread")
+        if refuse_stage:
+            sched.stage_window = lambda *a, **k: None
+        fe = IngestFrontend(sched, depth=depth, window=CoalesceWindow(
+            max_rows=ROWS, max_ticks=2, max_latency_s=0.001))
+        fe.pause()
+        tks = [fe.submit(s, b, batch_id=f"b{i}")
+               for i, b in enumerate(batches)]
+        fe.resume()
+        fe.flush(timeout=30)
+        res = [t.result(timeout=10) for t in tks]
+        table = _table(sched, r)
+        fe.close()
+        return [(x.status, x.tick, x.coalesced_with, x.lsn)
+                for x in res], table, fe, sched
+
+    got, table, fe, sched = drive(
+        "serial", 1 if mode == "depth1" else 2, mode == "unfused")
+    want, table2, fe2, _ = drive("staged", 2, False)
+    assert got == want and table == table2
+    assert [x[:3] for x in got] == [("applied", i + 1, 0)
+                                    for i in range(len(batches))]
+    assert fe.windows_staged == 0
+    assert fe.blocks_resolved_before_retire == 0
+    assert fe.applied == len(batches) and fe._pending_res == 0
+    assert sched.megatick_windows == 3 and sched.megatick_fallbacks == 0
+    assert fe2.windows_staged == 3
 
 
 # -- stage-complete budget release -----------------------------------------

@@ -16,6 +16,7 @@ no ring, no busy seconds.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 
@@ -167,7 +168,7 @@ def test_pump_spans_tile_and_share_window_ids(tmp_path, traced):
         f0, f1, fargs = st["fsync"]
         w0, w1, wargs = st["wire_wait"]
         assert w0 == pytest.approx(f0, abs=0.01)
-        assert w1 <= f1 + 0.01
+        assert w0 <= w1 <= f1 + 0.01    # still emitted, and reads >= 0
         assert wargs["win"] == fargs["win"] == st["execute"][2]["win"]
         a0, a1, _ = st["admit_lock_wait"]
         assert st["admission"][0] <= a0 + 0.01
@@ -176,6 +177,65 @@ def test_pump_spans_tile_and_share_window_ids(tmp_path, traced):
     assert all(set(t["stages"]) == set(trace_mod.STAGES)
                and t["sum_us"] == pytest.approx(t["e2e_us"], abs=0.05)
                for t in tl.values())
+    # every window's block says where it resolved and how many windows
+    # were dispatched and unretired then; the frontend counts the
+    # blocks that did not wait for their retire, and publishes it
+    blocks = [a for nm, *_, a in spans if nm == "resolve_block"]
+    assert all(a["where"] in ("committer", "pump")
+               and 0 <= a["inflight"] <= fe.depth for a in blocks)
+    assert 1 <= fe.blocks_resolved_before_retire <= n
+    from reflow_tpu.utils.metrics import summarize_serve
+    assert summarize_serve(fe).to_dict()[
+        "blocks_resolved_before_retire"] == fe.blocks_resolved_before_retire
+
+
+@pytest.mark.parametrize("durable", [True, False])
+def test_resolve_block_where_and_wire_wait_at_dispatch(durable, tmp_path,
+                                                       traced, monkeypatch):
+    """The window's block is wired when its dispatch returns: with the
+    committer's fsync held a little, a durable window's ``resolve_block``
+    runs on the committer with the window still in flight, its tickets'
+    ``wire_wait`` is a sliver of their ``fsync`` stage (which holds the
+    disk wait), and the six stages tile; a non-durable window resolves
+    on the pump at its dispatch, itself in flight."""
+    real = os.fsync
+
+    def slow(fd):
+        if threading.current_thread().name == "reflow-wal-committer":
+            time.sleep(0.1)
+        return real(fd)
+
+    monkeypatch.setattr(os, "fsync", slow)
+    g, s = _loop_free()
+    ex = get_executor("tpu")
+    sched = (DurableScheduler(g, ex, wal_dir=str(tmp_path / "wal"),
+                              fsync="tick", committer="thread")
+             if durable else DirtyScheduler(g, ex))
+    fe = _drive_pipelined(sched, s, _batches(7, 2), max_rows=6, k=2)
+    spans = _spans()
+    fe.close()
+    (blk,) = [a for nm, *_, a in spans if nm == "resolve_block"]
+    assert blk["where"] == ("committer" if durable else "pump")
+    assert blk["win"] == 1 and blk["tickets"] == 2
+    # a lone window is retired as soon as its dispatch has returned:
+    # held 100 ms at the disk, its block resolves after that retire
+    assert blk["inflight"] == (0 if durable else 1)
+    assert fe.blocks_resolved_before_retire == (0 if durable else 1)
+    stages = {}
+    for name, track, t0, t1, _ in spans:
+        if track.startswith("ticket/"):
+            stages.setdefault(track, {})[name] = t1 - t0
+    assert len(stages) == 2
+    for st in stages.values():
+        assert 0.0 <= st["wire_wait"] <= st["fsync"] + 10.0
+        if durable:
+            assert st["fsync"] >= 50e3
+            assert st["wire_wait"] < st["fsync"] / 2
+    tl = obs.ticket_timelines(obs.chrome_events())
+    assert len(tl) == 2 and all(
+        set(t["stages"]) == set(trace_mod.STAGES)
+        and t["sum_us"] == pytest.approx(t["e2e_us"], abs=0.05)
+        for t in tl.values())
 
 
 # -- (b) window_device ---------------------------------------------------------
