@@ -482,7 +482,7 @@ def cfg4_knn(smoke: bool, log) -> None:
             # consults the value (lowerings._fold_vectors), so zero rows
             # stand in for the device-generated preload vectors
             return DeltaBatch(np.asarray(ids, np.int64),
-                              np.zeros((len(ids), dim), np.float32),
+                              np.zeros((len(ids), dim), np.dtype(doc_dtype)),
                               -np.ones(len(ids), np.int64))
 
         t0 = time.perf_counter()

@@ -336,7 +336,7 @@ def knn_phase(cfg: dict, dev) -> dict:
     t0 = time.perf_counter()
     ret_ids = np.arange(c["retract_rows"], dtype=np.int64)
     sched.push(kg.docs, DeltaBatch(
-        ret_ids, np.zeros((len(ret_ids), dim), np.float32),
+        ret_ids, np.zeros((len(ret_ids), dim), np.int8),
         -np.ones(len(ret_ids), np.int64)))
     sched.tick()
     rescan_s = time.perf_counter() - t0

@@ -24,7 +24,8 @@ from typing import Any, Hashable, Iterable, Mapping, Tuple
 
 import numpy as np
 
-__all__ = ["Spec", "DeltaBatch", "collection_counter", "counter_to_batch"]
+__all__ = ["Spec", "DeltaBatch", "collection_counter", "counter_to_batch",
+           "lossy_value_cast"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +51,25 @@ class Spec:
 
     def as_unique(self) -> "Spec":
         return dataclasses.replace(self, unique=True)
+
+
+def lossy_value_cast(spec: "Spec | None", batch) -> "str | None":
+    """Why ``batch``'s values cannot be stored at ``spec``'s dtype, or
+    None. The host boundary casts a batch's values to the source spec's
+    dtype when it pads them into a device slot; real-valued rows sent to
+    an integer source would be truncated there without a word (a float32
+    unit vector sent to an int8 embedding source becomes zeros), so
+    admission and ``push`` refuse them. Device-resident batches and
+    host-only (object) values are not looked at."""
+    if spec is None or hasattr(batch, "nonzero"):
+        return None
+    want = np.dtype(spec.value_dtype)
+    have = getattr(batch.values, "dtype", None)
+    if have is None or want.kind not in "iu" or have.kind in "iubO":
+        return None
+    return (f"values of dtype {have} sent to a source whose spec stores "
+            f"{want}: the cast would truncate them (encode the rows for "
+            f"the source first)")
 
 
 class DeltaBatch:

@@ -824,6 +824,15 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 
 # -- KnnIndex (SURVEY.md §2 item 14: vmapped cosine + Pallas top-k) --------
 
+#: op kind -> what its nodes count on the device, in the order of their
+#: ``counters`` state leaf (int32 each). A KnnIndex: ticks that rescanned
+#: the corpus, ticks that took the incremental merge, delta rows folded
+#: into its two tables (wraps after 2^31 rows). They ride in the state,
+#: so they cost no program output and no host sync; the executor reads
+#: them when a snapshot is taken (``TpuExecutor.op_counters``).
+OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded")}
+
+
 def knn_state(op, q_spec: Spec, d_spec: Spec) -> dict:
     Q, D = q_spec.key_space, d_spec.key_space
     dim, k = op.dim, op.k
@@ -838,6 +847,7 @@ def knn_state(op, q_spec: Spec, d_spec: Spec) -> dict:
         "dlive": jnp.zeros((D,), jnp.bool_),
         "emitted": jnp.zeros((Q, k, 2), jnp.float32),
         "em_has": jnp.zeros((Q,), jnp.bool_),
+        "counters": jnp.zeros((len(OP_COUNTERS["knn"]),), jnp.int32),
     }
 
 
@@ -846,13 +856,31 @@ def _norm_rows(v):
     return jnp.where(n > 0, v / jnp.maximum(n, 1e-30), 0.0)
 
 
+def _last_rows(delta, cap: int):
+    """Row order within one tick's delta, resolved: per key the LAST
+    non-padding row wins (the host oracle applies rows in order, and a
+    merged feed can hold insert-then-delete or two updates of one id).
+    Returns the (insert, retract) masks of the winning rows; every key
+    has at most one winner, so scatters by them never meet a duplicate.
+    """
+    row = jnp.arange(delta.keys.shape[0], dtype=jnp.int32)
+    real = delta.weights != 0
+    slot = jnp.where(real, delta.keys, cap)
+    last = jnp.full((cap + 1,), -1, jnp.int32).at[slot].max(row)
+    wins = real & (last[slot] == row)
+    return wins & (delta.weights > 0), wins & (delta.weights < 0)
+
+
 def _fold_vectors(vec, live, delta):
-    """Retract-then-insert fold of vector deltas into a dense table (an
-    in-tick update = retract + insert resolves to the insert)."""
-    C = delta.capacity
+    """Fold one tick's vector deltas into a dense table in row order:
+    per id the last row decides both the vector and liveness
+    (insert-then-delete ends dead, delete-then-insert ends live with the
+    new vector, two inserts keep the second). Returns the table, the
+    live mask and the masks of the rows that won."""
     cap = vec.shape[0]
-    ins = jnp.where(delta.weights > 0, delta.keys, cap)
-    ret = jnp.where(delta.weights < 0, delta.keys, cap)
+    ins_m, ret_m = _last_rows(delta, cap)
+    ins = jnp.where(ins_m, delta.keys, cap)
+    ret = jnp.where(ret_m, delta.keys, cap)
     if vec.dtype == jnp.int8:
         # int8 tables receive PRE-normalized, pre-quantized rows
         # (workloads/knn.quantize_int8): store raw — renormalizing a
@@ -865,57 +893,30 @@ def _fold_vectors(vec, live, delta):
         vals = _norm_rows(jnp.asarray(delta.values, jnp.float32))
         vec = vec.at[ins].set(jnp.asarray(vals, vec.dtype), mode="drop")
     live = live.at[ret].set(False, mode="drop").at[ins].set(True, mode="drop")
-    return vec, live
+    return vec, live, ins_m, ret_m
 
 
-def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
-    from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
-                                         score_form, topk)
+def _knn_incremental(qvec, dvec, emitted, em_has, di, d_ins, k, prec,
+                     score_of=None):
+    """Incremental merge: the emitted top-k rows stay valid (nothing
+    they name was retracted or updated), so merge them with the scores
+    of just this tick's inserted docs ``di`` (``d_ins``: the rows that
+    won). ``score_of`` lets the sharded lowering score from its slice."""
+    from reflow_tpu.kernels.topk import NEG, score_form, topk
 
-    dq, dd = ins
-    if dq is None:
-        dq = DeviceDelta.empty(node.inputs[0].spec)
-    if dd is None:
-        dd = DeviceDelta.empty(node.inputs[1].spec)
-    Q = node.inputs[0].spec.key_space
-    D = node.inputs[1].spec.key_space
-    k = op.k
-
-    # an insert whose doc id is ALREADY live is an in-place update: its
-    # stale score may sit in a query's emitted top-k, and the
-    # incremental merge would keep treating it as a valid candidate —
-    # updates therefore rescan, exactly like retractions (checked
-    # against the PRE-fold live mask; padding rows have weight 0)
-    doc_update = jnp.any((dd.weights > 0) & state["dlive"][dd.keys])
-
-    qvec, qlive = _fold_vectors(state["qvec"], state["qlive"], dq)
-    dvec, dlive = _fold_vectors(state["dvec"], state["dlive"], dd)
-    emitted, em_has = state["emitted"], state["em_has"]
-    prec = (jax.lax.Precision.HIGHEST if op.precision == "highest"
-            else jax.lax.Precision.DEFAULT)
-
-    # fresh doc-insert and query-retract ticks take the incremental
-    # merge (a retracted query just stops emitting); query
-    # inserts/updates, doc retractions and doc UPDATES rescan the
-    # corpus (chunked, MXU)
-    need_full = (jnp.any(dd.weights < 0) | jnp.any(dq.weights > 0)
-                 | doc_update)
-
-    def full_path(_):
-        return chunked_corpus_topk(qvec, dvec, dlive, k, op.scan_chunk,
-                                   precision=prec)
-
-    def incr_path(_):
-        # current top-k rows stay valid (no retractions): merge them with
-        # scores against just the delta docs
-        em_ids = emitted[:, :, 0].astype(jnp.int32)            # [Q, k]
-        em_vals = jnp.where(em_has[:, None] & (em_ids >= 0),
-                            emitted[:, :, 1], NEG)
-        di = dd.keys                                           # [Cd]
-        s_new = jnp.dot(score_form(qvec), score_form(dvec[di]).T,
-                        preferred_element_type=jnp.float32,
-                        precision=prec)                        # [Q, Cd]
-        s_new = jnp.where((dd.weights > 0)[None, :], s_new, NEG)
+    Q = qvec.shape[0]
+    em_ids = emitted[:, :, 0].astype(jnp.int32)                # [Q, k]
+    em_vals = jnp.where(em_has[:, None] & (em_ids >= 0),
+                        emitted[:, :, 1], NEG)
+    with jax.named_scope("knn.score"):
+        if score_of is not None:
+            s_new = score_of(di, d_ins)
+        else:
+            s_new = jnp.dot(score_form(qvec), score_form(dvec[di]).T,
+                            preferred_element_type=jnp.float32,
+                            precision=prec)                    # [Q, Cd]
+            s_new = jnp.where(d_ins[None, :], s_new, NEG)
+    with jax.named_scope("knn.topk"):
         cand_vals = jnp.concatenate([em_vals, s_new], axis=1)
         cand_ids = jnp.concatenate(
             [em_ids, jnp.broadcast_to(di, (Q, di.shape[0]))], axis=1)
@@ -926,7 +927,58 @@ def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
         cand_vals = jnp.take_along_axis(cand_vals, order, axis=1)
         vals, sel = topk(cand_vals, k)
         ids = jnp.take_along_axis(cand_ids, sel, axis=1)
-        return vals, ids
+    return vals, ids
+
+
+def _knn_count(counters, need_full, *masks):
+    """The node's device counters after this tick (``OP_COUNTERS``)."""
+    rows = sum(jnp.sum(m.astype(jnp.int32)) for m in masks)
+    full = need_full.astype(jnp.int32)
+    return counters + jnp.stack([full, 1 - full, rows])
+
+
+def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
+    from reflow_tpu.kernels.topk import NEG, chunked_corpus_topk
+
+    dq, dd = ins
+    if dq is None:
+        dq = DeviceDelta.empty(node.inputs[0].spec)
+    if dd is None:
+        dd = DeviceDelta.empty(node.inputs[1].spec)
+    Q = node.inputs[0].spec.key_space
+    k = op.k
+
+    with jax.named_scope("knn.fold"):
+        qvec, qlive, q_ins, q_ret = _fold_vectors(
+            state["qvec"], state["qlive"], dq)
+        dvec, dlive, d_ins, d_ret = _fold_vectors(
+            state["dvec"], state["dlive"], dd)
+        # what the winning doc rows do to ids that were live BEFORE the
+        # fold: an insert is an in-place update (its stale score may sit
+        # in a query's emitted top-k, and the incremental merge would
+        # keep treating it as a valid candidate), a retraction takes a
+        # candidate away. Either rescans; a row that only touches a
+        # fresh id (insert, or insert-then-delete within the tick) does
+        # not. Padding rows have weight 0 and never win.
+        was_live = state["dlive"][dd.keys]
+        doc_change = jnp.any((d_ins | d_ret) & was_live)
+    emitted, em_has = state["emitted"], state["em_has"]
+    prec = (jax.lax.Precision.HIGHEST if op.precision == "highest"
+            else jax.lax.Precision.DEFAULT)
+
+    # fresh doc-insert and query-retract ticks take the incremental
+    # merge (a retracted query just stops emitting); query
+    # inserts/updates, doc retractions and doc UPDATES rescan the
+    # corpus (chunked, MXU)
+    need_full = jnp.any(q_ins) | doc_change
+
+    def full_path(_):
+        return chunked_corpus_topk(qvec, dvec, dlive, k, op.scan_chunk,
+                                   precision=prec)
+
+    def incr_path(_):
+        return _knn_incremental(qvec, dvec, emitted, em_has, dd.keys,
+                                d_ins, k, prec)
 
     vals, ids = jax.lax.cond(need_full, full_path, incr_path, None)
     ids = jnp.where(vals <= NEG, -1, ids)
@@ -945,7 +997,9 @@ def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
     new_emitted = jnp.where(ins_m[:, None, None], new_row, emitted)
     new_has = jnp.where(ins_m, True, jnp.where(ret_m & ~qlive, False, em_has))
     return out, {"qvec": qvec, "qlive": qlive, "dvec": dvec, "dlive": dlive,
-                 "emitted": new_emitted, "em_has": new_has}
+                 "emitted": new_emitted, "em_has": new_has,
+                 "counters": _knn_count(state["counters"], need_full,
+                                        q_ins, q_ret, d_ins, d_ret)}
 
 
 # -- dispatch --------------------------------------------------------------

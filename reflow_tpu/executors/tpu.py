@@ -28,6 +28,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
 
 from reflow_tpu.delta import DeltaBatch
 from reflow_tpu.executors.base import Executor
@@ -81,8 +82,8 @@ def _dispatch_notes(K: int, window: bool, traced: bool):
         yield
 
 
-def _completion_token(states):
-    """One scalar OUTPUT of a loop-free window program that nobody
+def _completion_token(states, counter_ids=()):
+    """One small OUTPUT of a loop-free window program that nobody
     donates, for the device watcher to wait on: every other output of
     that program is state (donated to the next window) or the fresh
     ingress stack (re-adopted by the queue and donated by a later
@@ -92,14 +93,24 @@ def _completion_token(states):
     program a *traced* dispatch builds has it: with the token in every
     program the untraced paced TF-IDF cell read p50 97-102 ms for the
     parent's 93-96 on the v5e (PERF.md, PR 24), so an untraced leader
-    runs the program it always ran."""
+    runs the program it always ran.
+
+    A graph whose operators count on the device (``counter_ids``: the
+    nodes with a ``counters`` state leaf) gets the same one output as an
+    int32 vector, the scalar's bits followed by those counters: the
+    watcher, which waits on the token anyway, then reads what each
+    window left them at, for no further output and no dispatch."""
     import jax.numpy as jnp
 
     tok = jnp.zeros((), jnp.float32)
     for x in jax.tree.leaves(states):
         if getattr(x, "size", 0):
             tok = tok + x[(0,) * x.ndim].astype(jnp.float32)
-    return tok
+    if not counter_ids:
+        return tok
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(tok, jnp.int32)[None]]
+        + [states[nid]["counters"] for nid in counter_ids])
 
 
 class _DeviceWatch:
@@ -153,6 +164,8 @@ class _DeviceWatch:
             prev_done = done
             ex.device_busy_s += done - start
             ex.windows_done += 1
+            counters = (ex._note_counters(np.asarray(token)[1:])
+                        if token.ndim else None)
             # the device may start while the launch call is still
             # returning (on a busy host that takes milliseconds), so
             # the span is a lower bound of the window's device time
@@ -163,6 +176,10 @@ class _DeviceWatch:
                     "launch_s": t_launch - t_launch0}
             if win is not None:
                 args["win"] = win
+            if counters:
+                # cumulative since bind, as this window left them
+                args["counters"] = {
+                    name: list(c.values()) for name, c in counters.items()}
             _trace.evt("window_device", start, done - start,
                        track=self.track, args=args)
             self.seen += 1
@@ -324,6 +341,9 @@ class TpuExecutor(Executor):
         self.windows_done = 0
         self._watch: Optional[_DeviceWatch] = None
         self.device_watch_error: Optional[BaseException] = None
+        #: node name -> {counter: value}: the last reading of each
+        #: operator's device-resident counters (``op_counters``)
+        self._op_counters: Dict[str, Dict[str, int]] = {}
 
     #: subclasses whose traced programs close over executor-specific
     #: context (e.g. the sharded executor's mesh/axis in ``_lower``) must
@@ -362,6 +382,44 @@ class TpuExecutor(Executor):
         nothing was)."""
         if self._watch is not None:
             self._watch.drain()
+
+    def _counting(self) -> List[Tuple[Node, Tuple[str, ...]]]:
+        """The bound graph's nodes whose lowering keeps counters in its
+        device state (a ``counters`` leaf), each with their names."""
+        from reflow_tpu.executors.lowerings import OP_COUNTERS
+
+        return [(n, OP_COUNTERS[n.op.kind])
+                for n in (self.graph.nodes if self.graph else ())
+                if n.kind == "op" and n.op.kind in OP_COUNTERS]
+
+    def counter_names(self) -> Dict[str, Tuple[str, ...]]:
+        """Node name -> the counters that node keeps on the device."""
+        return {n.name: names for n, names in self._counting()}
+
+    def _note_counters(self, flat) -> Dict[str, Dict[str, int]]:
+        """Keep one reading of every counting node's counters, given
+        flat in node order, and return it by node name."""
+        at, out = 0, {}
+        for n, names in self._counting():
+            out[n.name] = {c: int(v) for c, v in
+                           zip(names, flat[at:at + len(names)])}
+            at += len(names)
+        self._op_counters = out
+        return out
+
+    def op_counters(self) -> Dict[str, Dict[str, int]]:
+        """What the operators counted on the device, by node name
+        (``lowerings.OP_COUNTERS``; cumulative since bind). This is a
+        device read: it waits for the last dispatched window, so call it
+        where a snapshot is taken, not per tick. From another thread
+        than the dispatching one the state it reads may already be
+        donated to the next window; the last reading is returned then."""
+        try:
+            flat = [v for n, _ in self._counting()
+                    for v in np.asarray(self.states[n.id]["counters"])]
+        except (RuntimeError, KeyError):  # donated under us, or rebinding
+            return self._op_counters
+        return self._note_counters(flat)
 
     def close(self) -> None:
         """Stop the device watcher, if tracing ever started one. The
@@ -892,6 +950,7 @@ class TpuExecutor(Executor):
                 else:
                     pass_fn = self.build_pass_fn(list(plan))
                     with_token = tr
+                    counter_ids = tuple(n.id for n, _ in self._counting())
 
                     def scan_fn(op_states, ing_stack):
                         def body(states, ing):
@@ -910,7 +969,8 @@ class TpuExecutor(Executor):
                         out = (states,
                                jax.tree.map(jnp.zeros_like, ing_stack))
                         if with_token:
-                            out += (_completion_token(states),)
+                            out += (_completion_token(states,
+                                                      counter_ids),)
                         return out
 
                     prog = jax.jit(scan_fn, donate_argnums=(0, 1))

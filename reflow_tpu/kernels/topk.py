@@ -22,7 +22,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["topk", "chunked_corpus_topk", "NEG"]
+__all__ = ["topk", "chunked_corpus_topk", "NEG", "KERNEL_NAME"]
+
+#: the Pallas kernel's fixed name: a device trace lists every call of it
+#: under this one operation name, whatever program it was compiled into
+KERNEL_NAME = "reflow_topk"
 
 
 #: sentinel for "no candidate" — finite so arithmetic/compares stay clean
@@ -70,6 +74,7 @@ def _topk_pallas(scores: jax.Array, k: int,
             jax.ShapeDtypeStruct((q, k), jnp.int32),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(scores)
     return vals, idx
 
@@ -130,17 +135,19 @@ def chunked_corpus_topk(qvec: jax.Array, dvec: jax.Array, dlive: jax.Array,
         lo = c * chunk
         blk = jax.lax.dynamic_slice_in_dim(dvec, lo, chunk, 0)
         live = jax.lax.dynamic_slice_in_dim(dlive, lo, chunk, 0)
-        s = jnp.dot(score_form(qvec), score_form(blk).T,
-                    preferred_element_type=jnp.float32,
-                    precision=precision)
-        s = jnp.where(live[None, :], s, NEG)
-        cand_vals = jnp.concatenate([vals, s], axis=1)
-        cand_ids = jnp.concatenate(
-            [ids, jnp.broadcast_to(
-                lo + jnp.arange(chunk, dtype=jnp.int32), (q, chunk))],
-            axis=1)
-        vals, sel = topk(cand_vals, k, use_pallas)
-        ids = jnp.take_along_axis(cand_ids, sel, axis=1)
+        with jax.named_scope("knn.score"):
+            s = jnp.dot(score_form(qvec), score_form(blk).T,
+                        preferred_element_type=jnp.float32,
+                        precision=precision)
+            s = jnp.where(live[None, :], s, NEG)
+        with jax.named_scope("knn.topk"):
+            cand_vals = jnp.concatenate([vals, s], axis=1)
+            cand_ids = jnp.concatenate(
+                [ids, jnp.broadcast_to(
+                    lo + jnp.arange(chunk, dtype=jnp.int32), (q, chunk))],
+                axis=1)
+            vals, sel = topk(cand_vals, k, use_pallas)
+            ids = jnp.take_along_axis(cand_ids, sel, axis=1)
         return vals, ids
 
     init = (jnp.full((q, k), NEG, jnp.float32),

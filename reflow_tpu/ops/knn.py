@@ -9,8 +9,14 @@ its exact host semantics here and a device lowering in
 Semantics
 ---------
 Inputs: port 0 = query deltas {qid: vec}, port 1 = corpus deltas
-{did: vec}; weights +-1 insert/retract (an update is retract + insert —
-re-inserting a live id without retracting it first is undefined).
+{did: vec}; weights +-1 insert/retract. Rows apply IN ORDER, within a
+tick as across ticks, and per id the last row wins, for the vector and
+for liveness alike: an update is retract + insert, or just an insert of
+the live id (the new vector replaces the old); insert-then-delete ends
+dead; delete-then-insert ends live with the new vector. The serve
+frontend merges queued micro-batches of one source into one tick's
+delta, so all of these reach one tick; ``apply`` below is the
+specification and the device lowering resolves rows the same way.
 Maintains, per live query, the top-k corpus ids by cosine similarity.
 Emits Reduce-style retract-old/insert-new rows keyed by query id; the
 value is a ``[k, 2]`` float32 array of (doc_id, score) rows, padded with

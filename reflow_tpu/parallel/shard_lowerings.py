@@ -365,7 +365,10 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
     its slice, and the merged result is identical everywhere). Emission is
     partitioned by query range so the egress delta stays row-sharded.
     """
-    from reflow_tpu.executors.lowerings import _fold_vectors, _norm_rows
+    from reflow_tpu.executors.lowerings import (_fold_vectors,
+                                                _knn_count,
+                                                _knn_incremental,
+                                                _last_rows)
     from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
                                          score_form, topk)
 
@@ -387,15 +390,24 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
     gd = jax.tree.map(lambda x: jax.lax.all_gather(x, axis, tiled=True), dd)
     gd_l = _localize(gd, base_d, Dl)
 
-    qvec, qlive = _fold_vectors(state["qvec"], state["qlive"], gq)
-    dvec, dlive = _fold_vectors(state["dvec"], state["dlive"], gd_l)
+    with jax.named_scope("knn.fold"):
+        qvec, qlive, q_ins, q_ret = _fold_vectors(
+            state["qvec"], state["qlive"], gq)
+        dvec, dlive, l_ins, l_ret = _fold_vectors(
+            state["dvec"], state["dlive"], gd_l)
+        # the winning rows of the whole gathered delta, the same on every
+        # shard; what they do to ids live before the fold is known only
+        # to the owner, so that is folded with one pmax
+        d_ins, d_ret = _last_rows(gd, D)
+        hit = jnp.any((l_ins | l_ret) & state["dlive"][gd_l.keys])
+        doc_change = jax.lax.pmax(hit.astype(jnp.int32), axis) > 0
     emitted, em_has = state["emitted"], state["em_has"]
     prec = (jax.lax.Precision.HIGHEST if op.precision == "highest"
             else jax.lax.Precision.DEFAULT)
 
-    # uniform across shards (computed from the gathered deltas), so every
+    # uniform across shards (the gathered deltas and one pmax), so every
     # device takes the same lax.cond branch and collectives line up
-    need_full = jnp.any(gd.weights < 0) | jnp.any(gq.weights > 0)
+    need_full = jnp.any(q_ins) | doc_change
 
     def _merge2(av, ai, bv, bi):
         """Merge two [Q, k] candidate sets; ties break to the lowest id.
@@ -428,29 +440,21 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
             acc_v, acc_i = _merge2(acc_v, acc_i, cur_v, cur_i)
         return acc_v, acc_i
 
-    def incr_path(_):
-        em_ids = emitted[:, :, 0].astype(jnp.int32)
-        em_vals = jnp.where(em_has[:, None] & (em_ids >= 0),
-                            emitted[:, :, 1], NEG)
+    def _score_owned(di, won):
         # per-entry scores from the OWNED folded vectors (exactly the
         # single-device dvec[di] semantics), combined with one pmax —
         # non-owned entries contribute NEG
-        di = gd.keys
         own = (di >= base_d) & (di < base_d + Dl)
         di_l = jnp.where(own, di - base_d, 0)
         s_loc = jnp.dot(score_form(qvec), score_form(dvec[di_l]).T,
                         preferred_element_type=jnp.float32,
                         precision=prec)                        # [Q, Cd]
-        s_loc = jnp.where((own & (gd.weights > 0))[None, :], s_loc, NEG)
-        s_new = jax.lax.pmax(s_loc, axis)
-        cand_vals = jnp.concatenate([em_vals, s_new], axis=1)
-        cand_ids = jnp.concatenate(
-            [em_ids, jnp.broadcast_to(di, (Q, di.shape[0]))], axis=1)
-        order = jnp.argsort(cand_ids, axis=1, stable=True)
-        cand_ids = jnp.take_along_axis(cand_ids, order, axis=1)
-        cand_vals = jnp.take_along_axis(cand_vals, order, axis=1)
-        vals, sel = topk(cand_vals, k)
-        return vals, jnp.take_along_axis(cand_ids, sel, axis=1)
+        s_loc = jnp.where((own & won)[None, :], s_loc, NEG)
+        return jax.lax.pmax(s_loc, axis)
+
+    def incr_path(_):
+        return _knn_incremental(qvec, dvec, emitted, em_has, gd.keys,
+                                d_ins, k, prec, score_of=_score_owned)
 
     vals, ids = jax.lax.cond(need_full, full_path, incr_path, None)
     ids = jnp.where(vals <= NEG, -1, ids)
@@ -472,14 +476,16 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
     new_emitted = jnp.where(ins_m[:, None, None], new_row, emitted)
     new_has = jnp.where(ins_m, True, jnp.where(ret_m & ~qlive, False, em_has))
     return out, {"qvec": qvec, "qlive": qlive, "dvec": dvec, "dlive": dlive,
-                 "emitted": new_emitted, "em_has": new_has}
+                 "emitted": new_emitted, "em_has": new_has,
+                 "counters": _knn_count(state["counters"], need_full,
+                                        q_ins, q_ret, d_ins, d_ret)}
 
 
 #: per-leaf shard_map specs for the knn state: corpus sharded, queries +
 #: emitted table replicated (consumed by ShardedTpuExecutor)
 def knn_state_specs(axis: str):
     return {"qvec": None, "qlive": None, "dvec": axis, "dlive": axis,
-            "emitted": None, "em_has": None}
+            "emitted": None, "em_has": None, "counters": None}
 
 
 def lower_node_sharded(node: Node, state, ins: Sequence[DeviceDelta],

@@ -24,11 +24,12 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from reflow_tpu.delta import DeltaBatch
+from reflow_tpu.delta import DeltaBatch, lossy_value_cast
 from reflow_tpu.executors import CpuExecutor, Executor
 from reflow_tpu.utils.config import env_float
 from reflow_tpu.graph import FlowGraph, GraphError, Node
 from reflow_tpu.obs import trace as _trace
+from reflow_tpu.utils.faults import DeliveryError
 
 __all__ = ["DirtyScheduler", "TickResult"]
 
@@ -245,6 +246,11 @@ class DirtyScheduler:
         """
         if source.kind not in ("source", "loop"):
             raise GraphError(f"can only push to sources/loops, not {source}")
+        why = lossy_value_cast(source.spec, batch)
+        if why is not None:
+            # refused before the id is registered: a corrected batch may
+            # come again under the same id
+            raise DeliveryError(f"{source}: {why}")
         if batch_id is not None and not self._register_batch_id(batch_id):
             return False
         # device-resident batches are enqueued unconditionally: their
@@ -739,6 +745,16 @@ class DirtyScheduler:
                   lambda: getattr(self.executor, "device_busy_s", 0.0))
         reg.gauge(f"{key}.windows_done",
                   lambda: getattr(self.executor, "windows_done", 0))
+        # what the operators counted on the device (a KnnIndex: ticks
+        # that rescanned, ticks that merged incrementally, rows folded),
+        # read from the device when a snapshot is taken
+        read = getattr(self.executor, "op_counters", None)
+        for node, names in (getattr(self.executor, "counter_names", dict)()
+                            .items()):
+            for c in names:
+                reg.gauge(f"{key}.{node}.{c}",
+                          lambda node=node, c=c: read().get(node, {})
+                          .get(c, 0))
         self._metric_keys.append((reg, key))
         return key
 

@@ -74,6 +74,7 @@ import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
+from reflow_tpu.delta import lossy_value_cast
 from reflow_tpu.graph import GraphError, Node
 from reflow_tpu.obs import trace as _trace
 from reflow_tpu.scheduler import SourceCursor
@@ -375,6 +376,15 @@ class IngestFrontend:
                 ticket._resolve(TicketResult(APPLIED, batch_id,
                                              reason="empty batch"))
                 self._trace_submit(ticket, "empty")
+                return ticket
+            why = lossy_value_cast(source.spec, batch)
+            if why is not None:
+                # the pump would cast the rows to the spec's dtype and
+                # fold garbage: the producer is told instead
+                self.rejected += 1
+                ticket._resolve(TicketResult(REJECTED, batch_id,
+                                             reason=why))
+                self._trace_submit(ticket, "rejected")
                 return ticket
             nbytes = self._charge_bytes(source, batch, device)
             if not self._admit(source, nbytes, ticket, batch_id, deadline):

@@ -52,7 +52,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence
 
-from reflow_tpu.delta import DeltaBatch
+from reflow_tpu.delta import DeltaBatch, lossy_value_cast
 from reflow_tpu.graph import Node
 from reflow_tpu.scheduler import DirtyScheduler, TickResult
 from reflow_tpu.wal.log import WriteAheadLog
@@ -196,7 +196,8 @@ class DurableScheduler(DirtyScheduler):
              batch_id: Optional[str] = None) -> bool:
         if self._wal_suspended:
             return super().push(source, batch, batch_id=batch_id)
-        if source.kind not in ("source", "loop"):
+        if (source.kind not in ("source", "loop")
+                or lossy_value_cast(source.spec, batch) is not None):
             # fail before logging what the base scheduler would reject
             return super().push(source, batch, batch_id=batch_id)
         if batch_id is None:

@@ -84,8 +84,10 @@ class EmbeddingStore:
     def insert_batch(self, ids: np.ndarray, *,
                      quantize: bool = False) -> DeltaBatch:
         """``quantize=True`` sends int8-encoded rows (1 byte/dim wire
-        cost — pair with ``build_graph(doc_dtype=jnp.int8)``); the host
-        mirror keeps the raw f32 vectors for the oracle either way."""
+        cost — what ``build_graph(doc_dtype=jnp.int8)`` needs: an int8
+        source refuses float rows at the host boundary rather than
+        truncating them to zeros); the host mirror keeps the raw f32
+        vectors for the oracle either way."""
         vals = self._random(len(ids))
         for i, v in zip(ids, vals):
             self.vecs[int(i)] = v
@@ -93,9 +95,14 @@ class EmbeddingStore:
         return DeltaBatch(np.asarray(ids, np.int64), wire,
                           np.ones(len(ids), np.int64))
 
-    def retract_batch(self, ids: np.ndarray) -> DeltaBatch:
+    def retract_batch(self, ids: np.ndarray, *,
+                      quantize: bool = False) -> DeltaBatch:
+        """Retraction rows in the wire dtype asked for, as
+        :meth:`insert_batch` sends them: ``quantize=True`` for an int8
+        source (which refuses float rows at the host boundary)."""
         vals = np.stack([self.vecs.pop(int(i)) for i in ids])
-        return DeltaBatch(np.asarray(ids, np.int64), vals,
+        wire = quantize_int8(vals) if quantize else vals
+        return DeltaBatch(np.asarray(ids, np.int64), wire,
                           -np.ones(len(ids), np.int64))
 
     def reference_topk(self, queries: np.ndarray, k: int

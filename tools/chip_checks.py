@@ -214,7 +214,7 @@ def _knn_ring(cfg: dict, mesh) -> dict:
     inserts = [store.insert_batch(np.arange(i * chunk, (i + 1) * chunk),
                                   quantize=True) for i in range(2 * n)]
     ret = np.arange(0, D, 7, dtype=np.int64)
-    retract = DeltaBatch(ret, np.zeros((len(ret), dim), np.float32),
+    retract = DeltaBatch(ret, np.zeros((len(ret), dim), np.int8),
                          -np.ones(len(ret), np.int64))
 
     def run(ex):
