@@ -298,7 +298,7 @@ def knn_phase(cfg: dict, dev) -> dict:
     from reflow_tpu.delta import DeltaBatch
     from reflow_tpu.executors import get_executor
     from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
-                                         score_form, topk)
+                                         fold_topk, score_form, topk)
     from reflow_tpu.scheduler import DirtyScheduler
     from reflow_tpu.workloads import knn
 
@@ -331,8 +331,9 @@ def knn_phase(cfg: dict, dev) -> dict:
     sched.tick()
     insert_s = time.perf_counter() - t0
     # ... and one retraction tick: forces the chunked full-corpus rescan,
-    # i.e. the kernel at [Q, k + chunk]. A device retraction only clears
-    # the id's live bit, so zero rows stand in for the device-made vectors.
+    # i.e. the fold kernel at [Q, k] + [Q, chunk]. A device retraction
+    # only clears the id's live bit, so zero rows stand in for the
+    # device-made vectors.
     t0 = time.perf_counter()
     ret_ids = np.arange(c["retract_rows"], dtype=np.int64)
     sched.push(kg.docs, DeltaBatch(
@@ -366,33 +367,62 @@ def knn_phase(cfg: dict, dev) -> dict:
             and np.array_equal(got_vals, np.asarray(ref_vals)),
             "served top-k table != lax.top_k rescan of the same state")
 
-    # the kernel alone, on literally the same scores: one [Q, k + chunk]
-    # candidate matrix as the rescan builds it, Pallas vs lax.top_k
+    # the kernels alone, on literally the same scores. The generic entry:
+    # one [Q, k + chunk] candidate matrix, Pallas vs lax.top_k
     @jax.jit
-    def scores(q, d, l):
-        s = jnp.dot(score_form(q), score_form(d[:chunk]).T,
+    def chunk_scores(q, d, l, at):
+        blk = jax.lax.dynamic_slice_in_dim(d, at, chunk, 0)
+        s = jnp.dot(score_form(q), score_form(blk).T,
                     preferred_element_type=jnp.float32, precision=prec)
-        s = jnp.where(l[None, :chunk], s, NEG)
-        return jnp.concatenate([jnp.full((Q, k), NEG, jnp.float32), s], 1)
+        return jnp.where(
+            jax.lax.dynamic_slice_in_dim(l, at, chunk, 0)[None, :], s, NEG)
 
-    s = scores(st["qvec"], st["dvec"], st["dlive"])
+    def scores_at(at):
+        return chunk_scores(st["qvec"], st["dvec"], st["dlive"], at)
+
+    no_vals = jnp.full((Q, k), NEG, jnp.float32)
+    s = jnp.concatenate([no_vals, scores_at(0)], 1)
     pv, pi = jax.jit(lambda x: topk(x, k, use_pallas=True))(s)
     lv, li = jax.lax.top_k(s, k)
     require(np.array_equal(np.asarray(pi), np.asarray(li))
             and np.array_equal(np.asarray(pv), np.asarray(lv)),
             f"Pallas top-k != lax.top_k on the same {s.shape} scores")
+
+    # the rescan's fold step, mid-scan: the carry [Q, k] the chunk before
+    # `lo` leaves and the chunk at `lo` [Q, chunk], as the scan hands
+    # them over — the fold kernel vs its XLA body vs one candidate matrix
+    # with an id block beside it (lax.top_k for columns, a gather for ids)
+    lo = D // chunk // 2 * chunk
+    fold = jax.jit(fold_topk, static_argnums=(4, 5))
+    carry = fold(no_vals, jnp.full((Q, k), -1, jnp.int32),
+                 scores_at(lo - chunk), jnp.int32(lo - chunk), k, False)
+    sc = scores_at(lo)
+    fv, fi = fold(*carry, sc, jnp.int32(lo), k, True)
+    xv, xi = fold(*carry, sc, jnp.int32(lo), k, False)
+    bv, sel = jax.lax.top_k(jnp.concatenate([carry[0], sc], 1), k)
+    bi = jnp.take_along_axis(jnp.concatenate(
+        [carry[1], jnp.broadcast_to(
+            lo + jnp.arange(chunk, dtype=jnp.int32), (Q, chunk))], 1),
+        sel, axis=1)
+    for what, v, i in (("its XLA body", xv, xi), ("the id block", bv, bi)):
+        require(np.array_equal(np.asarray(fi), np.asarray(i))
+                and np.array_equal(np.asarray(fv), np.asarray(v)),
+                f"fold kernel != {what} on the same carry {carry[0].shape}"
+                f" + chunk {sc.shape} at lo {lo}")
     pallas_in_tick = jax.default_backend() == "tpu"
     say(f"knn Q {Q} dim {dim} k {k} chunk {chunk}, {live} live of {D} "
         f"slots (cut: {D - next_id - c['insert_rows']} slots left empty): "
         f"preload {preload_s:.2f}s, insert tick {insert_s:.3f}s, "
         f"retraction rescan {rescan_s:.3f}s; Pallas kernel "
         f"{'compiled' if pallas_in_tick else 'interpreted'} at {s.shape} "
-        f"== lax.top_k; served table == lax.top_k rescan")
+        f"== lax.top_k; fold kernel at {carry[0].shape} + {sc.shape}, lo "
+        f"{lo} == XLA body == id block; served table == lax.top_k rescan")
     return {
         "Q": Q, "dim": dim, "k": k, "scan_chunk": chunk,
         "corpus_slots": D, "corpus_live": live,
         "pallas_compiled": pallas_in_tick,
         "kernel_scores_shape": list(s.shape),
+        "fold_kernel_shapes": [list(carry[0].shape), list(sc.shape)],
         "preload_s": round(preload_s, 3),
         "insert_tick_s": round(insert_s, 4),
         "rescan_tick_s": round(rescan_s, 4),

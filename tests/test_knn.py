@@ -265,3 +265,145 @@ def test_pallas_topk_interpreted_equals_lax_top_k(q, n, k):
     ref_vals, ref_ids = jax.lax.top_k(s, k)
     assert np.array_equal(np.asarray(ids), np.asarray(ref_ids))
     assert np.array_equal(np.asarray(vals), np.asarray(ref_vals))
+
+
+# -- the rescan's fold step (kernels.topk.fold_topk) ------------------------
+
+def _fold_oracle(vals, ids, s, lo, k):
+    """The formulation ``chunked_corpus_topk`` had before the fold step
+    was carry-aware, kept as the oracle: one candidate matrix, an id
+    block beside it, ``lax.top_k`` for columns, a gather for ids."""
+    import jax
+    import jax.numpy as jnp
+
+    q, c = s.shape
+    cand_vals = jnp.concatenate([vals, s], axis=1)
+    cand_ids = jnp.concatenate(
+        [ids, jnp.broadcast_to(lo + jnp.arange(c, dtype=jnp.int32),
+                               (q, c))], axis=1)
+    vals, sel = jax.lax.top_k(cand_vals, k)
+    return vals, jnp.take_along_axis(cand_ids, sel, axis=1)
+
+
+def _fold_case(name, q, c, k, lo):
+    """``(vals, ids, s)``: a sorted carry and a score chunk at one
+    decimal (so equal scores abound everywhere), shaped by ``name``."""
+    from reflow_tpu.kernels.topk import NEG
+
+    rng = np.random.default_rng(sum(map(ord, name)) + c)
+    s = np.round(rng.normal(size=(q, c)), 1).astype(np.float32)
+    vals = -np.sort(-np.round(rng.normal(size=(q, k)), 1), axis=1)
+    vals = vals.astype(np.float32)
+    ids = rng.integers(0, max(lo, 1), size=(q, k)).astype(np.int32)
+    if name == "ties":
+        # the carry's scores again in the chunk, twice each (carry
+        # against chunk, and inside the chunk), and a run inside the carry
+        vals[:, 1] = vals[:, 0]
+        vals[:, 2:] = np.minimum(vals[:, 2:], vals[:, :1])
+        w = min(k, c // 2)
+        s[:, :w] = vals[:, :w]
+        s[:, c - w:] = vals[:, :w]
+    elif name == "first_step":
+        vals[:], ids[:] = NEG, -1
+    elif name == "dead_slots":
+        s[:, ::3] = NEG
+        s[1] = NEG                       # a query that sees nothing live
+        vals[:, k // 2:], ids[:, k // 2:] = NEG, -1
+    elif name == "chunk_below_carry":
+        vals += 100.0
+    else:
+        assert name == "plain"
+    return vals, ids, s
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel_interpreted", "xla_body"])
+@pytest.mark.parametrize("name,q,c,k,lo", [
+    ("ties", 256, 8192, 16, 8192 * 37),
+    ("plain", 256, 8192, 16, 0),
+    ("first_step", 16, 8192, 16, 0),
+    ("dead_slots", 16, 300, 4, 600),
+    ("ties", 16, 300, 4, 600),
+    ("chunk_below_carry", 16, 300, 4, 300),
+    ("first_step", 16, 300, 4, 0),
+    ("first_step", 12, 3, 8, 0),
+    ("dead_slots", 12, 3, 8, 9),
+    ("ties", 8, 6, 16, 60),
+])
+def test_fold_topk_equals_the_id_block_formulation(name, q, c, k, lo,
+                                                   use_pallas):
+    """``fold_topk`` — the Pallas kernel interpreted, and the XLA body —
+    against the id-block formulation on the same ``(vals, ids, s, lo)``:
+    values and ids exactly, every query, every rank. At the rescan's
+    shape ``[256, 16] + [256, 8192]``, at a ragged chunk (the kernel's
+    pad branch) and at a chunk narrower than k; ``lo`` traced, as the
+    scan passes it. The compiled kernel gets the same comparison on the
+    chip in ``chip_smoke.py``."""
+    import jax
+    import jax.numpy as jnp
+
+    from reflow_tpu.kernels.topk import fold_topk
+
+    vals, ids, s = _fold_case(name, q, c, k, lo)
+    got_v, got_i = jax.jit(
+        lambda v, i, x, at: fold_topk(v, i, x, at, k, use_pallas)
+    )(vals, ids, s, jnp.int32(lo))
+    ref_v, ref_i = _fold_oracle(jnp.asarray(vals), jnp.asarray(ids),
+                                jnp.asarray(s), lo, k)
+    assert np.array_equal(np.asarray(got_i), np.asarray(ref_i))
+    assert np.array_equal(np.asarray(got_v), np.asarray(ref_v))
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel_interpreted", "xla_body"])
+@pytest.mark.parametrize("d,chunk", [(1024, 128), (200, 8192)])
+def test_chunked_corpus_topk_equals_bruteforce_lowest_id(d, chunk,
+                                                         use_pallas):
+    """The whole rescan over several chunks (and over one ragged one)
+    against NumPy: all scores at once, ordered by (score descending, id
+    ascending). Quarter-integer vectors make the scores exact and equal
+    in droves; a fifth of the corpus is dead."""
+    import jax.numpy as jnp
+
+    from reflow_tpu.kernels.topk import NEG, chunked_corpus_topk
+
+    q, dim, k = 16, 8, 8
+    rng = np.random.default_rng(d)
+    qv = rng.integers(-2, 3, size=(q, dim)).astype(np.float32) / 4
+    dv = rng.integers(-2, 3, size=(d, dim)).astype(np.float32) / 4
+    live = rng.random(d) > 0.2
+    vals, ids = chunked_corpus_topk(jnp.asarray(qv), jnp.asarray(dv),
+                                    jnp.asarray(live), k, chunk,
+                                    use_pallas=use_pallas)
+    scores = np.where(live[None, :], qv @ dv.T, NEG)
+    order = np.lexsort((np.broadcast_to(np.arange(d), (q, d)), -scores),
+                       axis=1)[:, :k]
+    assert len(np.unique(scores[0])) < d // 4          # ties were planted
+    assert np.array_equal(np.asarray(ids), order)
+    assert np.array_equal(np.asarray(vals),
+                          np.take_along_axis(scores, order, axis=1))
+
+
+def test_rescan_lowers_without_id_block_or_gather():
+    """Structural, a count and never a speed: the rescan's program (XLA
+    body, CPU) holds no ``[Q, k + chunk]`` integer array — the id block
+    the fold step used to build beside the scores — and gathers from
+    nothing; the ids come from column arithmetic."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from reflow_tpu.kernels.topk import chunked_corpus_topk
+
+    q, d, dim, k, chunk = 16, 1024, 8, 4, 256
+    lowered = jax.jit(
+        lambda qv, dv, live: chunked_corpus_topk(qv, dv, live, k, chunk,
+                                                 use_pallas=False)
+    ).lower(jnp.zeros((q, dim)), jnp.zeros((d, dim)),
+            jnp.ones((d,), bool))
+    for text in (lowered.as_text(), lowered.compile().as_text()):
+        assert "while" in text                          # the scan is there
+        assert not re.search(r"\bgather\b", text)
+        assert f"{q}x{k + chunk}xi32" not in text       # StableHLO
+        assert f"s32[{q},{k + chunk}]" not in text      # HLO

@@ -1,0 +1,89 @@
+"""The top-k kernels compiled for the chip, without the chip: the TPU's
+compiler is installed here and compiles for a v5e that is described and
+not attached, so what Mosaic would refuse on the chip (a misaligned
+slice, too much VMEM, an operand it cannot place) fails here, at the
+cell's real widths. Nothing runs: no result, no time. The one file
+whose fixture loads the TPU's library (never at import)."""
+
+import importlib
+import re
+
+import pytest
+
+Q, K, CHUNK, D, DIM = 256, 16, 8192, 1 << 20, 768
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def kernels(monkeypatch):
+    """``kernels.topk`` taking its TPU branch (the compiled kernel) while
+    the backend here is the CPU."""
+    mod = importlib.import_module("reflow_tpu.kernels.topk")
+    monkeypatch.setattr(mod, "_which", lambda use_pallas: (True, False))
+    return mod
+
+
+def _shape(one_chip, shape, dtype):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("q,c,k", [(Q, CHUNK, K), (16, 300, 4), (12, 3, 8)],
+                         ids=["cell", "ragged", "narrower_than_k"])
+def test_fold_kernel_compiles(one_chip, kernels, q, c, k):
+    import jax
+    import jax.numpy as jnp
+
+    text = jax.jit(
+        lambda v, i, s, lo: kernels.fold_topk(v, i, s, lo, k)
+    ).lower(_shape(one_chip, (q, k), jnp.float32),
+            _shape(one_chip, (q, k), jnp.int32),
+            _shape(one_chip, (q, c), jnp.float32),
+            _shape(one_chip, (), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("n", [8208, 65552, 2 * K],
+                         ids=["one_chunk", "load_tick_merge", "ring_merge"])
+def test_generic_kernel_compiles(one_chip, kernels, n):
+    import jax
+    import jax.numpy as jnp
+
+    text = jax.jit(lambda x: kernels.topk(x, K)).lower(
+        _shape(one_chip, (Q, n), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_rescan_program_on_the_chip_has_no_id_block(one_chip, kernels):
+    """The cell's whole rescan as the v5e compiler leaves it: the loop
+    body is the matmul fusion and the kernel; no ``s32[256, 8208]``, no
+    padded ``[256, 8320]`` score block, no gather."""
+    import jax
+    import jax.numpy as jnp
+
+    text = jax.jit(
+        lambda q, d, live: kernels.chunked_corpus_topk(q, d, live, K, CHUNK)
+    ).lower(_shape(one_chip, (Q, DIM), jnp.bfloat16),
+            _shape(one_chip, (D, DIM), jnp.int8),
+            _shape(one_chip, (D,), jnp.bool_)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"f32[{Q},{CHUNK}]" in text
+    for gone in (f"s32[{Q},{K + CHUNK}]", f"f32[{Q},{K + CHUNK}]",
+                 f"[{Q},8320]"):
+        assert gone not in text
+    assert not re.search(r"\bgather\(", text)

@@ -194,7 +194,7 @@ def check_proc(args) -> dict:
 def _knn_ring(cfg: dict, mesh) -> dict:
     """Sharded k-NN against the single-device executor on the same host
     batches: the ring merge runs ``topk`` at ``[Q, 2k]`` inside the
-    shard_map region, the per-shard rescan at ``[Q, k + chunk]``."""
+    shard_map region, the per-shard rescan folds ``[Q, k]`` + ``[Q, chunk]``."""
     import jax.numpy as jnp
     import numpy as np
 
