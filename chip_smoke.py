@@ -121,8 +121,6 @@ def pagerank_phase(cfg: dict, devices, root: str, *, make_executor=None,
     with a ``ShardedTpuExecutor`` factory and ``shards=4``."""
     import jax
 
-    from bench import _build_pagerank     # arena sized as the bench does
-    from bench_configs import _pad_batch
     from reflow_tpu.executors import get_executor
     from reflow_tpu.net import LoopbackTransport
     from reflow_tpu.serve import (APPLIED, CoalesceWindow, IngestFrontend,
@@ -140,9 +138,11 @@ def pagerank_phase(cfg: dict, devices, root: str, *, make_executor=None,
     # graph, so reference_ranks(web) is what the served state must equal
     if make_executor is None:
         make_executor = lambda: get_executor("tpu")     # noqa: E731
-    pr, web = _build_pagerank(n, e, cfg["churn"], tol, seed=7, shards=shards)
+    arena = pagerank.churn_arena_capacity(e, cfg["churn"], shards)
+    pr = pagerank.build_graph(n, tol=tol, arena_capacity=arena)
+    web = pagerank.WebGraph.random(n, e, seed=7)
     init = web.initial_batch()
-    waves = [[_pad_batch(web.churn(cfg["churn"]), n_churn)
+    waves = [[web.churn(cfg["churn"]).padded(n_churn)
               for _ in range(w * k)]
              for w in (1, cfg["steady_windows"])]   # warm (compiles), steady
     ref = pagerank.reference_ranks(web)
@@ -235,7 +235,7 @@ def pagerank_phase(cfg: dict, devices, root: str, *, make_executor=None,
     del sched, ex, fe
 
     # -- the guarantee: recover a fresh scheduler from the same WAL ----
-    pr2, _ = _build_pagerank(n, e, cfg["churn"], tol, seed=7, shards=shards)
+    pr2 = pagerank.build_graph(n, tol=tol, arena_capacity=arena)
     t0 = time.perf_counter()
     ex2 = make_executor()
     sched2 = DurableScheduler(pr2.graph, ex2, wal_dir=wal_dir, fsync="tick",
@@ -294,7 +294,6 @@ def knn_phase(cfg: dict, dev) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench_configs import knn_preload_chunk
     from reflow_tpu.delta import DeltaBatch
     from reflow_tpu.executors import get_executor
     from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
@@ -315,7 +314,7 @@ def knn_phase(cfg: dict, dev) -> dict:
     sched.push(kg.queries, DeltaBatch(
         np.arange(Q, dtype=np.int64), store._random(Q),
         np.ones(Q, np.int64)))
-    gen = knn_preload_chunk(c["preload_rows"], dim, D, jnp.int8)
+    gen = knn.preload_chunk(c["preload_rows"], dim, D, jnp.int8)
     next_id = 0
     for ix in range(c["preload_chunks"]):       # corpus made on the device
         sched.push(kg.docs, gen(np.int32(ix), np.int32(next_id)))

@@ -17,7 +17,7 @@ that true:
   missing name means the table went stale.
 
 Writes (``env["REFLOW_X"] = ...``, ``setdefault``) are exempt — the
-bench harness builds child-process environments and that is the point.
+process harness builds child-process environments and that is the point.
 """
 
 from __future__ import annotations

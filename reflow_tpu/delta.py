@@ -160,6 +160,19 @@ class DeltaBatch:
     def scale(self, factor: int) -> "DeltaBatch":
         return DeltaBatch(self.keys, self.values, self.weights * factor)
 
+    def padded(self, rows: int) -> "DeltaBatch":
+        """This batch padded to ``rows`` rows with weight-0, key-0 rows
+        of its value shape and dtype, so a stream of batches hits ONE
+        device capacity bucket (batches wandering across buckets keep
+        recompiling in steady state). A batch of ``rows`` or more is
+        returned as it is."""
+        pad = rows - len(self)
+        if pad <= 0:
+            return self
+        vals = np.zeros((pad,) + self.values.shape[1:], self.values.dtype)
+        return DeltaBatch.concat([self, DeltaBatch(
+            np.zeros(pad, np.int64), vals, np.zeros(pad, np.int64))])
+
     def to_counter(self) -> Counter:
         acc: Counter = Counter()
         for k, v, w in self.rows():

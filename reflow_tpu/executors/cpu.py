@@ -3,7 +3,7 @@
 Interprets each dirty node with the op's exact host-side semantics
 (``ops/core.py``): dict/Counter state, arbitrary hashable keys and values.
 Deliberately simple — this is the baseline the TPU executor is
-differentially tested against and benchmarked against (north star: ≥20×).
+differentially tested against (the oracle, not the thing to beat).
 """
 
 from __future__ import annotations

@@ -180,7 +180,7 @@ class ReplicaServer:
             })
         if op == "view":
             # published view at a consistent cut — parity checks across
-            # process boundaries (bench oracle, harness barrier probes)
+            # process boundaries (test oracles, harness barrier probes)
             horizon, view = r.view_at(args[0])
             return ("ok", horizon, dict(view))
         return ("err", f"unknown op {op!r}")

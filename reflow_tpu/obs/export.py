@@ -10,9 +10,9 @@ the file at https://ui.perfetto.dev or ``chrome://tracing``.
 
 ``ticket_timelines()`` is the shared reader: given a chrome event list
 it reconstructs each sampled ticket's stage durations and end-to-end
-span — ``tools/trace_inspect.py`` and the ``REFLOW_BENCH_OBS=1`` bench
-both consume it, so the decomposition check and the human report can
-never drift apart.
+span — ``tools/trace_inspect.py`` and the decomposition check
+(``tests/test_obs.py::test_ticket_stages_tile_e2e_exactly``) both
+consume it, so the check and the human report can never drift apart.
 """
 
 from __future__ import annotations

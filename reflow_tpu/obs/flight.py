@@ -22,7 +22,7 @@ by the kill), and order by the header anchors.
 
 **Crash model.** ``flush()`` pushes buffered lines through the file
 object into the OS page cache (no fsync — the recorder survives
-process death, which is the chaos benches' failure mode; surviving
+process death, which is the chaos tests' failure mode; surviving
 power loss is the WAL's job, not the flight recorder's). Eager flushes
 fire on the events worth dying with: fence rejects, promotions,
 breaker trips (:func:`note`).

@@ -8,8 +8,8 @@ from a :class:`~reflow_tpu.obs.fleet.TelemetryShipper` to the
 :class:`TelemetryServer`. It deliberately reuses the replication
 stack's parts (``Transport``/``Conn`` framing, ``ReconnectPolicy``
 backoff, ``WireFaults`` injection via ``FaultyTransport``) so the
-telemetry plane inherits the same fault model the chaos bench already
-trusts, with one inversion: **telemetry loss is always tolerated**. A
+telemetry plane inherits the same fault model the replication tests
+already trust, with one inversion: **telemetry loss is always tolerated**. A
 dropped snapshot is a stale gauge, never an error — no call in this
 module may block a data-path thread or let a telemetry failure
 propagate as an exception.
@@ -54,7 +54,7 @@ _POLL_S = 0.2
 def node_id() -> str:
     """This process's id on the telemetry plane: ``REFLOW_FLEET_NODE``
     when set, else ``node-<pid>`` (unique per process on one host —
-    the single-host fleet the benches run)."""
+    the single-host fleet the process harness runs)."""
     nid = env_str("REFLOW_FLEET_NODE")
     return nid if nid else f"node-{os.getpid()}"
 

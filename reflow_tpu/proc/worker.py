@@ -59,7 +59,7 @@ __all__ = ["ControlledReplicaServer", "run_leader", "run_replica",
 
 #: producer batch shape: words per batch, vocabulary size — small
 #: enough that dedup/coalescing paths all engage, deterministic so the
-#: bench oracle can regenerate any batch from (producer, seq) alone
+#: test oracle can regenerate any batch from (producer, seq) alone
 _BATCH_WORDS = 8
 _BATCH_VOCAB = 50
 
@@ -108,7 +108,7 @@ def _graph(workload: str):
 
 def producer_batch_words(index: int, seq: int) -> List[str]:
     """The batch a producer child submits for (producer ``index``,
-    ``seq``) — a pure function, shared with the bench oracle so acked
+    ``seq``) — a pure function, shared with the test oracle so acked
     ``batch_id``s alone reconstruct the exact submitted content."""
     base = (index + 1) * 100003 + seq * 9176
     return [f"w{(base + i * 31) % _BATCH_VOCAB}"

@@ -37,7 +37,8 @@ tier forced — admission and pumping are **injectable**):
 Steady-state traffic rides the fused streaming path: the pump calls
 ``tick_many`` (never a synchronous ``tick``), so on a device executor
 no mid-stream forced syncs happen — the zero-``forced_syncs`` property
-``REFLOW_BENCH_SERVE=1`` asserts.
+``tests/test_serve.py::test_multi_producer_differential_matches_bare_loop``
+asserts.
 
 Durability pipeline (durable schedulers): the pump never blocks on an
 fsync. ``tick_many(wait_durable=False)`` returns once the window's WAL

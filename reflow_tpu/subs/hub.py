@@ -112,7 +112,7 @@ class _Shard:
 class SubHandle:
     """In-process subscriber: drains its hub outbox directly into a
     :class:`~reflow_tpu.subs.query.QueryState`. This is both the
-    programmatic API and the unit the 100k-subscriber bench simulates
+    programmatic API and the unit a many-subscriber test simulates
     (the wire :class:`~reflow_tpu.subs.client.Subscriber` wraps the
     same state machine around a transport)."""
 

@@ -85,7 +85,7 @@ _TILE_DIR = "tiles"
 
 #: process-wide high-water marks of tiled checkpoint IO — the largest
 #: single frame pickled on a save and unpickled on a restore. The
-#: tiles bench asserts both stay under 2x the tile budget; reset with
+#: ``tests/test_tiles.py`` asserts both stay under 2x the tile budget; reset with
 #: :func:`reset_tile_io_stats` around a measured window.
 TILE_IO_STATS = {"writer_peak_frame_bytes": 0,
                  "reader_peak_frame_bytes": 0}

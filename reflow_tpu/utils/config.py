@@ -32,7 +32,6 @@ from typing import Dict, Optional
 __all__ = ["Knob", "KNOBS", "ReflowConfig", "declare", "env_flag",
            "env_float", "env_int", "env_str", "knob_table"]
 
-
 # -- knob registry ----------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -50,7 +49,6 @@ class Knob:
 KNOBS: Dict[str, Knob] = {}
 
 _KINDS = ("flag", "int", "float", "str")
-_UNSET = object()
 
 
 def declare(name: str, kind: str, default, doc: str) -> str:
@@ -80,36 +78,35 @@ def _raw(name: str, env) -> Optional[str]:
     return None if v is None or v == "" else v
 
 
-def env_flag(name: str, default=_UNSET, *, env=None) -> bool:
+def env_flag(name: str, *, env=None) -> bool:
     """Boolean knob: unset/empty -> default; else any value but "0" is
     True (so ``REFLOW_X=1`` enables, ``REFLOW_X=0`` disables)."""
     v = _raw(name, env)
     if v is None:
-        d = KNOBS[name].default if default is _UNSET else default
-        return bool(d)
+        return bool(KNOBS[name].default)
     return v != "0"
 
 
-def env_int(name: str, default=_UNSET, *, env=None) -> Optional[int]:
+def env_int(name: str, *, env=None) -> Optional[int]:
     v = _raw(name, env)
     if v is None:
-        d = KNOBS[name].default if default is _UNSET else default
+        d = KNOBS[name].default
         return None if d is None else int(d)
     return int(v)
 
 
-def env_float(name: str, default=_UNSET, *, env=None) -> Optional[float]:
+def env_float(name: str, *, env=None) -> Optional[float]:
     v = _raw(name, env)
     if v is None:
-        d = KNOBS[name].default if default is _UNSET else default
+        d = KNOBS[name].default
         return None if d is None else float(d)
     return float(v)
 
 
-def env_str(name: str, default=_UNSET, *, env=None) -> Optional[str]:
+def env_str(name: str, *, env=None) -> Optional[str]:
     v = _raw(name, env)
     if v is None:
-        d = KNOBS[name].default if default is _UNSET else default
+        d = KNOBS[name].default
         return None if d is None else str(d)
     return v
 
@@ -123,7 +120,6 @@ def knob_table() -> str:
         rows.append(f"| `{k.name}` | {k.kind} | `{k.default}` | "
                     f"{k.doc} |")
     return "\n".join(rows)
-
 
 # -- core runtime knobs -----------------------------------------------------
 
@@ -157,94 +153,7 @@ declare("REFLOW_TRACE_RING", "int", 262144,
 declare("REFLOW_TRACE_SAMPLE", "int", 16,
         "ticket sampling stride: 1-in-N tickets get a span timeline")
 declare("REFLOW_TRACE_OUT", "str", None,
-        "chrome-trace export path (bench obs mode / export default)")
-
-# -- bench protocol ---------------------------------------------------------
-
-declare("REFLOW_BENCH_ALL", "flag", True,
-        "run the full config sweep in the default bench mode "
-        "(0 = config-3 only)")
-declare("REFLOW_BENCH_SMOKE", "flag", False,
-        "CI-scale every bench mode (small graphs, short windows)")
-declare("REFLOW_BENCH_CHILD", "str", None,
-        "internal: which single config a bench child process runs")
-declare("REFLOW_BENCH_NODES", "int", None,
-        "pagerank bench graph nodes (default 100k, smoke 1k)")
-declare("REFLOW_BENCH_EDGES", "int", None,
-        "pagerank bench graph edges (default 1M, smoke 10k)")
-declare("REFLOW_BENCH_CHURN", "float", 0.01,
-        "per-tick churn fraction in the streaming benches")
-declare("REFLOW_BENCH_STREAM_TICKS", "int", None,
-        "pipelined window length (default 16, smoke 4)")
-declare("REFLOW_BENCH_CPU_FULL", "flag", False,
-        "run the CPU oracle at full scale instead of the capped sweep")
-declare("REFLOW_BENCH_CPU_EDGES_CAP", "int", None,
-        "CPU oracle measured at <= this many edges (default 200k)")
-declare("REFLOW_BENCH_DEFER", "str", "1",
-        "deferred-fixpoint mode for the bench loop (1/0/auto)")
-declare("REFLOW_BENCH_TRACE", "str", None,
-        "directory for an xprof device trace of one churn tick")
-declare("REFLOW_BENCH_MODEL_AXIS", "int", 0,
-        "model-parallel axis size for the image_embed config")
-declare("REFLOW_BENCH_IMG_PER_TICK", "int", 256,
-        "image_embed bench: images folded per tick")
-declare("REFLOW_BENCH_KNN_DTYPE", "str", "int8",
-        "knn bench wire dtype for document uploads")
-declare("REFLOW_BENCH_KNN_PRELOAD", "int", None,
-        "knn bench preloaded document count cap")
-declare("REFLOW_BENCH_RECOVERY", "flag", False,
-        "bench mode: WAL crash-recovery walls")
-declare("REFLOW_BENCH_RECOVERY_TICKS", "int", 1000,
-        "recovery bench crash-backlog size (ticks)")
-declare("REFLOW_BENCH_RECOVERY_TPU_TICKS", "int", None,
-        "recovery bench device-path backlog (default backlog/10)")
-declare("REFLOW_BENCH_SERVE", "flag", False,
-        "bench mode: streaming ingestion frontend throughput")
-declare("REFLOW_BENCH_SERVE_BATCHES", "int", None,
-        "serve bench micro-batches per producer (default 250, smoke 40)")
-declare("REFLOW_BENCH_TIER", "flag", False,
-        "bench mode: multi-graph serving tier")
-declare("REFLOW_BENCH_TIER_BATCHES", "int", None,
-        "tier bench micro-batches per producer (default 200, smoke 30)")
-declare("REFLOW_BENCH_CONTROL", "flag", False,
-        "bench mode: control-plane step-load surge/heal")
-declare("REFLOW_BENCH_OBS", "flag", False,
-        "bench mode: tracing + telemetry overhead and decomposition")
-declare("REFLOW_BENCH_OBS_BATCHES", "int", None,
-        "obs bench micro-batches per producer (default 250, smoke 40)")
-declare("REFLOW_BENCH_WALPIPE", "flag", False,
-        "bench mode: asynchronous durability pipeline")
-declare("REFLOW_BENCH_WALPIPE_BATCHES", "int", None,
-        "walpipe bench batches per producer at 16p (default 4, smoke 2)")
-declare("REFLOW_BENCH_MEGATICK", "flag", False,
-        "bench mode: compiled mega-tick windows vs the per-tick twin")
-declare("REFLOW_BENCH_PIPELINE", "flag", False,
-        "bench mode: pipelined window execution depth 2 vs depth 1")
-declare("REFLOW_BENCH_SHARDSERVE", "flag", False,
-        "bench mode: pod-scale spread/sharded serving")
-declare("REFLOW_BENCH_SHARDSERVE_BATCHES", "int", None,
-        "shardserve bench batches per producer (default 48, smoke 8)")
-declare("REFLOW_BENCH_REPLICA", "flag", False,
-        "bench mode: WAL shipping + read-replica scaling")
-declare("REFLOW_BENCH_REPLICA_N", "int", 4,
-        "replica bench follower count")
-declare("REFLOW_BENCH_REPLICA_READ_S", "float", None,
-        "replica bench per-leg read window seconds (default 2.0, "
-        "smoke 0.6)")
-declare("REFLOW_BENCH_FAILOVER", "flag", False,
-        "bench mode: leader kill + epoch-fenced promotion")
-declare("REFLOW_BENCH_FAILOVER_N", "int", 2,
-        "failover bench follower count")
-declare("REFLOW_BENCH_FAILOVER_RUN_S", "float", None,
-        "failover bench per-phase write window seconds (default 1.0, "
-        "smoke 0.3)")
-declare("REFLOW_BENCH_CHAOS", "flag", False,
-        "bench mode: chaos soak — faulty shipping links + leader kill")
-declare("REFLOW_BENCH_CHAOS_N", "int", 3,
-        "chaos bench follower count")
-declare("REFLOW_BENCH_CHAOS_RUN_S", "float", None,
-        "chaos bench per-phase write window seconds (default 1.2, "
-        "smoke 0.4)")
+        "chrome-trace export path (obs.export_chrome_trace's default)")
 
 # -- replication transport (docs/guide.md 'Replication over the wire') ------
 
@@ -285,12 +194,6 @@ declare("REFLOW_COMPACT_MIN_SEGMENTS", "int", 3,
 declare("REFLOW_COMPACT_KEEP_SEGMENTS", "int", 1,
         "newest sealed segments a compaction pass leaves untouched "
         "(headroom between the fold and the committer's write head)")
-declare("REFLOW_BENCH_COMPACT", "flag", False,
-        "bench mode: bounded-history recovery/bootstrap — full-history "
-        "replay vs {checkpoint chain + compacted tail}")
-declare("REFLOW_BENCH_COMPACT_TICKS", "int", None,
-        "compact bench batches per producer per leg "
-        "(default 480, smoke 160)")
 
 # -- tiled maintenance (docs/guide.md 'Tiled maintenance') ------------------
 
@@ -305,16 +208,6 @@ declare("REFLOW_TILE_SHIP_RETRIES", "int", 3,
         "per-tile resend attempts when a bootstrap tile unit is "
         "NACKed (CRC mismatch on the follower) before the shipper "
         "falls back to a whole-chain bootstrap")
-declare("REFLOW_BENCH_TILES", "flag", False,
-        "bench mode: tiled maintenance — two identically-fed legs at "
-        "state >= 8x the tile budget; tiled leg must bound compaction "
-        "and checkpoint/restore peak under 2x budget, recover + "
-        "bootstrap with exact parity vs the monolithic leg, survive "
-        "kill -9 at every per-tile crash seam, and match top_k/lookup "
-        "against the untiled snapshot oracle")
-declare("REFLOW_BENCH_TILES_TICKS", "int", None,
-        "tiles bench batches per producer per leg "
-        "(default 320, smoke 120)")
 
 # -- fleet telemetry (docs/guide.md 'Fleet telemetry') ----------------------
 
@@ -333,12 +226,6 @@ declare("REFLOW_FLEET_STALE_S", "float", 2.0,
 declare("REFLOW_FLEET_LAG_SPREAD_MAX", "int", 64,
         "fleet lag-spread gauge (max-min follower horizon, ticks) "
         "above which the control plane logs an advisory action")
-declare("REFLOW_BENCH_FLEETOBS", "flag", False,
-        "bench mode: fleet telemetry plane — overhead A/B + causal "
-        "chains + stale-marking on the chaos topology")
-declare("REFLOW_BENCH_FLEETOBS_BATCHES", "int", None,
-        "fleetobs bench batches per producer per A/B leg "
-        "(default 320, smoke 160)")
 
 # -- ingestion RPC + process harness ('Multi-process deployment') -----------
 
@@ -368,17 +255,6 @@ declare("REFLOW_PROC_POLL_S", "float", 0.05,
 declare("REFLOW_PROC_PYTHON", "str", None,
         "interpreter used to spawn harness children "
         "(default sys.executable)")
-declare("REFLOW_BENCH_MULTIPROC", "flag", False,
-        "bench mode: multi-process chaos — producer + replica OS "
-        "processes, kill -9 storm, leader kill + cross-process "
-        "promotion, exactly-once resubmit over the RPC")
-declare("REFLOW_BENCH_MULTIPROC_N", "int", 3,
-        "multiproc bench replica process count")
-declare("REFLOW_BENCH_MULTIPROC_PRODUCERS", "int", 4,
-        "multiproc bench producer process count")
-declare("REFLOW_BENCH_MULTIPROC_RUN_S", "float", None,
-        "multiproc bench per-phase write window seconds "
-        "(default 1.5, smoke 0.6)")
 
 # -- reactive reads ('Reactive reads') --------------------------------------
 
@@ -403,17 +279,6 @@ declare("REFLOW_SUB_MAX_FRAMES", "int", 256,
 declare("REFLOW_SUB_IO_TIMEOUT_S", "float", 5.0,
         "per-operation send/recv timeout on subscription "
         "connections (Subscriber <-> SubscriptionServer)")
-declare("REFLOW_BENCH_SUBS", "flag", False,
-        "bench mode: reactive reads — one replica fans deltas to "
-        "100k simulated subscribers under 16-producer write load; "
-        "write-path p99 overhead, exact delta-vs-pull parity, "
-        "partition/heal resume with zero gaps and zero duplicates")
-declare("REFLOW_BENCH_SUBS_N", "int", None,
-        "subs bench simulated subscriber count "
-        "(default 100_000, smoke 2000)")
-declare("REFLOW_BENCH_SUBS_RUN_S", "float", None,
-        "subs bench per-leg write window seconds "
-        "(default 2.0, smoke 0.6)")
 
 # -- end-to-end tracing & flight recorder ('Follow-the-write') ---------------
 
@@ -433,18 +298,6 @@ declare("REFLOW_FLIGHT_FLUSH_EVERY", "int", 64,
         "flight recorder flushes after this many buffered events "
         "(control-plane events — fence/promote/breaker — always "
         "flush eagerly)")
-declare("REFLOW_BENCH_E2ETRACE", "flag", False,
-        "bench mode: follow-the-write — multiproc topology under "
-        "16-producer load with live wire subscribers and tracing on; "
-        "kill -9 a replica and the leader mid-run, then assert "
-        "sampled writes show complete submit→deliver chains, the "
-        "freshness decomposition tiles ack→deliver, and every killed "
-        "child's flight recording is recovered from its disk corner")
-declare("REFLOW_BENCH_E2ETRACE_RUN_S", "float", None,
-        "e2etrace bench per-leg write window seconds "
-        "(default 1.5, smoke 0.6)")
-declare("REFLOW_BENCH_E2ETRACE_PRODUCERS", "int", 16,
-        "e2etrace bench producer process count")
 
 
 # -- the config dataclass ---------------------------------------------------

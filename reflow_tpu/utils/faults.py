@@ -165,12 +165,12 @@ class WireFaults:
     by :meth:`decide` (mutually exclusive outcomes, probabilities are
     independent weights normalized against staying healthy); scripted
     faults (:meth:`partition` / :meth:`heal` / :meth:`reset_once`) are
-    imperative switches the chaos bench throws on a timeline.
+    imperative switches a chaos test throws on a timeline.
 
     Thread-safe: one link's client may be probed from the shipper pump
     and a read-tier prober concurrently, and counters must not tear.
     :meth:`quiesce` zeroes every probability and heals partitions — the
-    bench's "faults stop" moment, after which replicas must converge.
+    "faults stop" moment, after which replicas must converge.
     """
 
     #: per-message outcomes decide() can roll, in roll order
@@ -222,8 +222,8 @@ class WireFaults:
     def set_rates(self, *, delay_p: Optional[float] = None,
                   delay_s: Optional[float] = None,
                   **rates: float) -> None:
-        """Rewire per-message probabilities mid-run — the chaos
-        bench's 'storm on' switch (:meth:`quiesce` is the off switch,
+        """Rewire per-message probabilities mid-run — the 'storm on'
+        switch (:meth:`quiesce` is the off switch,
         so links can attach and handshake over a quiet wire first).
         Keyword names are :data:`OUTCOMES` entries."""
         with self._lock:
@@ -238,7 +238,7 @@ class WireFaults:
 
     def quiesce(self) -> None:
         """Stop all faults: zero every probability, heal partitions,
-        disarm pending resets. The bench's 'faults stop' switch."""
+        disarm pending resets. The 'faults stop' switch."""
         with self._lock:
             for k in self.p:
                 self.p[k] = 0.0

@@ -35,7 +35,7 @@ def percentile(xs, q: float) -> float:
 
 def _jsonify(obj):
     """Recursively coerce numpy scalars/arrays to plain Python so the
-    result survives ``json.dumps`` — the bench writes metric records to
+    result survives ``json.dumps`` — metric records are written to
     JSON so runs can be diffed across PRs."""
     if isinstance(obj, np.generic):
         return obj.item()
@@ -179,7 +179,7 @@ class ServeMetrics:
 
     ``coalesce_factor`` is the headline: micro-batches applied per
     scheduler tick. 1.0 means the window never merged anything (light
-    traffic); the serve bench asserts > 1 under 16 producers.
+    traffic); ``tests/test_serve.py`` asserts > 1 under 8 producers.
     """
 
     policy: str

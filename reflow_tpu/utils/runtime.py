@@ -3,8 +3,8 @@ point places, and the opt-in lock-order detector.
 
 Compile cache (:func:`place_compile_cache`): every device program this
 repo builds is compiled at first use and compilation is the largest
-share of a cold start, so entry points (``chip_smoke.py``, ``bench.py``
-and its children, the device tools) share one persistent JAX
+share of a cold start, so entry points (``chip_smoke.py``,
+``benchmarks/run.py``, the device tools) share one persistent JAX
 compilation cache. Library code never calls it — ``import reflow_tpu``
 configures nothing.
 

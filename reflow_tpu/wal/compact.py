@@ -340,7 +340,7 @@ class WalCompactor:
     def reclaimable_bytes(self) -> int:
         """Bytes the next pass could fold (sizes of the eligible
         segments) — drops to ~one folded segment after a pass, which is
-        the bounded-footprint signal the bench asserts on."""
+        the bounded-footprint signal."""
         rng = self.eligible_range()
         if not rng:
             return 0

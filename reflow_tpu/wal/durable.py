@@ -36,7 +36,8 @@ whoever uploaded the batch had the host payload first; hand it to
 automatically from ``submit(..., preimage=...)``) and the WAL logs that
 pre-image while the device batch flows on untouched.
 ``log_readbacks`` counts the fallback materializations — zero on a
-well-formed streaming path (the ``REFLOW_BENCH_WALPIPE=1`` assertion).
+well-formed streaming path (``tests/test_pipeline.py`` holds it for
+pre-imaged device submissions under either committer).
 
 Crash-point injection (``crash=utils.faults.CrashInjector(...)``) fires
 at the named seams above plus the WAL's own pipeline seams:

@@ -57,8 +57,7 @@ acknowledgement path gates on the watermarks above, and why a crash
 that loses queued frames loses only *unacknowledged* batches (the
 upstream re-sends; replay dedups). ``committer="inline"`` restores the
 fully synchronous pre-pipeline behavior — every frame is written and
-every barrier fsynced in the appending thread (the
-``REFLOW_BENCH_WALPIPE=1`` baseline).
+every barrier fsynced in the appending thread.
 
 A crashed process may leave a torn final record (partial write). The
 read side (:func:`scan_wal`) tolerates exactly that: a bad frame at the

@@ -64,6 +64,33 @@ def quantize_int8(vals: np.ndarray) -> np.ndarray:
     return np.clip(np.round(u * 127.0), -127, 127).astype(np.int8)
 
 
+def preload_chunk(rows: int, dim: int, n_docs: int, doc_dtype):
+    """Jitted ``(seed, base) -> DeviceDelta`` minting one ``rows``-row
+    corpus insert batch with the on-chip RNG (ids ``base..base+rows``
+    mod ``n_docs``): a device-resident corpus preload."""
+    import jax
+    import jax.numpy as jnp
+
+    from reflow_tpu.executors.device_delta import DeviceDelta
+
+    @jax.jit
+    def gen_chunk(seed, base):
+        kk = jax.random.fold_in(jax.random.PRNGKey(3), seed)
+        vals = jax.random.normal(kk, (rows, dim), jnp.float32)
+        keys = (base + jnp.arange(rows, dtype=jnp.int32)) % n_docs
+        if doc_dtype == jnp.int8:
+            # device-side form of quantize_int8
+            nrm = jnp.sqrt(jnp.sum(vals * vals, axis=1, keepdims=True))
+            unit = vals / jnp.maximum(nrm, 1e-30)
+            out = jnp.clip(jnp.round(unit * 127.0), -127, 127
+                           ).astype(jnp.int8)
+        else:
+            out = jnp.asarray(vals, doc_dtype)
+        return DeviceDelta(keys, out, jnp.ones((rows,), jnp.int32))
+
+    return gen_chunk
+
+
 # -- host-side data + churn driver ----------------------------------------
 
 @dataclasses.dataclass

@@ -96,6 +96,21 @@ def build_graph(n_nodes: int, *, damping: float = DAMPING, tol: float = 1e-4,
     return PageRankGraph(g, ranks, teleport, edges, j, new_rank)
 
 
+def churn_arena_capacity(n_edges: int, churn: float, shards: int = 1) -> int:
+    """``arena_capacity`` for a graph of ``n_edges`` live edges that takes
+    ``churn * n_edges`` rewires per tick: live rows plus churn headroom.
+    In-program compaction (executors/arena.py via join_core's lax.cond)
+    reclaims cancelled pairs at high water, so capacity doesn't scale
+    with ticks. A sharded executor bounds every tick against the
+    PER-SHARD slice under worst-case key skew (one shard owning every
+    row), so a mesh of ``shards`` needs that many times the
+    single-device arena."""
+    from reflow_tpu.executors.device_delta import bucket_capacity
+
+    churn_cap = bucket_capacity(2 * int(churn * n_edges) + 2)
+    return shards * (bucket_capacity(n_edges) + 8 * churn_cap)
+
+
 def _contrib_merge(k, rank, vb):
     """(rank, [dst, invdeg]) -> [dst, rank·invdeg].
 

@@ -372,6 +372,73 @@ def test_follower_cursor_in_compacted_range_reanchors(tmp_path):
     sched.close()
 
 
+def test_bounded_history_leg_equals_unbounded_and_cold_starts(tmp_path):
+    # two identically-fed leaders — one keeps its whole history, one
+    # cuts chain elements and folds its sealed tail as it goes. The
+    # bounded leg must end on the same view, crash-recover from
+    # {chain + compacted tail} to it, bootstrap a FRESH replica from
+    # the directories alone to it, and hold fewer bytes on disk
+    def du(path):
+        return sum(os.path.getsize(os.path.join(b, f))
+                   for b, _d, fs in os.walk(path) for f in fs)
+
+    def feed(t):
+        # every odd tick retracts its predecessor: live state stays
+        # small while history grows — the compactor's whole case
+        rng = np.random.default_rng(t - t % 2)
+        words = " ".join(f"w{int(x)}" for x in rng.integers(0, 60, 16))
+        return f"t{t}", wordcount.ingest_lines(
+            [words], weight=-1 if t % 2 else 1)
+
+    legs = {}
+    for label in ("full", "bounded"):
+        wal_dir = str(tmp_path / f"wal-{label}")
+        root = str(tmp_path / "ckpt") if label == "bounded" else None
+        g, src, sink = wordcount.build_graph()
+        sched = DurableScheduler(g, wal_dir=wal_dir, fsync="tick",
+                                 segment_bytes=1 << 12)
+        chain = comp = None
+        if root:
+            chain = CheckpointChain(root, delta_every=3)
+            comp = WalCompactor(sched.wal, ckpt_dir=root,
+                                min_segments=2, keep_segments=1)
+        for t in range(121):
+            bid, b = feed(t)
+            sched.push(src, b, batch_id=bid)
+            sched.tick()
+            if chain is not None and t in (29, 59):
+                chain.save(sched)       # the rest is the replay tail
+                comp.compact_once()
+        sched.wal.sync()
+        if comp is not None:
+            while comp.compact_once() is not None:
+                pass            # fold the sealed tail completely
+            assert comp.folds >= 1
+            comp.close()
+        legs[label] = (wal_dir, root, {kv: w for kv, w
+                                       in sched.view(sink.name).items()
+                                       if w != 0}, sched._tick)
+        sched.close()
+    wal_dir, root, view, tick = legs["bounded"]
+    assert view == legs["full"][2] and view     # same fold, exactly
+    got, got_tick, rep = recovered_view(wal_dir, root)
+    assert {kv: w for kv, w in got.items() if w != 0} == view
+    assert got_tick == tick and rep.checkpoint_loaded
+    ship = SegmentShipper(wal_dir=wal_dir, ckpt_dir=root)
+    g2, _s2, sink2 = wordcount.build_graph()
+    replica = ReplicaScheduler(g2, str(tmp_path / "boot"), name="boot")
+    ship.attach(replica)
+    assert replica.bootstraps == 1
+    for _ in range(200):
+        if replica.published_horizon() == tick:
+            break
+        ship.pump_once()
+    assert replica.view_at(sink2.name) == (tick, view)
+    ship.close()
+    replica.close()
+    assert du(wal_dir) + du(root) < du(legs["full"][0])
+
+
 def test_compacted_record_partial_dedup_fails_loud(tmp_path):
     # a folded record whose batch ids are PARTIALLY in the restorer's
     # dedup window has no per-id slice to apply — silent divergence is

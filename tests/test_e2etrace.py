@@ -2,7 +2,7 @@
 freshness decomposition, and the crash-surviving flight recorder.
 
 Three contracts under test, hermetically (the kill -9 chaos twin is
-``REFLOW_BENCH_E2ETRACE=1 python bench.py``):
+``tests/test_proc.py::test_leader_kill9_promotes_a_replica_child_exactly_once``):
 
 - **wire compatibility** — the causality token is a defaulted trailing
   field on ``SubmitReq``/``SubmitAck``/``DeltaFrame``, trimmed when
@@ -166,6 +166,95 @@ def test_unsampled_write_appears_nowhere(tmp_path, monkeypatch):
         obs.disable()
         trace.reset()
         prod.close()
+        srv.close()
+        fe.close()
+        sched.wal.close()
+
+
+def test_real_stack_records_the_full_chain_and_freshness_tiles(
+        tmp_path, monkeypatch):
+    """One sampled write followed through the REAL stack — producer ->
+    ingestion RPC -> frontend -> WAL -> shipper -> TCP replica link ->
+    replay -> hub fan-out -> wire subscriber — carries one token at all
+    nine links, minted in the leader's epoch, and ``trace_inspect``'s
+    ack->deliver stages, read off those real spans, tile the write's
+    end-to-end latency."""
+    import time
+
+    from reflow_tpu.net import (ReconnectPolicy, RemoteFollower,
+                                ReplicaServer, TcpTransport)
+    from reflow_tpu.serve import ReplicaScheduler
+    from reflow_tpu.subs import (Subscriber, SubscriptionHub,
+                                 SubscriptionServer)
+    from reflow_tpu.wal import SegmentShipper
+
+    monkeypatch.setattr(trace, "SAMPLE_EVERY", 1)
+    ti = _load_tool("trace_inspect")
+    g, src, sink = wordcount.build_graph()
+    # epoch 1: what a promoted leader serves under
+    sched = DurableScheduler(g, wal_dir=str(tmp_path / "wal"),
+                             fsync="tick", epoch=1)
+    fe = IngestFrontend(sched, start=True)
+    lt = LoopbackTransport()
+    srv = RpcIngestServer(fe, lt).start()
+    g2, _s, _k = wordcount.build_graph()
+    rep = ReplicaScheduler(g2, str(tmp_path / "r0"), name="r0")
+    rsrv = ReplicaServer(rep, TcpTransport()).start()
+    link = RemoteFollower(
+        TcpTransport(), rsrv.address, name="r0",
+        policy=ReconnectPolicy("r0", base_s=0.005, cap_s=0.05, seed=0),
+        io_timeout_s=2.0)
+    ship = SegmentShipper(sched.wal, leader_tick=lambda: sched._tick)
+    hub = SubscriptionHub(rep, name="r0", idle_poll_s=0.005)
+    rep.attach_hub(hub)
+    ssrv = SubscriptionServer(hub, lt).start()
+    obs.enable()
+    trace.reset()
+    prod = RemoteProducer(lt, srv.address, name="p0")
+    sub = Subscriber(lt, ssrv.address, sink.name, kind="view",
+                     name="sub-r0")
+    try:
+        ship.attach(link)
+        sub.pump(wait_s=0.01)       # registered before the writes land
+        toks = []
+        for i in range(3):
+            t = prod.submit(src, wordcount.ingest_lines([f"aa b{i}"]),
+                            batch_id=f"b{i}")
+            assert t.result(10).status == APPLIED
+            toks.append(t.cause)
+        # once the hello has landed, the token's epoch field is the
+        # serving leader's (the first is minted before the connect)
+        assert all(tok.startswith("p0#1#") for tok in toks[1:]), toks
+        fe.flush()
+        sched.wal.sync()
+        deadline = time.monotonic() + 20
+        while time.monotonic() < deadline and not (
+                rep.published_horizon() == sched._tick
+                and sub.horizon >= sched._tick):
+            ship.pump_once()
+            sub.pump(wait_s=0.01)
+        assert sub.horizon >= sched._tick and sub.gaps_total == 0
+        path = str(tmp_path / "trace.json")
+        obs.export_chrome_trace(path)
+        report = ti.inspect([path], require_chain=list(ti.FULL_CHAIN))
+        causal = report["causal"]
+        assert causal["full_chains"] >= 1, causal["span_names"]
+        assert causal["required_chains"] >= 1
+        fresh = report["freshness"]
+        assert fresh is not None and fresh["chains"] >= 1
+        assert set(fresh["stages"]) == set(ti.FRESHNESS_STAGES)
+        assert fresh["max_dev_frac"] <= 0.10, fresh["worst"]
+    finally:
+        obs.disable()
+        trace.reset()
+        sub.close()
+        prod.close()
+        ssrv.close()
+        hub.close()
+        ship.close()
+        link.close()
+        rsrv.close()
+        rep.close()
         srv.close()
         fe.close()
         sched.wal.close()
@@ -336,6 +425,36 @@ def test_flight_ring_rotates_and_respawn_archives_prev(tmp_path):
     names = [ev["name"] for ev in merged["events"]]
     assert "promote" in names and "breaker_open" in names
     assert not any(ev.get("seq") == 999 for ev in merged["events"])
+
+
+def test_flight_recording_survives_a_real_kill9(tmp_path):
+    """What a process wrote to its flight ring before SIGKILL took it —
+    no close, no atexit, no trace export — is still in its disk corner:
+    the eagerly flushed note and the spans flushed before it."""
+    import signal
+    import subprocess
+    import sys
+
+    corner = str(tmp_path / "leader" / "flight")
+    child = (
+        "import os, signal\n"
+        "from reflow_tpu.obs import flight\n"
+        f"rec = flight.install({corner!r}, node='leader')\n"
+        "for i in range(3):\n"
+        "    rec.record('wal_append', float(i), 1.0, 'wal',\n"
+        "               {'cause': f'p0#0#{i}'})\n"
+        "flight.note('promote', epoch=1, horizon=7)\n"
+        "rec.record('wal_append', 9.0, 1.0, 'wal', {'cause': 'lost'})\n"
+        "os.kill(os.getpid(), signal.SIGKILL)\n")
+    repo = os.path.dirname(_TOOLS)
+    proc = subprocess.run(
+        [sys.executable, "-c", child], cwd=repo, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo))
+    assert proc.returncode == -signal.SIGKILL
+    merged = _load_tool("reflow_flight").merge([str(tmp_path)])
+    assert merged["nodes"]["leader"]["events"] >= 4
+    names = [ev["name"] for ev in merged["events"]]
+    assert names.count("wal_append") >= 3 and "promote" in names
 
 
 def test_flight_publish_metrics_unregisters_on_close(tmp_path):

@@ -1,5 +1,5 @@
-"""The process entry points this repo is run through — ``chip_smoke.py``
-and ``bench.py`` — hold the rules a directly attached chip imposes: no
+"""The process entry point this repo is run through on a chip —
+``chip_smoke.py`` — holds the rules a directly attached chip imposes: no
 silent CPU, one process per chip, failure reaches the exit status."""
 
 import json
@@ -60,19 +60,3 @@ def test_chip_smoke_tiny_form_needs_the_cpu_stated():
     assert out.returncode != 0
     assert '"ok"' not in out.stdout
     assert "JAX_PLATFORMS=cpu" in out.stderr
-
-
-def test_bench_parent_stays_off_jax_and_child_failure_exits_nonzero():
-    """``python bench.py``: a child that raises makes the command exit
-    non-zero (the induced failure: a graph with no nodes), and the
-    parent reached its spawn without importing jax (``_spawn`` refuses
-    otherwise, with a different message)."""
-    out = _run(["bench.py"], JAX_PLATFORMS="cpu", REFLOW_BENCH_SMOKE="1",
-               REFLOW_BENCH_ALL="0", REFLOW_BENCH_NODES="0")
-    assert out.returncode != 0
-    assert "[pr_tpu] child finished" in out.stderr
-    assert "rc=1" in out.stderr
-    assert "imported jax" not in out.stderr
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["value"] is None and "pr_tpu" in rec["failed_children"]
-    assert "error" in rec

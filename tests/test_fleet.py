@@ -20,6 +20,7 @@ import importlib.util
 import json
 import os
 import pickle
+import time
 
 import numpy as np
 import pytest
@@ -386,6 +387,91 @@ def test_shipper_to_aggregator_over_tcp_and_fleet_query():
         agg.close()
 
 
+def test_fleet_view_equals_ground_truth_and_serves_through_partition(
+        tmp_path):
+    """The whole plane on a live topology: each replica's own registry
+    rides a TelemetryShipper over TCP, and at quiesce the aggregator's
+    per-node horizons / lag / spread EQUAL what the replicas report
+    directly. Then one node's telemetry link is partitioned: the fleet
+    view keeps answering with that node stale-marked (last-known
+    horizon, an alert, never an error), the shipper counts its dropped
+    beats, and the node comes back once the link heals."""
+    from reflow_tpu.net import FaultyTransport
+    from reflow_tpu.utils.faults import WireFaults
+
+    clk = FakeClock()
+    g, src, _sink = wordcount.build_graph()
+    sched = DurableScheduler(g, wal_dir=str(tmp_path / "wal"),
+                             fsync="tick")
+    ship = SegmentShipper(sched.wal, leader_tick=lambda: sched._tick)
+    agg = FleetAggregator(retention=8, stale_after_s=1.0, clock=clk)
+    tsrv = TelemetryServer(agg, TcpTransport()).start()
+    replicas, shippers, faults = [], [], []
+    probe = TelemetryLink(TcpTransport(), tsrv.address, node="probe",
+                          io_timeout_s=2.0)
+    try:
+        for i in range(2):
+            gr, _s, _k = wordcount.build_graph()
+            r = ReplicaScheduler(gr, str(tmp_path / f"r{i}"),
+                                 name=f"r{i}")
+            ship.attach(r)
+            reg = obs.MetricsRegistry()
+            r.publish_metrics(reg)
+            wf = WireFaults(seed=91 + i)
+            shippers.append(TelemetryShipper(
+                reg, FaultyTransport(TcpTransport(), wf), tsrv.address,
+                node=f"r{i}",
+                policy=ReconnectPolicy(f"tele/r{i}", base_s=0.0,
+                                       cap_s=0.0, seed=0),
+                io_timeout_s=0.25))
+            replicas.append(r)
+            faults.append(wf)
+        drive(sched, src, 5)
+        pump_until_caught(ship, sched, replicas)
+        assert all(sh.ship_once() for sh in shippers)
+        truth = {r.name: r.published_horizon() for r in replicas}
+        snap = agg.fleet_snapshot()
+        assert {n: e["horizon"] for n, e in snap["nodes"].items()} \
+            == truth == {"r0": sched._tick, "r1": sched._tick}
+        assert all(e["lag_ticks"] == 0 for e in snap["nodes"].values())
+        assert snap["gauges"]["lag_spread"] == 0
+        assert snap["gauges"]["epoch_agree"] is True
+        assert snap["gauges"]["nodes_total"] == 2 and not snap["alerts"]
+
+        faults[0].partition("c2s")          # r0's telemetry link only
+        drive(sched, src, 2, seed=1)
+        pump_until_caught(ship, sched, replicas)
+        clk.advance(2.0)
+        assert shippers[0].ship_once() is False
+        assert shippers[1].ship_once()
+        assert shippers[0].dropped >= 1
+        during = probe.fetch_fleet()        # served, never an error
+        assert agg.stale_nodes() == ["r0"]
+        assert during["nodes"]["r0"]["stale"] is True
+        assert during["nodes"]["r0"]["horizon"] == truth["r0"]
+        assert during["nodes"]["r1"]["horizon"] == sched._tick
+        assert any(a.startswith("stale: r0") for a in during["alerts"])
+
+        faults[0].heal()
+        deadline = time.monotonic() + 10
+        while not shippers[0].ship_once():
+            assert time.monotonic() < deadline, "link never healed"
+            time.sleep(0.01)
+        after = probe.fetch_fleet()
+        assert after["nodes"]["r0"]["stale"] is False
+        assert after["nodes"]["r0"]["horizon"] == sched._tick
+    finally:
+        probe.close()
+        for sh in shippers:
+            sh.close()
+        tsrv.close()
+        agg.close()
+        ship.close()
+        for r in replicas:
+            r.close()
+        sched.wal.close()
+
+
 def test_telemetry_loss_tolerated_never_raises():
     """A dead aggregator: every beat is a dropped counter, the data
     path never sees an exception, and the link state degrades."""
@@ -489,26 +575,6 @@ def test_fleet_inspect_file_json_and_fail_on_alert(tmp_path, capsys):
         f.write(json.dumps({"schema": "other/1"}))
     with pytest.raises(SystemExit):
         fi.main([path, "--json"])
-
-
-def test_fleet_inspect_bench_dir_backfill_tolerant(tmp_path, capsys):
-    (tmp_path / "new.json").write_text(json.dumps(
-        {"schema": "reflow.bench/1", "mode": "fleetobs",
-         "rows_per_s": 1}))
-    (tmp_path / "old.json").write_text(json.dumps(
-        {"metric": "x", "rows_per_s": 2.0}))  # pre-stamp bench
-    (tmp_path / "other.json").write_text(json.dumps(
-        {"schema": "reflow.fleet/1"}))        # not a bench result
-    (tmp_path / "junk.json").write_text("{broken")
-    fi = _load_tool("fleet_inspect")
-    assert fi.main(["--bench-dir", str(tmp_path), "--json"]) == 0
-    out = json.loads(capsys.readouterr().out)
-    assert out["schema"] == "reflow.fleet_benchdir/1"
-    assert out["stamped"] == 1 and out["unstamped"] == 1
-    by_file = {e["file"]: e for e in out["benches"]}
-    assert by_file["new.json"]["mode"] == "fleetobs"
-    assert by_file["old.json"]["mode"] is None
-    assert "other.json" not in by_file
 
 
 def test_reflow_top_render_marks_stale_and_disconnect():

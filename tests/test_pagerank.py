@@ -33,6 +33,18 @@ def run_pagerank(executor_name, web, churn_ticks=0):
 as_array = pagerank.ranks_to_array
 
 
+@pytest.mark.parametrize("shards", [1, 4])
+def test_churn_arena_capacity_at_the_1m_edge_deployment(shards):
+    # the pagerank-1m deployment: 1M live edges, 1% rewired a tick.
+    # live rows round up to 2^20; a tick's 2*10_000 + 2 retract+insert
+    # rows round up to 2^15, eight of them as headroom; a mesh bounds
+    # every tick per shard under worst-case skew, so times the shards
+    cap = pagerank.churn_arena_capacity(1_000_000, 0.01, shards)
+    assert cap == shards * ((1 << 20) + 8 * (1 << 15))
+    pr = pagerank.build_graph(64, arena_capacity=cap)
+    assert pr.join.op.arena_capacity == cap
+
+
 def test_pagerank_cpu_matches_numpy_reference():
     web = pagerank.WebGraph.random(N, E, seed=1)
     ranks, _, _ = run_pagerank("cpu", web)

@@ -455,6 +455,70 @@ def test_serial_paths_resolve_as_before(mode, tmp_path):
     assert fe2.windows_staged == 3
 
 
+# -- device-resident submissions log their host pre-image -------------------
+
+def _sink_graph():
+    """``_graph`` plus a sink: the shape the pre-imaged ingest protocol
+    is served on (a sink keeps the graph on the per-tick path, where a
+    device-resident batch rides its own feed slot)."""
+    g, s, r = _graph()
+    return g, s, g.sink(r, "out")
+
+
+def _view(sched, sink):
+    return {(int(k), round(float(v), 3)): w
+            for (k, v), w in sched.view(sink.name).items() if w}
+
+
+@pytest.mark.parametrize("committer", ["inline", "thread"])
+def test_preimaged_device_submissions_log_without_a_readback(committer,
+                                                             tmp_path):
+    """A device-resident batch submitted with ``preimage=`` is logged
+    from the host pre-image: ``log_readbacks`` stays 0 under either
+    committer, every applied ticket names its covering LSN, the view
+    equals the CPU oracle's (so inline == pipelined), and the log
+    replays into a fresh host scheduler to the same view. Without the
+    pre-image the same submission costs exactly one materialize."""
+    from reflow_tpu.executors.device_delta import to_device
+
+    batches = _mk_batches(11, n=6)
+    extra = _mk_batches(12, n=1)[0]
+    g0, s0, k0 = _sink_graph()
+    oracle = DirtyScheduler(g0, get_executor("cpu"))
+    for b in batches:
+        oracle.push(s0, b)
+        oracle.tick()
+    want = _view(oracle, k0)
+    oracle.push(s0, extra)
+    oracle.tick()
+
+    g, s, sink = _sink_graph()
+    sched = DurableScheduler(g, get_executor("tpu"),
+                             wal_dir=str(tmp_path / "wal"), fsync="record",
+                             committer=committer)
+    fe = IngestFrontend(sched, window=CoalesceWindow(
+        max_rows=64, max_ticks=1, max_latency_s=0.001))
+    try:
+        results = [fe.submit(s, to_device(b, s.spec), batch_id=f"b{j}",
+                             preimage=b).result(timeout=30)
+                   for j, b in enumerate(batches)]
+        fe.flush(timeout=30)
+        assert all(x.applied and x.lsn for x in results)
+        assert sched.log_readbacks == 0
+        assert _view(sched, sink) == want
+        assert fe.submit(s, to_device(extra, s.spec),
+                         batch_id="bare").result(timeout=30).applied
+        assert sched.log_readbacks == 1
+    finally:
+        fe.close()
+        sched.close()
+    g2, _s2, k2 = _sink_graph()
+    fresh = DirtyScheduler(g2)
+    report = recover(fresh, str(tmp_path / "wal"))
+    assert report.replayed_pushes == len(batches) + 1
+    assert _view(fresh, k2) == _view(oracle, k0)
+
+
 # -- stage-complete budget release -----------------------------------------
 
 def test_stage_release_unblocks_producer_before_retire():

@@ -182,6 +182,99 @@ def test_child_kill9_respawn_rejoins_the_barrier(tmp_path):
         h.close()
 
 
+def test_leader_kill9_promotes_a_replica_child_exactly_once(tmp_path):
+    """kill -9 the leader *process*: ``ProcHarness.coordinator`` drains
+    the dead leader's on-disk WAL, a replica child promotes in place
+    (epoch 1) and serves ingestion, ``retarget_producers`` swings the
+    producer children, their in-doubt batches resubmit — and every
+    acked batch is in the promoted leader's view exactly once, the
+    survivor agrees at the same horizon, and a late epoch-0 shipment
+    from the ex-leader is NACKed ``fenced`` over the wire."""
+    from reflow_tpu.net import RemoteFollower
+    from reflow_tpu.scheduler import DirtyScheduler
+    from reflow_tpu.wal.log import _MAGIC
+    from reflow_tpu.wal.ship import Shipment, ShipNack
+
+    n_prod, rnames = 2, ["r0", "r1"]
+    h = ProcHarness(str(tmp_path))
+    try:
+        h.spawn_leader(fsync="tick", epoch=0)
+        for nm in rnames:
+            h.spawn_replica(nm)
+        h.attach_replicas()
+        for i in range(n_prod):
+            h.spawn_producer(f"p{i}", index=i, pace_s=0.05)
+        # every child ships telemetry to the parent's aggregator
+        assert h.aggregator.await_nodes(1 + len(rnames) + n_prod,
+                                        timeout_s=15.0)
+        time.sleep(0.5)
+
+        coord = h.coordinator(epoch=0, confirm_intervals=2,
+                              drain_timeout_s=10.0)
+        h.kill9("leader")
+        promote_evt, now = None, 0.0
+        deadline = time.monotonic() + 60.0
+        while promote_evt is None and time.monotonic() < deadline:
+            for e in coord.step(now):
+                if e.get("kind") == "failover_promote":
+                    promote_evt = e
+            now += 1.0
+            time.sleep(0.02)
+        assert promote_evt is not None, "leader death never promoted"
+        assert promote_evt["winner"] in rnames
+        assert promote_evt["epoch"] == 1
+        assert h.leader_name == promote_evt["winner"]
+        time.sleep(0.5)     # producers reconnect + resubmit in doubt
+
+        exits = [h.child(f"p{i}").stop() for i in range(n_prod)]
+        for st in exits:
+            assert st is not None and st["ok"], st
+            assert st["in_doubt"] == [], st["name"]
+        # the kill really forced the reconnect and resubmit paths
+        assert sum(st["reconnects"] for st in exits) >= n_prod
+        assert sum(st["resubmits"] for st in exits) >= 1
+
+        g, src, sink = wordcount.build_graph()
+        ingest = ControlClient(h.ingest_address, io_timeout_s=30.0)
+        ingest.call("flush", 20.0)
+        _, leader_tick, leader_view = ingest.call("view", sink.name)
+        # zero acked-write loss, no double fold: refold every acked
+        # (producer, seq) — the content is a pure function of the id
+        oracle = DirtyScheduler(g)
+        for i, st in enumerate(exits):
+            assert st["acked"]
+            for seq, _status in st["acked"]:
+                oracle.push(src, wordcount.ingest_lines(
+                    [" ".join(producer_batch_words(i, seq))]),
+                    batch_id=f"p{i}-{seq}")
+        oracle.tick()
+        want = {kv: w for kv, w in oracle.view(sink.name).items() if w}
+        assert {kv: w for kv, w in leader_view.items() if w} == want
+
+        # the survivor: exact parity at the new leader's horizon ...
+        (survivor,) = [nm for nm in rnames if nm != h.leader_name]
+        h.barrier(names=[survivor], min_horizon=leader_tick,
+                  timeout_s=30.0)
+        _, rh, rv = h.control(survivor).call("view", sink.name)
+        assert rh == leader_tick
+        assert {kv: w for kv, w in rv.items() if w} == want
+        # ... and a zombie's late shipment is refused by epoch
+        link = RemoteFollower(TcpTransport(),
+                              h.replica_address(survivor), name=survivor)
+        try:
+            link.subscribe()
+            resp = link.receive(Shipment(0, len(_MAGIC), b"", len(_MAGIC),
+                                         False, None, 0, epoch=0))
+        finally:
+            link.close()
+        assert isinstance(resp, ShipNack)
+        assert resp.reason.startswith("fenced")
+        assert h.kills == 1 and h.respawns == 0
+
+    finally:
+        h.close()
+
+
 def test_cli_role_replica_json_status(tmp_path):
     """tools/reflow_proc.py --role replica --json: first stdout line is
     the ready JSON with the OS-assigned address, EOF on stdin is a

@@ -84,8 +84,10 @@ def test_multi_producer_differential_matches_bare_loop():
             bare.push(src2, payload(p, j))
             bare.tick()
     assert dict(sched.view(sink.name)) == dict(bare.view(sink2.name))
-    # coalescing actually engaged: fewer ticks than micro-batches
+    # coalescing actually engaged: fewer ticks than micro-batches,
+    # and the pump never forced a mid-stream sync
     assert sched._tick < n_prod * per
+    assert sched.forced_syncs == 0
     sm = summarize_serve(fe)
     assert sm.applied == n_prod * per
     assert sm.coalesce_factor > 1.0

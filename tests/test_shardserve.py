@@ -112,6 +112,29 @@ def test_sharded_queue_buffers_are_sharded():
         assert sh.spec[0] is None, sh
 
 
+def test_sharded_tenant_served_through_the_tier_pump():
+    """One hot tenant spanning the mesh behind a ``ServeTier``: its
+    submissions commit as fused sharded windows (no fallback) and the
+    view equals the CPU per-tick oracle's exactly."""
+    ticks = _mixed_ticks(23, n_ticks=8)
+    want = _oracle(ticks)
+    g, (s0, s1), r = _small_graph()
+    hot = DirtyScheduler(g, ShardedTpuExecutor(make_mesh(4)))
+    tier = ServeTier(max_bytes=8 << 20, pump_threads=2)
+    try:
+        h = tier.register("hot", hot, GraphConfig(window=WINDOW))
+        srcs = {0: s0, 1: s1}
+        tks = [h.submit(srcs[ix], _batch(rows))
+               for tick in ticks for ix, rows in tick.items()]
+        h.flush(timeout=60)
+        assert all(t.result(timeout=60).applied for t in tks)
+        assert _table(hot, r) == want
+        assert hot.megatick_windows >= 1 and hot.megatick_fallbacks == 0
+        assert hot.executor.device_label == "mesh[4]"
+    finally:
+        tier.close()
+
+
 # -- tenant placement --------------------------------------------------------
 
 def _tpu_graph():
@@ -143,6 +166,12 @@ def test_spread_placement_lands_distinct_devices():
             h.flush(timeout=10)
             want = {j: float(2 * (i + 1)) for j in range(4)}  # map doubles
             assert _table(sched, r) == want
+        scheds = [sched for _h, sched, *_ in handles]
+        assert all(s.megatick_windows >= 1 and s.megatick_fallbacks == 0
+                   for s in scheds)
+        # identical tenants on different devices still trace their
+        # window program once (the plan-signature cache)
+        assert sum(s.executor.megatick_cache_hits for s in scheds) >= 1
     finally:
         tier.close()
 

@@ -355,11 +355,15 @@ def pump_to(sub, horizon, timeout_s=10.0):
         f"subscriber stuck at {sub.horizon} (< {horizon})"
 
 
-def test_wire_reconnect_resumes_gap_free_dup_free(tmp_path):
+@pytest.mark.parametrize("kind,params", [
+    ("view", ()), ("topk", (5, "weight")),
+    # a row live before the partition and retracted during it
+    ("lookup", (("w0", 5.0),))])
+def test_wire_reconnect_resumes_gap_free_dup_free(tmp_path, kind, params):
     sched, ship, rep, hub, src, sink = make_stack(tmp_path)
     lt = LoopbackTransport()
     srv = SubscriptionServer(hub, lt).start()
-    sub = Subscriber(lt, srv.address, sink.name, kind="view",
+    sub = Subscriber(lt, srv.address, sink.name, kind=kind, params=params,
                      policy=wire_policy("sub-p0"))
     srv2 = None
     try:

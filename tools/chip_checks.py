@@ -252,8 +252,6 @@ def _spread_tenants(cfg: dict, devices) -> dict:
     each tenant's state on its own device, ranks against the reference."""
     import jax
 
-    from bench import _build_pagerank
-    from bench_configs import _pad_batch
     from reflow_tpu.executors import get_executor
     from reflow_tpu.scheduler import DirtyScheduler
     from reflow_tpu.serve import (APPLIED, CoalesceWindow, GraphConfig,
@@ -269,9 +267,11 @@ def _spread_tenants(cfg: dict, devices) -> dict:
     tenants = []
     try:
         for i in range(len(devices)):
-            pr, web = _build_pagerank(n, e, cfg["churn"], tol, seed=7 + i)
+            pr = pagerank.build_graph(n, tol=tol, arena_capacity=(
+                pagerank.churn_arena_capacity(e, cfg["churn"])))
+            web = pagerank.WebGraph.random(n, e, seed=7 + i)
             init = web.initial_batch()
-            churn = [_pad_batch(web.churn(cfg["churn"]), n_churn)
+            churn = [web.churn(cfg["churn"]).padded(n_churn)
                      for _ in range(2 * k)]
             sched = DirtyScheduler(pr.graph, get_executor("tpu"))
             h = tier.register(f"pr{i}", sched, GraphConfig(

@@ -5,7 +5,6 @@ Usage::
 
     python tools/fleet_inspect.py --connect HOST:PORT         # live query
     python tools/fleet_inspect.py fleet.json                  # saved snapshot
-    python tools/fleet_inspect.py --bench-dir out/            # bench JSONs
     ... --json                                                # machine form
 
 ``--connect`` dials a :class:`~reflow_tpu.obs.wire.TelemetryServer`
@@ -15,17 +14,11 @@ states / epoch / staleness, the derived cross-node gauges, and the
 alert lines. Exit status is 0 even when nodes are stale — staleness is
 a *reported* condition, not a tool failure; ``--fail-on-alert`` makes
 alerts fatal for CI smokes.
-
-``--bench-dir`` summarizes ``bench.py --json-out`` files instead: every
-``*.json`` carrying a ``reflow.bench/1`` schema stamp is listed by
-mode. Pre-stamp files (older benches) are tolerated and shown as
-``mode=?`` — the reader is backfill-tolerant by design.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -33,7 +26,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 FLEET_SCHEMA = "reflow.fleet/1"
-BENCH_SCHEMA = "reflow.bench/1"
 
 
 def fetch_live(hostport: str, timeout_s: float = 2.0) -> dict:
@@ -63,36 +55,6 @@ def load_snapshot(path: str) -> dict:
                          f"{FLEET_SCHEMA} snapshot "
                          f"(schema={snap.get('schema')!r})")
     return snap
-
-
-def read_bench_dir(path: str) -> dict:
-    """Summarize ``bench.py --json-out`` files under ``path``. Files
-    without the ``reflow.bench/1`` stamp (pre-stamp benches) are kept
-    with ``mode=None`` rather than rejected."""
-    entries = []
-    for p in sorted(glob.glob(os.path.join(path, "*.json"))):
-        try:
-            with open(p) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        if not isinstance(doc, dict):
-            continue
-        if doc.get("schema") not in (BENCH_SCHEMA, None):
-            continue  # some other tool's JSON (fleet/trace/...)
-        if doc.get("schema") is None and "mode" not in doc \
-                and not any(k.endswith("_per_s") or k == "results"
-                            for k in doc):
-            continue  # doesn't look like a bench result at all
-        entries.append({"file": os.path.basename(p),
-                        "schema": doc.get("schema"),
-                        "mode": doc.get("mode"),
-                        "keys": sorted(doc)[:12]})
-    return {"schema": "reflow.fleet_benchdir/1", "dir": path,
-            "benches": entries,
-            "stamped": sum(1 for e in entries
-                           if e["schema"] == BENCH_SCHEMA),
-            "unstamped": sum(1 for e in entries if e["schema"] is None)}
 
 
 def _print_fleet(snap: dict) -> None:
@@ -156,41 +118,23 @@ def _print_fleet(snap: dict) -> None:
         print(f"  ALERT: {line}")
 
 
-def _print_benchdir(summary: dict) -> None:
-    print(f"{summary['dir']}: {len(summary['benches'])} bench file(s) "
-          f"({summary['stamped']} stamped, "
-          f"{summary['unstamped']} pre-stamp)")
-    for e in summary["benches"]:
-        mode = e["mode"] if e["mode"] is not None else "?"
-        print(f"  {e['file']:<32} mode={mode}")
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("snapshot", nargs="?",
                     help="saved reflow.fleet/1 JSON file")
     ap.add_argument("--connect", metavar="HOST:PORT",
                     help="dial a live TelemetryServer instead")
-    ap.add_argument("--bench-dir", metavar="DIR",
-                    help="summarize bench.py --json-out files instead")
     ap.add_argument("--json", action="store_true",
                     help="print the summary as one JSON line")
     ap.add_argument("--fail-on-alert", action="store_true",
                     help="exit 1 when the fleet has any alert line")
     args = ap.parse_args(argv)
-    if args.bench_dir:
-        summary = read_bench_dir(args.bench_dir)
-        if args.json:
-            print(json.dumps(summary))
-        else:
-            _print_benchdir(summary)
-        return 0
     if args.connect:
         snap = fetch_live(args.connect)
     elif args.snapshot:
         snap = load_snapshot(args.snapshot)
     else:
-        ap.error("need a snapshot file, --connect, or --bench-dir")
+        ap.error("need a snapshot file or --connect")
         return 2
     if args.json:
         print(json.dumps(snap))

@@ -22,11 +22,12 @@ below the noise floor. The first line printed names the device — a
 number from a CPU run is not a device time.
 
 Usage:  python tools/profile_tick.py            # full scale, on the chip
-        REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu python tools/profile_tick.py
+        JAX_PLATFORMS=cpu python tools/profile_tick.py --tiny
 """
 
 from __future__ import annotations
 
+import argparse
 import os
 import sys
 import time
@@ -35,19 +36,20 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from reflow_tpu.utils.config import env_flag  # noqa: E402
-
 
 def log(*a):
     print(*a, file=sys.stderr, flush=True)
 
 
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="1k nodes / 10k edges: the CPU sanity size")
+    tiny = ap.parse_args().tiny
+
     import jax
     import jax.numpy as jnp
 
-    from bench import _build_pagerank
-    from bench_configs import _barrier, _timed_tick
     from reflow_tpu.delta import DeltaBatch
     from reflow_tpu.executors import get_executor
     from reflow_tpu.scheduler import DirtyScheduler
@@ -59,13 +61,14 @@ def main():
     print(f"platform={dev.platform} device_kind={dev.device_kind} "
           f"devices={len(jax.devices())}")
 
-    smoke = env_flag("REFLOW_BENCH_SMOKE")
-    n_nodes = 1_000 if smoke else 100_000
-    n_edges = 10_000 if smoke else 1_000_000
+    n_nodes = 1_000 if tiny else 100_000
+    n_edges = 10_000 if tiny else 1_000_000
     churn = 0.01
-    K = 4 if smoke else 8
+    K = 4 if tiny else 8
 
-    pr, web = _build_pagerank(n_nodes, n_edges, churn, 1e-4)
+    pr = pagerank.build_graph(n_nodes, tol=1e-4, arena_capacity=(
+        pagerank.churn_arena_capacity(n_edges, churn)))
+    web = pagerank.WebGraph.random(n_nodes, n_edges, seed=7)
     ex = get_executor("tpu")
     sched = DirtyScheduler(pr.graph, ex)
     sched.push(pr.teleport, pagerank.teleport_batch(n_nodes))
@@ -74,7 +77,8 @@ def main():
 
     # absorb the churn-shape compile
     sched.push(pr.edges, web.churn(churn))
-    _timed_tick(sched)
+    sched.tick()
+    jax.block_until_ready(ex.states)
 
     # churn batches are retract+insert pairs over m rewired edges; size
     # the zero batch the same WITHOUT calling churn() (churn mutates the
@@ -89,7 +93,7 @@ def main():
     def window(feeds, tag):
         t0 = time.perf_counter()
         agg = sched.tick_many(feeds)
-        _barrier(ex)
+        jax.block_until_ready(ex.states)
         wall = time.perf_counter() - t0
         agg.block()
         log(f"{tag}: {wall:.3f}s for {len(feeds)} ticks "

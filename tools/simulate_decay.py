@@ -1,7 +1,7 @@
 """Offline frontier-decay model of the PageRank churn tick (numpy).
 
 Reproduces the delta-vector loop's per-pass dynamics (tol-gated emission
-diff over the bench graph at full scale) on the host, to size the budget
+diff over the 100k-node / 1M-edge graph) on the host, to size the budget
 tiers against the REAL frontier: per pass it reports live frontier keys,
 frontier edges, which gather tier the device loop would pick, and the
 modeled gather/scatter row cost. This is the tool that says whether the
@@ -22,13 +22,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():
-    from bench import _build_pagerank
     from reflow_tpu.executors.linear_fixpoint import _edge_budget_tiers
+    from reflow_tpu.workloads import pagerank
 
     n_nodes, n_edges, churn, tol = 100_000, 1_000_000, 0.01, 1e-4
     damping = 0.85
-    pr, web = _build_pagerank(n_nodes, n_edges, churn, tol)
-    arena_cap = pr.join.op.arena_capacity
+    web = pagerank.WebGraph.random(n_nodes, n_edges, seed=7)
+    arena_cap = pagerank.churn_arena_capacity(n_edges, churn)
     tiers = _edge_budget_tiers(arena_cap)
     print(f"arena {arena_cap}, tiers {tiers}")
 

@@ -1,19 +1,19 @@
 #!/usr/bin/env bash
-# The tier-1 verify gate, verbatim from ROADMAP.md — CI and humans run
-# the IDENTICAL command (CPU-forced jax, `slow`-marked tests excluded,
-# collection errors tolerated so one broken module can't hide the rest).
-# Prints DOTS_PASSED=<n> (count of passing-test dots) and exits with
-# pytest's status.
+# The tier-1 verify gate — CI and humans run the IDENTICAL command
+# (CPU-forced jax, `slow`-marked tests excluded, collection errors
+# tolerated so one broken module can't hide the rest). Prints
+# DOTS_PASSED=<n> (count of passing-test dots) and exits with pytest's
+# status. The floor on that count is the driver's (PERF_LEDGER.jsonl).
 set -o pipefail
 cd "$(dirname "$0")/.."
 
-# fast-fail static pass BEFORE the 15-minute pytest budget: a syntax
-# error or obvious undefined name should cost seconds, not a timeout.
-# pyflakes is optional in the image; compileall is stdlib.
-python -m compileall -q reflow_tpu tests tools bench.py bench_configs.py \
+# fast-fail static pass BEFORE the pytest budget: a syntax error or an
+# obvious undefined name should cost seconds, not a timeout. pyflakes
+# is optional in the image; compileall is stdlib.
+python -m compileall -q reflow_tpu tests tools chip_smoke.py \
   || { echo "TIER1: compileall failed"; exit 2; }
 if python -c "import pyflakes" 2>/dev/null; then
-  python -m pyflakes reflow_tpu bench.py bench_configs.py \
+  python -m pyflakes reflow_tpu chip_smoke.py \
     || { echo "TIER1: pyflakes failed"; exit 2; }
 fi
 # reflow-lint: the project's own invariant checker (lock discipline,
@@ -30,519 +30,16 @@ rc=${PIPESTATUS[0]}
 dots=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
 echo DOTS_PASSED=$dots
 
-# regression floor: the suite passed 570 at the PR-20 baseline (533 at
-# PR 18, 395 at PR 11, 380 at PR 10, 333 at PR 8, 315 at PR 6); a run
-# below the previous baseline means previously-green tests broke (or
-# silently vanished), even if pytest's own exit status reads clean.
-FLOOR=${TIER1_FLOOR:-560}
-if [ "$dots" -lt "$FLOOR" ]; then
-  echo "TIER1: DOTS_PASSED=$dots below floor $FLOOR"
-  rc=4
-fi
-
-# optional (RUN_BENCH=1): the lockcheck smoke — re-run the concurrent
-# suites (serve/tier/failover: producers, pump pools, shippers,
-# failover coordinator) with the runtime lock-order monitor armed.
-# Every named_lock acquisition feeds the held-before graph; ANY cycle
-# raises LockOrderError and fails the run. The static twin is the
-# reflow-lint lock pass above; this leg catches the orders the AST
-# can't see (callbacks, cross-module call chains).
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
+# the lockcheck re-run: the concurrent suites (serve/tier/failover:
+# producers, pump pools, shippers, failover coordinator) with the
+# runtime lock-order monitor armed. Every named_lock acquisition feeds
+# the held-before graph; ANY cycle raises LockOrderError and fails the
+# run. The static twin is the reflow-lint lock pass above; this catches
+# the orders the AST can't see (callbacks, cross-module call chains).
+if [ $rc -eq 0 ]; then
   REFLOW_LOCKCHECK=1 JAX_PLATFORMS=cpu timeout -k 10 600 \
     python -m pytest tests/test_serve.py tests/test_tier.py \
     tests/test_failover.py -q -m 'not slow' -p no:cacheprovider \
-    || { echo "TIER1: lockcheck smoke failed"; rc=3; }
-fi
-
-# optional (RUN_BENCH=1): the serve-mode smoke — sustained ingestion
-# throughput must coalesce (>1 micro-batch/tick at 16 producers) with
-# zero forced syncs; ~seconds on CPU at smoke scale.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_SERVE=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py > /tmp/_t1_serve.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_serve.json"))
-assert r["coalesce_gt_1_at_16p"], r
-assert r["zero_forced_syncs"], r
-print(f"TIER1 serve smoke: {r['serve_16p_rows_per_s']} rows/s @16p, "
-      f"coalesce {r['serve_16p_coalesce_factor']}x")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the tier-mode smoke — 4 graphs x 4 producers
-# on a 2-thread pump pool: zero forced syncs, pump-crash isolation with
-# exactly-once recovery, and a bounded quiet-tenant admission p99.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_TIER=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py > /tmp/_t1_tier.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_tier.json"))
-assert r["zero_forced_syncs"], r
-assert r["crash_exactly_once"], r
-assert r["quiet_p99_bounded"], r
-print(f"TIER1 tier smoke: {r['tier_rows_per_s_4g_2threads']} rows/s "
-      f"(4g, 2 threads), crash isolation ok, quiet p99 "
-      f"{r['quiet_admission_p99_us']}us")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the control-mode smoke — the self-healing
-# control plane under step load: during a hot-tenant surge only the
-# surging graph is browned out (the quiet tenant's admission p99 stays
-# bounded), the tier returns to its configured policies within the
-# analytic bound of control intervals after the surge ends, and a
-# pump-crash storm trips the circuit breaker then heals through
-# half-open with no manual intervention.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_CONTROL=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py > /tmp/_t1_control.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_control.json"))
-assert r["quiet_p99_bounded"], r
-assert r["only_hot_degraded"], r
-assert r["recovered_within_bound"], r
-assert r["breaker_opened"], r
-assert r["breaker_recovered"], r
-assert r["sibling_applied_during_storm"], r
-assert r["post_recovery_applied"], r
-print(f"TIER1 control smoke: quiet p99 {r['quiet_admission_p99_us']}us "
-      f"during surge, recovered in {r['recovery_ticks']} ticks "
-      f"(bound {r['recovery_bound_ticks']}), breaker open->closed in "
-      f"{r['breaker_heal_s']}s")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the obs-mode smoke — tracing + telemetry on
-# the 16-producer serve protocol: the exported chrome trace must be
-# valid JSON with span events, and every sampled ticket's stage
-# durations must sum to within 10% of its end-to-end latency.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_OBS=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    REFLOW_TRACE_OUT=/tmp/_t1_obs_trace.json \
-    timeout -k 10 300 python bench.py > /tmp/_t1_obs.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_obs.json"))
-assert r["decomposition_ok"], r
-assert r["snapshot_schema_ok"], r
-t = json.load(open(r["trace_file"]))  # must parse as chrome trace JSON
-evs = [e for e in t["traceEvents"] if e.get("ph") == "X"]
-assert evs and all("ts" in e and "dur" in e and "tid" in e for e in evs), \
-    "trace events malformed"
-print(f"TIER1 obs smoke: {r['sampled_tickets']} tickets decomposed "
-      f"(max dev {100 * r['decomposition_max_dev_frac']:.2f}%), "
-      f"{len(evs)} trace spans, overhead "
-      f"{100 * r['obs_overhead_frac']:.2f}%")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the walpipe-mode smoke — the asynchronous
-# durability pipeline: device-resident pre-imaged submissions under
-# fsync="record" must log with ZERO forced materialize readbacks, and
-# the pipelined committer must not be slower than the inline one.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_WALPIPE=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py > /tmp/_t1_walpipe.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_walpipe.json"))
-assert r["zero_materialize_readbacks"], r
-assert r["pipelined_ge_inline"], r
-assert r["replay_view_matches"], r
-print(f"TIER1 walpipe smoke: {r['walpipe_speedup_16p']}x pipelined vs "
-      f"inline @16p, 0 log readbacks, replay ok")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the mega-tick smoke — the compiled window
-# path must engage (no fallbacks), produce views identical to the
-# per-tick twin, and keep the amortized per-tick wall within a generous
-# CI bound of the window's dispatch wall (the acceptance target is 2x
-# on device; CPU-backed CI gets slack for scheduling noise).
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_MEGATICK=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py > /tmp/_t1_megatick.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_megatick.json"))
-assert r["views_match"], r
-assert r["megatick_fallbacks"] == 0, r
-assert r["amortized_over_dispatch_x"] < 25, r
-print(f"TIER1 megatick smoke: tick_s_amortized {r['tick_s_amortized']}s "
-      f"vs window_dispatch_s {r['window_dispatch_s']}s "
-      f"({r['amortized_over_dispatch_x']}x), "
-      f"{r['megatick_windows']} fused windows, views match")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the pipeline smoke — pipelined window
-# execution: depth 2 must produce tables EXACTLY equal to depth 1 (same
-# fused program, same slots, same order — bitwise), never fall back to
-# per-tick, genuinely overlap host staging with in-flight dispatch
-# (stage_overlap_frac > 0), and pay no amortized-tick throughput tax.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_PIPELINE=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py > /tmp/_t1_pipeline.json || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_pipeline.json"))
-assert r["views_match"] and r["max_abs_diff"] == 0.0, r
-assert r["twin_views_match"], r
-assert r["zero_fallbacks"], r
-assert r["overlap_at_depth2"], r
-assert r["depth2_not_slower"], r
-print(f"TIER1 pipeline smoke: depth2 {r['depth2_tick_s_amortized']}s/tick "
-      f"vs depth1 {r['depth1_tick_s_amortized']}s/tick "
-      f"({r['depth2_vs_depth1_x']}x), overlap "
-      f"{100 * r['depth2_stage_overlap_frac']:.0f}%, parity exact")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the shardserve smoke — pod-scale serving
-# under 8 forced host devices: spread tenants must land on distinct
-# devices and share window programs (cache hits), the sharded hot
-# tenant must run fused windows across the mesh, views must match the
-# CPU oracle EXACTLY, and no config may fall back. The >=-baseline
-# rows/s acceptance holds on real multi-chip hardware; forced host
-# devices share the CI cores, so here the flags carry the bench's
-# documented cpu slack and the smoke asserts them plus exactness.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_SHARDSERVE=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 590 python bench.py --json-out /tmp/_t1_shardserve.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_shardserve.json"))
-assert r["views_match"], r
-assert r["spread_max_abs_diff"] == 0.0, r
-assert r["sharded_max_abs_diff"] == 0.0, r
-assert r["spread_fallbacks"] == 0 and r["sharded_fallbacks"] == 0, r
-assert r["spread_devices_distinct"], r
-assert r["spread_cache_hits"] > 0, r
-assert r["spread_ge_baseline"] and r["sharded_ge_baseline"], r
-print(f"TIER1 shardserve smoke: spread {r['spread_rows_per_s']} rows/s "
-      f"on {len(r['spread_devices'])} devices "
-      f"({r['spread_cache_hits']} shared-program hits), sharded "
-      f"{r['sharded_rows_per_s']} rows/s on {r['sharded_device']}, "
-      f"views exact")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the replica smoke — WAL shipping + read
-# replicas under sustained 16-producer writes: leader-vs-replica views
-# at the same horizon must match EXACTLY, replica lag must settle
-# within one commit window after quiesce, and aggregate replica read
-# QPS must beat the serialized leader baseline. The acceptance target
-# is >=2x with 4 replicas; CI cores are shared between producers,
-# shipper, replayers, and readers, so the smoke gate takes the bench's
-# documented CPU slack (>=1.5x) and asserts exactness + lag unchanged.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_REPLICA=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py --json-out /tmp/_t1_replica.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_replica.json"))
-assert r["parity_max_abs_diff"] == 0.0, r
-assert r["lag_bound_ok"], r
-assert r["ship_nacks"] == 0, r
-assert r["read_scaling_x"] >= 1.5, r
-print(f"TIER1 replica smoke: {r['replicas']} replicas "
-      f"{r['replica_read_qps']} reads/s vs leader "
-      f"{r['leader_read_qps']} reads/s ({r['read_scaling_x']}x), "
-      f"parity exact, final lag {r['final_lag_ticks']} ticks "
-      f"(bound {r['window_ticks']})")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the failover smoke — kill the leader under
-# sustained 16-producer writes: the FailoverCoordinator must detect,
-# fence, elect and promote within a bounded wall; zero acked-write loss
-# (final view == a fold of every acked batch, exactly once); the new
-# leader's view at the promotion horizon must equal the winner-
-# replica's published view EXACTLY; the zombie's appends rejected.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_FAILOVER=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py --json-out /tmp/_t1_failover.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_failover.json"))
-assert r["acked_loss_max_abs_diff"] == 0, r
-assert r["promotion_parity_max_abs_diff"] == 0, r
-assert r["fence_rejected_appends"] >= 1, r
-assert r["epoch"] == 1, r
-assert r["detection_s"] + r["promotion_s"] + r["first_window_s"] < 30, r
-print(f"TIER1 failover smoke: {r['winner']} promoted to epoch "
-      f"{r['epoch']} — detect {r['detection_s']}s, promote "
-      f"{r['promotion_s']}s, first window {r['first_window_s']}s; "
-      f"{r['acked_batches']} acked batches, zero loss, parity exact")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the chaos smoke — WAL shipping over real TCP
-# links through the seeded fault injector (drop/dup/reorder/corrupt/
-# delay + a scripted one-way partition and connection reset), then
-# quiesce and a leader kill: zero acked-write loss, exact view parity
-# at equal horizons, lag <= one commit window after faults stop, and
-# every post-fence shipment from the ex-leader NACKed, never ACKed.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_CHAOS=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py --json-out /tmp/_t1_chaos.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_chaos.json"))
-assert r["acked_loss_max_abs_diff"] == 0, r
-assert r["parity_max_abs_diff"] == 0, r
-assert r["promotion_parity_max_abs_diff"] == 0, r
-assert r["lag_after_quiesce_ticks"] <= r["window_ticks"], r
-assert r["ex_leader_fence_nacks"] >= 1, r
-assert r["ex_leader_post_fence_acks"] == 0, r
-assert r["reconnects_total"] >= 1, r
-assert r["retransmit_bytes"] > 0, r
-print(f"TIER1 chaos smoke: {r['acked_batches']} acked batches, zero "
-      f"loss, parity exact at equal horizons; converged "
-      f"{r['converge_s']}s after quiesce (lag "
-      f"{r['lag_after_quiesce_ticks']} <= {r['window_ticks']}); "
-      f"{r['reconnects_total']} reconnect(s), "
-      f"{r['retransmit_bytes']} retransmit byte(s); ex-leader fenced "
-      f"({r['ex_leader_fence_nacks']} NACK(s), 0 ACKs)")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the bounded-history smoke — two identically-
-# fed 16-producer legs (unbounded oracle vs incremental checkpoint
-# chain + key-level WAL compaction): history >= 10x live state, leader
-# crash-recovery AND fresh-replica bootstrap each >= 5x faster than
-# full-history replay and within 2x of a fresh-full-checkpoint
-# restore, exact view parity everywhere, zero acked-write loss, and a
-# bounded on-disk footprint after the final compaction pass.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_COMPACT=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py --json-out /tmp/_t1_compact.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_compact.json"))
-assert r["parity_max_abs_diff"] == 0, r
-assert r["zero_acked_loss"], r
-assert r["history_ratio_ok"], r
-assert r["recover_speedup_ok"], r
-assert r["bootstrap_speedup_ok"], r
-assert r["recover_near_floor_ok"], r
-assert r["bootstrap_near_floor_ok"], r
-assert r["footprint_bounded_ok"], r
-assert r["chain_saves"] >= 1 and r["compact_folds"] >= 1, r
-print(f"TIER1 compact smoke: history {r['history_ratio']}x state — "
-      f"recover {r['recover_speedup_x']}x, bootstrap "
-      f"{r['bootstrap_speedup_x']}x vs full replay (floor "
-      f"{r['fresh_full_restore_s']}s); {r['acked_batches']} acked "
-      f"batches, parity exact, zero loss; {r['compact_folds']} "
-      f"fold(s), {r['chain_saves']} chain save(s), footprint "
-      f"{r['wal_bounded_bytes']}/{r['wal_full_bytes']} bytes")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the tiles smoke — tiled maintenance: two
-# identically-fed legs at state >= 8x the tile budget; the tiled leg
-# must bound compaction and checkpoint writer/reader peaks under 2x
-# budget, recover + bootstrap (through the per-file tile-unit
-# protocol) with exact parity vs the monolithic leg, survive a kill
-# at every per-tile crash seam with zero acked loss, answer top-k and
-# point lookups identically to an untiled snapshot oracle, and keep
-# small-state restore walls within 1.2x of untiled.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_TILES=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 590 python bench.py --json-out /tmp/_t1_tiles.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_tiles.json"))
-assert r["schema"] == "reflow.bench/1" and r["mode"] == "tiles", r
-assert r["legs_parity_max_abs_diff"] == 0, r
-assert r["zero_acked_loss"], r
-assert r["state_over_budget_x"] >= 8, r
-assert 0 < r["compact_peak_tile_bytes"] <= 2 * r["tile_bytes"], r
-assert 0 < r["ckpt_writer_peak_bytes"] <= 2 * r["tile_bytes"], r
-assert 0 < r["ckpt_reader_peak_bytes"] <= 2 * r["tile_bytes"], r
-assert r["ckpt_tile_count"] >= 4, r
-assert r["tile_bootstraps"] >= 1 and r["tile_units_shipped"] > 0, r
-assert r["topk_parity_ok"], r
-assert len(r["crash_seams_survived"]) == 4, r
-assert r["restore_wall_ok"] and r["bootstrap_wall_ok"], r
-print(f"TIER1 tiles smoke: state {r['state_over_budget_x']}x budget — "
-      f"compact peak {r['compact_peak_tile_bytes']}B, ckpt peaks "
-      f"{r['ckpt_writer_peak_bytes']}/{r['ckpt_reader_peak_bytes']}B "
-      f"(budget {r['tile_bytes']}B), {r['ckpt_tile_count']} tiles, "
-      f"{r['tile_units_shipped']} unit(s) shipped, "
-      f"{len(r['crash_seams_survived'])} seam(s) survived, walls "
-      f"{r['restore_wall_ratio_x']}x/{r['bootstrap_wall_ratio_x']}x, "
-      f"parity exact, zero loss")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the fleetobs smoke — the fleet telemetry
-# plane on the replicated TCP topology: aggregator horizons must EQUAL
-# ground truth at quiesce, at least one post-heal causal chain must
-# span ship_segment->net_send->replica_replay (re-checked through
-# trace_inspect --require-chain), the aggregator must keep serving
-# stale-marked through a telemetry-link partition and recover, the
-# saved fleet snapshot must round-trip through fleet_inspect as
-# reflow.fleet/1, and every bench JSON this run produced must carry
-# the reflow.bench/1 stamp (fleet_inspect --bench-dir). The <3%
-# overhead acceptance holds on an uncontended host; shared CI cores
-# make wall ratios noise, so the smoke takes a generous sanity ceiling
-# and prints the measured number.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_FLEETOBS=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    REFLOW_TRACE_OUT=/tmp/_t1_fleet_trace.json \
-    timeout -k 10 590 python bench.py --json-out /tmp/_t1_fleetobs.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_fleetobs.json"))
-assert r["schema"] == "reflow.bench/1" and r["mode"] == "fleetobs", r
-assert r["lag_spread_agg"] == r["lag_spread_truth"], r
-assert r["lag_after_quiesce_ticks"] == 0, r
-assert r["post_heal_required_chains"] >= 1, r
-assert r["stale_during_partition"] == ["r0"], r
-assert r["telemetry_partition_recovered"], r
-assert r["fleet_nodes"] == r["replicas"] + 1, r
-assert r["fleetobs_overhead_frac"] < 0.5, r
-print(f"TIER1 fleetobs smoke: {r['fleet_nodes']} nodes, lag spread "
-      f"{r['lag_spread_agg']} == truth, "
-      f"{r['post_heal_required_chains']} post-heal causal chain(s), "
-      f"served stale-marked through telemetry partition "
-      f"({r['telemetry_dropped_r0']} dropped), overhead "
-      f"{100 * r['fleetobs_overhead_frac']:.2f}%")
-EOF
-  python tools/trace_inspect.py /tmp/_t1_fleet_trace.json \
-    --require-chain ship_segment,net_send,replica_replay > /dev/null \
-    || { echo "TIER1: fleetobs require-chain failed"; rc=3; }
-  python tools/fleet_inspect.py /tmp/reflow_fleet_snapshot.json --json \
-    > /tmp/_t1_fleet_snap.json \
-    || { echo "TIER1: fleet_inspect snapshot failed"; rc=3; }
-  python - <<'EOF' || rc=3
-import json
-s = json.load(open("/tmp/_t1_fleet_snap.json"))
-assert s["schema"] == "reflow.fleet/1", s
-assert s["gauges"]["nodes_total"] >= 4 and not s["alerts"], s
-d = json.load(__import__("os").popen(
-    "python tools/fleet_inspect.py --bench-dir /tmp --json"))
-assert d["schema"] == "reflow.fleet_benchdir/1", d
-assert any(e["mode"] == "fleetobs" for e in d["benches"]), d
-print(f"TIER1 fleetobs consumers: fleet/1 snapshot ok "
-      f"({s['gauges']['nodes_total']} nodes, 0 alerts), bench dir "
-      f"{d['stamped']} stamped / {d['unstamped']} pre-stamp")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the multiproc smoke — the whole control
-# plane as real OS processes (leader + replicas + remote producers
-# over the ingestion RPC), a kill -9 storm over every replica
-# (respawn, recover over the mirrored WAL, rejoin through the
-# cross-process horizon barrier) and then the leader (cross-process
-# promotion; producers retarget and resubmit through the hello dedup
-# handshake): zero acked-write loss vs a deterministic refold oracle,
-# exact survivor parity at the promoted leader's horizon, empty
-# in-doubt set on every producer, every kill accounted for. Children
-# are reaped with deadlines — a wedged child fails the smoke instead
-# of hanging it.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_MULTIPROC=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 590 python bench.py --json-out /tmp/_t1_multiproc.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_multiproc.json"))
-assert r["acked_loss_max_abs_diff"] == 0, r
-assert r["parity_max_abs_diff"] == 0, r
-assert r["epoch"] == 1, r
-assert r["fleet_nodes_seen"], r
-assert r["reconnects_total"] >= r["producers"], r
-assert r["resubmits_total"] >= 1, r
-assert r["kills"] == r["replicas"] + 1, r
-assert r["respawns"] == r["replicas"], r
-print(f"TIER1 multiproc smoke: {r['replicas']} replica + "
-      f"{r['producers']} producer processes — {r['kills']} kill -9s, "
-      f"{r['respawns']} respawns, {r['winner']} promoted to epoch "
-      f"{r['epoch']} in {r['promotion_s']}s; {r['acked_batches']} "
-      f"acked batches, zero loss, survivor parity exact at tick "
-      f"{r['leader_tick']}; {r['reconnects_total']} reconnect(s), "
-      f"{r['resubmits_total']} resubmit(s), {r['deduped_total']} "
-      f"deduped")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the subs smoke — reactive reads: one
-# replica's SubscriptionHub fanning per-window deltas to simulated
-# subscribers (plus real wire subscribers through a mid-run
-# partition + heal of their endpoint) under sustained 16-producer
-# writes: exact push-vs-pull parity at equal horizons, zero gaps and
-# zero duplicate applies on resume, and the write path's admission
-# p99 within 2x the no-subscriber baseline (with the bench's
-# documented absolute floor so shared CI cores can't turn scheduler
-# jitter into a spurious fail).
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_SUBS=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 300 python bench.py --json-out /tmp/_t1_subs.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_subs.json"))
-assert r["schema"] == "reflow.bench/1" and r["mode"] == "subs", r
-assert r["subs"]["parity_max_abs_diff"] == 0, r
-assert r["write_p99_bounded"], r
-assert r["subs"]["active_subs"] >= r["subscribers"], r
-assert r["subs"]["wire_reconnects"] >= r["wire_subscribers"], r
-print(f"TIER1 subs smoke: {r['subscribers']} subscribers, "
-      f"{r['subs']['fanout_rows_per_s']} fan-out rows/s, write p99 "
-      f"{r['write_p99_overhead_x']}x baseline (bounded), parity "
-      f"exact, {r['subs']['wire_reconnects']} wire reconnect(s) "
-      f"gap-free")
-EOF
-fi
-
-# optional (RUN_BENCH=1): the e2etrace smoke — follow-the-write across
-# the whole process fleet: sampled writes must stitch one causal chain
-# producer_submit -> rpc_admit -> admission -> wal_append ->
-# ship_segment -> net_send -> replica_replay -> sub_fanout ->
-# sub_deliver through a kill -9 of a replica AND the leader (with a
-# post-promotion chain in the new epoch), the ack->push freshness
-# decomposition must tile end-to-end latency within 10%, unstamped
-# wire messages must stay byte-identical to the legacy encoding, and
-# every killed child's flight recording must be recoverable from its
-# disk corner. The kept traces are re-checked through trace_inspect
-# --require-chain, same as a human post-mortem would.
-if [ "${RUN_BENCH:-0}" = "1" ] && [ $rc -eq 0 ]; then
-  REFLOW_BENCH_E2ETRACE=1 REFLOW_BENCH_SMOKE=1 JAX_PLATFORMS=cpu \
-    timeout -k 10 590 python bench.py --json-out /tmp/_t1_e2etrace.json \
-    > /dev/null || rc=3
-  python - <<'EOF' || rc=3
-import json
-r = json.load(open("/tmp/_t1_e2etrace.json"))
-assert r["schema"] == "reflow.bench/1" and r["mode"] == "e2etrace", r
-assert r["wire_compat_identical"], r
-assert r["full_chains"] >= 1, r
-assert r["required_chains"] >= 1, r
-assert r["freshness_max_dev_frac"] <= 0.10, r
-assert r["post_promotion_submits"] >= 1, r
-assert "leader" in r["flight_nodes"], r
-print(f"TIER1 e2etrace smoke: {r['full_chains']} full chain(s) across "
-      f"{r['trace_files_merged']} processes, freshness e2e p50 "
-      f"{r['freshness_e2e_p50_us']:.0f}us (tiling dev "
-      f"{100 * r['freshness_max_dev_frac']:.2f}%), "
-      f"{r['post_promotion_submits']} post-promotion sampled "
-      f"submit(s), flight recordings from "
-      f"{len(r['flight_nodes'])} node(s)")
-EOF
-  python tools/trace_inspect.py /tmp/reflow_e2etrace_traces/*-trace.json \
-    --require-chain producer_submit,rpc_admit,admission,wal_append,ship_segment,net_send,replica_replay,sub_fanout,sub_deliver \
-    > /dev/null \
-    || { echo "TIER1: e2etrace require-chain failed"; rc=3; }
+    || { echo "TIER1: lockcheck run failed"; rc=3; }
 fi
 exit $rc
