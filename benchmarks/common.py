@@ -73,7 +73,7 @@ def bucket_capacity(n: int, floor: int = 64) -> int:
 def pad_batch(batch: DeltaBatch, rows: int) -> DeltaBatch:
     """Pad a host batch to ``rows`` with weight-0 rows (semantic no-ops),
     so every batch of a mix lands in one capacity bucket. Copied from
-    ``bench_configs._pad_batch``."""
+    ``DeltaBatch.padded``."""
     n = len(batch)
     if n >= rows:
         return batch

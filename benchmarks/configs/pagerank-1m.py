@@ -3,9 +3,9 @@
 the program's (``reflow_tpu.workloads.pagerank.build_graph``).
 
 ``WebGraph``, the churn, ``reference_ranks`` and ``ranks_to_array`` are
-copied from ``reflow_tpu/workloads/pagerank.py`` and the arena sizing
-from ``bench._build_pagerank``, so that later changes to those files do
-not move the yardstick. Differences from the originals: the out-degree
+copied from ``reflow_tpu/workloads/pagerank.py``, the arena sizing from
+its ``churn_arena_capacity``, so that later changes to that file do not
+move the yardstick. Differences from the originals: the out-degree
 is computed once (rewiring preserves it; the original recomputes it over
 all edges for every batch), and churn is drawn per lane from that lane's
 own edges, so lanes never touch the same edge and their batches commute.
@@ -133,7 +133,8 @@ class Reference:
 
 def build(cfg: dict):
     """The deployment's graph, from the program; arena sized for live
-    rows plus churn headroom as ``bench._build_pagerank`` does."""
+    rows plus churn headroom as ``workloads.pagerank.churn_arena_capacity``
+    does."""
     from reflow_tpu.workloads import pagerank
 
     churn_cap = bucket_capacity(

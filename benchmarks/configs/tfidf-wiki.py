@@ -14,7 +14,12 @@ system is sent is the change in the article's term counts, one row per
 The interning of pairs and the delta an edit produces follow
 ``reflow_tpu/workloads/tfidf.py`` (``Corpus.edit``), over NumPy arrays
 and not dicts of Python ints, because an article here has thousands of
-tokens and a corpus tens of millions. Each lane edits only its own
+tokens and a corpus tens of millions (only the few pairs an article
+gets after the load are kept in a dict). Minting an edit is what every
+run pays for twice, in the generator before the window and in the
+leader after it, a quarter of a million times in the backlog cell: it
+is written for few NumPy calls (``tests/test_streams.py`` holds it to
+the plain formulation). Each lane edits only its own
 articles (``doc % lanes``), so lanes' batches commute and an edit's
 retractions always find their rows.
 """
@@ -75,6 +80,7 @@ class Stream:
         self.docs: List[np.ndarray] = []             # doc -> its tokens
         self.doc_terms: List[np.ndarray] = []        # doc -> sorted terms
         self.doc_pids: List[np.ndarray] = []         # ... and their pair ids
+        self.doc_later: List[dict] = []              # pairs since the load
         rng = np.random.default_rng([seed, 0])
         self._load_rng = rng
         rank = rng.permutation(cfg["docs"])          # doc -> Zipf rank
@@ -113,6 +119,7 @@ class Stream:
         cuts = np.searchsorted(doc, np.arange(1, n)).tolist()
         self.doc_terms = np.split(term, cuts)
         self.doc_pids = np.split(pids, cuts)
+        self.doc_later = [None] * n
         vals = np.stack([term, doc], axis=-1).astype(np.float32)
         parts = max(1, -(-self.used // cfg["load_rows_per_tick"]))
         edges = np.linspace(0, self.used, parts + 1).astype(np.int64)
@@ -122,24 +129,47 @@ class Stream:
 
     def _pids(self, doc: int, terms: np.ndarray) -> np.ndarray:
         """Pair ids of one article's ``terms`` (sorted, unique); a pair
-        not seen before gets the next id (``Corpus._pair``)."""
+        not seen before gets the next id (``Corpus._pair``). The pairs
+        of the load are looked up in its sorted arrays; a pair the
+        article got since is kept in a dict, ``term -> pair id``, which
+        ``filed`` sorts in when the reference asks."""
         have, pids = self.doc_terms[doc], self.doc_pids[doc]
-        pos = np.searchsorted(have, terms)
-        hit = pos < len(have)
-        hit[hit] = have[pos[hit]] == terms[hit]
-        out = np.empty(len(terms), np.int64)
-        out[hit] = pids[pos[hit]]
-        fresh = ~hit
-        k = int(fresh.sum())
-        if k:
-            if self.used + k > self.n_pairs:
+        # an article has terms from the load on: ``have`` is not empty
+        near = np.minimum(np.searchsorted(have, terms), len(have) - 1)
+        out = pids[near]
+        miss = np.flatnonzero(have[near] != terms)
+        if len(miss):
+            later = self.doc_later[doc]
+            if later is None:
+                later = self.doc_later[doc] = {}
+            ids = []
+            for term in terms[miss].tolist():
+                pid = later.get(term)
+                if pid is None:
+                    pid = later[term] = self.used
+                    self.used += 1
+                ids.append(pid)
+            if self.used > self.n_pairs:
                 raise ValueError(
                     f"pair capacity overflow (> {self.n_pairs})")
-            out[fresh] = np.arange(self.used, self.used + k)
-            self.used += k
-            self.doc_terms[doc] = np.insert(have, pos[fresh], terms[fresh])
-            self.doc_pids[doc] = np.insert(pids, pos[fresh], out[fresh])
+            out[miss] = ids
         return out
+
+    def filed(self):
+        """Every article's terms, sorted, and their pair ids beside
+        them: the load's arrays with the later pairs sorted in."""
+        for doc, later in enumerate(self.doc_later):
+            if later:
+                n = len(later)
+                terms = np.concatenate([self.doc_terms[doc], np.fromiter(
+                    later.keys(), np.int64, n)])
+                pids = np.concatenate([self.doc_pids[doc], np.fromiter(
+                    later.values(), np.int64, n)])
+                order = terms.argsort()
+                self.doc_terms[doc] = terms[order]
+                self.doc_pids[doc] = pids[order]
+                self.doc_later[doc] = None
+        return self.doc_terms, self.doc_pids
 
     def next(self, lane: int) -> Minted:
         at = self._at[lane] % _BLOCK
@@ -162,14 +192,18 @@ class Stream:
         start = int(where[at] * (len(old) - take + 1))
         put = words[ends[at] - in_n[at]:ends[at]]
         gone = old[start:start + take]
-        # the change in the article's term counts (``Corpus.edit``)
-        sign = np.ones(len(put) + take, np.int64)
-        sign[len(put):] = -1
+        # the change in the article's term counts (``Corpus.edit``):
+        # +1 for a word put in, -1 for one taken out, summed by term
+        # (``np.unique`` and ``np.bincount`` written out: one sort)
         while True:
-            terms, inv = np.unique(np.concatenate([put, gone]),
-                                   return_inverse=True)
-            wgt = np.bincount(inv, weights=sign, minlength=len(terms)
-                              ).astype(np.int64)
+            both = np.concatenate([put, gone])
+            perm = both.argsort()
+            both = both[perm]
+            first = np.empty(len(both), bool)
+            first[0] = True
+            np.not_equal(both[1:], both[:-1], out=first[1:])
+            starts = np.flatnonzero(first)
+            wgt = np.add.reduceat(np.where(perm < len(put), 1, -1), starts)
             moved = wgt != 0
             if moved.any():
                 break
@@ -179,7 +213,7 @@ class Stream:
             put[0] = (put[0] + 1) % self.cfg["vocab"]
         self.docs[doc] = np.concatenate(
             [old[:start], put, old[start + take:]])
-        terms, wgt = terms[moved].astype(np.int64), wgt[moved]
+        terms, wgt = both[starts][moved].astype(np.int64), wgt[moved]
         vals = np.empty((len(terms), 2), np.float32)
         vals[:, 0] = terms
         vals[:, 1] = doc
@@ -211,14 +245,15 @@ class Reference:
         s = self.s
         doc, term, count = _pair_counts(self.docs)
         # a pair's id: where the generator's mirror filed it
-        starts = np.fromiter((len(t) for t in s.doc_terms), np.int64,
-                             len(s.doc_terms))
+        doc_terms, doc_pids = s.filed()
+        starts = np.fromiter((len(t) for t in doc_terms), np.int64,
+                             len(doc_terms))
         starts = np.concatenate([[0], np.cumsum(starts)])
         key = (doc << _DOC_SHIFT) | term
-        filed = (np.repeat(np.arange(len(s.doc_terms), dtype=np.int64),
+        filed = (np.repeat(np.arange(len(doc_terms), dtype=np.int64),
                            np.diff(starts)) << _DOC_SHIFT
-                 ) | np.concatenate(s.doc_terms)
-        pids = np.concatenate(s.doc_pids)[np.searchsorted(filed, key)]
+                 ) | np.concatenate(doc_terms)
+        pids = np.concatenate(doc_pids)[np.searchsorted(filed, key)]
         df_terms, df = np.unique(term, return_counts=True)
         out = {"tf": (pids, count.astype(np.float64)),
                "df": (df_terms, df.astype(np.float64)),

@@ -66,11 +66,16 @@ def rescan_floor_s(cfg: dict, device_kind: str) -> float:
 
 
 def topk_call_bytes(cfg: dict) -> float:
-    """What one kernel call of the rescan must touch: its
-    ``[queries, k + scan_chunk]`` float32 candidate block in, and
-    ``[queries, k]`` float32 values and int32 indices out. The block as
-    the program pads it to a lane multiple is wider; the pad is not
-    counted."""
+    """What one kernel call of the rescan must touch. Since PR 27 the
+    kernel reads the ``[queries, k]`` carry (float32 values, int32 ids)
+    and the ``[queries, scan_chunk]`` float32 score chunk as they are,
+    and writes ``[queries, k]`` values and ids. Counted here: the
+    carry's values and the chunk in (``4 * queries * (k + scan_chunk)``)
+    and values and ids out. Left out: the carry's ids in, ``4 * queries
+    * k`` = 16 KB of 8.44 MB at the cell's sizes, so a share of the
+    roofline taken from this reads 0.2 % low, never high. The count
+    stays as PR 26 set it: a reading that moves on an unchanged program
+    would be worse than one that is 0.2 % low."""
     q, k = cfg["queries"], cfg["k"]
     width = k + min(cfg["scan_chunk"], cfg["doc_slots"])
     return 4.0 * q * width + 8.0 * q * k

@@ -1,7 +1,9 @@
 """The Pallas top-k kernel against the memory roofline: the bytes its
-calls must touch (a ``[queries, k + scan_chunk]`` float32 block in,
-``[queries, k]`` values and indices out, a call) at the HBM peak, over
-the time the trace shows for them. The kernel does no MXU work and the
+calls must touch (the ``[queries, k]`` carry's values and the
+``[queries, scan_chunk]`` float32 score chunk in, ``[queries, k]``
+values and ids out, a call; the carry's ids in, 0.2 % of it, are not
+counted: ``knn_model.topk_call_bytes``) at the HBM peak, over the time
+the trace shows for them. The kernel does no MXU work and the
 VPU has no published peak, so this is a share of the memory roofline
 only, and small: k sweeps of compare-and-select over a block that is
 read from HBM once. Only the rescan's calls are priced; if a run also

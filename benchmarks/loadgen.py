@@ -21,6 +21,7 @@ import argparse
 import gc
 import json
 import os
+import resource
 import sys
 import threading
 import time
@@ -40,6 +41,11 @@ import traffic_plan as tp                                  # noqa: E402
 DRAIN_S = 240.0
 #: and on one send or receive over its link after this long
 LINK_WAIT_S = 60.0
+
+
+def peak_rss_bytes() -> int:
+    """This process's peak resident set (Linux counts it in KiB)."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
 def emit(obj: dict) -> None:
@@ -182,7 +188,8 @@ def main(argv=None) -> int:
     gc.collect()
     gc.freeze()
     emit({"ev": "ready", "mint_s": now() - t0,
-          "batches": sum(len(m) for m in minted)})
+          "batches": sum(len(m) for m in minted),
+          "rss_bytes": peak_rss_bytes()})
 
     from reflow_tpu.net import TcpTransport
     from reflow_tpu.serve import APPLIED, RemoteProducer

@@ -18,8 +18,8 @@ import bisect
 import math
 from typing import Dict, List, Optional, Sequence
 
-__all__ = ["percentile", "join", "completion_rate", "freshness_ms",
-           "Joined"]
+__all__ = ["percentile", "join", "done_inside", "completion_rate",
+           "freshness_ms", "Joined"]
 
 
 def percentile(xs: Sequence[float], q: float) -> float:
@@ -83,6 +83,14 @@ def join(batches: Sequence[dict], windows: Sequence[dict]) -> Joined:
     return Joined(out, wins)
 
 
+def done_inside(j: Joined, t_open: float, t_close: float) -> List[dict]:
+    """The windows that hold batches and completed (device and acks)
+    inside ``[t_open, t_close]``: what a rate, or a count of what the
+    window drained, is taken over."""
+    return [w for w in j.windows if w["done"] is not None
+            and w["n_batches"] and t_open <= w["done"] <= t_close]
+
+
 def completion_rate(j: Joined, t_open: float, t_close: float
                     ) -> Optional[dict]:
     """Rows per second between the first and the last window completion
@@ -95,9 +103,8 @@ def completion_rate(j: Joined, t_open: float, t_close: float
     completion and the window's nearer end, to ``median_gap_s``, the
     run's own cadence. None when fewer than two windows completed
     inside."""
-    done = sorted((w["done"], w["rows"], w["ix"]) for w in j.windows
-                  if w["done"] is not None and w["n_batches"]
-                  and t_open <= w["done"] <= t_close)
+    done = sorted((w["done"], w["rows"], w["ix"])
+                  for w in done_inside(j, t_open, t_close))
     if len(done) < 2:
         return None
     t_first, t_last = done[0][0], done[-1][0]

@@ -13,7 +13,9 @@ as a deployment does (``DurableScheduler`` -> ``IngestFrontend`` ->
 (``loadgen.py``) as a child process. Everything up to the opening of the
 window is ``setup_s``; then it measures for ``--seconds``; then, outside
 the window, it decides ``correct``. The last line of stdout is the one
-JSON object of the contract; everything else comes before it.
+JSON object of the contract; everything else comes before it. The
+numbers compared, each beside its limit, are that object's last key
+(``checks``) and the last lines of stderr.
 
 Nothing here names a cell, a configuration or a mix: they are entries of
 ``BENCHMARK.json`` and files found by name (see README.md beside this).
@@ -349,7 +351,8 @@ def _run(args, cell, cfg, traffic, mod, run_dir, gen, closers,
         gen.expect("prefilled")
     probe.drain()
     say(f"generator minted {ready['batches']} batches in "
-        f"{ready['mint_s']:.2f}s; connected"
+        f"{ready['mint_s']:.2f}s (peak RSS "
+        f"{ready['rss_bytes'] / 1e9:.2f} GB); connected"
         + (" and prefilled" if kind == "prefilled" else ""))
 
     # -- the window ------------------------------------------------------
@@ -450,7 +453,9 @@ def _run(args, cell, cfg, traffic, mod, run_dir, gen, closers,
     checks = list(mod.compare(cfg, got, expected))
     ref_s = now() - t0
 
+    t0 = now()
     lost, twice, logged = wal_holds(wal_dir, sent)
+    wal_s = now() - t0
     # A ticket that reads DEDUPED belongs to a batch its producer sent
     # again after a link reset: the first copy was applied, the second
     # refused, which is the delivery guarantee at work and not a fault.
@@ -500,7 +505,8 @@ def _run(args, cell, cfg, traffic, mod, run_dir, gen, closers,
         checks.append(Check("rate_edge_s", edge, limit, edge <= limit))
     for c in checks:
         say(c.line())
-    say(f"reference and comparison {ref_s:.2f}s; WAL held "
+    say(f"reference and comparison {ref_s:.2f}s, WAL re-read "
+        f"{wal_s:.2f}s; WAL held "
         f"{len(set(sent) - set(lost))} of {len(sent)} batch ids, its "
         f"ticks {'agree' if agree else 'DISAGREE'} with the tickets'; "
         f"counters {counters}")
@@ -579,6 +585,9 @@ def _run(args, cell, cfg, traffic, mod, run_dir, gen, closers,
                 raise Failed(f"the run produced no {m['name']}")
             metrics[m["name"]] = {"value": float(values[m["name"]]),
                                   "unit": m["unit"]}
+    # every number compared beside its limit, as the line's last key
+    result["checks"] = {c.name: {"value": c.value, "limit": c.limit,
+                                 "ok": c.ok} for c in checks}
     return result
 
 
@@ -682,6 +691,9 @@ def main(argv=None) -> int:
                     help="the CPU rehearsal; needs JAX_PLATFORMS=cpu")
     args = ap.parse_args(argv)
     result = run_cell(args)
+    for name, c in result["checks"].items():
+        print(Check(name, **c).line(), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -15,3 +15,17 @@ ROOT = os.path.dirname(BENCH)
 for p in (BENCH, ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
+
+
+def tiny_cell(config: str, mix: str):
+    """A configuration and a mix at their ``tiny`` sizes, with the
+    configuration's module: ``(cfg, traffic, mod)``."""
+    import manifest as mf
+
+    cfg = mf.with_tiny(mf.load_json(os.path.join(
+        BENCH, "configs", config + ".json")), True)
+    traffic = mf.with_tiny(mf.load_json(os.path.join(
+        BENCH, "traffic", mix + ".json")), True)
+    mod = mf.load_module(os.path.join(BENCH, "configs", config + ".py"),
+                         config)
+    return cfg, traffic, mod

@@ -1,9 +1,15 @@
 """The manifest against the contract's static rules."""
 
+import glob
 import json
 import os
 
+import pytest
+
 import manifest as mf
+import traffic_plan as tp
+
+TRAFFIC = sorted(glob.glob(os.path.join(mf.HERE, "traffic", "*.json")))
 
 
 def test_manifest_has_no_problem():
@@ -38,3 +44,15 @@ def test_every_cell_resolves_to_files_and_nothing_in_run_names_one():
         for word in (w["name"], w["config"], w["traffic"]):
             assert word not in src, f"run.py/loadgen.py name {word!r}"
     json.dumps(man)
+
+
+@pytest.mark.parametrize("path", TRAFFIC, ids=os.path.basename)
+def test_a_backlog_divides_by_its_lanes(path):
+    """``per_lane`` mints ``batches // producers`` a lane: the file has
+    to say a number that drops nothing, at full size and ``tiny``."""
+    raw = mf.load_json(path)
+    for tiny in (False, True):
+        t = mf.with_tiny(raw, tiny)
+        if t["arrivals"] in ("prefilled", "closed"):
+            assert t["batches"] % t["producers"] == 0, (path, tiny)
+            assert tp.per_lane(t, 40.0) * t["producers"] == t["batches"]

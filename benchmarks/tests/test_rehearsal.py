@@ -22,7 +22,8 @@ def _run(cell, trace, seed):
          str(trace), "--tiny"],
         capture_output=True, text=True, env=env, timeout=600)
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
-    return json.loads(p.stdout.strip().splitlines()[-1]), p.stdout
+    return (json.loads(p.stdout.strip().splitlines()[-1]),
+            p.stdout + p.stderr)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -37,8 +38,13 @@ def test_cell_rehearsal(cell):
     assert res["device"]["platform"] == "cpu"
     assert set(res["metrics"]) == {m["name"] for m in c.end_to_end}
     assert all(v["value"] > 0 for v in res["metrics"].values())
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit: in the log, as
+    # the last lines of stderr, and as the result line's last key
     assert out.count("] check ") >= 6
+    assert list(res)[-1] == "checks" and len(res["checks"]) >= 6
+    assert all(c["ok"] for c in res["checks"].values())
+    last = out.strip().splitlines()[-len(res["checks"]):]
+    assert [x.split()[1].rstrip(":") for x in last] == list(res["checks"])
 
 
 @pytest.mark.parametrize("cell", CELLS)
