@@ -297,7 +297,8 @@ def knn_phase(cfg: dict, dev) -> dict:
     from reflow_tpu.delta import DeltaBatch
     from reflow_tpu.executors import get_executor
     from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
-                                         fold_topk, score_form, topk)
+                                         fold_topk, score_form,
+                                         sweep_blocks, topk)
     from reflow_tpu.scheduler import DirtyScheduler
     from reflow_tpu.workloads import knn
 
@@ -357,7 +358,7 @@ def knn_phase(cfg: dict, dev) -> dict:
     # the served table (Pallas inside the tick program on a TPU) against
     # the same rescan selected by lax.top_k, from the executor's own state
     prec = jax.lax.Precision.DEFAULT
-    ref_vals, ref_ids = jax.jit(
+    ref_vals, ref_ids, _ = jax.jit(
         lambda q, d, l: chunked_corpus_topk(q, d, l, k, chunk,
                                             use_pallas=False,
                                             precision=prec)
@@ -390,24 +391,45 @@ def knn_phase(cfg: dict, dev) -> dict:
     # the rescan's fold step, mid-scan: the carry [Q, k] the chunk before
     # `lo` leaves and the chunk at `lo` [Q, chunk], as the scan hands
     # them over — the fold kernel vs its XLA body vs one candidate matrix
-    # with an id block beside it (lax.top_k for columns, a gather for ids)
+    # with an id block beside it (lax.top_k for columns, a gather for
+    # ids). The kernel sweeps a block as often as a row of it has
+    # columns above its carry's k-th score, so the same comparison is
+    # made where no row takes anything (the chunk pushed under the
+    # carry: every block's gate stays shut) and where every row takes k
+    # (pushed over it: what an ascending-score corpus hands the fold at
+    # every chunk); the sweeps it counts must be its XLA body's, 0 and
+    # k a block there.
     lo = D // chunk // 2 * chunk
-    fold = jax.jit(fold_topk, static_argnums=(4, 5))
-    carry = fold(no_vals, jnp.full((Q, k), -1, jnp.int32),
-                 scores_at(lo - chunk), jnp.int32(lo - chunk), k, False)
+    fold = jax.jit(fold_topk, static_argnums=(5, 6))
+    none = jnp.zeros((sweep_blocks(Q),), jnp.int32)
+    carry = fold(no_vals, jnp.full((Q, k), -1, jnp.int32), none,
+                 scores_at(lo - chunk), jnp.int32(lo - chunk), k, False)[:2]
     sc = scores_at(lo)
-    fv, fi = fold(*carry, sc, jnp.int32(lo), k, True)
-    xv, xi = fold(*carry, sc, jnp.int32(lo), k, False)
-    bv, sel = jax.lax.top_k(jnp.concatenate([carry[0], sc], 1), k)
-    bi = jnp.take_along_axis(jnp.concatenate(
-        [carry[1], jnp.broadcast_to(
-            lo + jnp.arange(chunk, dtype=jnp.int32), (Q, chunk))], 1),
-        sel, axis=1)
-    for what, v, i in (("its XLA body", xv, xi), ("the id block", bv, bi)):
-        require(np.array_equal(np.asarray(fi), np.asarray(i))
-                and np.array_equal(np.asarray(fv), np.asarray(v)),
-                f"fold kernel != {what} on the same carry {carry[0].shape}"
-                f" + chunk {sc.shape} at lo {lo}")
+    swept = {}
+    for case, shift in (("mid-scan", 0.0), ("no entrant", -4.0),
+                        ("every row takes k", 4.0)):
+        x = jnp.where(sc > NEG, sc + shift, NEG)
+        fv, fi, fn = fold(*carry, none, x, jnp.int32(lo), k, True)
+        xv, xi, xn = fold(*carry, none, x, jnp.int32(lo), k, False)
+        bv, sel = jax.lax.top_k(jnp.concatenate([carry[0], x], 1), k)
+        bi = jnp.take_along_axis(jnp.concatenate(
+            [carry[1], jnp.broadcast_to(
+                lo + jnp.arange(chunk, dtype=jnp.int32), (Q, chunk))], 1),
+            sel, axis=1)
+        for what, v, i in (("its XLA body", xv, xi),
+                           ("the id block", bv, bi)):
+            require(np.array_equal(np.asarray(fi), np.asarray(i))
+                    and np.array_equal(np.asarray(fv), np.asarray(v)),
+                    f"fold kernel != {what} ({case}) on the same carry "
+                    f"{carry[0].shape} + chunk {sc.shape} at lo {lo}")
+        require(np.array_equal(np.asarray(fn), np.asarray(xn)),
+                f"fold kernel counts {np.asarray(fn).tolist()} sweeps, its "
+                f"XLA body {np.asarray(xn).tolist()} ({case})")
+        swept[case] = int(jnp.sum(fn))
+    require(swept["no entrant"] == 0
+            and swept["every row takes k"] == k * sweep_blocks(Q),
+            f"sweeps {swept}: want 0 where nothing enters and k a block "
+            f"where every row takes k")
     pallas_in_tick = jax.default_backend() == "tpu"
     say(f"knn Q {Q} dim {dim} k {k} chunk {chunk}, {live} live of {D} "
         f"slots (cut: {D - next_id - c['insert_rows']} slots left empty): "
@@ -415,13 +437,15 @@ def knn_phase(cfg: dict, dev) -> dict:
         f"retraction rescan {rescan_s:.3f}s; Pallas kernel "
         f"{'compiled' if pallas_in_tick else 'interpreted'} at {s.shape} "
         f"== lax.top_k; fold kernel at {carry[0].shape} + {sc.shape}, lo "
-        f"{lo} == XLA body == id block; served table == lax.top_k rescan")
+        f"{lo} == XLA body == id block (sweeps {swept}); served table == "
+        f"lax.top_k rescan")
     return {
         "Q": Q, "dim": dim, "k": k, "scan_chunk": chunk,
         "corpus_slots": D, "corpus_live": live,
         "pallas_compiled": pallas_in_tick,
         "kernel_scores_shape": list(s.shape),
         "fold_kernel_shapes": [list(carry[0].shape), list(sc.shape)],
+        "fold_kernel_sweeps": swept,
         "preload_s": round(preload_s, 3),
         "insert_tick_s": round(insert_s, 4),
         "rescan_tick_s": round(rescan_s, 4),

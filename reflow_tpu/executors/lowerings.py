@@ -827,10 +827,14 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: op kind -> what its nodes count on the device, in the order of their
 #: ``counters`` state leaf (int32 each). A KnnIndex: ticks that rescanned
 #: the corpus, ticks that took the incremental merge, delta rows folded
-#: into its two tables (wraps after 2^31 rows). They ride in the state,
-#: so they cost no program output and no host sync; the executor reads
-#: them when a snapshot is taken (``TpuExecutor.op_counters``).
-OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded")}
+#: into its two tables (wraps after 2^31 rows), and the chunk sweeps its
+#: rescans' folds ran (``kernels.topk.fold_topk``; an incremental tick
+#: adds none; wraps, so a reader takes differences modulo 2^32). They
+#: ride in the state, so they cost no program output and no host sync;
+#: the executor reads them when a snapshot is taken
+#: (``TpuExecutor.op_counters``). New names are appended: readers go by
+#: position.
+OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps")}
 
 
 def knn_state(op, q_spec: Spec, d_spec: Spec) -> dict:
@@ -930,11 +934,11 @@ def _knn_incremental(qvec, dvec, emitted, em_has, di, d_ins, k, prec,
     return vals, ids
 
 
-def _knn_count(counters, need_full, *masks):
+def _knn_count(counters, need_full, sweeps, *masks):
     """The node's device counters after this tick (``OP_COUNTERS``)."""
     rows = sum(jnp.sum(m.astype(jnp.int32)) for m in masks)
     full = need_full.astype(jnp.int32)
-    return counters + jnp.stack([full, 1 - full, rows])
+    return counters + jnp.stack([full, 1 - full, rows, sweeps])
 
 
 def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
@@ -977,10 +981,10 @@ def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
                                    precision=prec)
 
     def incr_path(_):
-        return _knn_incremental(qvec, dvec, emitted, em_has, dd.keys,
-                                d_ins, k, prec)
+        return *_knn_incremental(qvec, dvec, emitted, em_has, dd.keys,
+                                 d_ins, k, prec), jnp.int32(0)
 
-    vals, ids = jax.lax.cond(need_full, full_path, incr_path, None)
+    vals, ids, sweeps = jax.lax.cond(need_full, full_path, incr_path, None)
     ids = jnp.where(vals <= NEG, -1, ids)
     new_row = jnp.stack([ids.astype(jnp.float32), vals], axis=-1)  # [Q,k,2]
 
@@ -998,7 +1002,7 @@ def _lower_knn(op, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
     new_has = jnp.where(ins_m, True, jnp.where(ret_m & ~qlive, False, em_has))
     return out, {"qvec": qvec, "qlive": qlive, "dvec": dvec, "dlive": dlive,
                  "emitted": new_emitted, "em_has": new_has,
-                 "counters": _knn_count(state["counters"], need_full,
+                 "counters": _knn_count(state["counters"], need_full, sweeps,
                                         q_ins, q_ret, d_ins, d_ret)}
 
 

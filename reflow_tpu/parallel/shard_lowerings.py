@@ -425,8 +425,8 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
 
     def full_path(_):
         chunk = min(op.scan_chunk, Dl)
-        vals_l, ids_l = chunked_corpus_topk(qvec, dvec, dlive, k, chunk,
-                                            precision=prec)
+        vals_l, ids_l, sweeps = chunked_corpus_topk(
+            qvec, dvec, dlive, k, chunk, precision=prec)
         ids_g = jnp.where(vals_l <= NEG, -1, ids_l + base_d)
         # ring merge over ICI neighbors (ppermute): n-1 hops, each passing
         # a [Q, k] candidate window and merging into the local best —
@@ -438,7 +438,8 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
             cur_v = jax.lax.ppermute(cur_v, axis, perm)
             cur_i = jax.lax.ppermute(cur_i, axis, perm)
             acc_v, acc_i = _merge2(acc_v, acc_i, cur_v, cur_i)
-        return acc_v, acc_i
+        # the counters are replicated: every shard's sweeps, summed
+        return acc_v, acc_i, jax.lax.psum(sweeps, axis)
 
     def _score_owned(di, won):
         # per-entry scores from the OWNED folded vectors (exactly the
@@ -453,10 +454,11 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
         return jax.lax.pmax(s_loc, axis)
 
     def incr_path(_):
-        return _knn_incremental(qvec, dvec, emitted, em_has, gd.keys,
-                                d_ins, k, prec, score_of=_score_owned)
+        return *_knn_incremental(qvec, dvec, emitted, em_has, gd.keys,
+                                 d_ins, k, prec,
+                                 score_of=_score_owned), jnp.int32(0)
 
-    vals, ids = jax.lax.cond(need_full, full_path, incr_path, None)
+    vals, ids, sweeps = jax.lax.cond(need_full, full_path, incr_path, None)
     ids = jnp.where(vals <= NEG, -1, ids)
     new_row = jnp.stack([ids.astype(jnp.float32), vals], axis=-1)  # [Q,k,2]
 
@@ -477,7 +479,7 @@ def _lower_knn_sharded(op, node: Node, state, ins, axis: str, n: int
     new_has = jnp.where(ins_m, True, jnp.where(ret_m & ~qlive, False, em_has))
     return out, {"qvec": qvec, "qlive": qlive, "dvec": dvec, "dlive": dlive,
                  "emitted": new_emitted, "em_has": new_has,
-                 "counters": _knn_count(state["counters"], need_full,
+                 "counters": _knn_count(state["counters"], need_full, sweeps,
                                         q_ins, q_ret, d_ins, d_ret)}
 
 

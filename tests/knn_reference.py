@@ -21,6 +21,32 @@ def bf16(x):
     return b.view(np.float32)
 
 
+#: the rows a sweep of the fold kernel covers (``kernels.topk._BQ``)
+ROW_BLOCK = 8
+
+
+def sweeps_rule(vals, s, k):
+    """What ``kernels.topk.fold_topk`` counts when it folds the chunk
+    ``s [Q, C]`` into the carry ``vals [Q, k]``: per block of 8 rows,
+    the most chunk columns any of its rows has that strictly beat the
+    carry's k-th score, at most k."""
+    q = s.shape[0]
+    beat = np.zeros(-(-q // ROW_BLOCK) * ROW_BLOCK, np.int64)
+    beat[:q] = np.minimum((s > vals.min(axis=1, keepdims=True)).sum(axis=1),
+                          k)
+    return beat.reshape(-1, ROW_BLOCK).max(axis=1)
+
+
+def scan_sweeps(chunks, q, k, neg):
+    """The rule summed over a rescan: ``chunks`` are its ``[q, C]``
+    score chunks in scan order, the carry starts at ``neg``."""
+    carry, total = np.full((q, k), neg, np.float32), 0
+    for s in chunks:
+        total += int(sweeps_rule(carry, s, k).sum())
+        carry = -np.sort(-np.concatenate([carry, s], axis=1), axis=1)[:, :k]
+    return total
+
+
 def _unit(v):
     v = np.asarray(v, np.float32)
     n = np.sqrt(np.sum(v * v, axis=-1, keepdims=True))

@@ -8,6 +8,8 @@ from reflow_tpu import DeltaBatch, DirtyScheduler
 from reflow_tpu.executors import CpuExecutor, get_executor
 from reflow_tpu.workloads import knn
 
+from knn_reference import ROW_BLOCK, scan_sweeps, sweeps_rule
+
 Q, D, DIM, K = 16, 256, 32, 4
 
 
@@ -334,6 +336,28 @@ def _fold_case(name, q, c, k, lo):
         vals[:, k // 2:], ids[:, k // 2:] = NEG, -1
     elif name == "chunk_below_carry":
         vals += 100.0
+    elif name == "one_entrant_beside_none":
+        # the first 8-row block sweeps nothing; in the second, one row
+        # takes one column and the other seven none
+        vals += 100.0
+        s[ROW_BLOCK + 2, c // 2] = vals[ROW_BLOCK + 2, k - 1] + 0.5
+    elif name == "one_row_takes_k":
+        # one row of a block replaces its whole carry (more than k
+        # columns beat it), the block's other seven take nothing
+        vals += 100.0
+        s[3] += 200.0
+    elif name == "equal_to_the_kth":
+        # columns that tie the carry's k-th score: the carry wins, none
+        # enters; one row also has a column strictly above it
+        vals += 100.0
+        s[:, ::2] = vals[:, k - 1:]
+        s[5, 1] = vals[5, k - 1] + 0.1
+    elif name == "young_carry":
+        vals[:, k // 2:], ids[:, k // 2:] = NEG, -1
+    elif name == "chunk_above_carry":
+        # what an ascending-score corpus hands the fold at every chunk:
+        # all of the chunk beats all of the carry
+        vals -= 100.0
     else:
         assert name == "plain"
     return vals, ids, s
@@ -352,6 +376,17 @@ def _fold_case(name, q, c, k, lo):
     ("first_step", 12, 3, 8, 0),
     ("dead_slots", 12, 3, 8, 9),
     ("ties", 8, 6, 16, 60),
+    ("one_entrant_beside_none", 16, 8192, 16, 8192 * 5),
+    ("one_row_takes_k", 16, 8192, 16, 8192 * 5),
+    ("equal_to_the_kth", 16, 8192, 16, 8192 * 5),
+    ("young_carry", 16, 8192, 16, 8192),
+    ("chunk_above_carry", 16, 8192, 16, 8192 * 5),
+    ("one_entrant_beside_none", 16, 300, 4, 600),
+    ("one_row_takes_k", 16, 300, 4, 600),
+    ("equal_to_the_kth", 12, 300, 4, 600),
+    ("chunk_above_carry", 12, 300, 4, 600),
+    ("young_carry", 12, 3, 8, 9),
+    ("chunk_above_carry", 12, 3, 8, 9),
 ])
 def test_fold_topk_equals_the_id_block_formulation(name, q, c, k, lo,
                                                    use_pallas):
@@ -360,32 +395,48 @@ def test_fold_topk_equals_the_id_block_formulation(name, q, c, k, lo,
     values and ids exactly, every query, every rank. At the rescan's
     shape ``[256, 16] + [256, 8192]``, at a ragged chunk (the kernel's
     pad branch) and at a chunk narrower than k; ``lo`` traced, as the
-    scan passes it. The compiled kernel gets the same comparison on the
-    chip in ``chip_smoke.py``."""
+    scan passes it. The kernel sweeps a block as often as a row of it
+    has columns above its carry's k-th score, so the cases also deal
+    those every way: none in a block beside one in the next, one row
+    that takes k beside seven that take nothing, columns that only tie
+    the k-th score, a carry half unfilled, a chunk that replaces the
+    whole carry. The sweeps it reports are the rule's
+    (``knn_reference.sweeps_rule``). The compiled kernel gets the same
+    comparison on the chip in ``chip_smoke.py``."""
     import jax
     import jax.numpy as jnp
 
-    from reflow_tpu.kernels.topk import fold_topk
+    from reflow_tpu.kernels.topk import fold_topk, sweep_blocks
 
     vals, ids, s = _fold_case(name, q, c, k, lo)
-    got_v, got_i = jax.jit(
-        lambda v, i, x, at: fold_topk(v, i, x, at, k, use_pallas)
-    )(vals, ids, s, jnp.int32(lo))
+    had = np.arange(sweep_blocks(q), dtype=np.int32)
+    got_v, got_i, got_n = jax.jit(
+        lambda v, i, n, x, at: fold_topk(v, i, n, x, at, k, use_pallas)
+    )(vals, ids, had, s, jnp.int32(lo))
     ref_v, ref_i = _fold_oracle(jnp.asarray(vals), jnp.asarray(ids),
                                 jnp.asarray(s), lo, k)
     assert np.array_equal(np.asarray(got_i), np.asarray(ref_i))
     assert np.array_equal(np.asarray(got_v), np.asarray(ref_v))
+    assert np.array_equal(np.asarray(got_n), had + sweeps_rule(vals, s, k))
 
 
 @pytest.mark.parametrize("use_pallas", [True, False],
                          ids=["kernel_interpreted", "xla_body"])
-@pytest.mark.parametrize("d,chunk", [(1024, 128), (200, 8192)])
-def test_chunked_corpus_topk_equals_bruteforce_lowest_id(d, chunk,
+@pytest.mark.parametrize("d,chunk,law", [
+    (1024, 128, "random"), (200, 8192, "random"),
+    (1024, 128, "ascending"), (200, 8192, "ascending"), (6, 8192, "random"),
+])
+def test_chunked_corpus_topk_equals_bruteforce_lowest_id(d, chunk, law,
                                                          use_pallas):
-    """The whole rescan over several chunks (and over one ragged one)
-    against NumPy: all scores at once, ordered by (score descending, id
-    ascending). Quarter-integer vectors make the scores exact and equal
-    in droves; a fifth of the corpus is dead."""
+    """The whole rescan over several chunks (and over one ragged one,
+    and one narrower than k) against NumPy: all scores at once, ordered
+    by (score descending, id ascending). Quarter-integer vectors make
+    the scores exact and equal in droves; a fifth of the corpus is dead.
+    ``ascending`` is the order that costs the gated kernel most: a
+    query's scores rise with the id (in runs of four equal ones), so
+    every chunk replaces its whole carry; the queries whose scores fall
+    with the id instead take nothing after the first chunk. The scan's
+    sweeps are the rule's, chunk by chunk."""
     import jax.numpy as jnp
 
     from reflow_tpu.kernels.topk import NEG, chunked_corpus_topk
@@ -394,17 +445,31 @@ def test_chunked_corpus_topk_equals_bruteforce_lowest_id(d, chunk,
     rng = np.random.default_rng(d)
     qv = rng.integers(-2, 3, size=(q, dim)).astype(np.float32) / 4
     dv = rng.integers(-2, 3, size=(d, dim)).astype(np.float32) / 4
+    if law == "ascending":
+        dv[:, 1:] = 0
+        dv[:, 0] = (np.arange(d) // 4) / 4
+        qv[::3, 0], qv[1::3, 0] = 0.5, -0.25
     live = rng.random(d) > 0.2
-    vals, ids = chunked_corpus_topk(jnp.asarray(qv), jnp.asarray(dv),
-                                    jnp.asarray(live), k, chunk,
-                                    use_pallas=use_pallas)
+    vals, ids, sweeps = chunked_corpus_topk(
+        jnp.asarray(qv), jnp.asarray(dv), jnp.asarray(live), k, chunk,
+        use_pallas=use_pallas)
     scores = np.where(live[None, :], qv @ dv.T, NEG)
     order = np.lexsort((np.broadcast_to(np.arange(d), (q, d)), -scores),
                        axis=1)[:, :k]
-    assert len(np.unique(scores[0])) < d // 4          # ties were planted
+    assert len(np.unique(scores[0])) <= max(d // 4 + 1, 6)  # ties planted
+    best = np.take_along_axis(scores, order, axis=1)
+    if d < k:           # the carry's unfilled places stay (NEG, -1), and
+        order = np.where(best > NEG, order, -1)    # they win ties with
+        pad = ((0, 0), (0, k - d))                 # dead documents
+        order = np.pad(order, pad, constant_values=-1)
+        best = np.pad(best, pad, constant_values=NEG)
     assert np.array_equal(np.asarray(ids), order)
-    assert np.array_equal(np.asarray(vals),
-                          np.take_along_axis(scores, order, axis=1))
+    assert np.array_equal(np.asarray(vals), best)
+    want = scan_sweeps((scores[:, lo:lo + chunk].astype(np.float32)
+                        for lo in range(0, d, min(chunk, d))), q, k, NEG)
+    assert int(sweeps) == want
+    if law == "ascending" and d > chunk:
+        assert want == k * (d // chunk) * (q // ROW_BLOCK)
 
 
 def test_rescan_lowers_without_id_block_or_gather():
@@ -422,7 +487,7 @@ def test_rescan_lowers_without_id_block_or_gather():
     q, d, dim, k, chunk = 16, 1024, 8, 4, 256
     lowered = jax.jit(
         lambda qv, dv, live: chunked_corpus_topk(qv, dv, live, k, chunk,
-                                                 use_pallas=False)
+                                                 use_pallas=False)[:2]
     ).lower(jnp.zeros((q, dim)), jnp.zeros((d, dim)),
             jnp.ones((d,), bool))
     for text in (lowered.as_text(), lowered.compile().as_text()):

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from knn_reference import KnnReference
+from knn_reference import KnnReference, scan_sweeps
 from reflow_tpu import DeltaBatch, DirtyScheduler, obs
 from reflow_tpu.executors import get_executor
 from reflow_tpu.net import LoopbackTransport
@@ -356,7 +356,7 @@ def test_knn_counters_count_and_are_published():
             [_ins([50], rows[34:35]), _ret([50], rows[34:35])]), "incr"),
         (kg.queries, _ret([0], np.zeros((1, DIM), np.float32)), "incr"),
     ]
-    full = incr = rows_n = 0
+    full = incr = rows_n = sweeps = 0
     for source, batch, path in steps:
         sched.push(source, batch)
         sched.tick()
@@ -364,16 +364,65 @@ def test_knn_counters_count_and_are_published():
         incr += path == "incr"
         rows_n += len(set(batch.keys.tolist()))      # winning rows
         got = sched.executor.op_counters()["index"]
-        assert got == {"rescans": full, "incremental": incr,
-                       "rows_folded": rows_n}, (path, got)
+        # the fourth counter, the rescans' chunk sweeps: an incremental
+        # tick adds none, a rescan of a corpus with live rows in it some
+        # (the first rescan meets an empty one); the exact count is held
+        # in tests/test_knn.py
+        swept = got.pop("sweeps") - sweeps
+        assert (swept > 0) == (path == "full" and full > 1), (path, swept)
+        sweeps += swept
+        assert list(got.items()) == [
+            ("rescans", full), ("incremental", incr),
+            ("rows_folded", rows_n)], (path, got)
     snap = reg.snapshot()["gauges"]
     assert snap[f"{key}.index.rescans"] == 3
     assert snap[f"{key}.index.incremental"] == 5
     assert snap[f"{key}.index.rows_folded"] == rows_n
+    assert snap[f"{key}.index.sweeps"] == sweeps
+    assert sched.executor.counter_names() == {
+        "index": ("rescans", "incremental", "rows_folded", "sweeps")}
     # a graph without such a node publishes none, and reads {}
     from reflow_tpu.workloads import wordcount
     g, *_ = wordcount.build_graph()
     assert getattr(DirtyScheduler(g).executor, "op_counters", dict)() == {}
+
+
+def test_sweeps_counter_is_the_rule_and_stands_still_on_incremental_ticks():
+    """The node's ``sweeps`` after a rescan of a two-chunk corpus: the
+    tick program's count (XLA body here) == the kernel's, interpreted on
+    the node's own state == the rule in NumPy over the same score
+    chunks. Counts, never speeds. A tick that takes the incremental
+    merge adds 0."""
+    import jax
+
+    from reflow_tpu.kernels.topk import (NEG, chunked_corpus_topk,
+                                         score_form)
+
+    rng = np.random.default_rng(6)
+    kg = _graph("int8")
+    sched = DirtyScheduler(kg.graph, get_executor("tpu"))
+    sched.push(kg.queries, _ins(np.arange(Q), rng.normal(size=(Q, DIM))))
+    sched.push(kg.docs, _ins(np.arange(100), _rows(rng, 100, "int8")))
+    sched.tick()
+    st = sched.executor.states[kg.index.id]
+    got = sched.executor.op_counters()["index"]
+    assert got["rescans"] == 1 and got["sweeps"] > 0
+
+    *_, kernel = chunked_corpus_topk(st["qvec"], st["dvec"], st["dlive"],
+                                     K, CHUNK, use_pallas=True)
+    rule = scan_sweeps((np.asarray(jnp.where(
+        st["dlive"][lo:lo + CHUNK][None, :],
+        jnp.dot(score_form(st["qvec"]),
+                score_form(st["dvec"][lo:lo + CHUNK]).T,
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT), NEG))
+        for lo in range(0, D, CHUNK)), Q, K, NEG)
+    assert got["sweeps"] == int(kernel) == rule
+
+    sched.push(kg.docs, _ins(np.arange(100, 108), _rows(rng, 8, "int8")))
+    sched.tick()
+    after = sched.executor.op_counters()["index"]
+    assert after["incremental"] == 1 and after["sweeps"] == got["sweeps"]
 
 
 def test_traced_windows_carry_the_counters(monkeypatch):
@@ -405,7 +454,10 @@ def test_traced_windows_carry_the_counters(monkeypatch):
         fe.close()
         assert sched.executor.device_watch_error is None
         assert len(seen) == sched.megatick_windows == 3
-        assert seen == [[1, 2, Q + 16], [1, 4, Q + 32], [1, 6, Q + 48]]
+        # four values a node; the one rescan met an empty corpus, and
+        # the incremental ticks sweep nothing
+        assert seen == [[1, 2, Q + 16, 0], [1, 4, Q + 32, 0],
+                        [1, 6, Q + 48, 0]]
         assert sched.executor.op_counters()["index"]["incremental"] == 6
     finally:
         obs.disable()

@@ -50,9 +50,10 @@ def test_fold_kernel_compiles(one_chip, kernels, q, c, k):
     import jax.numpy as jnp
 
     text = jax.jit(
-        lambda v, i, s, lo: kernels.fold_topk(v, i, s, lo, k)
+        lambda v, i, n, s, lo: kernels.fold_topk(v, i, n, s, lo, k)
     ).lower(_shape(one_chip, (q, k), jnp.float32),
             _shape(one_chip, (q, k), jnp.int32),
+            _shape(one_chip, (kernels.sweep_blocks(q),), jnp.int32),
             _shape(one_chip, (q, c), jnp.float32),
             _shape(one_chip, (), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
