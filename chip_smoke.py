@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-One process, one chip, two phases, every check fatal:
+One process, one chip, three phases, every check fatal:
 
 1. **The served device path, end to end** at the repo's flagship size
    (BASELINE config 3: incremental PageRank, 100 000 nodes / 1 000 000
@@ -24,6 +24,9 @@ One process, one chip, two phases, every check fatal:
    device), one insert tick and one retraction tick with the Pallas top-k
    in the tick program, its result equal to ``jax.lax.top_k`` on the same
    scores.
+3. **The indexed join** (``executors/arena.py``) at the kernel level:
+   skewed right rows over several ticks, then the left rows they waited
+   for; its late pairs equal the dense sweep's and NumPy's.
 
 The default invocation needs a TPU and never finishes on anything else.
 ``--tiny`` is the small CPU form tier-1 drives; it is reached only by
@@ -50,6 +53,8 @@ FULL = {
     "knn": {"Q": 256, "D": 1 << 20, "dim": 768, "k": 16, "chunk": 8192,
             "preload_rows": 1 << 16, "preload_chunks": 15,
             "insert_rows": 8192, "retract_rows": 1024},
+    "join": {"keys": 1 << 16, "arena": 1 << 20, "rows": 1 << 15,
+             "ticks": 6, "left_rows": 2048},
 }
 TINY = {
     "nodes": 256, "edges": 2048, "churn": 0.01, "tol": 1e-4,
@@ -57,6 +62,8 @@ TINY = {
     "knn": {"Q": 16, "D": 2048, "dim": 32, "k": 4, "chunk": 512,
             "preload_rows": 512, "preload_chunks": 3,
             "insert_rows": 256, "retract_rows": 64},
+    "join": {"keys": 64, "arena": 4096, "rows": 256, "ticks": 4,
+             "left_rows": 32},
 }
 
 #: generous wall bounds on each blocking wait, so a wedged pump or link
@@ -452,6 +459,90 @@ def knn_phase(cfg: dict, dev) -> dict:
     }
 
 
+# -- phase 3: the indexed join against the sweep it replaces ------------------
+
+def join_phase(cfg: dict, dev) -> dict:
+    """One unique-left join at the kernel level: right rows arrive for
+    ``ticks`` ticks (skewed: one key takes a quarter of them), then the
+    left rows they were waiting for, all late. The indexed lowering's
+    output (``executors/arena.py``: key-sorted appends, segment chains,
+    budgeted probe) == the dense table-by-arena sweep's == NumPy's, as
+    multisets of (key, left value, right value, weight)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from reflow_tpu.delta import Spec
+    from reflow_tpu.executors.device_delta import DeviceDelta
+    from reflow_tpu.executors.lowerings import join_core, join_state
+    from reflow_tpu.graph import FlowGraph
+
+    c = cfg["join"]
+    K, R, C, L = c["keys"], c["arena"], c["rows"], c["left_rows"]
+    g = FlowGraph("smoke_join")
+    left = g.source("l", Spec((), np.int32, key_space=K, unique=True))
+    right = g.source("r", Spec((), np.int32, key_space=K))
+    j = g.join(left, right,
+               merge=lambda k, va, vb: jnp.stack([va, vb], axis=-1),
+               spec=Spec((2,), np.int32, key_space=K), arena_capacity=R,
+               product_slack=c["ticks"] * C // L + 1)
+    rng = np.random.default_rng(7)
+    step = jax.jit(lambda st, da, db: join_core(
+        j.op, K, R, np.int32, st, da, db, oshape=(2,)))
+    states = {ix: jax.device_put(join_state(j.op, left.spec, right.spec,
+                                            indexed=ix), dev)
+              for ix in (True, False)}
+    rk, rv = [], []
+    for _ in range(c["ticks"]):
+        keys = np.where(rng.random(C) < 0.25, 3,
+                        rng.integers(0, K, C)).astype(np.int32)
+        vals = rng.integers(1, 1 << 30, C).astype(np.int32)
+        rk.append(keys)
+        rv.append(vals)
+        db = DeviceDelta(jnp.asarray(keys), jnp.asarray(vals),
+                         jnp.ones((C,), jnp.int32))
+        for ix in states:
+            _, states[ix] = step(states[ix], None, db)
+    rk, rv = np.concatenate(rk), np.concatenate(rv)
+    lk = np.concatenate([[3], rng.choice(
+        np.setdiff1d(np.arange(K), [3]), L - 1, replace=False)]
+    ).astype(np.int32)
+    lv = rng.integers(1, 1 << 30, L).astype(np.int32)
+    da = DeviceDelta(jnp.asarray(lk), jnp.asarray(lv),
+                     jnp.ones((L,), jnp.int32))
+    t0 = time.perf_counter()
+    outs = {}
+    for ix in states:
+        out, states[ix] = step(states[ix], da, None)
+        require(not bool(states[ix]["error"]),
+                f"join ({'indexed' if ix else 'dense'}) latched its error")
+        w = np.asarray(out.weights)
+        rows = np.concatenate([np.asarray(out.keys)[:, None],
+                               np.asarray(out.values)], axis=1)[w != 0]
+        require((w[w != 0] == 1).all(), "a late pair's weight is not 1")
+        outs[ix] = rows[np.lexsort(rows.T[::-1])]
+    late_s = time.perf_counter() - t0
+    lval = np.zeros(K, np.int64)
+    held = np.zeros(K, bool)
+    lval[lk], held[lk] = lv, True
+    want = np.stack([rk, lval[rk], rv], axis=1)[held[rk]]
+    want = want[np.lexsort(want.T[::-1])]
+    require(np.array_equal(outs[True], outs[False]),
+            "indexed join != dense join on the same arena and delta")
+    require(np.array_equal(outs[True], want), "join != NumPy")
+    counters = np.asarray(states[True]["counters"]).tolist()
+    require(counters[1] == len(want) and counters[2] == len(rk),
+            f"join counters {counters}: want {len(want)} late pairs and "
+            f"{len(rk)} arena rows")
+    say(f"join K {K} arena {R}: {len(rk)} right rows in {c['ticks']} ticks "
+        f"(key 3 holds {int((rk == 3).sum())}), then {L} left rows: "
+        f"{len(want)} late pairs, indexed == dense == NumPy; counters "
+        f"{counters}")
+    return {"keys": K, "arena": R, "right_rows": int(len(rk)),
+            "late_pairs": int(len(want)),
+            "late_ticks_s": round(late_s, 4)}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true",
@@ -475,6 +566,7 @@ def main(argv=None) -> int:
     try:
         pr = pagerank_phase(cfg, [dev], root)
         kn = knn_phase(cfg, dev)
+        jn = join_phase(cfg, dev)
     finally:
         shutil.rmtree(root, ignore_errors=True)
 
@@ -482,7 +574,7 @@ def main(argv=None) -> int:
         "schema": "reflow.chip_smoke/1", "form": "tiny" if args.tiny
         else "full", "device": device,
         "compile_cache": {"dir": cache_dir, "entries_at_start": cached},
-        "pagerank": pr, "knn": kn,
+        "pagerank": pr, "knn": kn, "join": jn,
         "total_s": round(time.perf_counter() - t_start, 2),
         "claim": None,
     }), flush=True)

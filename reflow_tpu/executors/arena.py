@@ -28,22 +28,45 @@ each shard compacts its slice and its slot of ``rcount``).
 ``propagate_plan_caps`` is the host-side static counterpart: the
 pre-dispatch capacity walk that rejects statically impossible ingress
 sizes and sizes the mega-tick ingress queue against the arenas.
+
+**The arena index** (``index_state`` / ``index_probe`` / ``index_append``
+/ ``reindex``). A unique-left join of a loop-free graph keeps, beside the
+log, what lets δA ⋈ B_old cost ``delta rows x matches`` and not
+``arena_capacity``: every tick's appends land key-SORTED, so the rows one
+tick gave one key are one contiguous *segment*, and the segments of a key
+are chained newest to oldest (``head[K]`` -> first row of the newest
+segment; at a segment's first row ``seg_len`` rows and ``seg_prev`` the
+first row of the one before; ``deg[K]`` rows in all). An append writes
+O(delta) entries. A probe reads ``deg`` to lay every delta row's pairs
+into a static budget of slots, then walks the chains one segment of
+every probed key per step: as many steps as the most *ticks* any probed
+key was appended in, whatever the rows. A compaction re-sorts the log,
+after which every key is one segment again and the index is derived from
+the sorted log in one pass (``reindex``). That is a program of its own,
+which the executor runs between ticks when a window's appends might not
+fit (``TpuExecutor._make_room``): the sort of a whole arena is most of a
+tick program's code and compile time, and a tick never needs it. The
+index is part of the join's state: it travels with the arena through
+donation, checkpoints and rebinds, only ``reindex`` compacts an indexed
+arena, and so the two are never out of step.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Container, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from reflow_tpu.graph import GraphError
 
-__all__ = ["compact_arena", "propagate_plan_caps"]
+__all__ = ["compact_arena", "propagate_plan_caps", "index_state",
+           "index_probe", "index_append", "reindex"]
 
 
 def propagate_plan_caps(plan, ingress_caps: Dict[int, int],
-                        divisor: int = 1) -> Dict[int, int]:
+                        divisor: int = 1,
+                        indexed: Container[int] = ()) -> Dict[int, int]:
     """Static per-tick capacity propagation against the Join arenas.
 
     Walks ``plan`` in topo order carrying worst-case per-node egress row
@@ -57,6 +80,9 @@ def propagate_plan_caps(plan, ingress_caps: Dict[int, int],
     This is both the per-tick executor's pre-dispatch sanity check and
     the mega-tick ingress queue's capacity negotiation: queue slots are
     only allocated for capacities this propagation accepts.
+    ``indexed``: ids of the joins that keep an arena index, whose δA
+    product is a budgeted enumeration like the multiset joins' and not a
+    sweep of the arena.
     """
     outs_cap: Dict[int, int] = dict(ingress_caps)
     for node in plan:
@@ -74,6 +100,10 @@ def propagate_plan_caps(plan, ingress_caps: Dict[int, int],
                     f"{node}: a single tick's right-delta capacity "
                     f"({caps[1]} rows) exceeds the per-shard arena "
                     f"capacity {cap}; raise arena_capacity")
+            if node.id in indexed:
+                outs_cap[node.id] = (node.op.product_slack * caps[0]
+                                     + caps[1])
+                continue
             if not node.inputs[0].spec.unique:
                 La = ((node.op.left_arena_capacity
                        or node.op.arena_capacity) // divisor)
@@ -171,3 +201,135 @@ def compact_arena(state: dict) -> dict:
         # CSR cache over the old ordering invalidates (linear_fixpoint)
         out["gen"] = state["gen"] + 1
     return out
+
+
+# -- the arena index (see the module docstring) ----------------------------
+
+def index_state(K: int, R: int) -> dict:
+    """The index leaves of a join's state over ``K`` keys and an arena
+    of ``R`` rows, for an empty arena."""
+    return {
+        "seg_len": jnp.zeros((R,), jnp.int32),
+        "seg_prev": jnp.full((R,), -1, jnp.int32),
+        "head": jnp.full((K,), -1, jnp.int32),
+        "deg": jnp.zeros((K,), jnp.int32),
+    }
+
+
+def _segments(sk: jax.Array, n) -> Tuple[jax.Array, jax.Array]:
+    """Rows ``[0, n)`` of ``sk`` are key-sorted: -> (first-of-segment
+    mask, segment length at each first row and 0 elsewhere)."""
+    C = sk.shape[0]
+    i = jnp.arange(C, dtype=jnp.int32)
+    inside = i < n
+    first = inside & ((i == 0) | (sk != jnp.roll(sk, 1)))
+    # the next boundary strictly after each row: a later first row, or
+    # the end of the sorted stretch
+    edge = jnp.where(first | ~inside, i, C)
+    after = jax.lax.cummin(
+        jnp.concatenate([edge[1:], jnp.full((1,), C, jnp.int32)]),
+        reverse=True)
+    return first, jnp.where(first, jnp.minimum(after, n) - i, 0)
+
+
+def index_probe(state: dict, dk: jax.Array, dlive: jax.Array, T: int):
+    """Every (delta row, arena row) pair that shares a key, laid into
+    ``T`` slots: -> (owner delta row [T], arena row [T], valid [T],
+    overflow, steps). ``dk`` are keys local to the index; a true pair
+    count beyond ``T`` returns overflow (the caller latches the sticky
+    error) and the pairs that fit. ``steps``: the chain walk's trips,
+    each a pass over the ``T`` slots: the segments of the probed key
+    that has most (one a tick it was appended in since the last
+    ``reindex``: only a compaction shortens a chain)."""
+    C = dk.shape[0]
+    K = state["head"].shape[0]
+    k_c = jnp.clip(dk, 0, K - 1)
+    d = jnp.where(dlive, state["deg"][k_c], 0)
+    cum = jnp.cumsum(d)
+    start = cum - d
+    j = jnp.arange(T, dtype=jnp.int32)
+    rows = jnp.arange(C, dtype=jnp.int32)
+
+    def owner_at(pos, active):
+        # slot -> the last active row whose ``pos`` is at or before it
+        # (positions rise with the row index, so a running max of the
+        # scattered row indices is exactly that)
+        marks = jnp.full((T,), -1, jnp.int32).at[
+            jnp.where(active, pos, T)].max(rows, mode="drop")
+        return jax.lax.cummax(marks)
+
+    own = owner_at(start, d > 0)
+    own_c = jnp.maximum(own, 0)
+    valid = (own >= 0) & (j < start[own_c] + d[own_c])
+
+    def live(c):
+        return jnp.any(c[0] >= 0)
+
+    def step(c):
+        cur, at, src, steps = c
+        act = cur >= 0
+        cur_c = jnp.maximum(cur, 0)
+        n = jnp.where(act, state["seg_len"][cur_c], 0)
+        o = owner_at(at, act)
+        o_c = jnp.maximum(o, 0)
+        hit = (o >= 0) & (j < at[o_c] + n[o_c])
+        src = jnp.where(hit, cur_c[o_c] + (j - at[o_c]), src)
+        return (jnp.where(act, state["seg_prev"][cur_c], -1), at + n, src,
+                steps + 1)
+
+    cur0 = jnp.where(d > 0, state["head"][k_c], -1)
+    _, _, src, steps = jax.lax.while_loop(
+        live, step, (cur0, start, jnp.zeros((T,), jnp.int32),
+                     jnp.zeros((), jnp.int32)))
+    return own_c, src, valid, cum[-1] > T, steps
+
+
+def index_append(state: dict, keys, vals, w) -> Tuple[dict, jax.Array]:
+    """Append the live rows of one tick's right delta, key-sorted, as one
+    segment a key, and chain them in. Whoever runs the ticks has made
+    room (``reindex``); rows past the arena's end are dropped and
+    reported. -> (state', overflow)."""
+    R = state["rkeys"].shape[0]
+    K = state["head"].shape[0]
+    C = keys.shape[0]
+    live = w != 0
+    n_app = jnp.sum(live.astype(jnp.int32))
+    skey = jnp.where(live, jnp.clip(keys, 0, K - 1), K)
+    order = jnp.argsort(skey, stable=True)
+    sk = skey[order]
+    first, seg_len = _segments(sk, n_app)
+    i = jnp.arange(C, dtype=jnp.int32)
+    rc = state["rcount"]
+    row = rc + i
+    pos = jnp.where(i < n_app, row, R)
+    fkey = jnp.where(first, sk, K)
+    out = dict(state)
+    out["rkeys"] = state["rkeys"].at[pos].set(sk, mode="drop")
+    out["rvals"] = state["rvals"].at[pos].set(vals[order], mode="drop")
+    out["rw"] = state["rw"].at[pos].set(w[order], mode="drop")
+    out["rcount"] = rc + n_app
+    out["seg_len"] = state["seg_len"].at[pos].set(seg_len, mode="drop")
+    out["seg_prev"] = state["seg_prev"].at[pos].set(
+        jnp.where(first, state["head"][jnp.minimum(sk, K - 1)], -1),
+        mode="drop")
+    out["head"] = state["head"].at[fkey].set(row, mode="drop")
+    out["deg"] = state["deg"].at[fkey].add(seg_len, mode="drop")
+    return out, out["rcount"] > R
+
+
+def reindex(state: dict) -> dict:
+    """Compact the arena (which leaves it sorted by key) and derive the
+    index from it: one segment a key."""
+    st = compact_arena(state)
+    rk, n = st["rkeys"], st["rcount"]
+    R = rk.shape[0]
+    K = st["head"].shape[0]
+    first, seg_len = _segments(rk, n)
+    fkey = jnp.where(first, rk, K)
+    st["seg_len"] = seg_len
+    st["seg_prev"] = jnp.full((R,), -1, jnp.int32)
+    st["head"] = jnp.full((K,), -1, jnp.int32).at[fkey].set(
+        jnp.arange(R, dtype=jnp.int32), mode="drop")
+    st["deg"] = jnp.zeros((K,), jnp.int32).at[fkey].set(seg_len,
+                                                        mode="drop")
+    return st

@@ -65,7 +65,8 @@ def reduce_state(op: Reduce, in_spec: Spec, out_spec: Spec) -> dict:
     if op.how not in LINEAR_DEVICE_REDUCERS:
         # min/max, scalar AND vector: retraction-capable candidate buffer
         # with lexicographic row ordering (the host oracle's tuple order)
-        return minmax_state(op, K, vshape, oshape, out_spec.value_dtype)
+        return minmax_state(op, K, vshape, oshape, out_spec.value_dtype,
+                            in_spec.value_dtype)
     return {
         "wsum": jnp.zeros((K,) + vshape, jnp.float32),
         "wcnt": jnp.zeros((K,), jnp.int32),
@@ -74,7 +75,11 @@ def reduce_state(op: Reduce, in_spec: Spec, out_spec: Spec) -> dict:
     }
 
 
-def join_state(op: Join, left_spec: Spec, right_spec: Spec) -> dict:
+def join_state(op: Join, left_spec: Spec, right_spec: Spec,
+               indexed: bool = False) -> dict:
+    """``indexed``: a unique-left join of a loop-free graph keeps
+    an arena index and its device counters (``arena.index_state``,
+    ``OP_COUNTERS``); the executor says which joins those are."""
     K = left_spec.key_space
     R = op.arena_capacity
     if not left_spec.unique:
@@ -99,7 +104,14 @@ def join_state(op: Join, left_spec: Spec, right_spec: Spec) -> dict:
             "gen": jnp.zeros((), jnp.int32),
             "error": jnp.zeros((), jnp.bool_),
         }
+    from reflow_tpu.executors.arena import index_state
+
+    extra = {}
+    if indexed:
+        extra = dict(index_state(K, R), counters=jnp.zeros(
+            (len(OP_COUNTERS["join"]),), jnp.int32))
     return {
+        **extra,
         "lval": jnp.zeros((K,) + tuple(left_spec.value_shape),
                           left_spec.value_dtype),
         "lw": jnp.zeros((K,), jnp.int32),
@@ -246,14 +258,30 @@ def _lex_lt(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.where(has, av < bv, False)
 
 
-def minmax_state(op: Reduce, K: int, in_vshape, out_vshape, odtype) -> dict:
+def _cand_dtype(in_dtype):
+    """Candidates ride in the input's own dtype when that is a 32-bit
+    integer (a float32 holds integers exactly only up to 2^24, and a
+    maximum must be one of the rows), else in float32."""
+    return jnp.int32 if jnp.dtype(in_dtype) == jnp.int32 else jnp.float32
+
+
+def _cand_top(cdt):
+    """The value no candidate can have: +inf, or the largest int32 (an
+    integer input therefore lies strictly inside ±(2^31 - 1))."""
+    return (jnp.inf if cdt == jnp.float32
+            else jnp.iinfo(jnp.int32).max)
+
+
+def minmax_state(op: Reduce, K: int, in_vshape, out_vshape, odtype,
+                 in_dtype=jnp.float32) -> dict:
     """State for the retraction-capable min/max (candidate buffer),
     scalar and vector values alike (a scalar is the V=1 row case).
 
     Values ride sign-normalized (``sign*v``, sign = +1 for min / -1 for
     max) so one lex-MIN kernel serves both. ``cand_v``/``cand_w`` hold
-    the R lex-smallest (normalized) distinct value ROWS per key with
-    their multiset weights (any sign: anti-rows are legal transients),
+    the R lex-smallest (normalized) distinct value ROWS per key (flat:
+    ``[K, R*V]``) with their multiset weights (any sign: anti-rows are
+    legal transients),
     stored in ascending lex order — the kernel's rank-ordered rebuild
     maintains that invariant. ``over_lo`` is a MONOTONE watermark row:
     the lex-smallest value ever evicted; ``over_maybe_pos`` latches
@@ -270,17 +298,23 @@ def minmax_state(op: Reduce, K: int, in_vshape, out_vshape, odtype) -> dict:
     V = 1
     for s in in_vshape:
         V *= s
+    cdt = _cand_dtype(in_dtype)
     return {
-        "cand_v": jnp.full((K, R, V), jnp.inf, jnp.float32),
+        # [K, R*V], not [K, R, V]: the TPU keeps a 2-D table with the
+        # key axis minor and gathers / scatters its rows in place; the
+        # 3-D one it copied whole into a padded layout and back every
+        # tick (8 GB of temporaries at 2^23 keys)
+        "cand_v": jnp.full((K, R * V), _cand_top(cdt), cdt),
         "cand_w": jnp.zeros((K, R), jnp.int32),
         # monotone per-key latches — overflow rows lose their identity,
         # so nothing can ever clear them (see utils refresh for the
         # host-triggered reset path)
-        "over_lo": jnp.full((K, V), jnp.inf, jnp.float32),
+        "over_lo": jnp.full((K, V), _cand_top(cdt), cdt),
         "over_maybe_pos": jnp.zeros((K,), jnp.bool_),
         "emitted": jnp.zeros((K,) + tuple(out_vshape), odtype),
         "emitted_has": jnp.zeros((K,), jnp.bool_),
         "error": jnp.zeros((), jnp.bool_),
+        "counters": jnp.zeros((len(OP_COUNTERS["reduce"]),), jnp.int32),
     }
 
 
@@ -309,139 +343,219 @@ def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
     of evicted or not-yet-inserted values — legal multiset transients)
     occupy buffer slots as anti-rows and cancel against later inserts.
     """
-    sign = jnp.float32(1.0 if op.how == "min" else -1.0)
-    R = state["cand_v"].shape[1]
-    V = state["cand_v"].shape[2]
+    cdt = state["cand_v"].dtype
+    sign = jnp.asarray(1 if op.how == "min" else -1, cdt)
+    R = state["cand_w"].shape[1]
+    V = state["cand_v"].shape[1] // R
     C = d.capacity
-    INF = jnp.float32(jnp.inf)
+    INF = jnp.asarray(_cand_top(cdt), cdt)
 
     live = d.weights != 0
     dval = jnp.where(live[:, None],
-                     sign * d.values.reshape(C, V).astype(jnp.float32),
-                     INF)
+                     sign * d.values.reshape(C, V).astype(cdt), INF)
 
-    # touched keys -> dense slots [0, n_t)
-    skey = jnp.where(live, d.keys, K)
-    order = jnp.argsort(skey)
-    sk = skey[order]
-    prev = jnp.concatenate([jnp.full((1,), -1, sk.dtype), sk[:-1]])
-    first = (sk != prev) & (sk < K)
-    slot_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1
-    # slot -> key
-    tkeys = jnp.full((C,), K, jnp.int32).at[
-        jnp.where(first, slot_sorted, C)].set(sk.astype(jnp.int32),
+    with jax.named_scope("minmax.merge"):
+        # touched keys -> dense slots [0, n_t)
+        skey = jnp.where(live, d.keys, K)
+        order = jnp.argsort(skey)
+        sk = skey[order]
+        prev = jnp.concatenate([jnp.full((1,), -1, sk.dtype), sk[:-1]])
+        first = (sk != prev) & (sk < K)
+        slot_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1
+        # slot -> key
+        tkeys = jnp.full((C,), K, jnp.int32).at[
+            jnp.where(first, slot_sorted, C)].set(sk.astype(jnp.int32),
+                                                  mode="drop")
+        # original row -> slot (dead rows -> C)
+        row_slot = jnp.full((C,), C, jnp.int32).at[order].set(
+            jnp.where(sk < K, slot_sorted, C))
+
+        tk_c = jnp.minimum(tkeys, K - 1)
+        tvalid = tkeys < K
+        n_t = jnp.sum(tvalid.astype(jnp.int32))
+
+        # a delta of C rows can touch C keys and rarely does (a stream
+        # join's output is mostly its budget's empty slots, and skewed
+        # besides): the buffers are rebuilt S slots at a time, as many
+        # times as the touched keys need, so the sort is over the
+        # touched keys' buffers and not over C of them
+        S = C // 8 if C >= 256 and C % 8 == 0 else C
+        dw = jnp.where(live, d.weights, 0)
+
+        def merge(lo):
+            """Rebuild the buffers of slots ``[lo, lo + S)`` from their
+            buffered rows and the delta's: -> (buffers' values [S, R,
+            V] and weights [S, R], per slot the lex-smallest evicted
+            row and whether a positive row was evicted, rows
+            evicted)."""
+            tk = jax.lax.dynamic_slice(tk_c, (lo,), (S,))
+            tv = jax.lax.dynamic_slice(tvalid, (lo,), (S,))
+            bw = jnp.where(tv[:, None], state["cand_w"][tk], 0)   # [S, R]
+            bv = jnp.where((bw != 0)[:, :, None],
+                           state["cand_v"][tk].reshape(S, R, V), INF)
+
+            # merged candidate rows: S*R buffer rows + C delta rows
+            slot_b = jnp.where(
+                bw.reshape(-1) != 0,
+                jnp.repeat(jnp.arange(S, dtype=jnp.int32), R), S)
+            rs = row_slot - lo
+            mslot = jnp.concatenate(
+                [slot_b, jnp.where((rs >= 0) & (rs < S), rs, S)])
+            mval = jnp.concatenate([bv.reshape(S * R, V), dval])  # [M, V]
+            mw = jnp.concatenate([bw.reshape(-1), dw])
+            M = mslot.shape[0]
+
+            # lex order: slot primary, then value columns (np.lexsort:
+            # LAST key is primary)
+            o2 = jnp.lexsort(tuple(mval[:, q] for q in range(V - 1, -1, -1))
+                             + (mslot,))
+            s2, v2, w2 = mslot[o2], mval[o2], mw[o2]
+            pv = jnp.concatenate([jnp.full((1,), -1, s2.dtype), s2[:-1]])
+            pval = jnp.concatenate([jnp.full((1, V), -INF), v2[:-1]])
+            first2 = ((s2 != pv) | jnp.any(v2 != pval, axis=1)) & (s2 < S)
+            gid = jnp.cumsum(first2.astype(jnp.int32)) - 1
+            gid_c = jnp.where(s2 < S, gid, M - 1)
+            netw = jnp.zeros((M,), jnp.int32).at[gid_c].add(
+                jnp.where(s2 < S, w2, 0))
+            net_here = netw[gid_c]
+            alive = first2 & (net_here != 0)
+
+            # rank among alive rows within each slot
+            ca = jnp.cumsum(alive.astype(jnp.int32))
+            slot_start = (s2 != pv) & (s2 < S)
+            base = jnp.zeros((S + 1,), jnp.int32).at[
+                jnp.where(slot_start, s2, S)].set(
+                ca - alive.astype(jnp.int32), mode="drop")
+            rank = ca - 1 - base[jnp.minimum(s2, S)]
+            keep = alive & (rank < R)
+            evict = alive & (rank >= R)
+
+            # rebuilt buffers per slot (rank-ordered: ascending lex)
+            flat = jnp.where(keep, jnp.minimum(s2, S - 1) * R + rank, S * R)
+            nb_v = jnp.full((S * R + 1, V), INF).at[flat].set(
+                v2, mode="drop")[:S * R].reshape(S, R, V)
+            nb_w = jnp.zeros((S * R + 1,), jnp.int32).at[flat].set(
+                net_here, mode="drop")[:S * R].reshape(S, R)
+
+            # evictions: the slot's FIRST evicted row (rank == R) is the
+            # lex-smallest evicted (rows are sorted), and it lowers the
+            # over_lo watermark; a positive-net eviction latches
+            # over_maybe_pos (both monotone — overflow rows lose their
+            # identity, so these can never be cleared)
+            first_ev = evict & (rank == R)
+            ev_lo = jnp.full((S + 1, V), INF).at[
+                jnp.where(first_ev, s2, S)].set(v2, mode="drop")[:S]
+            ev_pos = jnp.zeros((S + 1,), jnp.bool_).at[
+                jnp.where(evict & (net_here > 0), s2, S)].set(
+                True, mode="drop")[:S]
+            return (nb_v, nb_w, ev_lo, ev_pos,
+                    jnp.sum(evict.astype(jnp.int32)))
+
+        if S == C:
+            nb_v, nb_w, ev_lo, ev_pos, n_evicted = merge(0)
+        else:
+            def more(c):
+                return c[0] < n_t
+
+            def some(c):
+                lo, parts, n = c
+                got = merge(lo)
+                at = ((lo, 0, 0), (lo, 0), (lo, 0), (lo,))
+                return (lo + S,
+                        tuple(jax.lax.dynamic_update_slice(p, g, a)
+                              for p, g, a in zip(parts, got, at)),
+                        n + got[4])
+
+            _, (nb_v, nb_w, ev_lo, ev_pos), n_evicted = jax.lax.while_loop(
+                more, some,
+                (jnp.zeros((), jnp.int32),
+                 (jnp.full((C, R, V), INF), jnp.zeros((C, R), jnp.int32),
+                  jnp.full((C, V), INF), jnp.zeros((C,), jnp.bool_)),
+                 jnp.zeros((), jnp.int32)))
+
+        sidx = jnp.where(tvalid, tkeys, K)
+        cand_v = state["cand_v"].at[sidx].set(nb_v.reshape(C, R * V),
                                               mode="drop")
-    # original row -> slot (dead rows -> C)
-    row_slot = jnp.full((C,), C, jnp.int32).at[order].set(
-        jnp.where(sk < K, slot_sorted, C))
+        cand_w = state["cand_w"].at[sidx].set(nb_w, mode="drop")
+        lo_g = jnp.where(tvalid[:, None], state["over_lo"][tk_c], INF)
+        new_lo = jnp.where(_lex_lt(ev_lo, lo_g)[:, None], ev_lo, lo_g)
+        over_lo = state["over_lo"].at[sidx].set(new_lo, mode="drop")
+        new_mp = tvalid & (state["over_maybe_pos"][tk_c] | ev_pos)
+        over_maybe_pos = state["over_maybe_pos"].at[sidx].set(
+            new_mp, mode="drop")
 
-    tk_c = jnp.minimum(tkeys, K - 1)
-    tvalid = tkeys < K
-    bw = jnp.where(tvalid[:, None], state["cand_w"][tk_c], 0)    # [C, R]
-    bv = jnp.where((bw != 0)[:, :, None], state["cand_v"][tk_c], INF)
-
-    # merged candidate rows: C*R buffer rows + C delta rows
-    slot_b = jnp.where(bw.reshape(-1) != 0,
-                       jnp.repeat(jnp.arange(C, dtype=jnp.int32), R), C)
-    mslot = jnp.concatenate([slot_b, row_slot])
-    mval = jnp.concatenate([bv.reshape(C * R, V), dval])         # [M, V]
-    mw = jnp.concatenate([bw.reshape(-1), jnp.where(live, d.weights, 0)])
-    M = mslot.shape[0]
-
-    # lex order: slot primary, then value columns (np.lexsort: LAST key
-    # is primary)
-    o2 = jnp.lexsort(tuple(mval[:, q] for q in range(V - 1, -1, -1))
-                     + (mslot,))
-    s2, v2, w2 = mslot[o2], mval[o2], mw[o2]
-    pv = jnp.concatenate([jnp.full((1,), -1, s2.dtype), s2[:-1]])
-    pval = jnp.concatenate([jnp.full((1, V), -INF), v2[:-1]])
-    first2 = ((s2 != pv) | jnp.any(v2 != pval, axis=1)) & (s2 < C)
-    gid = jnp.cumsum(first2.astype(jnp.int32)) - 1
-    gid_c = jnp.where(s2 < C, gid, M - 1)
-    netw = jnp.zeros((M,), jnp.int32).at[gid_c].add(
-        jnp.where(s2 < C, w2, 0))
-    net_here = netw[gid_c]
-    alive = first2 & (net_here != 0)
-
-    # rank among alive rows within each slot
-    ca = jnp.cumsum(alive.astype(jnp.int32))
-    slot_start = (s2 != pv) & (s2 < C)
-    base = jnp.zeros((C + 1,), jnp.int32).at[
-        jnp.where(slot_start, s2, C)].set(ca - alive.astype(jnp.int32),
-                                          mode="drop")
-    rank = ca - 1 - base[jnp.minimum(s2, C)]
-    keep = alive & (rank < R)
-    evict = alive & (rank >= R)
-
-    # rebuilt buffers per slot (rank-ordered: ascending lex)
-    flat = jnp.where(keep, jnp.minimum(s2, C - 1) * R + rank, C * R)
-    nb_v = jnp.full((C * R + 1, V), INF).at[flat].set(
-        v2, mode="drop")[:C * R].reshape(C, R, V)
-    nb_w = jnp.zeros((C * R + 1,), jnp.int32).at[flat].set(
-        net_here, mode="drop")[:C * R].reshape(C, R)
-
-    # evictions: the slot's FIRST evicted row (rank == R) is the
-    # lex-smallest evicted (rows are sorted), and it lowers the over_lo
-    # watermark; a positive-net eviction latches over_maybe_pos (both
-    # monotone — overflow rows lose their identity, so these can never
-    # be cleared)
-    first_ev = evict & (rank == R)
-    ev_lo = jnp.full((C + 1, V), INF).at[
-        jnp.where(first_ev, s2, C)].set(v2, mode="drop")[:C]
-    ev_pos = jnp.zeros((C + 1,), jnp.bool_).at[
-        jnp.where(evict & (net_here > 0), s2, C)].set(
-        True, mode="drop")[:C]
-
-    sidx = jnp.where(tvalid, tkeys, K)
-    cand_v = state["cand_v"].at[sidx].set(nb_v, mode="drop")
-    cand_w = state["cand_w"].at[sidx].set(nb_w, mode="drop")
-    lo_g = jnp.where(tvalid[:, None], state["over_lo"][tk_c], INF)
-    new_lo = jnp.where(_lex_lt(ev_lo, lo_g)[:, None], ev_lo, lo_g)
-    over_lo = state["over_lo"].at[sidx].set(new_lo, mode="drop")
-    over_maybe_pos = state["over_maybe_pos"] | jnp.zeros(
-        (K,), jnp.bool_).at[sidx].set(ev_pos, mode="drop")
-
-    # dense aggregate over the key range. Existence mirrors the host
-    # oracle's any(w > 0) positive-support rule: provable from the
-    # buffer alone unless a positive row was ever evicted. Exactness of
-    # the buffered minimum additionally needs bmin strictly lex-below
-    # the eviction watermark: at equality an evicted ANTI-row at that
-    # very value could cancel the buffered positive support.
-    pos = cand_w > 0                                  # [K, R]
-    has_pos = jnp.any(pos, axis=1)
-    fi = jnp.argmax(pos, axis=1)
-    bmin = jnp.take_along_axis(cand_v, fi[:, None, None],
-                               axis=1)[:, 0]          # [K, V]
-    unknown = ((~has_pos & over_maybe_pos)
-               | (has_pos & ~_lex_lt(bmin, over_lo)))
-    exists = has_pos
     # cand_w accumulates per-(key, value) net weights ACROSS ticks with
     # only the per-batch 2**24 mass guard upstream (check_weight_mass);
     # sustained re-insertion of one value could wrap int32 silently and
     # flip existence/min decisions (ADVICE r3). Latch loudly at 2**30 —
     # far below wrap, with room for any single legal batch on top.
     w_over = jnp.any(jnp.abs(nb_w) > (1 << 30))
+    emitted, em_has = state["emitted"], state["emitted_has"]
+
+    def decide(cw, cv, lo, maybe_pos, em, has, n):
+        """Aggregate and emission masks of ``n`` keys from their
+        buffers. Existence mirrors the host oracle's any(w > 0)
+        positive-support rule: provable from the buffer alone unless a
+        positive row was ever evicted. Exactness of the buffered minimum
+        additionally needs bmin strictly lex-below the eviction
+        watermark: at equality an evicted ANTI-row at that very value
+        could cancel the buffered positive support."""
+        pos = cw > 0                                      # [n, R]
+        has_pos = jnp.any(pos, axis=1)
+        fi = jnp.argmax(pos, axis=1)
+        bmin = jnp.take_along_axis(cv, fi[:, None, None],
+                                   axis=1)[:, 0]          # [n, V]
+        unknown = ((~has_pos & maybe_pos)
+                   | (has_pos & ~_lex_lt(bmin, lo)))
+        agg_rows = sign * jnp.where(has_pos[:, None], bmin, 0)
+        aggv = jnp.asarray(agg_rows.reshape((n,) + tuple(out_vshape)),
+                           odtype)
+        changed = _differs(aggv, em, op.tol)
+        ins_m = has_pos & ~unknown & (~has | changed)
+        ret_m = has & ((~has_pos | changed) & ~unknown)
+        return aggv, has_pos, unknown, ins_m, ret_m
+
+    if C >= K:
+        # dense aggregate over the key range: diff every key's buffer
+        # against what was emitted
+        aggv, exists, unknown, ins_m, ret_m = decide(
+            cand_w, cand_v.reshape(K, R, V), over_lo, over_maybe_pos,
+            emitted, em_has, K)
+        okeys = key_offset + jnp.arange(K, dtype=jnp.int32)
+        old = emitted
+        new_emitted = jnp.where(_bcast_w(ins_m, aggv), aggv, emitted)
+        new_has = jnp.where(ins_m, True,
+                            jnp.where(ret_m & ~exists, False, em_has))
+    else:
+        # sparse: only the touched keys can have moved, so the tick
+        # reads and emits 2C rows whatever K is (the linear reducers'
+        # rule, ``_lower_reduce``); a key whose answer became unknowable
+        # latches the error in the tick that made it so
+        old = emitted[tk_c]
+        aggv, exists, unknown, ins_m, ret_m = decide(
+            nb_w, nb_v, new_lo, new_mp, old, tvalid & em_has[tk_c], C)
+        unknown, ins_m, ret_m = (unknown & tvalid, ins_m & tvalid,
+                                 ret_m & tvalid)
+        okeys = key_offset + tk_c
+        set_ins = jnp.where(ins_m, tkeys, K)
+        new_emitted = emitted.at[set_ins].set(aggv, mode="drop")
+        new_has = em_has.at[set_ins].set(True, mode="drop").at[
+            jnp.where(ret_m & ~exists, tkeys, K)].set(False, mode="drop")
     error = state["error"] | jnp.any(unknown) | w_over
 
-    emitted, em_has = state["emitted"], state["emitted_has"]
-    agg_rows = sign * jnp.where(has_pos[:, None], bmin, 0.0)
-    aggv = jnp.asarray(agg_rows.reshape((K,) + tuple(out_vshape)), odtype)
-    changed = _differs(aggv, emitted, op.tol)
-    ins_m = exists & ~unknown & (~em_has | changed)
-    ret_m = em_has & ((~exists | changed) & ~unknown)
-    gkeys = key_offset + jnp.arange(K, dtype=jnp.int32)
     out = DeviceDelta(
-        keys=jnp.concatenate([gkeys, gkeys]),
-        values=jnp.concatenate([emitted, aggv]),
+        keys=jnp.concatenate([okeys, okeys]),
+        values=jnp.concatenate([old, aggv]),
         weights=jnp.concatenate(
             [-ret_m.astype(jnp.int32), ins_m.astype(jnp.int32)]),
     )
-    new_emitted = jnp.where(_bcast_w(ins_m, aggv), aggv, emitted)
-    new_has = jnp.where(ins_m, True,
-                        jnp.where(ret_m & ~exists, False, em_has))
-    return out, {"cand_v": cand_v, "cand_w": cand_w, "over_lo": over_lo,
+    new_state = {"cand_v": cand_v, "cand_w": cand_w, "over_lo": over_lo,
                  "over_maybe_pos": over_maybe_pos, "emitted": new_emitted,
                  "emitted_has": new_has, "error": error}
+    if "counters" in state:
+        new_state["counters"] = state["counters"] + jnp.stack(
+            [n_t, n_evicted])
+    return out, new_state
 
 
 def _scatter_contribs(d: DeviceDelta, K: int):
@@ -486,10 +600,11 @@ def minmax_refresh_core(op: Reduce, K: int, out_vshape, odtype, state,
         jnp.where(live, d.keys, K)].set(True, mode="drop")
     st = dict(state)
     tb = touched[:, None]
-    st["cand_v"] = jnp.where(touched[:, None, None], jnp.inf,
-                             state["cand_v"])
+    top = jnp.asarray(_cand_top(state["cand_v"].dtype),
+                      state["cand_v"].dtype)
+    st["cand_v"] = jnp.where(tb, top, state["cand_v"])
     st["cand_w"] = jnp.where(tb, 0, state["cand_w"])
-    st["over_lo"] = jnp.where(tb, jnp.inf, state["over_lo"])
+    st["over_lo"] = jnp.where(tb, top, state["over_lo"])
     st["over_maybe_pos"] = jnp.where(touched, False,
                                      state["over_maybe_pos"])
     out, st2 = minmax_core(op, K, out_vshape, odtype, st, d, key_offset)
@@ -715,6 +830,74 @@ def _keyed_product(dk, dv, dw, ak, av, aw, K: int, T: int, emit,
     return DeviceDelta(k + key_offset, vals, w), overflow
 
 
+def _join_core_indexed(op: Join, K: int, R: int, state,
+                       da: Optional[DeviceDelta], db: Optional[DeviceDelta],
+                       merge_v, key_offset) -> Tuple[DeviceDelta, dict]:
+    """Unique-left join over an indexed arena (``arena.index_*``): the
+    same bilinear update as the dense path, δA ⋈ B_old + (A+δA) ⋈ δB,
+    with the first product a key-matched pair enumeration at a static
+    budget of ``product_slack x delta capacity`` slots (sticky error
+    past it) and not a gather over the whole arena, so a tick's output
+    and cost follow the delta. Counts its work (``OP_COUNTERS``). Room
+    for the appends is made between ticks (:func:`join_reindex`): an
+    append past the arena's end latches the sticky error."""
+    from reflow_tpu.executors.arena import index_append, index_probe
+
+    st = dict(state)
+    err = state["error"]
+    outs = []
+    zero = jnp.zeros((), jnp.int32)
+    late = pairs = steps = zero
+
+    if da is not None:
+        with jax.named_scope("join.probe"):
+            wa = da.weights
+            own, row, valid, ovf, steps = index_probe(
+                st, da.keys, wa != 0, op.product_slack * da.capacity)
+            err = err | ovf
+            k = jnp.clip(da.keys, 0, K - 1)[own]
+            w = jnp.where(valid, wa[own] * st["rw"][row], 0)
+            vals = merge_v(k, da.values[own], st["rvals"][row])
+            outs.append(DeviceDelta(k + key_offset, vals, w))
+            late = jnp.sum((w != 0).astype(jnp.int32))
+            # fold δA into the left table
+            st["lw"] = st["lw"].at[da.keys].add(wa)
+            st["lval"] = st["lval"].at[
+                jnp.where(wa > 0, da.keys, K)].set(da.values, mode="drop")
+
+    if db is not None:
+        with jax.named_scope("join.append"):
+            kb, vb, wb = db.keys, db.values, db.weights
+            w = st["lw"][kb] * wb
+            vals = merge_v(kb, st["lval"][kb], vb)
+            outs.append(DeviceDelta(kb + key_offset, vals, w))
+            pairs = jnp.sum((w != 0).astype(jnp.int32))
+            st, ovf = index_append(st, kb, vb, wb)
+            err = err | ovf
+
+    st["error"] = err
+    st["counters"] = (state["counters"]
+                      + jnp.stack([pairs + late, late, zero, zero, zero,
+                                   steps])).at[2].set(st["rcount"])
+    out = DeviceDelta(
+        jnp.concatenate([o.keys for o in outs]),
+        jnp.concatenate([o.values for o in outs]),
+        jnp.concatenate([o.weights for o in outs]),
+    )
+    return out, st
+
+
+def join_reindex(state: dict) -> dict:
+    """Compact an indexed join's arena and rebuild its index
+    (``arena.reindex``), counted: the program the executor runs between
+    ticks when the arena might not hold a window's appends."""
+    from reflow_tpu.executors.arena import reindex
+
+    st = reindex(state)
+    st["counters"] = st["counters"].at[3].add(1).at[4].add(1)
+    return st
+
+
 def join_core(op: Join, K: int, R: int, odtype, state,
               da: Optional[DeviceDelta], db: Optional[DeviceDelta],
               key_offset=0, oshape=None) -> Tuple[DeviceDelta, dict]:
@@ -729,8 +912,10 @@ def join_core(op: Join, K: int, R: int, odtype, state,
     sweeps the arena, and a loop pass with no right deltas never appends.
 
     Unique-left state (dense ``lval``/``lw`` tables) takes the table×arena
-    path below; multiset-left state (a second ``lkeys``/... append arena)
-    takes :func:`_join_core_multiset`.
+    path below, or, where the state carries an arena index (a loop-free
+    join: ``join_state(indexed=True)``), :func:`_join_core_indexed`;
+    multiset-left state (a second ``lkeys``/... append arena) takes
+    :func:`_join_core_multiset`.
     """
 
     def merge_v(keys, va, vb):
@@ -749,6 +934,10 @@ def join_core(op: Join, K: int, R: int, odtype, state,
     if "lkeys" in state:
         return _join_core_multiset(op, K, R, state, da, db, merge_v,
                                    key_offset)
+
+    if "head" in state:
+        return _join_core_indexed(op, K, R, state, da, db, merge_v,
+                                  key_offset)
 
     ak, av, aw = state["rkeys"], state["rvals"], state["rw"]
     lval, lw = state["lval"], state["lw"]
@@ -834,7 +1023,20 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: the executor reads them when a snapshot is taken
 #: (``TpuExecutor.op_counters``). New names are appended: readers go by
 #: position.
-OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps")}
+#:
+#: An indexed join (``join_state(indexed=True)``): live pairs emitted,
+#: those of them the δA product found (a left row that arrived after its
+#: matches), the arena's rows now (a level, not a sum), index rebuilds
+#: and, of those, compactions for room (every one, since the executor
+#: rebuilds only to make room: ``join_reindex``), and the trips of the
+#: probe's
+#: chain walk (each a pass over its pair slots). A min/max reduce: keys
+#: a tick's delta touched, and distinct value rows pushed out of a
+#: candidate buffer. Only nodes whose state has the leaf count.
+OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
+               "join": ("pairs", "late_pairs", "arena_rows",
+                        "index_rebuilds", "compactions", "probe_steps"),
+               "reduce": ("touched", "evicted")}
 
 
 def knn_state(op, q_spec: Spec, d_spec: Spec) -> dict:

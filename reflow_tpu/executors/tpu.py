@@ -35,8 +35,9 @@ from reflow_tpu.executors.base import Executor
 from reflow_tpu.executors.device_delta import (DeviceDelta, bucket_capacity,
                                                check_weight_mass, to_device,
                                                to_host)
-from reflow_tpu.executors.lowerings import (DEVICE_REDUCERS, join_state,
-                                            lower_node, reduce_state)
+from reflow_tpu.executors.lowerings import (DEVICE_REDUCERS, join_reindex,
+                                            join_state, lower_node,
+                                            reduce_state)
 from reflow_tpu.graph import FlowGraph, GraphError, Node
 from reflow_tpu.obs import trace as _trace
 from reflow_tpu.utils.config import env_int
@@ -286,6 +287,22 @@ def _node_token(node: Node):
 
 class TpuExecutor(Executor):
     name = "tpu"
+    #: loop-free unique-left joins keep an arena index, and they and the
+    #: min/max reduces count on the device (the sharded executor, whose
+    #: per-shard scalars ride as mesh-length vectors, keeps neither)
+    _index_joins = True
+
+    @property
+    def states(self):
+        return self._states
+
+    @states.setter
+    def states(self, value):
+        # whoever hands over states (bind, a restored checkpoint, a
+        # snapshot) may bring arenas of any fill: what the host knew of
+        # the indexed arenas' room (``_make_room``) is void
+        self._states = value
+        self._arena_used: Dict[int, int] = {}
 
     def __init__(self, *, fixpoint: bool = True, linear_fixpoint: bool = True):
         super().__init__()
@@ -301,6 +318,8 @@ class TpuExecutor(Executor):
         #: mesh size for sharded subclasses: arena overflow is bounded
         #: against the per-shard slice (worst-case key skew)
         self._arena_divisor = 1
+        self._indexed_joins: set = set()
+        self._reindex = None
         #: the fused delta-vector loop runs on both the single-device and
         #: the sharded executor (the sharded variant runs the loop inside
         #: one shard_map region — see linear_fixpoint.py)
@@ -390,7 +409,8 @@ class TpuExecutor(Executor):
 
         return [(n, OP_COUNTERS[n.op.kind])
                 for n in (self.graph.nodes if self.graph else ())
-                if n.kind == "op" and n.op.kind in OP_COUNTERS]
+                if n.kind == "op" and n.op.kind in OP_COUNTERS
+                and "counters" in (self.states.get(n.id) or ())]
 
     def counter_names(self) -> Dict[str, Tuple[str, ...]]:
         """Node name -> the counters that node keeps on the device."""
@@ -452,6 +472,7 @@ class TpuExecutor(Executor):
         self._csr_cache.clear()
         self.graph = graph
         self.states = {}
+        self._indexed_joins = set()
         for loop in graph.loops:
             if loop.defer_passes:
                 # cross-tick residual deferral: the loop carries its
@@ -532,7 +553,16 @@ class TpuExecutor(Executor):
                             f"{node}: default-merge device Join needs a "
                             f"spec with {flat} flat value elements "
                             f"(va ++ vb), got {node.spec.value_shape}")
-                self.states[node.id] = join_state(op, in_specs[0], in_specs[1])
+                # a unique-left join of a loop-free graph keeps an arena
+                # index: its δA product follows the delta. Under a loop
+                # the frontier is most of the key space and the dense
+                # sweep has no pair budget to overflow
+                indexed = (self._index_joins and in_specs[0].unique
+                           and not op.linear_left and not graph.loops)
+                self.states[node.id] = join_state(op, in_specs[0],
+                                                  in_specs[1], indexed)
+                if indexed:
+                    self._indexed_joins.add(node.id)
             else:
                 raise GraphError(f"{node}: no TPU lowering for {op.kind}")
         if self.device is not None:
@@ -573,11 +603,11 @@ class TpuExecutor(Executor):
             self._cache[sig] = fn
 
         # fail loudly BEFORE truncation
-        self._track_arena(plan, {nid: d.capacity
-                                 for nid, d in dev_ingress.items()})
+        self._make_room(self._track_arena(
+            plan, {nid: d.capacity for nid, d in dev_ingress.items()}), 1)
         op_states = {nid: st for nid, st in self.states.items()}
         new_states, egress_dev = fn(op_states, dev_ingress)
-        self.states = new_states
+        self._states = new_states
 
         # everything stays device-resident: sink batches are materialized
         # lazily by the scheduler once per tick, loop back-edges feed the
@@ -979,13 +1009,13 @@ class TpuExecutor(Executor):
                             prog = _SHARED_WINDOW_PROGRAMS.setdefault(
                                 shared_sig, prog)
                 self._cache[sig] = prog
-            self._track_arena(plan, caps)
+            self._make_room(self._track_arena(plan, caps), K)
             kind = "window" if window else "pass_many"
             t_d0 = time.perf_counter() if tr else 0.0
             c_d0 = time.thread_time() if tr else 0.0
             with _dispatch_notes(K, window, tr):
                 out = prog(dict(self.states), stack)
-            self.states, fresh = out[0], out[1]
+            self._states, fresh = out[0], out[1]
             if staged is not None:
                 staged.fresh = fresh
             if tr:
@@ -1204,10 +1234,12 @@ class TpuExecutor(Executor):
                     "the buffer")
         if node.kind == "op" and node.op.kind == "join":
             return ("join sticky error: an arena overflowed (live rows + "
-                    "appends exceeded capacity even after in-program "
-                    "compaction — raise arena_capacity / "
-                    "left_arena_capacity); or a multiset-left product "
-                    "exceeded its pair budget (raise product_slack); or, "
+                    "appends exceeded capacity even after compaction, "
+                    "in-program or, for an indexed arena, between "
+                    "windows — raise arena_capacity / "
+                    "left_arena_capacity); or a multiset-left or indexed "
+                    "delta-by-arena product exceeded its pair budget of "
+                    "product_slack x delta capacity (raise product_slack); or, "
                     "under a sharded executor, sparse routing overflowed "
                     "its per-destination budget (key skew — raise delta "
                     "capacity or rebalance the key space); or a downstream "
@@ -1266,10 +1298,40 @@ class TpuExecutor(Executor):
         boundary producers) to their delta capacities. The propagation
         itself lives in :func:`arena.propagate_plan_caps` so the
         mega-tick ingress queue negotiates against the same rules.
+        Returns the per-node capacities it found (``_make_room`` reads
+        the indexed joins' right-delta capacities from them).
         """
         from reflow_tpu.executors.arena import propagate_plan_caps
 
-        propagate_plan_caps(plan, ingress_caps, self._arena_divisor)
+        return propagate_plan_caps(plan, ingress_caps, self._arena_divisor,
+                                   self._indexed_joins)
+
+    def _make_room(self, caps: Dict[int, int], ticks: int) -> None:
+        """Before ``ticks`` ticks at the per-node capacities ``caps``
+        (``_track_arena``'s): every indexed join's arena has room for
+        what they can append. The tick program only appends (an append
+        past the end latches the sticky error); compacting an arena and
+        rebuilding its index is ``join_reindex``, run from here when an
+        arena might not hold the appends. The host keeps an upper bound
+        of each arena's rows (every tick adds its right delta's whole
+        capacity) and reads the true count from the device only when the
+        bound reaches the end: one sync per ``arena_capacity`` rows of
+        capacity dispatched, and none in a tick."""
+        for nid in self._indexed_joins:
+            node = self.graph.nodes[nid]
+            need = ticks * caps.get(node.inputs[1].id, 0)
+            if not need:
+                continue
+            R = node.op.arena_capacity
+            used = self._arena_used.get(nid)
+            if used is None or used + need > R:
+                used = int(self._states[nid]["rcount"])
+            if used + need > R:
+                if self._reindex is None:
+                    self._reindex = jax.jit(join_reindex, donate_argnums=0)
+                self._states[nid] = self._reindex(self._states[nid])
+                used = int(self._states[nid]["rcount"])
+            self._arena_used[nid] = used + need
 
     # -- trace & compile one pass program ----------------------------------
 

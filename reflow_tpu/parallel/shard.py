@@ -100,9 +100,16 @@ class ShardedTpuExecutor(TpuExecutor):
 
     # -- bind: divisibility validation + sharded state placement -----------
 
+    _index_joins = False
+
     def bind(self, graph: FlowGraph) -> None:
         super().bind(graph)
         n = self.n
+        for st in self.states.values():
+            # a replicated counter every shard adds its own share to
+            # would read whatever shard is asked
+            if isinstance(st, dict) and "error" in st:
+                st.pop("counters", None)
         #: node ids whose state is mesh-REPLICATED (Map params: every
         #: shard runs the full model on its delta slice — data parallel),
         #: vs the default key/row sharding of table/arena states
