@@ -37,7 +37,8 @@ tick gave one key are one contiguous *segment*, and the segments of a key
 are chained newest to oldest (``head[K]`` -> first row of the newest
 segment; at a segment's first row ``seg_len`` rows and ``seg_prev`` the
 first row of the one before; ``deg[K]`` rows in all). An append writes
-O(delta) entries. A probe reads ``deg`` to lay every delta row's pairs
+O(delta) entries: the rows as one block at ``rcount``, ``head`` and
+``deg`` by key. A probe reads ``deg`` to lay every delta row's pairs
 into a static budget of slots, then walks the chains one segment of
 every probed key per step: as many steps as the most *ticks* any probed
 key was appended in, whatever the rows. A compaction re-sorts the log,
@@ -284,34 +285,53 @@ def index_probe(state: dict, dk: jax.Array, dlive: jax.Array, T: int):
     return own_c, src, valid, cum[-1] > T, steps
 
 
+def _write_block(col: jax.Array, at, new: jax.Array, n) -> jax.Array:
+    """``col`` with rows ``[at, at + n)`` taken from ``new[:n]`` and every
+    other row as it was; a row past ``col``'s end is dropped. One
+    contiguous read and one contiguous write of ``new``'s rows.
+    ``dynamic_update_slice`` clamps a start whose block would pass the
+    end, so the block is placed where it fits, ``back`` rows early, and
+    the new rows enter it ``back`` rows late (rolled: the rows that wrap
+    are not taken) over what the block held."""
+    R = col.shape[0]
+    new = new[:R]              # rows [R, C) of a wider delta never fit
+    C = new.shape[0]
+    lo = jnp.clip(at, 0, R - C)
+    back = jnp.minimum(at - lo, C)
+    i = jnp.arange(C, dtype=jnp.int32) - back
+    take = ((i >= 0) & (i < n)).reshape((C,) + (1,) * (new.ndim - 1))
+    held = jax.lax.dynamic_slice_in_dim(col, lo, C)
+    return jax.lax.dynamic_update_slice_in_dim(
+        col, jnp.where(take, jnp.roll(new, back, axis=0), held), lo, 0)
+
+
 def index_append(state: dict, keys, vals, w) -> Tuple[dict, jax.Array]:
     """Append the live rows of one tick's right delta, key-sorted, as one
-    segment a key, and chain them in. Whoever runs the ticks has made
-    room (``reindex``); rows past the arena's end are dropped and
-    reported. -> (state', overflow)."""
+    segment a key, and chain them in. The rows land at ``rcount``, one
+    after the other, so the arena's and the index's row columns are each
+    written as one block (``_write_block``); only ``head`` and ``deg``,
+    which are keyed, are scatters. Whoever runs the ticks has made room
+    (``reindex``); rows past the arena's end are dropped and reported,
+    and no row before ``rcount`` or from ``rcount + n_app`` on changes.
+    -> (state', overflow)."""
     R = state["rkeys"].shape[0]
     K = state["head"].shape[0]
-    C = keys.shape[0]
     live = w != 0
     n_app = jnp.sum(live.astype(jnp.int32))
     skey = jnp.where(live, jnp.clip(keys, 0, K - 1), K)
     order = jnp.argsort(skey, stable=True)
     sk = skey[order]
     first, seg_len = _segments(sk, n_app)
-    i = jnp.arange(C, dtype=jnp.int32)
     rc = state["rcount"]
-    row = rc + i
-    pos = jnp.where(i < n_app, row, R)
+    row = rc + jnp.arange(sk.shape[0], dtype=jnp.int32)
     fkey = jnp.where(first, sk, K)
+    seg_prev = jnp.where(first, state["head"][jnp.minimum(sk, K - 1)], -1)
     out = dict(state)
-    out["rkeys"] = state["rkeys"].at[pos].set(sk, mode="drop")
-    out["rvals"] = state["rvals"].at[pos].set(vals[order], mode="drop")
-    out["rw"] = state["rw"].at[pos].set(w[order], mode="drop")
+    for name, new in (("rkeys", sk), ("rvals", vals[order]),
+                      ("rw", w[order]), ("seg_len", seg_len),
+                      ("seg_prev", seg_prev)):
+        out[name] = _write_block(state[name], rc, new, n_app)
     out["rcount"] = rc + n_app
-    out["seg_len"] = state["seg_len"].at[pos].set(seg_len, mode="drop")
-    out["seg_prev"] = state["seg_prev"].at[pos].set(
-        jnp.where(first, state["head"][jnp.minimum(sk, K - 1)], -1),
-        mode="drop")
     out["head"] = state["head"].at[fkey].set(row, mode="drop")
     out["deg"] = state["deg"].at[fkey].add(seg_len, mode="drop")
     return out, out["rcount"] > R

@@ -10,6 +10,7 @@ Small seeded sizes, CPU."""
 import importlib.util
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -338,6 +339,200 @@ def test_indexed_product_equals_the_dense_one(seed):
     rk, n = np.asarray(ist["rkeys"]), int(ist["rcount"])
     deg = np.bincount(rk[:n], minlength=K)
     assert np.array_equal(np.asarray(ist["deg"]), deg)
+
+
+# -- the append: one block at ``rcount`` --------------------------------------
+
+
+def _scatter_append(state, keys, vals, w):
+    """``arena.index_append`` as it was before the block write: every
+    column, keyed or not, by a scatter of the delta's capacity (seven a
+    join). The reference the block append is held to, leaf for leaf."""
+    import jax.numpy as jnp
+
+    from reflow_tpu.executors.arena import _segments
+
+    R, K, C = state["rkeys"].shape[0], state["head"].shape[0], keys.shape[0]
+    live = w != 0
+    n_app = jnp.sum(live.astype(jnp.int32))
+    skey = jnp.where(live, jnp.clip(keys, 0, K - 1), K)
+    order = jnp.argsort(skey, stable=True)
+    sk = skey[order]
+    first, seg_len = _segments(sk, n_app)
+    i = jnp.arange(C, dtype=jnp.int32)
+    row = state["rcount"] + i
+    pos = jnp.where(i < n_app, row, R)
+    fkey = jnp.where(first, sk, K)
+    out = dict(state)
+    out["rkeys"] = state["rkeys"].at[pos].set(sk, mode="drop")
+    out["rvals"] = state["rvals"].at[pos].set(vals[order], mode="drop")
+    out["rw"] = state["rw"].at[pos].set(w[order], mode="drop")
+    out["rcount"] = state["rcount"] + n_app
+    out["seg_len"] = state["seg_len"].at[pos].set(seg_len, mode="drop")
+    out["seg_prev"] = state["seg_prev"].at[pos].set(
+        jnp.where(first, state["head"][jnp.minimum(sk, K - 1)], -1),
+        mode="drop")
+    out["head"] = state["head"].at[fkey].set(row, mode="drop")
+    out["deg"] = state["deg"].at[fkey].add(seg_len, mode="drop")
+    return out, out["rcount"] > R
+
+
+def _same_leaves(a, b):
+    assert a.keys() == b.keys()
+    for name in a:
+        assert np.array_equal(np.asarray(a[name]), np.asarray(b[name])), name
+
+
+def _appended(st, deltas):
+    """``deltas`` through both appends from the state ``st``, compared
+    after every tick: -> (state, overflow of the last tick)."""
+    import jax
+
+    from reflow_tpu.executors.arena import index_append
+
+    block, scatter = jax.jit(index_append), jax.jit(_scatter_append)
+    ref, ovf = st, False
+    for d in deltas:
+        st, ovf = block(st, d.keys, d.values, d.weights)
+        ref, rovf = scatter(ref, d.keys, d.values, d.weights)
+        _same_leaves(st, ref)
+        assert bool(ovf) == bool(rovf)
+    return st, bool(ovf)
+
+
+def test_the_append_lowers_to_block_writes_and_two_keyed_scatters():
+    """The mechanism in force: of what ``index_append`` writes only
+    ``head`` and ``deg`` are keyed, so only they are scatters; the five
+    row columns are one ``dynamic_update_slice`` each."""
+    import jax
+
+    from reflow_tpu.executors.arena import index_append
+
+    _, (ist, _dst) = _join_pair(16, 192)
+    d = _delta(np.random.default_rng(0), 16, 32, 20, ())
+    text = jax.jit(index_append).lower(
+        ist, d.keys, d.values, d.weights).as_text()
+    assert text.count('"stablehlo.scatter"(') == 2
+    assert len(re.findall(r"= stablehlo\.dynamic_update_slice ", text)) == 5
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
+@pytest.mark.parametrize("live_rows", [0, 1, 20, 32],
+                         ids=["none", "one", "interleaved", "all"])
+@pytest.mark.parametrize("seed", [1, 2])
+def test_block_append_equals_the_scatter_append(seed, live_rows):
+    """Six ticks in a row from an empty arena, dead rows interleaved
+    with the live ones (or no live row, or no dead one), weights of both
+    signs: every leaf of the state is the scatter append's after every
+    tick, the rows a block leaves behind its live ones among them."""
+    K, R, C = 16, 256, 32
+    _, (ist, _dst) = _join_pair(K, R)
+    rng = np.random.default_rng(seed)
+    deltas = [_delta(rng, K, C, live_rows, (), w_choices=(1, -1, 2))
+              for _ in range(6)]
+    # one tick of another size between them, as a late left-less tick is
+    deltas.insert(3, _delta(rng, K, C, 7, ()))
+    st, ovf = _appended(ist, deltas)
+    n = int(st["rcount"])
+    assert n == 6 * live_rows + 7 and not ovf
+    # behind the last live row the arena reads as it was made: dead
+    assert not np.asarray(st["rw"])[n:].any()
+    assert not np.asarray(st["seg_len"])[n:].any()
+    assert (np.asarray(st["seg_prev"])[n:] == -1).all()
+    assert not np.asarray(st["rkeys"])[n:].any()
+    assert not np.asarray(st["rvals"])[n:].any()
+
+
+@pytest.mark.parametrize("case", ["fits", "past_the_end", "full",
+                                  "delta_wider_than_the_arena"])
+def test_block_append_at_the_arenas_end(case):
+    """``rcount + C > R``: the block cannot start at ``rcount``
+    (``dynamic_update_slice`` would clamp it back over live rows). Live
+    rows that fit all land and nothing is reported; those past the end
+    are dropped and reported; no row before ``rcount`` changes."""
+    K, R, C = 16, 80, 32
+    _, (ist, _dst) = _join_pair(K, R)
+    rng = np.random.default_rng(5)
+    # 64 rows in: rcount + C = 96 > R = 80
+    st, ovf = _appended(ist, [_delta(rng, K, C, 32, ()) for _ in range(2)])
+    assert int(st["rcount"]) == 64 and not ovf
+    before = {k: np.array(v) for k, v in st.items()}
+    if case == "delta_wider_than_the_arena":
+        # the executor refuses such a graph; the function still drops
+        # exactly the rows past the end
+        last = _delta(rng, K, 96, 40, ())
+    else:
+        last = _delta(rng, K, C, {"fits": 16, "past_the_end": 25,
+                                  "full": 32}[case], ())
+    n_app = int(np.count_nonzero(np.asarray(last.weights)))
+    st, ovf = _appended(st, [last])
+    assert int(st["rcount"]) == 64 + n_app
+    assert ovf == (64 + n_app > R)
+    for name in ("rkeys", "rvals", "rw", "seg_len", "seg_prev"):
+        assert np.array_equal(np.asarray(st[name])[:64], before[name][:64])
+    landed = min(n_app, R - 64)
+    order = np.argsort(np.where(np.asarray(last.weights) != 0,
+                                np.asarray(last.keys), K), kind="stable")
+    assert np.array_equal(np.asarray(st["rvals"])[64:64 + landed],
+                          np.asarray(last.values)[order][:landed])
+    assert (np.asarray(st["rw"])[64:64 + landed] != 0).all()
+    assert not np.asarray(st["rw"])[64 + landed:].any()
+    # and once more on the arena that is over its end: nothing lands
+    st2, ovf2 = _appended(st, [_delta(rng, K, C, 32, ())])
+    if 64 + n_app >= R:
+        assert ovf2
+        for name in ("rkeys", "rvals", "rw", "seg_len", "seg_prev"):
+            assert np.array_equal(np.asarray(st2[name]),
+                                  np.asarray(st[name])), name
+
+
+@pytest.mark.parametrize("seed", [4, 5])
+def test_a_blocks_dead_tail_does_not_leak_through_a_reindex(seed):
+    """Append (dead rows in every block) -> ``join_reindex`` -> append ->
+    probe: the late product of a left delta over every key is the dense
+    join's, and so is the arena; the rows behind a block's live ones
+    were dead before the compaction and are after it."""
+    import jax
+    import jax.numpy as jnp
+
+    from reflow_tpu.executors.device_delta import DeviceDelta
+    from reflow_tpu.executors.lowerings import join_core, join_reindex
+
+    K, R, C = 16, 192, 32
+    j, (ist, dst) = _join_pair(K, R)
+    rng = np.random.default_rng(seed)
+    step = jax.jit(lambda st, da, db: join_core(
+        j.op, K, R, np.int32, st, da, db, oshape=(3,)))
+    sent = []
+    for tick in range(6):
+        db = _delta(rng, K, C, 9 + tick, ())
+        if tick == 4:                        # cancels tick 1's rows
+            db = DeviceDelta(sent[1].keys, sent[1].values, -sent[1].weights)
+        sent.append(db)
+        if tick in (2, 5):
+            ist = jax.jit(join_reindex)(ist)
+            n = int(ist["rcount"])
+            assert not np.asarray(ist["rw"])[n:].any()
+            assert not np.asarray(ist["seg_len"])[n:].any()
+        _, ist = step(ist, None, db)
+        _, dst = step(dst, None, db)
+    assert int(ist["counters"][3]) == 2
+    # tick 1's rows and their retractions went with the second compaction
+    assert int(ist["rcount"]) < int(dst["rcount"])
+    da = DeviceDelta(jnp.arange(C, dtype=jnp.int32) % K,
+                     jnp.asarray(rng.integers(1, 99, (C, 2)), jnp.int32),
+                     jnp.asarray((np.arange(C) < K).astype(np.int32)))
+    out_i, ist = step(ist, da, None)
+    out_d, dst = step(dst, da, None)
+    assert _multiset(out_i) == _multiset(out_d) and _multiset(out_i)
+    assert not bool(ist["error"]) and not bool(dst["error"])
+    arena = lambda st: _multiset(DeviceDelta(          # noqa: E731
+        st["rkeys"], st["rvals"][:, None], st["rw"]))
+    assert arena(ist) == arena(dst)
+    n = int(ist["rcount"])
+    assert np.array_equal(np.asarray(ist["deg"]),
+                          np.bincount(np.asarray(ist["rkeys"])[:n],
+                                      minlength=K))
 
 
 @pytest.mark.parametrize("ticks_a_window", [1, 4])
