@@ -272,6 +272,64 @@ def _cand_top(cdt):
             else jnp.iinfo(jnp.int32).max)
 
 
+def _block_slots(C: int) -> int:
+    """Slots of a delta of capacity ``C`` that a reduce takes at a time
+    when it writes its keyed tables (``_over_blocks``)."""
+    return C // 8 if C >= 256 and C % 8 == 0 else C
+
+
+def _block(x: jax.Array, lo, S: int) -> jax.Array:
+    return jax.lax.dynamic_slice_in_dim(x, lo, S)
+
+
+def _put_block(x: jax.Array, blk: jax.Array, lo) -> jax.Array:
+    return jax.lax.dynamic_update_slice_in_dim(x, blk, lo, 0)
+
+
+def _over_blocks(C: int, n, body, carry):
+    """``carry`` through ``body(lo, carry)`` for every block of
+    ``_block_slots(C)`` slots that the prefix ``[0, n)`` of a delta's
+    ``C`` compacted slots reaches, in order: -> (carry, trips). A
+    scatter into a keyed table costs by the slots of its update, live
+    or dropped, and a delta of ``C`` rows rarely holds ``C`` keys (a
+    stream join's output is mostly its budget's empty slots, a filter
+    keeps its input's capacity), so a reduce writes its tables block by
+    block over the slots the tick fills and not once over ``C``. The
+    tables ride in ``carry`` and every block reads them there, so they
+    are updated in place; a full delta takes 8 blocks over the same
+    ``C`` slots. Where a block is the whole delta the body runs once,
+    with no loop."""
+    S = _block_slots(C)
+    zero = jnp.zeros((), jnp.int32)
+    if S == C:
+        return body(zero, carry), zero + 1
+    lo, carry = jax.lax.while_loop(
+        lambda c: c[0] < n, lambda c: (c[0] + S, body(*c)), (zero, carry))
+    return carry, lo // S
+
+
+def _no_rows(C: int, vshape, dtype):
+    """A reduce's ``2 C`` output rows before any block filled them: the
+    retracted and inserted values and their masks, all dead."""
+    zrow = jnp.zeros((C,) + tuple(vshape), dtype)
+    zm = jnp.zeros((C,), jnp.bool_)
+    return zrow, zrow, zm, zm
+
+
+def _emit_block(emitted, em_has, rows, lo, tk, old, agg, exists, ins_m,
+                ret_m):
+    """One block's emission: keys ``tk`` (slots from ``lo``) retract
+    ``old`` where ``ret_m`` and insert ``agg`` where ``ins_m``. ->
+    (emitted', emitted_has', rows with the block's filled in)."""
+    K = emitted.shape[0]
+    set_ins = jnp.where(ins_m, tk, K)
+    return (emitted.at[set_ins].set(agg, mode="drop"),
+            em_has.at[set_ins].set(True, mode="drop").at[
+                jnp.where(ret_m & ~exists, tk, K)].set(False, mode="drop"),
+            tuple(_put_block(p, g, lo)
+                  for p, g in zip(rows, (old, agg, ret_m, ins_m))))
+
+
 def minmax_state(op: Reduce, K: int, in_vshape, out_vshape, odtype,
                  in_dtype=jnp.float32) -> dict:
     """State for the retraction-capable min/max (candidate buffer),
@@ -374,25 +432,22 @@ def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
         tvalid = tkeys < K
         n_t = jnp.sum(tvalid.astype(jnp.int32))
 
-        # a delta of C rows can touch C keys and rarely does (a stream
-        # join's output is mostly its budget's empty slots, and skewed
-        # besides): the buffers are rebuilt S slots at a time, as many
-        # times as the touched keys need, so the sort is over the
-        # touched keys' buffers and not over C of them
-        S = C // 8 if C >= 256 and C % 8 == 0 else C
+        # the buffers are rebuilt, and every keyed table written, S
+        # slots at a time, as many times as the touched keys need
+        # (``_over_blocks``): the sort is over the touched keys' buffers
+        # and the scatters over their slots, not over C of either
+        S = _block_slots(C)
         dw = jnp.where(live, d.weights, 0)
 
-        def merge(lo):
-            """Rebuild the buffers of slots ``[lo, lo + S)`` from their
-            buffered rows and the delta's: -> (buffers' values [S, R,
-            V] and weights [S, R], per slot the lex-smallest evicted
-            row and whether a positive row was evicted, rows
-            evicted)."""
-            tk = jax.lax.dynamic_slice(tk_c, (lo,), (S,))
-            tv = jax.lax.dynamic_slice(tvalid, (lo,), (S,))
-            bw = jnp.where(tv[:, None], state["cand_w"][tk], 0)   # [S, R]
+        def merge(cand_v, cand_w, tk, tv, lo):
+            """Rebuild the buffers of slots ``[lo, lo + S)`` (keys
+            ``tk``, live where ``tv``) from their buffered rows and the
+            delta's: -> (buffers' values [S, R, V] and weights [S, R],
+            per slot the lex-smallest evicted row and whether a positive
+            row was evicted, rows evicted)."""
+            bw = jnp.where(tv[:, None], cand_w[tk], 0)            # [S, R]
             bv = jnp.where((bw != 0)[:, :, None],
-                           state["cand_v"][tk].reshape(S, R, V), INF)
+                           cand_v[tk].reshape(S, R, V), INF)
 
             # merged candidate rows: S*R buffer rows + C delta rows
             slot_b = jnp.where(
@@ -451,47 +506,6 @@ def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
             return (nb_v, nb_w, ev_lo, ev_pos,
                     jnp.sum(evict.astype(jnp.int32)))
 
-        if S == C:
-            nb_v, nb_w, ev_lo, ev_pos, n_evicted = merge(0)
-        else:
-            def more(c):
-                return c[0] < n_t
-
-            def some(c):
-                lo, parts, n = c
-                got = merge(lo)
-                at = ((lo, 0, 0), (lo, 0), (lo, 0), (lo,))
-                return (lo + S,
-                        tuple(jax.lax.dynamic_update_slice(p, g, a)
-                              for p, g, a in zip(parts, got, at)),
-                        n + got[4])
-
-            _, (nb_v, nb_w, ev_lo, ev_pos), n_evicted = jax.lax.while_loop(
-                more, some,
-                (jnp.zeros((), jnp.int32),
-                 (jnp.full((C, R, V), INF), jnp.zeros((C, R), jnp.int32),
-                  jnp.full((C, V), INF), jnp.zeros((C,), jnp.bool_)),
-                 jnp.zeros((), jnp.int32)))
-
-        sidx = jnp.where(tvalid, tkeys, K)
-        cand_v = state["cand_v"].at[sidx].set(nb_v.reshape(C, R * V),
-                                              mode="drop")
-        cand_w = state["cand_w"].at[sidx].set(nb_w, mode="drop")
-        lo_g = jnp.where(tvalid[:, None], state["over_lo"][tk_c], INF)
-        new_lo = jnp.where(_lex_lt(ev_lo, lo_g)[:, None], ev_lo, lo_g)
-        over_lo = state["over_lo"].at[sidx].set(new_lo, mode="drop")
-        new_mp = tvalid & (state["over_maybe_pos"][tk_c] | ev_pos)
-        over_maybe_pos = state["over_maybe_pos"].at[sidx].set(
-            new_mp, mode="drop")
-
-    # cand_w accumulates per-(key, value) net weights ACROSS ticks with
-    # only the per-batch 2**24 mass guard upstream (check_weight_mass);
-    # sustained re-insertion of one value could wrap int32 silently and
-    # flip existence/min decisions (ADVICE r3). Latch loudly at 2**30 —
-    # far below wrap, with room for any single legal batch on top.
-    w_over = jnp.any(jnp.abs(nb_w) > (1 << 30))
-    emitted, em_has = state["emitted"], state["emitted_has"]
-
     def decide(cw, cv, lo, maybe_pos, em, has, n):
         """Aggregate and emission masks of ``n`` keys from their
         buffers. Existence mirrors the host oracle's any(w > 0)
@@ -515,33 +529,79 @@ def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
         ret_m = has & ((~has_pos | changed) & ~unknown)
         return aggv, has_pos, unknown, ins_m, ret_m
 
-    if C >= K:
-        # dense aggregate over the key range: diff every key's buffer
-        # against what was emitted
-        aggv, exists, unknown, ins_m, ret_m = decide(
-            cand_w, cand_v.reshape(K, R, V), over_lo, over_maybe_pos,
-            emitted, em_has, K)
-        okeys = key_offset + jnp.arange(K, dtype=jnp.int32)
-        old = emitted
-        new_emitted = jnp.where(_bcast_w(ins_m, aggv), aggv, emitted)
-        new_has = jnp.where(ins_m, True,
-                            jnp.where(ret_m & ~exists, False, em_has))
-    else:
-        # sparse: only the touched keys can have moved, so the tick
-        # reads and emits 2C rows whatever K is (the linear reducers'
-        # rule, ``_lower_reduce``); a key whose answer became unknowable
-        # latches the error in the tick that made it so
-        old = emitted[tk_c]
-        aggv, exists, unknown, ins_m, ret_m = decide(
-            nb_w, nb_v, new_lo, new_mp, old, tvalid & em_has[tk_c], C)
-        unknown, ins_m, ret_m = (unknown & tvalid, ins_m & tvalid,
-                                 ret_m & tvalid)
+    # sparse (C < K): only the touched keys can have moved, so the tick
+    # reads and emits 2C rows whatever K is (the linear reducers' rule,
+    # ``_lower_reduce``), a block's rows with its tables; a key whose
+    # answer became unknowable latches the error in the tick that made
+    # it so. Dense: every key's buffer is diffed against what was
+    # emitted, once the blocks are in.
+    sparse = C < K
+
+    def block(lo, c):
+        """Slots ``[lo, lo + S)``: their keys' buffers merged and
+        written back with the eviction latches and, when sparse, their
+        emission. Blocks hold disjoint keys, so none reads what another
+        wrote."""
+        t = dict(c["tables"])
+        tk, tv = _block(tk_c, lo, S), _block(tvalid, lo, S)
+        with jax.named_scope("minmax.merge"):
+            nb_v, nb_w, ev_lo, ev_pos, n_ev = merge(
+                t["cand_v"], t["cand_w"], tk, tv, lo)
+            sidx = jnp.where(tv, tk, K)
+            t["cand_v"] = t["cand_v"].at[sidx].set(
+                nb_v.reshape(S, R * V), mode="drop")
+            t["cand_w"] = t["cand_w"].at[sidx].set(nb_w, mode="drop")
+            lo_g = jnp.where(tv[:, None], t["over_lo"][tk], INF)
+            new_lo = jnp.where(_lex_lt(ev_lo, lo_g)[:, None], ev_lo, lo_g)
+            t["over_lo"] = t["over_lo"].at[sidx].set(new_lo, mode="drop")
+            new_mp = tv & (t["over_maybe_pos"][tk] | ev_pos)
+            t["over_maybe_pos"] = t["over_maybe_pos"].at[sidx].set(
+                new_mp, mode="drop")
+        # cand_w accumulates per-(key, value) net weights ACROSS ticks
+        # with only the per-batch 2**24 mass guard upstream
+        # (check_weight_mass); sustained re-insertion of one value could
+        # wrap int32 silently and flip existence/min decisions (ADVICE
+        # r3). Latch loudly at 2**30 — far below wrap, with room for any
+        # single legal batch on top.
+        out = {"tables": t, "evicted": c["evicted"] + n_ev,
+               "bad": c["bad"] | jnp.any(jnp.abs(nb_w) > (1 << 30))}
+        if sparse:
+            old = t["emitted"][tk]
+            aggv, exists, unknown, ins_m, ret_m = decide(
+                nb_w, nb_v, new_lo, new_mp, old,
+                tv & t["emitted_has"][tk], S)
+            t["emitted"], t["emitted_has"], out["rows"] = _emit_block(
+                t["emitted"], t["emitted_has"], c["rows"], lo, tk, old,
+                aggv, exists, ins_m & tv, ret_m & tv)
+            out["bad"] = out["bad"] | jnp.any(unknown & tv)
+        return out
+
+    tables = ("cand_v", "cand_w", "over_lo", "over_maybe_pos") + (
+        ("emitted", "emitted_has") if sparse else ())
+    carry = {"tables": {k: state[k] for k in tables},
+             "evicted": jnp.zeros((), jnp.int32),
+             "bad": jnp.zeros((), jnp.bool_)}
+    if sparse:
+        carry["rows"] = _no_rows(C, out_vshape, odtype)
+    got, blocks = _over_blocks(C, n_t, block, carry)
+    new_state = dict(got["tables"])
+    error = state["error"] | got["bad"]
+
+    if sparse:
+        old, aggv, ret_m, ins_m = got["rows"]
         okeys = key_offset + tk_c
-        set_ins = jnp.where(ins_m, tkeys, K)
-        new_emitted = emitted.at[set_ins].set(aggv, mode="drop")
-        new_has = em_has.at[set_ins].set(True, mode="drop").at[
-            jnp.where(ret_m & ~exists, tkeys, K)].set(False, mode="drop")
-    error = state["error"] | jnp.any(unknown) | w_over
+    else:
+        aggv, exists, unknown, ins_m, ret_m = decide(
+            new_state["cand_w"], new_state["cand_v"].reshape(K, R, V),
+            new_state["over_lo"], new_state["over_maybe_pos"],
+            state["emitted"], state["emitted_has"], K)
+        okeys = key_offset + jnp.arange(K, dtype=jnp.int32)
+        old = state["emitted"]
+        new_state["emitted"] = jnp.where(_bcast_w(ins_m, aggv), aggv, old)
+        new_state["emitted_has"] = jnp.where(
+            ins_m, True,
+            jnp.where(ret_m & ~exists, False, state["emitted_has"]))
+        error = error | jnp.any(unknown)
 
     out = DeviceDelta(
         keys=jnp.concatenate([okeys, okeys]),
@@ -549,12 +609,10 @@ def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
         weights=jnp.concatenate(
             [-ret_m.astype(jnp.int32), ins_m.astype(jnp.int32)]),
     )
-    new_state = {"cand_v": cand_v, "cand_w": cand_w, "over_lo": over_lo,
-                 "over_maybe_pos": over_maybe_pos, "emitted": new_emitted,
-                 "emitted_has": new_has, "error": error}
+    new_state["error"] = error
     if "counters" in state:
         new_state["counters"] = state["counters"] + jnp.stack(
-            [n_t, n_evicted])
+            [n_t, got["evicted"], blocks])
     return out, new_state
 
 
@@ -647,42 +705,58 @@ def _lower_reduce(op: Reduce, node: Node, state, ins) -> Tuple[DeviceDelta, dict
         new_emitted = jnp.where(ins_b, agg, emitted)
         new_has = jnp.where(ins_m, True, jnp.where(ret_m & ~exists, False, em_has))
     else:
-        # sparse mode: O(C) end to end, never O(K) — contributions
-        # scatter-add straight into the persistent tables (no zeros[K]
-        # staging table, no full-table add), and aggregation/emission
-        # runs only on the gathered touched rows. This is what makes
-        # small-edit streaming (config 2: 256-row edits into 2^20-key
-        # tables) cost per-edit work instead of per-vocabulary work.
-        contrib = _masked_contrib(d.weights, d.values).astype(jnp.float32)
-        wsum = state["wsum"].at[d.keys].add(
-            contrib.astype(state["wsum"].dtype))
-        wcnt = state["wcnt"].at[d.keys].add(d.weights)
-
+        # sparse mode: O(live rows) end to end, never O(K) —
+        # contributions scatter-add straight into the persistent tables
+        # (no zeros[K] staging table, no full-table add), and
+        # aggregation/emission runs only on the gathered touched rows.
+        # This is what makes small-edit streaming (config 2: 256-row
+        # edits into 2^20-key tables) cost per-edit work instead of
+        # per-vocabulary work. The rows are sorted by key first, so the
+        # live ones are the prefix [0, n_live), and the tables are
+        # written a block of that prefix at a time (``_over_blocks``).
+        S = _block_slots(C)
         live = d.weights != 0
         skey = jnp.where(live, d.keys, K)
         order = jnp.argsort(skey)
-        sk = skey[order]
+        sk = skey[order].astype(jnp.int32)       # dead rows: K, dropped
+        n_live = jnp.sum(live.astype(jnp.int32))
         prev = jnp.concatenate([jnp.full((1,), -1, sk.dtype), sk[:-1]])
         first = (sk != prev) & (sk < K)
-        tk = jnp.where(sk < K, sk, 0).astype(jnp.int32)
+        contrib = _masked_contrib(d.weights, d.values).astype(
+            state["wsum"].dtype)
 
-        agg, exists = _agg_tables(op, wsum[tk], wcnt[tk], vdtype)
-        em = emitted[tk]
-        has = em_has[tk]
-        changed = _differs(agg, em, op.tol)
-        ins_m = first & exists & (~has | changed)
-        ret_m = first & has & (~exists | changed)
+        def add(lo, c):
+            wsum, wcnt = c
+            at, rows = _block(sk, lo, S), _block(order, lo, S)
+            return (wsum.at[at].add(contrib[rows], mode="drop"),
+                    wcnt.at[at].add(d.weights[rows], mode="drop"))
+
+        # a key's rows may straddle two blocks: every block's adds land
+        # before any block's aggregate is read
+        (wsum, wcnt), _ = _over_blocks(
+            C, n_live, add, (state["wsum"], state["wcnt"]))
+
+        keys = jnp.where(sk < K, sk, 0)
+
+        def emit(lo, c):
+            emitted, em_has, rows = c
+            tk, head = _block(keys, lo, S), _block(first, lo, S)
+            agg, exists = _agg_tables(op, wsum[tk], wcnt[tk], vdtype)
+            em, has = emitted[tk], em_has[tk]
+            changed = _differs(agg, em, op.tol)
+            return _emit_block(emitted, em_has, rows, lo, tk, em, agg,
+                               exists, head & exists & (~has | changed),
+                               head & has & (~exists | changed))
+
+        (new_emitted, new_has, (em, agg, ret_m, ins_m)), _ = _over_blocks(
+            C, n_live, emit,
+            (emitted, em_has, _no_rows(C, emitted.shape[1:], vdtype)))
         out = DeviceDelta(
-            keys=jnp.concatenate([tk, tk]),
+            keys=jnp.concatenate([keys, keys]),
             values=jnp.concatenate([em, agg]),
             weights=jnp.concatenate(
                 [-ret_m.astype(jnp.int32), ins_m.astype(jnp.int32)]),
         )
-        set_ins = jnp.where(ins_m, tk, K)
-        new_emitted = emitted.at[set_ins].set(agg, mode="drop")
-        new_has = em_has.at[set_ins].set(True, mode="drop")
-        set_ret = jnp.where(ret_m & ~exists, tk, K)
-        new_has = new_has.at[set_ret].set(False, mode="drop")
 
     new_state = {"wsum": wsum, "wcnt": wcnt,
                  "emitted": new_emitted, "emitted_has": new_has}
@@ -1031,12 +1105,14 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: rebuilds only to make room: ``join_reindex``), and the trips of the
 #: probe's
 #: chain walk (each a pass over its pair slots). A min/max reduce: keys
-#: a tick's delta touched, and distinct value rows pushed out of a
-#: candidate buffer. Only nodes whose state has the leaf count.
+#: a tick's delta touched, distinct value rows pushed out of a
+#: candidate buffer, and blocks of ``_block_slots(C)`` slots its keyed
+#: tables were written by (``_over_blocks``: slots written = blocks x
+#: that). Only nodes whose state has the leaf count.
 OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
                "join": ("pairs", "late_pairs", "arena_rows",
                         "index_rebuilds", "compactions", "probe_steps"),
-               "reduce": ("touched", "evicted")}
+               "reduce": ("touched", "evicted", "blocks")}
 
 
 def knn_state(op, q_spec: Spec, d_spec: Spec) -> dict:

@@ -651,20 +651,23 @@ def test_a_loop_region_keeps_the_dense_join():
     assert len(ex._indexed_joins) == 2
 
 
+@pytest.mark.parametrize("K", [512, 4096], ids=["dense", "sparse"])
 @pytest.mark.parametrize("keys_touched", [20, 300])
 @pytest.mark.parametrize("how", ["min", "max"])
-def test_minmax_few_and_many_touched_keys(how, keys_touched):
-    """The buffered min / max merges an eighth of the slots when a
-    delta touches few keys and all of them when it touches many: both
-    against the CPU oracle, with retractions, int32 values past 2^24."""
-    K, C, ticks = 512, 512, 5
+def test_minmax_few_and_many_touched_keys(how, keys_touched, K):
+    """The buffered min / max merges, writes and (where the key space
+    is wider than the delta) emits an eighth of the slots at a time, as
+    often as the touched keys need — once when a delta touches few keys,
+    five times of the eight when it touches many: both against the CPU
+    oracle, with retractions, int32 values past 2^24."""
+    C, ticks = 512, 5
     g = FlowGraph("mm")
     src = g.source("s", Spec((2,), np.int32, key_space=K))
     red = g.reduce(src, how, candidates=4, name="m")
     sink = g.sink(red, "out")
     rng = np.random.default_rng(keys_touched)
     scheds = [DirtyScheduler(g, get_executor(e)) for e in ("cpu", "tpu")]
-    held = []
+    held, before = [], {"touched": 0, "blocks": 0}
     for t in range(ticks):
         n = C - 40
         keys = rng.integers(0, keys_touched, n)
@@ -680,13 +683,18 @@ def test_minmax_few_and_many_touched_keys(how, keys_touched):
             sc.push(src, DeltaBatch(keys.astype(np.int64),
                                     vals.astype(np.int32), w))
             sc.tick()
+        now = scheds[1].executor.op_counters()["m"]
+        # a tick's rows fill the 512-slot bucket: blocks of 64 slots
+        assert (now["blocks"] - before["blocks"]
+                == -(-(now["touched"] - before["touched"]) // 64))
+        before = now
     want = {k: tuple(int(x) for x in v)
             for k, v in scheds[0].view_dict(sink).items()}
     got = {k: tuple(int(x) for x in v)
            for k, v in scheds[1].view_dict(sink).items()}
     assert got == want and len(want) == keys_touched
-    counters = scheds[1].executor.op_counters()["m"]
-    assert counters["touched"] >= keys_touched
+    assert before["touched"] >= keys_touched
+    assert (before["blocks"] == ticks) == (keys_touched <= 64)
 
 
 # -- the control ---------------------------------------------------------------

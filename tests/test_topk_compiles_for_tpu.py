@@ -1,4 +1,5 @@
-"""The top-k kernels compiled for the chip, without the chip: the TPU's
+"""The top-k kernels, and a reduce's block writes into its keyed tables,
+compiled for the chip, without the chip: the TPU's
 compiler is installed here and compiles for a v5e that is described and
 not attached, so what Mosaic would refuse on the chip (a misaligned
 slice, too much VMEM, an operand it cannot place) fails here, at the
@@ -88,3 +89,51 @@ def test_rescan_program_on_the_chip_has_no_id_block(one_chip, kernels):
                  f"[{Q},8320]"):
         assert gone not in text
     assert not re.search(r"\bgather\(", text)
+
+
+@pytest.mark.parametrize("how,vshape,dtype,kw", [
+    ("max", (2,), "int32", {"candidates": 16}), ("sum", (3,), "float32", {})])
+def test_reduce_tables_ride_the_block_loop_in_place(one_chip, how, vshape,
+                                                    dtype, kw):
+    """A reduce over the NEXmark cell's 2^23 keys (a 1 024-slot delta
+    here: the maximum's sort takes a minute to compile at the cell's
+    8 192) as the v5e compiler leaves it: every table is donated and
+    aliased, none is copied on its way through the ``while`` loop
+    (``copy-start`` is the compiler's own prefetch into near memory),
+    and every scatter into one takes an eighth of the delta's slots."""
+    import jax
+    import jax.numpy as jnp
+
+    from reflow_tpu.delta import Spec
+    from reflow_tpu.executors import lowerings as lw
+    from reflow_tpu.executors.device_delta import DeviceDelta
+    from reflow_tpu.graph import FlowGraph
+
+    keys, cap = 1 << 23, 1024
+    g = FlowGraph("blocks")
+    node = g.reduce(g.source("s", Spec(vshape, dtype, key_space=keys)),
+                    how, name="r", **kw)
+    state = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: lw.reduce_state(
+            node.op, node.inputs[0].spec, node.spec)))
+    delta = DeviceDelta(_shape(one_chip, (cap,), jnp.int32),
+                        _shape(one_chip, (cap,) + vshape, dtype),
+                        _shape(one_chip, (cap,), jnp.int32))
+    comp = jax.jit(lambda s, d: lw._lower_reduce(node.op, node, s, [d]),
+                   donate_argnums=0).lower(state, delta).compile()
+    mem = comp.memory_analysis()
+    tables = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= 0.999 * tables
+    assert mem.temp_size_in_bytes < 16 << 20
+    text = comp.as_text()
+    assert re.search(r" while\(", text)
+    assert not re.search(r"= \S+\[%d[,\]]\S* copy\(" % keys, text)
+    updates = set()
+    for args in re.findall(r"= \S+\[%d[,\]]\S* scatter\(([^)]*)\)" % keys,
+                           text):
+        upd = re.findall(r"%[\w.\-]+", args)[-1]
+        shape = re.search(r"^\s*(?:ROOT )?%s = \w+\[(\d+)" % re.escape(upd),
+                          text, re.M)
+        updates.add(int(shape.group(1)))
+    assert updates == {lw._block_slots(cap)}
