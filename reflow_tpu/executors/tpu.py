@@ -39,6 +39,7 @@ from reflow_tpu.executors.lowerings import (DEVICE_REDUCERS, join_reindex,
                                             join_state, lower_node,
                                             reduce_state)
 from reflow_tpu.graph import FlowGraph, GraphError, Node
+from reflow_tpu.obs import threads as _threads
 from reflow_tpu.obs import trace as _trace
 from reflow_tpu.utils.config import env_int
 from reflow_tpu.utils.runtime import named_lock
@@ -121,8 +122,14 @@ class _DeviceWatch:
     ``[max(launch returned, previous window done), done]`` — so the
     program itself says when the device finished a window. The token is
     an output of the window program, never a second dispatch, and never
-    an array a later dispatch may donate. With tracing off the executor
-    builds none of this."""
+    an array a later dispatch may donate. Off the pump's path as it is,
+    it is also where the process's thread ledger is read
+    (``obs/threads.py``): one instant event ``thread_ledger`` on track
+    ``proc`` after a window's span, at most every ``LEDGER_EVERY_S``.
+    With tracing off the executor builds none of this."""
+
+    #: seconds between two ``thread_ledger`` events, at least
+    LEDGER_EVERY_S = 0.5
 
     def __init__(self, executor: "TpuExecutor"):
         self._ex = executor
@@ -146,7 +153,7 @@ class _DeviceWatch:
 
     def _run(self) -> None:
         ex = self._ex
-        prev_done = 0.0
+        prev_done = ledger_at = 0.0
         while True:
             item = self._q.get()
             if item is None:
@@ -183,6 +190,14 @@ class _DeviceWatch:
                     name: list(c.values()) for name, c in counters.items()}
             _trace.evt("window_device", start, done - start,
                        track=self.track, args=args)
+            if done - ledger_at >= self.LEDGER_EVERY_S:
+                ledger_at = done
+                read = _threads.ledger()
+                # what the read itself took rides along: the
+                # instrument's cost is in the trace it writes
+                read["read_s"] = time.perf_counter() - done
+                _trace.evt("thread_ledger", done, 0.0, track="proc",
+                           args=read)
             self.seen += 1
 
     def drain(self, timeout_s: float = 60.0) -> None:

@@ -28,7 +28,7 @@ Quickstart::
     obs.export_chrome_trace("trace.json")   # open in ui.perfetto.dev
 """
 
-from . import export, registry, trace  # noqa: F401
+from . import export, registry, threads, trace  # noqa: F401
 from .export import chrome_events, export_chrome_trace, ticket_timelines
 from .registry import (REGISTRY, SNAPSHOT_SCHEMA, Counter, Gauge,
                        MetricsRegistry, SnapshotEmitter)

@@ -333,6 +333,14 @@ class IngestFrontend:
                 "was uploaded from, not another device batch")
         t0 = time.perf_counter()
         deadline = None if timeout is None else t0 + timeout
+        # a ticket this frontend may choose to follow and that brings no
+        # token mints one from the epoch, which is the WAL's to say,
+        # under the WAL's lock; the committer resolves blocks (frontend
+        # lock) under that lock, so the epoch is read before the
+        # frontend lock is taken, never under it
+        epoch = (getattr(self.sched, "epoch", 0)
+                 if _trace.ENABLED and cause is None and sampled is not False
+                 else 0)
         with self._lock:
             t_lock = time.perf_counter() if _trace.ENABLED else t0
             self._crash_point("producer_submit")
@@ -353,8 +361,7 @@ class IngestFrontend:
                                                    sampled, cause)
                 if ticket.trace.sampled and ticket.trace.cause is None:
                     from reflow_tpu.obs.wire import node_id
-                    ticket.trace.cause = _trace.mint_cause(
-                        node_id(), getattr(self.sched, "epoch", 0))
+                    ticket.trace.cause = _trace.mint_cause(node_id(), epoch)
                 if ticket.trace.sampled:
                     # inside ``admission``: how long this producer stood
                     # at the frontend lock before admission could begin
@@ -681,14 +688,18 @@ class IngestFrontend:
         ``summarize_serve().to_dict()`` schema) as an obs metric source
         — live snapshots and offline summaries stay one schema.
         Unregistered automatically at :meth:`close`. Returns the source
-        key (``serve.<name>``)."""
-        from reflow_tpu.obs import REGISTRY
+        key (``serve.<name>``). Beside it goes the process's thread
+        ledger, source ``proc.threads`` (``obs/threads.py``): one key
+        however many frontends publish, and not this frontend's to take
+        away at its close."""
+        from reflow_tpu.obs import REGISTRY, threads
         from reflow_tpu.utils.metrics import summarize_serve
         reg = registry if registry is not None else REGISTRY
         key = f"serve.{self.name or 'frontend'}"
         reg.register_source(key,
                             lambda: summarize_serve(self).to_dict())
         self._metric_keys.append((reg, key))
+        threads.publish(reg)
         return key
 
     def _seal(self) -> None:

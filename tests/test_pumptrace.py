@@ -183,10 +183,53 @@ def test_pump_spans_tile_and_share_window_ids(tmp_path, traced):
     blocks = [a for nm, *_, a in spans if nm == "resolve_block"]
     assert all(a["where"] in ("committer", "pump")
                and 0 <= a["inflight"] <= fe.depth for a in blocks)
-    assert 1 <= fe.blocks_resolved_before_retire <= n
+    # whether a block resolves before its window retires is a race
+    # this drive does not decide (the next test holds the retire)
+    assert 0 <= fe.blocks_resolved_before_retire <= n
     from reflow_tpu.utils.metrics import summarize_serve
     assert summarize_serve(fe).to_dict()[
         "blocks_resolved_before_retire"] == fe.blocks_resolved_before_retire
+
+
+def test_block_resolves_before_a_held_retire(tmp_path, traced):
+    """The count that does not wait for the retire, made certain: the
+    lone window's retire is held until its block has resolved (at its
+    durability point, on whichever thread got there), so the block
+    finds its window dispatched and unretired."""
+    g, s = _loop_free()
+    sched = DurableScheduler(g, get_executor("tpu"),
+                             wal_dir=str(tmp_path / "wal"),
+                             fsync="tick", committer="thread")
+    fe = IngestFrontend(sched, depth=2, window=CoalesceWindow(
+        max_rows=6, max_ticks=2, max_latency_s=0.002))
+    retire = sched.retire_staged
+    held = []
+
+    def held_retire(handle):
+        deadline = time.monotonic() + 30
+        while fe.applied < 2 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        held.append(fe.applied)
+        return retire(handle)
+
+    sched.retire_staged = held_retire
+    fe.pause()
+    tickets = [fe.submit(s, b) for b in _batches(8, 2)]
+    fe.resume()
+    for t in tickets:
+        assert t.result(timeout=60).applied
+    fe.flush(timeout=60)
+    spans = _spans()
+    fe.close()
+    assert held == [2] and fe.windows_staged == 1
+    assert fe.blocks_resolved_before_retire == 1
+    (blk,) = [a for nm, *_, a in spans if nm == "resolve_block"]
+    assert blk["win"] == 1 and blk["tickets"] == 2
+    (ret,) = [(t0, t1) for nm, _, t0, t1, _ in spans
+              if nm == "window_retire"]
+    (res,) = [(t0, t1) for nm, _, t0, t1, _ in spans
+              if nm == "resolve_block"]
+    assert res[0] <= ret[1]
 
 
 @pytest.mark.parametrize("durable", [True, False])
@@ -292,14 +335,37 @@ def test_window_device_200_windows_touch_no_donated_array(traced):
 
 # -- (c) tracing off -----------------------------------------------------------
 
-def test_tracing_off_builds_nothing():
+def test_tracing_off_builds_nothing(monkeypatch):
     obs.disable()
     trace_mod.reset()
+    # every per-thread CPU clock read on the served path sits behind
+    # ``trace.ENABLED``: the pump's, the committer's, and (PR 39) the
+    # two a request costs an RPC handler
+    cpu_reads = []
+    real_thread_time = time.thread_time
+    monkeypatch.setattr(time, "thread_time", lambda: (
+        cpu_reads.append(threading.current_thread().name),
+        real_thread_time())[1])
     g, s = _loop_free()
     sched = DirtyScheduler(g, get_executor("tpu"))
     key = sched.publish_metrics(name="pumptrace-off")
     fe = _drive_pipelined(sched, s, _batches(4, 12), max_rows=6, k=2)
     assert fe.windows_staged >= 6
+    # the same frontend behind the ingest server: submits and the
+    # tickets' resolve long-polls through a handler thread
+    lt = LoopbackTransport()
+    srv = RpcIngestServer(fe, lt).start()
+    prod = RemoteProducer(lt, srv.address, name="p-off")
+    try:
+        for t in [prod.submit(s, b) for b in _batches(9, 6)]:
+            assert t.result(timeout=30).applied
+        assert srv.submits_total == 6 < srv.requests_total
+        assert any(t.name.startswith("rpc-serve/")
+                   for t in threading.enumerate())
+    finally:
+        prod.close()
+        srv.close()
+    assert cpu_reads == []
     assert not any(t.name.startswith("reflow-device-watch")
                    for t in threading.enumerate())
     assert sched.executor._watch is None
@@ -308,11 +374,42 @@ def test_tracing_off_builds_nothing():
     progs = [k for k in sched.executor._cache if k[0] == "pass_many"]
     assert progs and not any(k[-1] == "token" for k in progs)
     assert fe._clk is None
+    # no ring, so no ``thread_ledger``, no ``rpc_ops``, no ``rpc_serve``
     assert trace_mod._rings == [] and obs.chrome_events() == []
     snap = REGISTRY.snapshot()["gauges"]
     assert snap[f"{key}.device_busy_s"] == 0.0
     assert snap[f"{key}.windows_done"] == 0
     assert snap[f"{key}.megatick_windows"] == fe.windows_staged
+    fe.close()
+
+
+def test_submit_reads_the_epoch_outside_the_frontend_lock(tmp_path, traced):
+    """A followed ticket that brings no token mints one from the WAL's
+    epoch, read under the WAL's lock — which the committer holds while
+    it resolves a block under the frontend lock. So ``submit`` must not
+    hold the frontend lock while it asks (ROADMAP D0b: it did, and this
+    file's durable, in-process, traced drives stood still one run in
+    four under load)."""
+    g, s = _loop_free()
+    sched = DurableScheduler(g, get_executor("tpu"),
+                             wal_dir=str(tmp_path / "wal"),
+                             fsync="tick", committer="thread")
+    fe = IngestFrontend(sched, depth=2, window=CoalesceWindow(
+        max_rows=6, max_ticks=2, max_latency_s=0.002))
+    got = []
+    with sched.wal._lock:           # as the committer holds it
+        t = threading.Thread(
+            target=lambda: got.append(fe.submit(s, _batches(10, 1)[0])))
+        t.start()
+        time.sleep(0.2)
+        # the submit stands at the WAL's lock for the epoch, and the
+        # frontend lock is free for the committer meanwhile
+        assert t.is_alive() and not got
+        assert fe._lock.acquire(timeout=10)
+        fe._lock.release()
+    t.join(timeout=30)
+    assert got[0].result(timeout=60).applied
+    assert got[0].trace.cause.split("#")[1] == str(sched.epoch)
     fe.close()
 
 
