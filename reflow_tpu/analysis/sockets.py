@@ -14,11 +14,13 @@ rule machine-checks it:
   method (schedulers, clients) don't trip it.
 
 The check is per enclosing function on purpose: that is the unit in
-which a deadline discipline is visible to a reader, and the transport
-code re-arms ``settimeout`` before every blocking call precisely so
-each function is self-evidently bounded. Genuinely-blocking intent
-(rare, e.g. a tool that wants to wait forever) takes the standard
-waiver with a reason.
+which a deadline discipline is visible to a reader, and the listener
+re-arms ``settimeout`` before every ``accept`` precisely so each
+function is self-evidently bounded. A connection's socket is
+non-blocking and waits in one ``poll`` under the call's deadline
+(``_TcpConn._recv_into``): its ``recv_into`` cannot block and carries
+the waiver that says so. Genuinely-blocking intent (rare, e.g. a tool
+that wants to wait forever) takes the standard waiver with a reason.
 """
 
 from __future__ import annotations

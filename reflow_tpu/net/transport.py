@@ -26,6 +26,7 @@ transport-agnostic.
 
 from __future__ import annotations
 
+import select
 import socket
 import threading
 import time
@@ -43,6 +44,9 @@ __all__ = ["Conn", "Listener", "Transport", "LoopbackTransport",
            "TcpTransport", "default_io_timeout_s"]
 
 _HDR = len(MAGIC) + HEADER.size
+#: a TCP connection's receive buffer: a frame up to this long is parsed
+#: out of it, a longer one is received into a buffer of its own
+_RBUF = 1 << 16
 
 
 def default_io_timeout_s() -> float:
@@ -62,6 +66,14 @@ class Conn:
     #: payload was checked and unpickled. The ingest server's
     #: ``rpc_serve`` span starts there. None while tracing is off.
     last_rx: Optional[Tuple[float, int, float]] = None
+
+    #: system calls this end has made on its socket (``poll``,
+    #: ``recv_into``, ``send``) and the frames it has received and sent.
+    #: Plain ints, counted with tracing on or off; a loopback end makes
+    #: no system call and counts nothing
+    sock_calls = 0
+    frames_in = 0
+    frames_out = 0
 
     def _decode(self, hdr: bytes, payload: bytes) -> Any:
         """``decode_frame`` that, under tracing, stamps ``last_rx``."""
@@ -268,6 +280,23 @@ class LoopbackTransport(Transport):
 # -- TCP --------------------------------------------------------------------
 
 class _TcpConn(Conn):
+    """A framed connection over one socket, read through a buffer.
+
+    What a request costs its thread is system calls: each gives the
+    interpreter lock up, and beside computing threads the lock is a
+    millisecond away. So the socket is non-blocking from the start and
+    its timeout is never set again; a wait is one ``poll`` under the
+    call's own deadline. ``recv_msg`` takes whatever has arrived with
+    one ``recv_into`` — a small frame's header and payload together —
+    and parses the frame out of the connection's buffer; bytes past the
+    frame stay for the next call, which then makes no socket call at
+    all. A frame that the buffer cannot hold (its header says so) is
+    received straight into a buffer of its own length. ``send_raw``
+    writes a frame that fits the socket's buffer with one ``send`` and
+    waits, under its deadline, only for what did not fit. A request
+    that arrives whole and a reply that fits: ``poll``, ``recv_into``,
+    ``send``."""
+
     def __init__(self, sock: socket.socket) -> None:
         self._sock = sock
         self._closed = False
@@ -275,7 +304,13 @@ class _TcpConn(Conn):
         # request-response so this never contends in steady state
         self._send_lock = named_lock("net.tcp.send")
         self._recv_lock = named_lock("net.tcp.recv")
-        self._sock.settimeout(default_io_timeout_s())
+        self._sock.settimeout(0.0)
+        self._readable = select.poll()
+        self._readable.register(sock, select.POLLIN)
+        #: unread bytes are ``_rbuf[_rpos:_rend]``
+        self._rbuf = bytearray(_RBUF)
+        self._rview = memoryview(self._rbuf)
+        self._rpos = self._rend = 0
 
     def send_msg(self, obj: Any, timeout_s: Optional[float] = None) -> int:
         return self.send_raw(encode_frame(obj), timeout_s)
@@ -286,49 +321,121 @@ class _TcpConn(Conn):
             if self._closed:
                 raise TransportError("send on a closed TCP connection")
             try:
-                self._sock.settimeout(
-                    default_io_timeout_s() if timeout_s is None
-                    else timeout_s)
-                self._sock.sendall(data)
+                self.sock_calls += 1
+                try:
+                    sent = self._sock.send(data)
+                except BlockingIOError:
+                    sent = 0
+                if sent < len(data):
+                    self._send_rest(
+                        memoryview(data)[sent:],
+                        default_io_timeout_s() if timeout_s is None
+                        else timeout_s)
             except (OSError, ValueError) as e:
                 raise TransportError(f"TCP send failed: {e}") from e
+            self.frames_out += 1
         return len(data)
 
-    def _read_exact(self, n: int, deadline: float,
-                    idle_ok: bool = False) -> bytes:
-        buf = bytearray()
-        while len(buf) < n:
+    def _send_rest(self, rest: memoryview, timeout_s: float) -> None:
+        """What one ``send`` did not take: wait for room, under the
+        call's deadline, and send on."""
+        deadline = time.monotonic() + timeout_s
+        writable = select.poll()
+        writable.register(self._sock, select.POLLOUT)
+        while rest:
             left = deadline - time.monotonic()
-            if left <= 0:
-                raise TransportError("recv timed out mid-frame")
+            self.sock_calls += 1
+            if not writable.poll(1e3 * max(left, 0.0)):
+                raise TransportError("TCP send failed: timed out")
+            self.sock_calls += 1
             try:
-                self._sock.settimeout(left)
-                chunk = self._sock.recv(n - len(buf))
-            except socket.timeout as e:
-                # a timeout before ANY byte of the frame arrived leaves
-                # the stream synced (idle); one mid-frame does not
-                if idle_ok and not buf:
-                    raise WireTimeout(f"recv timed out: {e}") from e
-                raise TransportError(
-                    f"recv timed out mid-frame: {e}") from e
-            except OSError as e:
+                rest = rest[self._sock.send(rest):]
+            except BlockingIOError:
+                pass
+
+    def _recv_into(self, into: memoryview, deadline: float,
+                   idle: bool) -> int:
+        """One or more bytes of the stream into ``into``; how many.
+        ``idle``: no byte of the frame has arrived yet, so the next one
+        is waited for first and a timeout leaves the stream in sync.
+        Inside a frame the rest is as a rule there already and is taken
+        first; only then is it waited for."""
+        wait = idle
+        while True:
+            if wait:
+                left = deadline - time.monotonic()
+                self.sock_calls += 1
+                try:
+                    ready = self._readable.poll(1e3 * max(left, 0.0))
+                except (OSError, ValueError) as e:
+                    raise TransportError(f"TCP recv failed: {e}") from e
+                if self._closed:
+                    raise TransportError(
+                        "recv on a closed TCP connection")
+                if not ready:
+                    # a timeout before ANY byte of the frame arrived
+                    # leaves the stream synced (idle); one mid-frame
+                    # does not
+                    if idle:
+                        raise WireTimeout("recv timed out")
+                    raise TransportError("recv timed out mid-frame")
+            wait = True
+            self.sock_calls += 1
+            try:
+                # reflow-lint: waive socket-no-timeout -- the socket is non-blocking: the wait is the poll above, under the call's deadline
+                n = self._sock.recv_into(into)
+            except BlockingIOError:
+                continue
+            except (OSError, ValueError) as e:
                 raise TransportError(f"TCP recv failed: {e}") from e
-            if not chunk:
+            if not n:
                 raise TransportError("connection closed by peer")
-            buf += chunk
-        return bytes(buf)
+            return n
 
     def recv_msg(self, timeout_s: Optional[float] = None) -> Any:
         timeout_s = default_io_timeout_s() if timeout_s is None \
             else timeout_s
+        view = self._rview
         with self._recv_lock:
             if self._closed:
                 raise TransportError("recv on a closed TCP connection")
             deadline = time.monotonic() + timeout_s
-            hdr = self._read_exact(_HDR, deadline, idle_ok=True)
-            length = frame_size(hdr)  # FrameError propagates: reset
-            payload = self._read_exact(length, deadline)
-        return self._decode(hdr, payload)
+            while True:
+                pos, have = self._rpos, self._rend - self._rpos
+                if have >= _HDR:
+                    hdr = bytes(view[pos:pos + _HDR])
+                    length = frame_size(hdr)  # FrameError propagates: reset
+                    if have >= _HDR + length:
+                        payload = view[pos + _HDR:pos + _HDR + length]
+                        self._rpos += _HDR + length
+                        break
+                    if _HDR + length > len(view):
+                        payload = self._recv_long(length, deadline)
+                        break
+                if pos:
+                    # the start of a frame behind one already returned:
+                    # to the front, where the whole of it has room
+                    self._rbuf[:have] = self._rbuf[pos:self._rend]
+                    self._rpos, self._rend = 0, have
+                self._rend += self._recv_into(view[self._rend:], deadline,
+                                              idle=not have)
+            if self._rpos == self._rend:
+                self._rpos = self._rend = 0
+            self.frames_in += 1
+            # decoded under the lock: the payload may lie in the buffer
+            # the next ``recv_msg`` writes to
+            return self._decode(hdr, payload)
+
+    def _recv_long(self, length: int, deadline: float) -> memoryview:
+        """The payload of a frame the buffer cannot hold, whose header
+        (and perhaps more) the buffer has: into a buffer of its own."""
+        payload = memoryview(bytearray(length))
+        got = self._rend - self._rpos - _HDR
+        payload[:got] = self._rview[self._rpos + _HDR:self._rend]
+        self._rpos = self._rend = 0
+        while got < length:
+            got += self._recv_into(payload[got:], deadline, idle=False)
+        return payload
 
     def close(self) -> None:
         self._closed = True

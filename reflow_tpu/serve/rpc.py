@@ -11,7 +11,7 @@ Wire protocol (pickled tuples over ``net/framing.py``)::
 
     ("hello", producer, in_doubt_ids) -> ("ok", {graph, epoch, tick,
                                                  admitted})
-    ("submit",) + SubmitReq           -> ("ack",) + SubmitAck
+    ("submit",) + SubmitReq           -> ("ack",) + SubmitAck [+ fates]
     ("resolve",) + TicketResolve      -> ("ok", {batch_id: SubmitAck})
     ("ping",)                         -> ("ok", {graph, tick, lsn,
                                                  state})
@@ -29,6 +29,23 @@ outcome observable — ``RemoteProducer.last_hello["admitted"]`` — and
 lets tests pin the invariant; it is never required for safety, which
 rests on the mirror alone.
 
+Fates ride the replies the link already sends. A handler remembers, a
+connection, the ids that link submitted and that were acked
+``"pending"``; a request that says its client takes them
+(``SubmitReq.takes_fates``, which ``RemoteProducer`` always sets) has,
+after its own ack, the acks of those decided since the link's last
+reply (``SubmitAck.fates``: each what a ``resolve`` would have said, in
+the order they were submitted). A producer that keeps submitting so
+learns its fates with no request of their own; ``resolve`` is for the
+link that has gone quiet (a drain, a paced producer between submits),
+for failover and for evicted tickets. A fate is reported once, by
+whichever reply takes it first, and no earlier than a ``resolve`` would
+report it: the ticket is decided, its window's WAL records durable. A
+reply lost with its link leaves its tickets in doubt like any lost ack:
+resubmit, DEDUPED against the mirror, one fold. Both fields are
+trailing, defaulted and trimmed when unset: a request in the older form
+gets the older reply byte for byte.
+
 Ticket identity does NOT survive the server's ticket-table bound
 (``REFLOW_RPC_TICKETS``): an evicted in-flight ticket resolves as
 ``"unknown"`` and the producer resubmits — again safe by dedup. A
@@ -40,7 +57,7 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 from reflow_tpu.net.backoff import ReconnectPolicy
@@ -71,15 +88,17 @@ class SubmitReq(NamedTuple):
     the server adopts it instead of re-rolling, so every process
     records the same 1-in-N writes (only a connection that has never
     carried one, an untraced producer's, gets the server's own
-    1-in-N). Trailing + defaulted and trimmed
-    when None (:func:`_trim`) so untraced requests stay byte-identical
-    to the pre-trace wire protocol."""
+    1-in-N). ``takes_fates``: the client reads ``SubmitAck.fates``.
+    Both trailing + defaulted and trimmed when None (:func:`_trim`) so
+    an untraced request that takes none stays byte-identical to the
+    pre-trace wire protocol."""
 
     batch_id: str
     source: str                    # source/loop node name on the graph
     payload: Any                   # host DeltaBatch (picklable)
     timeout_s: Optional[float] = None
     cause: Optional[str] = None
+    takes_fates: Optional[bool] = None
 
 
 class SubmitAck(NamedTuple):
@@ -90,6 +109,9 @@ class SubmitAck(NamedTuple):
     pump crashed mid-admission; resubmit after backoff) or
     ``"unknown"`` (server holds no ticket for this id; resubmit).
     ``result`` carries the :class:`TicketResult` fields when terminal.
+    ``fates``, on a submit's own ack alone and only for a request with
+    ``takes_fates``: the trimmed acks of the link's earlier submits
+    decided since its last reply (module header).
     """
 
     batch_id: str
@@ -97,6 +119,7 @@ class SubmitAck(NamedTuple):
     result: Optional[tuple] = None
     reason: Optional[str] = None
     cause: Optional[str] = None    # echo of the request token (traced)
+    fates: Optional[tuple] = None
 
 
 class TicketResolve(NamedTuple):
@@ -108,12 +131,15 @@ class TicketResolve(NamedTuple):
 
 
 def _trim(fields: tuple) -> tuple:
-    """Drop exactly one trailing None before a frame hits the wire —
-    the ``Shipment`` compat pattern (net/client.py): an unstamped
-    request/ack pickles byte-identically to the pre-``cause`` protocol,
-    while the receiving NamedTuple's default fills the gap."""
-    if fields and fields[-1] is None:
-        fields = fields[:-1]
+    """Drop the two trailing fields of a request or an ack where they
+    are None (``cause``; ``takes_fates`` / ``fates``) before a frame
+    hits the wire — the ``Shipment`` compat pattern (net/client.py): an
+    unstamped request/ack that carries no fates pickles byte-identically
+    to the pre-``cause`` protocol, while the receiving NamedTuple's
+    defaults fill the gap."""
+    for _ in range(2):
+        if fields and fields[-1] is None:
+            fields = fields[:-1]
     return fields
 
 
@@ -136,6 +162,31 @@ def _frontend_of(handle):
     """Accept an ``IngestFrontend`` or anything carrying one (a
     ``GraphHandle`` from the serve tier exposes ``.frontend``)."""
     return getattr(handle, "frontend", handle)
+
+
+class _Link:
+    """What a handler thread keeps of its connection: the ids it acked
+    ``"pending"`` for a client that takes fates, oldest first (ids, not
+    tickets: the server's table stays a ticket's one holder, and an id
+    whose ticket has left it — evicted, or reported by a ``resolve`` —
+    is simply dropped when its turn comes), and how many fates went out
+    on acks and by ``resolve``. Plain ints, counted with tracing on or
+    off."""
+
+    __slots__ = ("conn", "undecided", "fates_on_ack", "fates_by_resolve")
+
+    def __init__(self, conn: Optional[Conn], bound: int) -> None:
+        self.conn = conn
+        self.undecided: "deque[str]" = deque(maxlen=bound)
+        self.fates_on_ack = 0
+        self.fates_by_resolve = 0
+
+    def counters(self) -> dict:
+        conn = self.conn
+        return {"sock_calls": getattr(conn, "sock_calls", 0),
+                "frames_in": getattr(conn, "frames_in", 0),
+                "fates_on_ack": self.fates_on_ack,
+                "fates_by_resolve": self.fates_by_resolve}
 
 
 class RpcIngestServer:
@@ -166,13 +217,17 @@ class RpcIngestServer:
         #: per handler thread (one a connection): the sampled ticket,
         #: if any, that the request being served produced (``ctx``, for
         #: the ``rpc_serve`` span), whether the peer has ever sent a
-        #: causality token (``peer_samples``), and under tracing the
-        #: thread's cost by operation (``ops``, ``_trace_begin``)
+        #: causality token (``peer_samples``), the connection's
+        #: :class:`_Link` (``link``), and under tracing the thread's
+        #: cost by operation (``ops``, ``_trace_begin``)
         self._served = threading.local()
         self.connections_total = 0
         self.requests_total = 0
         self.submits_total = 0
         self.evicted_tickets = 0
+        #: every connection's :class:`_Link`, each written by its own
+        #: handler thread alone (the totals below sum them)
+        self._links: list = []
 
     # the frontend is re-read per request: a tier ``rebind()`` revives
     # the same frontend object in place, and a ``GraphHandle`` always
@@ -180,6 +235,17 @@ class RpcIngestServer:
     @property
     def frontend(self):
         return _frontend_of(self.handle)
+
+    @property
+    def fates_on_ack_total(self) -> int:
+        """Fates of tickets acked ``"pending"`` that a later submit's
+        ack on the same link took."""
+        return sum(link.fates_on_ack for link in self._links)
+
+    @property
+    def fates_by_resolve_total(self) -> int:
+        """And those a ``resolve`` took."""
+        return sum(link.fates_by_resolve for link in self._links)
 
     @property
     def address(self):
@@ -218,7 +284,18 @@ class RpcIngestServer:
                 self._handlers.append(t)
             t.start()
 
+    def _link(self, conn: Optional[Conn] = None) -> _Link:
+        """The calling handler thread's :class:`_Link` (made on first
+        use: a test may call ``_dispatch`` with no connection)."""
+        link = getattr(self._served, "link", None)
+        if link is None:
+            link = self._served.link = _Link(conn, self.max_tickets)
+            with self._lock:
+                self._links.append(link)
+        return link
+
     def _serve_conn(self, conn: Conn) -> None:
+        self._link(conn)
         try:
             while not self._stop.is_set():
                 try:
@@ -354,7 +431,39 @@ class RpcIngestServer:
             # decided only now, from the ticket: an unsampled request
             # records nothing on this server
             self._served.ctx = (ctx, req.cause)
-        return self._ack_of(ticket)
+        if not req.takes_fates:
+            return self._ack_of(ticket)
+        # the link's decided tickets leave the table before this one
+        # takes its place there: at the bound a reported fate makes the
+        # room, where an eviction would cost a resubmission
+        link = self._link()
+        fates = self._link_fates(link)
+        ack = self._ack_of(ticket)
+        if ack.state == "pending":
+            link.undecided.append(ack.batch_id)
+        return ack._replace(fates=fates) if fates else ack
+
+    def _link_fates(self, link: _Link) -> tuple:
+        """The trimmed acks of the link's earlier submits that have
+        been decided since its last reply. A link's tickets are decided
+        in the order they were admitted, so its list is read from the
+        head up to the first undecided ticket: a submit pays for the
+        fates it carries, not for what is in flight."""
+        ids = link.undecided
+        if not ids:
+            return ()
+        decided = []
+        with self._lock:
+            while ids:
+                t = self._tickets.get(ids[0])
+                if t is not None:
+                    if not t.done():
+                        break
+                    decided.append(t)
+                ids.popleft()
+        fates = tuple(_trim(tuple(self._ack_of(t))) for t in decided)
+        link.fates_on_ack += len(fates)
+        return fates
 
     def _trace_begin(self, msg) -> Optional[float]:
         """Under tracing, before a request is dispatched: find its row
@@ -434,13 +543,15 @@ class RpcIngestServer:
 
     def _trace_ops(self, t: float) -> None:
         """One ``rpc_ops`` event: the calling handler thread's table as
-        it stands. ``since`` is when the table began: a handler born
-        after a reader's first look counts from zero."""
+        it stands, and beside it (``link``, no row of ``ops``) its
+        connection's counters. ``since`` is when both began: a handler
+        born after a reader's first look counts from zero."""
         served = self._served
         served.ops_at = t
         _trace.evt("rpc_ops", t, 0.0, args={
             "since": served.ops_since,
-            "ops": {k: list(v) for k, v in served.ops.items()}})
+            "ops": {k: list(v) for k, v in served.ops.items()},
+            "link": self._link().counters()})
 
     def _ack_of(self, ticket) -> SubmitAck:
         cause = _ticket_cause(ticket)
@@ -494,15 +605,18 @@ class RpcIngestServer:
             # them all (another may have resolved meanwhile)
             pending[0]._event.wait(min(remaining, _POLL_S))
         out = {}
+        fates = 0
         for bid, t in tickets.items():
             if t is None:
                 ack = SubmitAck(bid, "unknown",
                                 reason="no ticket on this server; resubmit")
             elif t.done():
                 ack = self._ack_of(t)
+                fates += 1
             else:
                 ack = SubmitAck(bid, "pending", cause=_ticket_cause(t))
             out[bid] = _trim(tuple(ack))
+        self._link().fates_by_resolve += fates
         return out
 
     def close(self) -> None:
@@ -611,6 +725,10 @@ class RemoteProducer:
         self.reconnects_total = 0
         self.link_failures = 0
         self.deduped_total = 0
+        #: tickets decided by a later submit's ack, and ``resolve``
+        #: round trips made
+        self.fates_on_ack_total = 0
+        self.resolves_total = 0
 
     @property
     def conn_state(self) -> str:
@@ -784,7 +902,7 @@ class RemoteProducer:
         ticket.submits += 1
         ticket.link_gen = self._gen
         req = SubmitReq(ticket.batch_id, ticket.source, ticket.payload,
-                        ticket.timeout_s, ticket.cause)
+                        ticket.timeout_s, ticket.cause, True)
         self.submits_total += 1
         t0 = time.perf_counter()
         resp = self._roundtrip(("submit",) + _trim(tuple(req)),
@@ -801,7 +919,14 @@ class RemoteProducer:
                              "submits": ticket.submits,
                              "ok": resp is not None})
         if isinstance(resp, tuple) and resp and resp[0] == "ack":
-            self._apply_ack(ticket, SubmitAck(*resp[1:]))
+            ack = SubmitAck(*resp[1:])
+            self._apply_ack(ticket, ack)
+            # the fates of earlier submits that rode this ack
+            for fields in ack.fates or ():
+                t = self._pending.get(fields[0])
+                if t is not None:
+                    self._apply_ack(t, SubmitAck(*fields))
+                    self.fates_on_ack_total += 1
         elif isinstance(resp, tuple) and resp and resp[0] == "err":
             # a protocol rejection (unknown source, malformed batch) is
             # deterministic — retrying the same request cannot succeed,
@@ -829,7 +954,8 @@ class RemoteProducer:
             # touch, then resubmit against the (revived or promoted)
             # frontend on a later pump
             ticket.link_gen = -1
-        # "pending": nothing to do — resolve polls will decide it
+        # "pending": nothing to do — a later submit's ack or a resolve
+        # poll will decide it
 
     def _pump(self, wait_s: float) -> None:
         """One client pump: ensure the link, (re)submit anything the
@@ -845,6 +971,7 @@ class RemoteProducer:
             ids = tuple(self._pending)
             if not ids:
                 return
+            self.resolves_total += 1
             resp = self._roundtrip(
                 ("resolve",) + tuple(TicketResolve(ids, wait_s)))
             if not (isinstance(resp, tuple) and len(resp) == 2

@@ -14,6 +14,8 @@ sleeps.
 import importlib.util
 import json
 import os
+import socket
+import threading
 import time
 
 import numpy as np
@@ -26,6 +28,7 @@ from reflow_tpu.net import (FaultyTransport, LoopbackTransport,
 from reflow_tpu.net.framing import (HEADER, MAGIC, FrameError,
                                     decode_frame, encode_frame,
                                     frame_size, split_frames)
+from reflow_tpu.net.transport import _RBUF, _TcpConn
 from reflow_tpu.obs import REGISTRY
 from reflow_tpu.serve import (FailoverCoordinator, ReadTier,
                               ReplicaScheduler)
@@ -682,3 +685,247 @@ def test_remote_follower_receive_speaks_ack_nack(tmp_path):
     srv.close()
     sched.close()
     replica.close()
+
+
+# -- the TCP connection's buffer: what a frame costs in socket calls ---------
+
+class CountingSock:
+    """A real socket that counts the calls ``_TcpConn`` makes on it."""
+
+    def __init__(self, sock):
+        self._sock = sock
+        self.calls = {}
+
+    def __getattr__(self, name):
+        fn = getattr(self._sock, name)
+        if name == "fileno" or not callable(fn):
+            return fn
+
+        def counted(*a, **kw):
+            self.calls[name] = self.calls.get(name, 0) + 1
+            return fn(*a, **kw)
+        return counted
+
+
+def tcp_pair():
+    """(connection under test over a counting socket, the raw peer
+    socket, the counting socket). The counts start after set-up."""
+    a, b = socket.socketpair()
+    sock = CountingSock(a)
+    conn = _TcpConn(sock)
+    sock.calls.clear()
+    return conn, b, sock
+
+
+def recv_exact(sock, n):
+    buf = bytearray()
+    sock.settimeout(5.0)
+    while len(buf) < n:
+        chunk = sock.recv(n - len(buf))
+        assert chunk
+        buf += chunk
+    return bytes(buf)
+
+
+def test_whole_small_frame_is_one_recv_into_and_no_settimeout():
+    conn, peer, sock = tcp_pair()
+    try:
+        for i in range(3):      # the timeout asked for does not change
+            peer.sendall(encode_frame(("req", i)))
+            calls = conn.sock_calls
+            assert conn.recv_msg(0.2) == ("req", i)
+            # one wait and one read: header and payload came together
+            assert conn.sock_calls - calls == 2
+        assert sock.calls == {"recv_into": 3}
+        # and under another timeout the socket's own is still not set
+        peer.sendall(encode_frame(("req", 3)))
+        assert conn.recv_msg(5.0) == ("req", 3)
+        assert "settimeout" not in sock.calls
+        # a reply that fits the socket's buffer is one send
+        calls = conn.sock_calls
+        conn.send_msg(("ok",), 5.0)
+        assert conn.sock_calls - calls == 1
+        assert sock.calls == {"recv_into": 4, "send": 1}
+        assert (conn.frames_in, conn.frames_out) == (4, 1)
+    finally:
+        conn.close()
+        peer.close()
+
+
+def test_second_frame_of_a_segment_costs_no_socket_call():
+    conn, peer, sock = tcp_pair()
+    try:
+        third = encode_frame(("c", b"z" * 100))
+        peer.sendall(encode_frame(("a", 1)) + encode_frame(("b", 2))
+                     + third[:20])
+        assert conn.recv_msg(0.2) == ("a", 1)
+        calls = conn.sock_calls
+        assert conn.recv_msg(0.2) == ("b", 2)
+        assert conn.sock_calls == calls and sock.calls == {"recv_into": 1}
+        # the cut third one is finished from the socket
+        peer.sendall(third[20:])
+        assert conn.recv_msg(0.2) == ("c", b"z" * 100)
+        assert conn.frames_in == 3
+    finally:
+        conn.close()
+        peer.close()
+
+
+_CUT_MSG = ("cut", b"\x5a" * 40, 7)
+_CUTS = sorted({1, len(MAGIC) - 1, len(MAGIC), len(MAGIC) + 1,
+                len(MAGIC) + HEADER.size - 1, len(MAGIC) + HEADER.size,
+                len(MAGIC) + HEADER.size + 1,
+                len(encode_frame(_CUT_MSG)) - 1})
+
+
+@pytest.mark.parametrize("cut", _CUTS)
+def test_frame_cut_at_a_boundary_decodes_equal(cut):
+    conn, peer, _sock = tcp_pair()
+    try:
+        raw = encode_frame(_CUT_MSG)
+        peer.sendall(raw[:cut])
+        t = threading.Timer(0.05, peer.sendall, (raw[cut:] + raw,))
+        t.start()
+        assert conn.recv_msg(2.0) == _CUT_MSG
+        assert conn.recv_msg(2.0) == _CUT_MSG     # and the stream is in sync
+        t.join()
+    finally:
+        conn.close()
+        peer.close()
+
+
+def test_long_frame_is_received_into_a_buffer_of_its_own():
+    conn, peer, sock = tcp_pair()
+    try:
+        big = ("payload", bytes(range(256)) * 4096)     # 1 MB
+        raw = encode_frame(big) + encode_frame(("next",))
+        t = threading.Thread(target=peer.sendall, args=(raw,))
+        t.start()
+        assert conn.recv_msg(5.0) == big
+        assert conn.recv_msg(5.0) == ("next",)
+        t.join()
+        # nothing was appended or copied through the connection's buffer
+        assert len(conn._rbuf) == _RBUF < len(raw)
+        assert set(sock.calls) == {"recv_into"}
+        # and the other way: a frame the socket's buffer cannot take in
+        # one send goes out whole, under the call's deadline
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(recv_exact(peer, len(raw))))
+        t.start()
+        conn.send_raw(raw, 5.0)
+        t.join()
+        assert got == [raw]
+    finally:
+        conn.close()
+        peer.close()
+
+
+def test_idle_is_wire_timeout_and_a_stall_inside_a_frame_is_not():
+    conn, peer, _sock = tcp_pair()
+    try:
+        with pytest.raises(WireTimeout):
+            conn.recv_msg(0.05)
+        # idle left the stream in sync: the next frame still reads
+        raw = encode_frame(("late", 1))
+        peer.sendall(raw)
+        assert conn.recv_msg(1.0) == ("late", 1)
+        # some bytes of a frame, then nothing past the deadline
+        peer.sendall(raw[:len(MAGIC) + 3])
+        t0 = time.monotonic()
+        with pytest.raises(TransportError) as e:
+            conn.recv_msg(0.05)
+        assert not isinstance(e.value, WireTimeout)
+        assert time.monotonic() - t0 < 2.0
+        # a long frame that stalls in its payload is no idle link either
+        conn2, peer2, _ = tcp_pair()
+        long = encode_frame(("big", b"q" * (2 * _RBUF)))
+        peer2.sendall(long[:_RBUF])
+        with pytest.raises(TransportError) as e:
+            conn2.recv_msg(0.05)
+        assert not isinstance(e.value, WireTimeout)
+        conn2.close()
+        peer2.close()
+    finally:
+        conn.close()
+        peer.close()
+
+
+def test_close_from_another_thread_ends_a_blocked_recv():
+    conn, peer, _sock = tcp_pair()
+    try:
+        t = threading.Timer(0.05, conn.close)
+        t.start()
+        t0 = time.monotonic()
+        with pytest.raises(TransportError) as e:
+            conn.recv_msg(10.0)
+        assert not isinstance(e.value, WireTimeout)
+        assert time.monotonic() - t0 < 5.0
+        t.join()
+        with pytest.raises(TransportError):
+            conn.send_msg(("x",), 1.0)
+        # the peer going away ends one too
+        conn2, peer2, _ = tcp_pair()
+        t = threading.Timer(0.05, peer2.close)
+        t.start()
+        with pytest.raises(TransportError, match="closed by peer"):
+            conn2.recv_msg(10.0)
+        t.join()
+        conn2.close()
+    finally:
+        conn.close()
+        peer.close()
+
+
+def test_bad_magic_length_and_crc_are_frame_errors_over_tcp():
+    good = encode_frame(("hello", 1))
+    hdr = len(MAGIC) + HEADER.size
+    flipped = bytearray(good)
+    flipped[-1] ^= 0x01
+    for raw in (b"XXNOPE00" + good[len(MAGIC):],
+                MAGIC + HEADER.pack((64 << 20) + 1, 0) + good[hdr:],
+                bytes(flipped)):
+        conn, peer, _sock = tcp_pair()
+        try:
+            peer.sendall(raw)
+            with pytest.raises(FrameError):
+                conn.recv_msg(1.0)
+        finally:
+            conn.close()
+            peer.close()
+
+
+def test_corrupt_frame_over_tcp_resets_connection_then_recovers(tmp_path):
+    """``test_corrupt_frame_resets_connection_then_recovers`` on real
+    sockets: ``send_raw`` is still the injector's seam, the mangled
+    frame fails the receiver's checks out of the buffer, the link
+    resets and the next one ships."""
+    faults = WireFaults()
+    sched, src, sink = make_leader(tmp_path)
+    replica = make_replica(tmp_path)
+    srv = ReplicaServer(replica, TcpTransport()).start()
+    link = RemoteFollower(FaultyTransport(TcpTransport(), faults),
+                          srv.address, name="r0",
+                          policy=fast_policy("r0"), io_timeout_s=0.5)
+    ship = SegmentShipper(sched.wal, leader_tick=lambda: sched._tick)
+    ship.attach(link)
+    try:
+        drive(sched, src, 2)
+        pump_until_caught(ship, sched, [replica])
+        faults.set_rates(corrupt_frame=1.0)
+        drive(sched, src, 2, start=2)
+        sched.wal.sync()
+        deadline = time.monotonic() + 10
+        while link.link_failures == 0 and time.monotonic() < deadline:
+            ship.pump_once()
+            time.sleep(0.002)
+        assert link.link_failures >= 1
+        faults.quiesce()
+        pump_until_caught(ship, sched, [replica])
+        assert srv.frame_resets >= 1
+        h, got = replica.view_at(sink.name)
+        assert h == sched._tick and got == live_view(sched, sink)
+    finally:
+        srv.close()
+        sched.close()
+        replica.close()
