@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-One process, one chip, three phases, every check fatal:
+One process, one chip, four phases, every check fatal:
 
 1. **The served device path, end to end** at the repo's flagship size
    (BASELINE config 3: incremental PageRank, 100 000 nodes / 1 000 000
@@ -27,6 +27,14 @@ One process, one chip, three phases, every check fatal:
 3. **The indexed join** (``executors/arena.py``) at the kernel level:
    skewed right rows over several ticks, then the left rows they waited
    for; its late pairs equal the dense sweep's and NumPy's.
+
+4. **The row fixpoint** (``executors/fixpoint.py``) at the size of the
+   benchmark's ``sssp-graph500`` cell: incremental SSSP over a Kronecker
+   graph, half loaded with the root in one tick (a fixpoint from
+   scratch), then a few insert batches each a one-tick window
+   (``tick_many``): every tick ``converged``, no sticky error, state
+   resident, distances equal to Bellman-Ford's to the bit, the program's
+   own counters say the same.
 
 The default invocation needs a TPU and never finishes on anything else.
 ``--tiny`` is the small CPU form tier-1 drives; it is reached only by
@@ -55,6 +63,7 @@ FULL = {
             "insert_rows": 8192, "retract_rows": 1024},
     "join": {"keys": 1 << 16, "arena": 1 << 20, "rows": 1 << 15,
              "ticks": 6, "left_rows": 2048},
+    "sssp": {"scale": 16, "edgefactor": 16, "ticks": 3},
 }
 TINY = {
     "nodes": 256, "edges": 2048, "churn": 0.01, "tol": 1e-4,
@@ -64,6 +73,7 @@ TINY = {
             "insert_rows": 256, "retract_rows": 64},
     "join": {"keys": 64, "arena": 4096, "rows": 256, "ticks": 4,
              "left_rows": 32},
+    "sssp": {"scale": 8, "edgefactor": 16, "ticks": 3},
 }
 
 #: generous wall bounds on each blocking wait, so a wedged pump or link
@@ -543,6 +553,102 @@ def join_phase(cfg: dict, dev) -> dict:
             "late_ticks_s": round(late_s, 4)}
 
 
+def _sssp_config():
+    """The benchmark configuration's module, for its Kronecker
+    generator: the one copy there is
+    (``benchmarks/configs/sssp-graph500.py``)."""
+    import importlib.util
+
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)       # the module's own ``common``
+    spec = importlib.util.spec_from_file_location(
+        "sssp_graph500", os.path.join(bench, "configs", "sssp-graph500.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def sssp_phase(cfg: dict, dev) -> dict:
+    """The row fixpoint (``FixpointProgram``: phase A, a ``while_loop``
+    over Join -> GroupBy -> min-Reduce, no exit pass) at the benchmark
+    cell's scale: half of a Kronecker graph and the root in one tick,
+    then ``ticks`` insert batches of 1 / 512 of the dataset, each a
+    one-tick fused window."""
+    import numpy as np
+
+    from reflow_tpu import DirtyScheduler
+    from reflow_tpu.executors import get_executor
+    from reflow_tpu.workloads import sssp
+
+    def both_ways(u, v, w):
+        return sssp.edge_batch(np.concatenate([u, v]),
+                               np.concatenate([v, u]),
+                               np.concatenate([w, w]))
+
+    c = cfg["sssp"]
+    n = 1 << c["scale"]
+    u, v, w = _sssp_config().kronecker(c["scale"], c["edgefactor"],
+                                       (0.57, 0.19, 0.19, 0.05), 7, 16)
+    half, batch = len(u) // 2, len(u) // 512
+    root = int(np.argmax(np.bincount(u[:half], minlength=n)
+                         + np.bincount(v[:half], minlength=n)))
+    sg = sssp.build_graph(n, arena_capacity=2 * len(u))
+    ex = get_executor("tpu")
+    sched = DirtyScheduler(sg.graph, ex)
+    t0 = time.perf_counter()
+    sched.push(sg.edges, both_ways(u[:half], v[:half], w[:half]))
+    sched.push(sg.seeds, sssp.seed_batch(root))
+    first = sched.tick()
+    load_s = time.perf_counter() - t0
+    require(first.quiesced, "sssp: the load tick did not quiesce")
+    require(ex.fixpoint_engine == "FixpointProgram",
+            f"sssp: engine {ex.fixpoint_engine!r}, want the row program")
+    passes, tick_s = [], []
+    for i in range(c["ticks"]):
+        lo, hi = half + i * batch, half + (i + 1) * batch
+        t0 = time.perf_counter()
+        r = sched.tick_many([{sg.edges: both_ways(u[lo:hi], v[lo:hi],
+                                                  w[lo:hi])}])
+        r.block()
+        tick_s.append(time.perf_counter() - t0)
+        require(bool(np.all(np.asarray(r.quiesced))),
+                f"sssp: window {i} did not converge")
+        passes.append(int(r.passes))
+    require(sched.megatick_windows == c["ticks"]
+            and sched.megatick_fallbacks == 0,
+            f"sssp: {sched.megatick_windows} fused windows, "
+            f"{sched.megatick_fallbacks} fallbacks")
+    ex.check_errors()
+    require_resident(ex.states, [dev], "sssp")
+    counters = ex.op_counters()
+    require(counters["dist"]["unquiesced"] == 0
+            and counters["dist"]["ticks"] == 1 + c["ticks"]
+            and counters["dist"]["passes"] == int(first.passes)
+            + sum(passes), f"sssp: loop counters {counters['dist']}")
+    hi = half + c["ticks"] * batch
+    want = sssp.reference_distances(
+        n, np.concatenate([u[:hi], v[:hi]]),
+        np.concatenate([v[:hi], u[:hi]]), np.concatenate([w[:hi], w[:hi]]),
+        root)
+    got = {int(k): float(x) for k, x in sched.read_table(sg.best).items()}
+    require(got == want,
+            f"sssp: {len(got)} served distances != Bellman-Ford's "
+            f"{len(want)}")
+    # the first window compiles its program; the later ones say what a
+    # pass costs with the host waiting on each
+    say(f"sssp scale {c['scale']}: {half} tuples + root in one tick, "
+        f"{int(first.passes)} passes, {load_s:.1f}s with its compile; "
+        f"{c['ticks']} windows of {batch} tuples: passes {passes}, "
+        f"{[round(t, 3) for t in tick_s]} s; {len(got)} of {n} vertices "
+        f"reached, distances == Bellman-Ford; counters {counters}")
+    return {"scale": c["scale"], "reached": len(got),
+            "load_passes": int(first.passes), "window_passes": passes,
+            "window_s": [round(t, 3) for t in tick_s],
+            "evicted": counters["best"]["evicted"]}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true",
@@ -567,6 +673,7 @@ def main(argv=None) -> int:
         pr = pagerank_phase(cfg, [dev], root)
         kn = knn_phase(cfg, dev)
         jn = join_phase(cfg, dev)
+        sp = sssp_phase(cfg, dev)
     finally:
         shutil.rmtree(root, ignore_errors=True)
 
@@ -574,7 +681,7 @@ def main(argv=None) -> int:
         "schema": "reflow.chip_smoke/1", "form": "tiny" if args.tiny
         else "full", "device": device,
         "compile_cache": {"dir": cache_dir, "entries_at_start": cached},
-        "pagerank": pr, "knn": kn, "join": jn,
+        "pagerank": pr, "knn": kn, "join": jn, "sssp": sp,
         "total_s": round(time.perf_counter() - t_start, 2),
         "claim": None,
     }), flush=True)

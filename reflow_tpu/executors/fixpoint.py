@@ -169,7 +169,7 @@ def _emitted_diff(snap: Tuple[jax.Array, jax.Array], state: dict,
     )
 
 
-def make_scan_program(tick_fn):
+def make_scan_program(tick_fn, token_fn=None):
     """K consecutive ticks fused into ONE device execution.
 
     ``lax.scan`` over the tick program with the K per-tick ingress
@@ -186,6 +186,10 @@ def make_scan_program(tick_fn):
     stack rides back out in (potentially) the same memory, so the
     persistent ingress queue can re-bind it (``run_window``) and keep
     slot-writing in place.
+
+    ``token_fn(states)``: the twin a traced dispatch builds also returns
+    its value, a small output nobody donates, for the device watcher
+    (``tpu._completion_token``: it carries the operators' counters).
     """
     import jax
 
@@ -198,7 +202,8 @@ def make_scan_program(tick_fn):
             return states2, (iters, rows, conv)
 
         states, ys = jax.lax.scan(body, op_states, ing_stack)
-        return states, ys, jax.tree.map(jnp.zeros_like, ing_stack)
+        out = (states, ys, jax.tree.map(jnp.zeros_like, ing_stack))
+        return out if token_fn is None else out + (token_fn(states),)
 
     return jax.jit(scan_fn, donate_argnums=(0, 1))
 
@@ -207,16 +212,19 @@ class _MacroTickMixin:
     """Shared macro-tick entry for the two fixpoint program kinds: both
     set ``self.tick_fn`` (the unjitted tick) in ``__init__``."""
 
-    def call_many(self, op_states, ing_stack, n_ticks: int):
-        """-> (states', (iters[K], rows[K], converged[K]), fresh_stack).
-        ``ing_stack`` is donated; ``fresh_stack`` is the zeroed
-        replacement the ingress queue re-binds."""
+    def call_many(self, op_states, ing_stack, n_ticks: int, token_fn=None):
+        """-> (states', (iters[K], rows[K], converged[K]), fresh_stack),
+        and last ``token_fn(states')`` where one is given (the traced
+        twin, ``make_scan_program``). ``ing_stack`` is donated;
+        ``fresh_stack`` is the zeroed replacement the ingress queue
+        re-binds."""
         cache = getattr(self, "_many_cache", None)
         if cache is None:
             cache = self._many_cache = {}
-        prog = cache.get(n_ticks)
+        key = (n_ticks, token_fn is not None)
+        prog = cache.get(key)
         if prog is None:
-            prog = cache[n_ticks] = make_scan_program(self.tick_fn)
+            prog = cache[key] = make_scan_program(self.tick_fn, token_fn)
         return prog(op_states, ing_stack)
 
 
@@ -263,9 +271,14 @@ class FixpointProgram(_MacroTickMixin):
         loops = structure.loops
         boundary = structure.boundary
         mi = max_iters
+        #: where this program counts (``lowerings.OP_COUNTERS["loop"]``):
+        #: the first loop's state, if the executor gave it the leaf
+        count_id = loops[0].id
+        n_fixed = 1 + (1 if exit_pass is not None else 0)
 
         def tick_fn(op_states, ingress):
-            states, eg_a = full_pass(op_states, ingress)
+            with jax.named_scope("fixpoint.phase_a"):
+                states, eg_a = full_pass(op_states, ingress)
             carry = {}
             for l in loops:
                 d = eg_a.get(l.id)
@@ -292,18 +305,30 @@ class FixpointProgram(_MacroTickMixin):
                 cr2 = {lid: eg[lid] for lid in cr}
                 return st2, cr2, it + 1, rows
 
-            states, carry, iters, rows = jax.lax.while_loop(
-                cond, body, (states, carry, jnp.zeros((), jnp.int32),
-                             jnp.zeros((), jnp.int32)))
+            with jax.named_scope("fixpoint.loop"):
+                states, carry, iters, rows = jax.lax.while_loop(
+                    cond, body, (states, carry, jnp.zeros((), jnp.int32),
+                                 jnp.zeros((), jnp.int32)))
             # converged iff the carry actually went dead (distinguishes
             # "quiesced on the last allowed iteration" from "exhausted")
             converged = live_rows(carry) == 0
 
             eg_b = {}
             if exit_pass is not None:
-                diffs = {n.id: _emitted_diff(snaps[n.id], states[n.id], n)
-                         for n in boundary}
-                states, eg_b = exit_pass(states, diffs)
+                with jax.named_scope("fixpoint.exit"):
+                    diffs = {n.id: _emitted_diff(snaps[n.id],
+                                                 states[n.id], n)
+                             for n in boundary}
+                    states, eg_b = exit_pass(states, diffs)
+
+            if "counters" in (states.get(count_id) or ()):
+                # cumulative, in the state: no output, no host sync
+                states = dict(states)
+                states[count_id] = dict(
+                    states[count_id],
+                    counters=states[count_id]["counters"] + jnp.stack(
+                        [n_fixed + iters, jnp.ones((), jnp.int32),
+                         1 - converged.astype(jnp.int32)]))
 
             sink_egress = {}
             for sid in self.sink_ids:

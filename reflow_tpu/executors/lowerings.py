@@ -76,10 +76,12 @@ def reduce_state(op: Reduce, in_spec: Spec, out_spec: Spec) -> dict:
 
 
 def join_state(op: Join, left_spec: Spec, right_spec: Spec,
-               indexed: bool = False) -> dict:
+               indexed: bool = False, counted: bool = False) -> dict:
     """``indexed``: a unique-left join of a loop-free graph keeps
     an arena index and its device counters (``arena.index_state``,
-    ``OP_COUNTERS``); the executor says which joins those are."""
+    ``OP_COUNTERS``); ``counted``: a unique-left join that sweeps its
+    arena (under a loop) keeps the counters alone. The executor says
+    which joins those are."""
     K = left_spec.key_space
     R = op.arena_capacity
     if not left_spec.unique:
@@ -107,9 +109,10 @@ def join_state(op: Join, left_spec: Spec, right_spec: Spec,
     from reflow_tpu.executors.arena import index_state
 
     extra = {}
-    if indexed:
-        extra = dict(index_state(K, R), counters=jnp.zeros(
-            (len(OP_COUNTERS["join"]),), jnp.int32))
+    if indexed or counted:
+        extra = dict(index_state(K, R) if indexed else {},
+                     counters=jnp.zeros((len(OP_COUNTERS["join"]),),
+                                        jnp.int32))
     return {
         **extra,
         "lval": jnp.zeros((K,) + tuple(left_spec.value_shape),
@@ -376,9 +379,54 @@ def minmax_state(op: Reduce, K: int, in_vshape, out_vshape, odtype,
     }
 
 
+def _merge_rows(C: int, K: int) -> int:
+    """Rows of a delta of capacity ``C`` over ``K`` keys that the
+    min/max merges when that many hold the tick's live rows: ``K``
+    (the fewest a dense merge, ``C >= K``, can take), in whole blocks,
+    where the delta has at least four times that; else ``C``."""
+    Cs = -(-K // 8) * 8
+    return Cs if C >= 4 * Cs else C
+
+
 def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
                 d: DeviceDelta, key_offset=0
                 ) -> Tuple[DeviceDelta, dict]:
+    """One tick of the buffered min/max (:func:`_minmax_merge`), over
+    the delta's live rows where they are few. A delta's capacity is its
+    producer's worst case: a join that sweeps its arena under a loop
+    hands over ``2 x arena_capacity`` slots a pass for a frontier's few
+    thousand rows, and the merge's sorts, gathers and scatters cost by
+    the slots, live or dead. So where the capacity is several times the
+    key space (``_merge_rows``) and the live rows fit, they are moved
+    to the front (one stable sort of the live mask: their order stays)
+    and a delta of ``_merge_rows`` slots is merged instead; a tick with
+    more live rows than that merges the whole delta. Both give the same
+    tables and the same rows out (the merge nets by key and value,
+    whatever the slots between), chosen on the device (``lax.cond``):
+    no budget, nothing dropped. A merge over the prefix counts its
+    ``blocks`` in slots of ``_block_slots(_merge_rows(C, K))``."""
+    C = d.capacity
+    Cs = _merge_rows(C, K)
+    if Cs == C:
+        return _minmax_merge(op, K, out_vshape, odtype, state, d,
+                             key_offset)
+
+    def over_prefix(st, dd):
+        with jax.named_scope("minmax.compact"):
+            front = jnp.argsort(dd.weights == 0, stable=True)[:Cs]
+            dd = DeviceDelta(dd.keys[front], dd.values[front],
+                             dd.weights[front])
+        return _minmax_merge(op, K, out_vshape, odtype, st, dd, key_offset)
+
+    def over_all(st, dd):
+        return _minmax_merge(op, K, out_vshape, odtype, st, dd, key_offset)
+
+    return jax.lax.cond(d.nonzero() <= Cs, over_prefix, over_all, state, d)
+
+
+def _minmax_merge(op: Reduce, K: int, out_vshape, odtype, state,
+                  d: DeviceDelta, key_offset=0
+                  ) -> Tuple[DeviceDelta, dict]:
     """One tick of the buffered min/max over a (per-shard) key range;
     ``d`` carries keys local to ``[0, K)``. Scalar and VECTOR values
     share this kernel: a candidate is a distinct value ROW [V], ordered
@@ -952,7 +1000,8 @@ def _join_core_indexed(op: Join, K: int, R: int, state,
     st["error"] = err
     st["counters"] = (state["counters"]
                       + jnp.stack([pairs + late, late, zero, zero, zero,
-                                   steps])).at[2].set(st["rcount"])
+                                   steps, zero, zero, zero])
+                      ).at[2].set(st["rcount"])
     out = DeviceDelta(
         jnp.concatenate([o.keys for o in outs]),
         jnp.concatenate([o.values for o in outs]),
@@ -1082,6 +1131,22 @@ def join_core(op: Join, K: int, R: int, odtype, state,
     )
     new_state = {"lval": lval, "lw": lw, "rkeys": rkeys, "rvals": rvals,
                  "rw": rw, "rcount": rcount, "gen": gen, "error": err}
+    if "counters" in state:
+        # a swept join counts what it did (``OP_COUNTERS``): whether a
+        # pass sweeps is static (``da`` is there or it is not), and a
+        # sweep passes over the arena's whole capacity twice, once for
+        # the retracted and once for the inserted left rows
+        n_live = [jnp.sum((o.weights != 0).astype(jnp.int32)) for o in outs]
+        zero = jnp.zeros((), jnp.int32)
+        late = sum(n_live[:2], zero) if da is not None else zero
+        left = (jnp.sum((da.weights != 0).astype(jnp.int32))
+                if da is not None else zero)
+        sweeps = 0 if da is None else 1
+        new_state["counters"] = (
+            state["counters"]
+            + jnp.stack([sum(n_live, zero), late, 0, 0, gen - state["gen"],
+                         0, sweeps, sweeps * 2 * R, left]).astype(jnp.int32)
+        ).at[2].set(rcount)
     return out, new_state
 
 
@@ -1108,11 +1173,34 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: a tick's delta touched, distinct value rows pushed out of a
 #: candidate buffer, and blocks of ``_block_slots(C)`` slots its keyed
 #: tables were written by (``_over_blocks``: slots written = blocks x
-#: that). Only nodes whose state has the leaf count.
+#: that; ``C`` is the merged delta's, ``_merge_rows`` where the merge
+#: ran over the live rows). Only nodes whose state has the leaf count.
+#:
+#: A unique-left join under a loop sweeps its arena and keeps no index
+#: (``join_state(counted=True)``): of the names above ``pairs`` (live
+#: rows it emitted, all three products), ``late_pairs`` (those of the
+#: sweep's two halves), ``arena_rows`` and ``compactions`` (in-program),
+#: and three of its own: ``sweeps``, the passes that swept the arena
+#: (the passes of a fixpoint in which the left side had a delta),
+#: ``swept_rows``, the arena slots those passes read: ``2 x
+#: arena_capacity`` a sweep, live or not (int32, wraps after 2^31 slots:
+#: a reader differences window by window, modulo 2^32), and
+#: ``left_rows``, the live rows of the left deltas folded into its
+#: table (retractions and inserts: under a loop, the frontier). An
+#: indexed join leaves the three at 0.
+#:
+#: ``"loop"`` is no operator: the row fixpoint program
+#: (``fixpoint.FixpointProgram``) counts in the state of its region's
+#: first loop node, where the executor gave it the leaf: ``passes`` as
+#: ``TickResult.passes`` has them (phase A, every trip of the
+#: ``while_loop``, the exit pass), ``ticks``, and ``unquiesced``, the
+#: ticks whose loop stopped at ``max_iters`` with its carry alive.
 OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
                "join": ("pairs", "late_pairs", "arena_rows",
-                        "index_rebuilds", "compactions", "probe_steps"),
-               "reduce": ("touched", "evicted", "blocks")}
+                        "index_rebuilds", "compactions", "probe_steps",
+                        "sweeps", "swept_rows", "left_rows"),
+               "reduce": ("touched", "evicted", "blocks"),
+               "loop": ("passes", "ticks", "unquiesced")}
 
 
 def knn_state(op, q_spec: Spec, d_spec: Spec) -> dict:

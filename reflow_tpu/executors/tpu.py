@@ -172,8 +172,11 @@ class _DeviceWatch:
             prev_done = done
             ex.device_busy_s += done - start
             ex.windows_done += 1
+            # an int32 vector carries the counters behind its first
+            # word; the fused linear fixpoint's token is its ``conv``
             counters = (ex._note_counters(np.asarray(token)[1:])
-                        if token.ndim else None)
+                        if token.ndim and token.dtype == np.int32
+                        else None)
             # the device may start while the launch call is still
             # returning (on a busy host that takes milliseconds), so
             # the span is a lower bound of the window's device time
@@ -223,10 +226,13 @@ class StagedWindow:
     window program, and everything :meth:`TpuExecutor.dispatch_window` /
     :meth:`TpuExecutor.retire_window` need to finish the lifecycle.
     ``fresh`` is filled by dispatch (the program's returned zeroed
-    pass-through stack) and consumed by retire."""
+    pass-through stack) and consumed by retire, as is ``done``: a
+    fixpoint window's per-tick ``converged`` flags, an output of its
+    program that nothing donates, which the retire waits for (None for
+    a loop-free window)."""
 
     __slots__ = ("plan", "caps", "K", "max_iters", "queue", "gen", "stack",
-                 "qsig", "fresh")
+                 "qsig", "fresh", "done")
 
     def __init__(self, plan, caps, K, max_iters, queue, gen, stack, qsig):
         self.plan = plan
@@ -238,6 +244,7 @@ class StagedWindow:
         self.stack = stack
         self.qsig = qsig
         self.fresh = None
+        self.done = None
 
 
 def _value_token(v):
@@ -419,13 +426,17 @@ class TpuExecutor(Executor):
 
     def _counting(self) -> List[Tuple[Node, Tuple[str, ...]]]:
         """The bound graph's nodes whose lowering keeps counters in its
-        device state (a ``counters`` leaf), each with their names."""
+        device state (a ``counters`` leaf), each with their names; a
+        loop node's are the row fixpoint program's."""
         from reflow_tpu.executors.lowerings import OP_COUNTERS
 
-        return [(n, OP_COUNTERS[n.op.kind])
-                for n in (self.graph.nodes if self.graph else ())
-                if n.kind == "op" and n.op.kind in OP_COUNTERS
-                and "counters" in (self.states.get(n.id) or ())]
+        out = []
+        for n in (self.graph.nodes if self.graph else ()):
+            kind = n.op.kind if n.kind == "op" else n.kind
+            if (kind in OP_COUNTERS
+                    and "counters" in (self.states.get(n.id) or ())):
+                out.append((n, OP_COUNTERS[kind]))
+        return out
 
     def counter_names(self) -> Dict[str, Tuple[str, ...]]:
         """Node name -> the counters that node keeps on the device."""
@@ -471,6 +482,25 @@ class TpuExecutor(Executor):
 
     # -- bind: validate lowerability, build device state -------------------
 
+    def _row_fixpoint_loop(self, graph: FlowGraph) -> Optional[Node]:
+        """The loop node in whose state the row fixpoint program counts
+        (``OP_COUNTERS["loop"]``), or None: where the graph's ticks will
+        not run on ``FixpointProgram`` as far as the graph alone says
+        (no loop, fusion off, no on-device structure, or a region the
+        fused linear program takes), and on the sharded executor, which
+        keeps no counters."""
+        from reflow_tpu.executors.fixpoint import analyze
+        from reflow_tpu.executors.linear_fixpoint import analyze_linear
+
+        if not (self._index_joins and self.fixpoint and graph.loops):
+            return None
+        structure = analyze(graph)
+        if structure is None or (
+                self.linear_fixpoint
+                and analyze_linear(graph, structure) is not None):
+            return None
+        return structure.loops[0]
+
     def bind(self, graph: FlowGraph) -> None:
         # compiled passes close over graph nodes: rebinding the *same* graph
         # (fresh state, e.g. a full-recompute baseline) keeps the jit cache;
@@ -488,6 +518,12 @@ class TpuExecutor(Executor):
         self.graph = graph
         self.states = {}
         self._indexed_joins = set()
+        counted_loop = self._row_fixpoint_loop(graph)
+        if counted_loop is not None:
+            from reflow_tpu.executors.lowerings import OP_COUNTERS
+            import jax.numpy as jnp
+            self.states[counted_loop.id] = {"counters": jnp.zeros(
+                (len(OP_COUNTERS["loop"]),), jnp.int32)}
         for loop in graph.loops:
             if loop.defer_passes:
                 # cross-tick residual deferral: the loop carries its
@@ -503,8 +539,9 @@ class TpuExecutor(Executor):
                         f"{loop}: defer_passes needs key_space > 0")
                 P = int(np.prod(loop.spec.value_shape)) if \
                     loop.spec.value_shape else 1
-                self.states[loop.id] = {
-                    "resid": jnp.zeros((K, P + 1), jnp.float32)}
+                self.states[loop.id] = dict(
+                    self.states.get(loop.id, {}),
+                    resid=jnp.zeros((K, P + 1), jnp.float32))
         for node in graph.nodes:
             if node.kind != "op":
                 continue
@@ -571,11 +608,15 @@ class TpuExecutor(Executor):
                 # a unique-left join of a loop-free graph keeps an arena
                 # index: its δA product follows the delta. Under a loop
                 # the frontier is most of the key space and the dense
-                # sweep has no pair budget to overflow
-                indexed = (self._index_joins and in_specs[0].unique
-                           and not op.linear_left and not graph.loops)
-                self.states[node.id] = join_state(op, in_specs[0],
-                                                  in_specs[1], indexed)
+                # sweep has no pair budget to overflow; there it counts
+                # its sweeps instead (a declared-linear left belongs to
+                # the fused linear fixpoint, which carries no counters)
+                counted = (self._index_joins and in_specs[0].unique
+                           and not op.linear_left)
+                indexed = counted and not graph.loops
+                self.states[node.id] = join_state(
+                    op, in_specs[0], in_specs[1], indexed,
+                    counted and not indexed)
                 if indexed:
                     self._indexed_joins.add(node.id)
             else:
@@ -809,6 +850,8 @@ class TpuExecutor(Executor):
         out = self.dispatch_window(sw)
         if out is None:
             return None
+        # a serial caller holds the window's results and paces itself
+        sw.done = None
         self.retire_window(sw)
         return out
 
@@ -926,7 +969,24 @@ class TpuExecutor(Executor):
         fresh zeroed stack back to the ingress queue, re-asserting
         placement and freeing the generation for restaging. Off the
         critical path — a pipelined pump runs this after the NEXT window
-        is already in flight."""
+        is already in flight.
+
+        A fixpoint window is retired once the device has finished it
+        (``sw.done``). Every call of a window's lifecycle is
+        asynchronous, and a fixpoint window costs its passes, seconds
+        where a frontier is deep: a caller that retired such windows
+        without waiting would run ahead of the device by as many as the
+        runtime queues, its producers would block on admission past the
+        RPC's submit cap, and all that was admitted would be owed after
+        the traffic stops (PERF.md, PR 41). With the wait, a pump of
+        depth ``d`` bounds the fixpoint windows the device has not
+        finished to ``d``: at 2 one running and one queued behind it,
+        all the overlap there is to have. A loop-free window is retired
+        as it was dispatched, asynchronously (one rule for both: an
+        issue of its own, ROADMAP B10)."""
+        if sw.done is not None:
+            sw.done.block_until_ready()
+            sw.done = None
         sw.queue.retire(sw.gen, sw.fresh)
         sw.fresh = None
 
@@ -1059,15 +1119,25 @@ class TpuExecutor(Executor):
         tr = _trace.ENABLED
         t_d0 = time.perf_counter() if tr else 0.0
         c_d0 = time.thread_time() if tr else 0.0
+        # a traced dispatch of the row program over a graph whose
+        # operators count runs a twin that also outputs the loop-free
+        # windows' token, counters and all; otherwise ``conv`` is the
+        # token: a program output the scheduler only ever reads
+        # (TickResult.quiesced), never donates
+        from reflow_tpu.executors.fixpoint import FixpointProgram
+
+        counter_ids = (tuple(n.id for n, _ in self._counting())
+                       if tr and isinstance(prog, FixpointProgram) else ())
+        extra = ((lambda st: _completion_token(st, counter_ids),)
+                 if counter_ids else ())
         with _dispatch_notes(K, window, tr):
-            new_states, (iters, rows, conv), fresh = prog.call_many(
-                dict(self.states), stack, K)
+            new_states, (iters, rows, conv), fresh, *token = prog.call_many(
+                dict(self.states), stack, K, *extra)
         if staged is not None:
-            staged.fresh = fresh
+            staged.fresh, staged.done = fresh, conv
         if tr:
-            # ``conv`` is the token: a program output the scheduler only
-            # ever reads (TickResult.quiesced), never donates
-            self._dispatched(conv, t_d0, c_d0, K, kind)
+            self._dispatched(token[0] if token else conv, t_d0, c_d0, K,
+                             kind)
         self.states = new_states
         extra_dirty = set(st.region_ids) | {n.id for n in st.exit_plan}
         passes_base = K * (1 + (1 if st.exit_plan else 0))

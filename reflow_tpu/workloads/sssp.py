@@ -1,11 +1,27 @@
 """Incremental single-source shortest paths: iterative Join + min-Reduce.
 
-A sixth example workload beyond the five BASELINE configs — the min-plus
-analog of PageRank's sum-loop, and the graph shape that exercises the
-retraction-capable device min/max (executors/lowerings.py
-``minmax_core``) inside the on-device fixpoint: every distance
-improvement emits retract(old)/insert(new) through the min-Reduce, and
-edge churn retracts relaxation candidates outright.
+The min-plus analog of PageRank's sum-loop, and the graph shape that
+exercises the retraction-capable device min/max (executors/lowerings.py
+``minmax_core``) inside the general on-device fixpoint
+(executors/fixpoint.py ``FixpointProgram``): every distance improvement
+emits retract(old)/insert(new) through the min-Reduce, and edge churn
+retracts relaxation candidates outright.
+
+**Served deployment.** The benchmark's ``sssp-graph500`` configuration
+(``benchmarks/configs/sssp-graph500.py``) serves this graph as Graph500's
+kernel 3 over a Kronecker graph that grows: half of the dataset's edge
+list loaded, the other half streamed as insert batches through
+``DurableScheduler`` -> ``IngestFrontend`` -> ``RpcIngestServer``, one
+tick a window, each tick its own fixpoint. That traffic is
+**insert-only**, the contract under which every tick quiesces (below);
+deletions need ``sched.rederive`` (:func:`repair`), which is not on the
+served path. What a run counts on the device
+(``TpuExecutor.op_counters()``, gauges
+``sched.sssp.<node>.<counter>``; ``lowerings.OP_COUNTERS``): the
+fixpoint program's ``dist.passes`` / ``ticks`` / ``unquiesced``, the
+swept join's ``relax.sweeps`` / ``swept_rows`` / ``pairs`` /
+``left_rows``, the minimum's ``best.touched`` / ``evicted`` /
+``blocks``.
 
 Graph::
 

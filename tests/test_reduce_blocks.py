@@ -353,3 +353,61 @@ def test_the_block_form_lowers_to_loops_over_an_eighth_of_the_slots():
             r'"stablehlo.scatter"\(([^)]*)\).*?:\s*\((tensor<%dx[^>]*>), '
             r'tensor<(\d+)x' % K, text, re.S)
         assert into_table and {int(n) for _, _, n in into_table} == {S}
+
+
+# -- a delta many times its key space: the merge runs over its live rows ----
+
+KW, CW = 64, 8192                     # a swept join's output over few keys
+
+
+def _wide_ticks(rng, n_live):
+    """Three ticks of ``n_live`` live rows dealt over ``CW`` slots: a
+    third of the keys, values that repeat, half of a tick's rows taken
+    back by the next."""
+    prev = None
+    for _ in range(3):
+        keys = rng.integers(0, KW // 3, n_live)
+        vals = rng.integers(1, 40, n_live).astype(np.float32) / 8
+        w = rng.choice([1, 1, 2], n_live)
+        if prev is not None and n_live > 1:
+            h = n_live // 2
+            keys[:h], vals[:h], w[:h] = prev[0][:h], prev[1][:h], -prev[2][:h]
+        prev = (keys.copy(), vals.copy(), w.copy())
+        at = np.sort(rng.choice(CW, n_live, replace=False))
+        k = np.zeros(CW, np.int32)
+        v = np.zeros(CW, np.float32)
+        ww = np.zeros(CW, np.int32)
+        k[at], v[at], ww[at] = keys, vals, w
+        yield DeviceDelta(jnp.asarray(k), jnp.asarray(v), jnp.asarray(ww))
+
+
+@pytest.mark.parametrize("n_live", [0, 1, KW - 1, KW, KW + 1, 4 * KW, CW],
+                         ids=lambda n: f"rows{n}")
+@pytest.mark.parametrize("how", ["min", "max"])
+def test_minmax_over_the_live_prefix_equals_the_merge_of_the_whole_delta(
+        how, n_live):
+    """``minmax_core`` merges ``_merge_rows`` slots where the live rows
+    fit them and the whole delta where they do not: the same tables,
+    the same rows out, the same keys touched and rows evicted as the
+    merge of the whole delta, tick after tick, on either side of the
+    choice."""
+    assert lw._merge_rows(CW, KW) == KW and lw._merge_rows(2 * KW, KW) == 2 * KW
+    g = FlowGraph("wide")
+    src = g.source("s", Spec((), np.float32, key_space=KW))
+    node = g.reduce(src, how, name="r", candidates=4)
+    args = (node.op, KW, (), np.float32)
+    st = ref = lw.reduce_state(node.op, node.inputs[0].spec, node.spec)
+    core = jax.jit(lambda s, d: lw.minmax_core(*args, s, d))
+    whole = jax.jit(lambda s, d: lw._minmax_merge(*args, s, d))
+    for d in _wide_ticks(np.random.default_rng(n_live), n_live):
+        out, st = core(st, d)
+        rout, ref = whole(ref, d)
+        _same_leaves({k: v for k, v in st.items() if k != "counters"},
+                     {k: v for k, v in ref.items() if k != "counters"})
+        _same_live_rows(out, rout)
+        assert [int(x) for x in st["counters"][:2]] == [
+            int(x) for x in ref["counters"][:2]]
+    if n_live >= KW:
+        assert int(st["counters"][1]) > 0     # buffers of four overflowed
+    text = core.lower(st, d).as_text()
+    assert "stablehlo.case" in text or "stablehlo.if" in text
