@@ -379,49 +379,63 @@ def minmax_state(op: Reduce, K: int, in_vshape, out_vshape, odtype,
     }
 
 
-def _merge_rows(C: int, K: int) -> int:
-    """Rows of a delta of capacity ``C`` over ``K`` keys that the
-    min/max merges when that many hold the tick's live rows: ``K``
-    (the fewest a dense merge, ``C >= K``, can take), in whole blocks,
-    where the delta has at least four times that; else ``C``."""
+def _merge_rungs(C: int, K: int) -> Tuple[int, ...]:
+    """The delta sizes the min/max merges a delta of capacity ``C`` over
+    ``K`` keys by, ascending: ``K`` slots (the fewest a dense merge,
+    ``C >= K``, can take, in whole blocks), then four times the rung
+    before while that is under ``C``, then ``C``; ``C`` alone where the
+    delta has under four times ``K`` slots."""
     Cs = -(-K // 8) * 8
-    return Cs if C >= 4 * Cs else C
+    if C < 4 * Cs:
+        return (C,)
+    rungs = []
+    while Cs < C:
+        rungs.append(Cs)
+        Cs *= 4
+    return tuple(rungs) + (C,)
 
 
 def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
                 d: DeviceDelta, key_offset=0
                 ) -> Tuple[DeviceDelta, dict]:
     """One tick of the buffered min/max (:func:`_minmax_merge`), over
-    the delta's live rows where they are few. A delta's capacity is its
-    producer's worst case: a join that sweeps its arena under a loop
-    hands over ``2 x arena_capacity`` slots a pass for a frontier's few
-    thousand rows, and the merge's sorts, gathers and scatters cost by
-    the slots, live or dead. So where the capacity is several times the
-    key space (``_merge_rows``) and the live rows fit, they are moved
-    to the front (one stable sort of the live mask: their order stays)
-    and a delta of ``_merge_rows`` slots is merged instead; a tick with
-    more live rows than that merges the whole delta. Both give the same
-    tables and the same rows out (the merge nets by key and value,
-    whatever the slots between), chosen on the device (``lax.cond``):
-    no budget, nothing dropped. A merge over the prefix counts its
-    ``blocks`` in slots of ``_block_slots(_merge_rows(C, K))``."""
-    C = d.capacity
-    Cs = _merge_rows(C, K)
-    if Cs == C:
+    as many of the delta's slots as its live rows need. A delta's
+    capacity is its producer's worst case: a join that sweeps its arena
+    under a loop hands over ``2 x arena_capacity`` slots a pass for a
+    frontier's few thousand rows, a hub's fan-out for some hundred
+    thousand, and the merge's sorts, gathers and scatters cost by the
+    slots, live or dead. So where the capacity is several times the key
+    space (``_merge_rungs``) the live rows are moved to the front (one
+    stable sort of the live mask: their order stays) and the merge runs
+    over the smallest rung that holds them all, the whole delta as it
+    stands past the last. Every rung is the same merge over a longer
+    prefix of the same rows, so each gives the tables and the rows out
+    of the whole delta's merge (it nets by key and value, whatever the
+    slots between), chosen on the device (``lax.switch`` on the live
+    count): no budget, nothing dropped, and one pass's rows never split
+    (a retraction and the insert that rides with it meet in one merge).
+    A merge over a rung of ``r`` slots adds ``r`` to ``merged_slots``
+    and counts its ``blocks`` in slots of ``_block_slots(r)``."""
+    rungs = _merge_rungs(d.capacity, K)
+    if len(rungs) == 1:
         return _minmax_merge(op, K, out_vshape, odtype, state, d,
                              key_offset)
+    with jax.named_scope("minmax.compact"):
+        front = jnp.argsort(d.weights == 0, stable=True)
 
-    def over_prefix(st, dd):
-        with jax.named_scope("minmax.compact"):
-            front = jnp.argsort(dd.weights == 0, stable=True)[:Cs]
-            dd = DeviceDelta(dd.keys[front], dd.values[front],
-                             dd.weights[front])
-        return _minmax_merge(op, K, out_vshape, odtype, st, dd, key_offset)
+    def over(r):
+        def merge(st, dd, at):
+            if r < dd.capacity:
+                at = at[:r]
+                dd = DeviceDelta(dd.keys[at], dd.values[at], dd.weights[at])
+            return _minmax_merge(op, K, out_vshape, odtype, st, dd,
+                                 key_offset)
+        return merge
 
-    def over_all(st, dd):
-        return _minmax_merge(op, K, out_vshape, odtype, st, dd, key_offset)
-
-    return jax.lax.cond(d.nonzero() <= Cs, over_prefix, over_all, state, d)
+    n = d.nonzero()
+    return jax.lax.switch(
+        sum((n > r).astype(jnp.int32) for r in rungs[:-1]),
+        [over(r) for r in rungs], state, d, front)
 
 
 def _minmax_merge(op: Reduce, K: int, out_vshape, odtype, state,
@@ -660,7 +674,7 @@ def _minmax_merge(op: Reduce, K: int, out_vshape, odtype, state,
     new_state["error"] = error
     if "counters" in state:
         new_state["counters"] = state["counters"] + jnp.stack(
-            [n_t, got["evicted"], blocks])
+            [n_t, got["evicted"], blocks, jnp.asarray(C, jnp.int32)])
     return out, new_state
 
 
@@ -1171,10 +1185,16 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: probe's
 #: chain walk (each a pass over its pair slots). A min/max reduce: keys
 #: a tick's delta touched, distinct value rows pushed out of a
-#: candidate buffer, and blocks of ``_block_slots(C)`` slots its keyed
+#: candidate buffer, blocks of ``_block_slots(C)`` slots its keyed
 #: tables were written by (``_over_blocks``: slots written = blocks x
-#: that; ``C`` is the merged delta's, ``_merge_rows`` where the merge
-#: ran over the live rows). Only nodes whose state has the leaf count.
+#: that), and ``merged_slots``, the ``C`` slots of the delta each merge
+#: ran over (int32, wraps: a reader differences modulo 2^32, as
+#: ``swept_rows``'). ``C`` is the merged delta's: where the merge
+#: follows the live rows (``_merge_rungs``) the rung a tick took, so
+#: under a ladder ``merged_slots`` sums the rungs taken and ``blocks``
+#: counts trips of whichever rung ran, an eighth of that rung each: the
+#: slots written are ``blocks`` x one size only where every tick took
+#: the same rung. Only nodes whose state has the leaf count.
 #:
 #: A unique-left join under a loop sweeps its arena and keeps no index
 #: (``join_state(counted=True)``): of the names above ``pairs`` (live
@@ -1199,7 +1219,7 @@ OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
                "join": ("pairs", "late_pairs", "arena_rows",
                         "index_rebuilds", "compactions", "probe_steps",
                         "sweeps", "swept_rows", "left_rows"),
-               "reduce": ("touched", "evicted", "blocks"),
+               "reduce": ("touched", "evicted", "blocks", "merged_slots"),
                "loop": ("passes", "ticks", "unquiesced")}
 
 

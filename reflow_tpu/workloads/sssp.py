@@ -21,7 +21,7 @@ served path. What a run counts on the device
 fixpoint program's ``dist.passes`` / ``ticks`` / ``unquiesced``, the
 swept join's ``relax.sweeps`` / ``swept_rows`` / ``pairs`` /
 ``left_rows``, the minimum's ``best.touched`` / ``evicted`` /
-``blocks``.
+``blocks`` / ``merged_slots``.
 
 Graph::
 

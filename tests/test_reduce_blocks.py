@@ -8,6 +8,8 @@ block of ``C`` slots and against a plain NumPy model of the candidate
 buffers; every state leaf after every tick, every live output row, and
 the ``blocks`` counter. Small seeded sizes, CPU."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -36,14 +38,14 @@ def _reduce_node(how, vshape, dtype, **kw):
     return g.reduce(src, how, name="r", **kw)
 
 
-def _device(keys, vals, w, vshape, dtype):
-    """A delta of capacity ``C`` with the given rows dealt over its
+def _device(keys, vals, w, vshape, dtype, cap=C):
+    """A delta of capacity ``cap`` with the given rows dealt over its
     slots in order, dead rows between them."""
     n = len(keys)
-    at = np.sort(np.random.default_rng(n).choice(C, n, replace=False))
-    k = np.zeros(C, np.int32)
-    v = np.zeros((C,) + vshape, dtype)
-    ww = np.zeros(C, np.int32)
+    at = np.sort(np.random.default_rng(n).choice(cap, n, replace=False))
+    k = np.zeros(cap, np.int32)
+    v = np.zeros((cap,) + vshape, dtype)
+    ww = np.zeros(cap, np.int32)
     k[at], v[at], ww[at] = keys, vals, w
     return DeviceDelta(jnp.asarray(k), jnp.asarray(v), jnp.asarray(ww))
 
@@ -325,8 +327,9 @@ def test_minmax_by_blocks_equals_one_block_of_the_capacity_and_the_model(
         want_blocks += -(-n_t // S)
         assert [int(x) for x in st["counters"][:2]] == [
             int(x) for x in ref["counters"][:2]]
-    n_t_all, evicted, blocks = (int(x) for x in st["counters"])
+    n_t_all, evicted, blocks, merged = (int(x) for x in st["counters"])
     assert blocks == want_blocks and evicted == model.evicted
+    assert merged == 4 * C                    # no ladder where C < K
     assert int(ref["counters"][2]) == 4       # one block of C, every tick
     assert n_t_all == 4 * touched
     if 0 < touched <= S:
@@ -355,9 +358,32 @@ def test_the_block_form_lowers_to_loops_over_an_eighth_of_the_slots():
         assert into_table and {int(n) for _, _, n in into_table} == {S}
 
 
-# -- a delta many times its key space: the merge runs over its live rows ----
+# -- a delta many times its key space: the merge follows its live rows -----
 
 KW, CW = 64, 8192                     # a swept join's output over few keys
+RUNGS = (64, 256, 1024, 4096, CW)
+
+
+def test_the_rungs_are_the_key_space_times_four_up_to_the_capacity():
+    assert lw._merge_rungs(CW, KW) == RUNGS
+    # the sssp-graph500 cell's minimum, and the first capacity that
+    # gets a second size at all
+    assert lw._merge_rungs(1 << 22, 1 << 16) == (
+        1 << 16, 1 << 18, 1 << 20, 1 << 22)
+    assert lw._merge_rungs(4 * KW, KW) == (KW, 4 * KW)
+    assert lw._merge_rungs(5 * KW, KW - 3) == (KW, 4 * KW, 5 * KW)
+    # no ladder under four times the key space (nexmark-q3q4's maximum:
+    # 8 192 slots under 2^23 keys), nor where the merge is sparse
+    for c, k in ((4 * KW - 8, KW), (2 * KW, KW), (KW, KW), (8192, 1 << 23)):
+        assert lw._merge_rungs(c, k) == (c,)
+
+
+def _rung(n_live):
+    return next(r for r in RUNGS if n_live <= r)
+
+
+def _dealt(keys, vals, w, cap=CW):
+    return _device(keys, vals, w, (), np.float32, cap)
 
 
 def _wide_ticks(rng, n_live):
@@ -373,41 +399,152 @@ def _wide_ticks(rng, n_live):
             h = n_live // 2
             keys[:h], vals[:h], w[:h] = prev[0][:h], prev[1][:h], -prev[2][:h]
         prev = (keys.copy(), vals.copy(), w.copy())
-        at = np.sort(rng.choice(CW, n_live, replace=False))
-        k = np.zeros(CW, np.int32)
-        v = np.zeros(CW, np.float32)
-        ww = np.zeros(CW, np.int32)
-        k[at], v[at], ww[at] = keys, vals, w
-        yield DeviceDelta(jnp.asarray(k), jnp.asarray(v), jnp.asarray(ww))
+        yield _dealt(keys, vals, w)
 
 
-@pytest.mark.parametrize("n_live", [0, 1, KW - 1, KW, KW + 1, 4 * KW, CW],
-                         ids=lambda n: f"rows{n}")
+@functools.lru_cache(maxsize=None)
+def _wide_pair(how, k=KW):
+    """The minimum (maximum) of ``k`` keys: -> (fresh state,
+    ``minmax_core``, ``_minmax_merge`` over the whole delta), compiled
+    once a ``how`` and delta shape."""
+    g = FlowGraph("wide")
+    src = g.source("s", Spec((), np.float32, key_space=k))
+    node = g.reduce(src, how, name="r", candidates=4)
+    args = (node.op, k, (), np.float32)
+    return (lw.reduce_state(node.op, node.inputs[0].spec, node.spec),
+            jax.jit(lambda s, d: lw.minmax_core(*args, s, d)),
+            jax.jit(lambda s, d: lw._minmax_merge(*args, s, d)))
+
+
+def _same_but_counters(st, ref):
+    _same_leaves({k: v for k, v in st.items() if k != "counters"},
+                 {k: v for k, v in ref.items() if k != "counters"})
+    assert [int(x) for x in st["counters"][:2]] == [
+        int(x) for x in ref["counters"][:2]]
+
+
+@pytest.mark.parametrize(
+    "n_live", [0, 1] + [r + i for r in RUNGS[:-1] for i in (-1, 0, 1)]
+    + [4 * KW + 7, CW], ids=lambda n: f"rows{n}")
 @pytest.mark.parametrize("how", ["min", "max"])
 def test_minmax_over_the_live_prefix_equals_the_merge_of_the_whole_delta(
         how, n_live):
-    """``minmax_core`` merges ``_merge_rows`` slots where the live rows
-    fit them and the whole delta where they do not: the same tables,
-    the same rows out, the same keys touched and rows evicted as the
-    merge of the whole delta, tick after tick, on either side of the
-    choice."""
-    assert lw._merge_rows(CW, KW) == KW and lw._merge_rows(2 * KW, KW) == 2 * KW
-    g = FlowGraph("wide")
-    src = g.source("s", Spec((), np.float32, key_space=KW))
-    node = g.reduce(src, how, name="r", candidates=4)
-    args = (node.op, KW, (), np.float32)
-    st = ref = lw.reduce_state(node.op, node.inputs[0].spec, node.spec)
-    core = jax.jit(lambda s, d: lw.minmax_core(*args, s, d))
-    whole = jax.jit(lambda s, d: lw._minmax_merge(*args, s, d))
+    """``minmax_core`` merges the smallest rung of ``_merge_rungs`` that
+    holds the live rows, the whole delta past the last: the same tables,
+    the same rows out, the same ``error``, the same keys touched and
+    rows evicted as the merge of the whole delta, tick after tick, on,
+    one under and one over every rung; ``merged_slots`` says which rung
+    each tick took."""
+    st, core, whole = _wide_pair(how)
+    ref = st
+    rung = _rung(n_live)
+    S_r = lw._block_slots(rung)
+    want_blocks = 0
     for d in _wide_ticks(np.random.default_rng(n_live), n_live):
+        touched = -int(st["counters"][0])
         out, st = core(st, d)
         rout, ref = whole(ref, d)
-        _same_leaves({k: v for k, v in st.items() if k != "counters"},
-                     {k: v for k, v in ref.items() if k != "counters"})
+        _same_but_counters(st, ref)
         _same_live_rows(out, rout)
-        assert [int(x) for x in st["counters"][:2]] == [
-            int(x) for x in ref["counters"][:2]]
+        # trips of the rung's own loop, an eighth of the rung each (one
+        # block where the rung is under 256 slots)
+        touched += int(st["counters"][0])
+        want_blocks += 1 if S_r == rung else -(-touched // S_r)
+    assert out.weights.shape == rout.weights.shape == (2 * KW,)
     if n_live >= KW:
         assert int(st["counters"][1]) > 0     # buffers of four overflowed
-    text = core.lower(st, d).as_text()
-    assert "stablehlo.case" in text or "stablehlo.if" in text
+    assert [int(x) for x in st["counters"][2:]] == [want_blocks, 3 * rung]
+    assert int(ref["counters"][3]) == 3 * CW
+
+
+@pytest.mark.parametrize("edge", RUNGS[:-1], ids=lambda r: f"edge{r}")
+@pytest.mark.parametrize("how", ["min", "max"])
+def test_a_retraction_and_its_better_insert_across_a_rungs_edge_meet_in_one_merge(
+        how, edge):
+    """A hub's pass: key 7 holds six values in a buffer of four (two
+    pushed out, the watermark set); the next tick takes its four
+    buffered ones back in the live rows just under ``edge`` and brings
+    a better one as live row ``edge`` itself, the first past the rung.
+    Merged together the better row is the answer, strictly inside the
+    watermark; a merge of the rung's rows alone would hollow the buffer
+    (no positive row left, a positive one evicted before) and latch
+    ``unknown``. The ladder takes the next rung whole: tables, rows and
+    ``error`` of the whole delta's merge."""
+    sign = 1.0 if how == "min" else -1.0
+    st, core, whole = _wide_pair(how)
+    ref = st
+    hub, fill = 7, edge + 9
+    first = _dealt(np.full(6, hub), sign * np.arange(1, 7, dtype=np.float32),
+                   np.ones(6, np.int32))
+    keys = 8 + np.arange(fill) % (KW - 8)
+    vals = sign * (10 + np.arange(fill) % 5).astype(np.float32)
+    w = np.ones(fill, np.int32)
+    keys[edge - 4:edge + 1] = hub
+    vals[edge - 4:edge] = sign * np.arange(1, 5, dtype=np.float32)
+    w[edge - 4:edge] = -1
+    vals[edge] = sign * 0.5
+    for d in (first, _dealt(keys, vals, w)):
+        out, st = core(st, d)
+        rout, ref = whole(ref, d)
+        _same_but_counters(st, ref)
+        _same_live_rows(out, rout)
+    assert not bool(st["error"]) and bool(st["over_maybe_pos"][hub])
+    assert float(st["emitted"][hub]) == sign * 0.5
+    assert _rung(fill) > edge
+    assert int(st["counters"][3]) == KW + _rung(fill)
+    # what a split at the edge would have done: the rung's rows alone
+    k2, v2, w2 = (np.asarray(x).copy() for x in (d.keys, d.values, d.weights))
+    w2[np.flatnonzero(w2)[edge:]] = 0
+    _, split = whole(whole(_wide_pair(how)[0], first)[1], DeviceDelta(
+        jnp.asarray(k2), jnp.asarray(v2), jnp.asarray(w2)))
+    assert bool(split["error"])
+
+
+def _sorts(jaxpr, into=None):
+    """Rows of every ``sort`` in a jaxpr, those of its inner jaxprs
+    (calls, loops, branches) included."""
+    into = [] if into is None else into
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            into.append(eqn.invars[0].aval.shape[0])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _sorts(sub, into)
+    return into
+
+
+def _switches(jaxpr):
+    out = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "cond":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                out += _switches(sub)
+    return out
+
+
+def test_the_program_holds_one_merge_a_rung_and_none_more_under_four_times_the_keys():
+    """One ``lax.switch`` of as many branches as rungs, each with one
+    merge over its own rung (its key sort over ``r`` rows, its lexsort
+    over ``S_r x R + r``), behind one sort of the ``CW`` live flags; a
+    delta of under four times the key space (``nexmark-q3q4``'s shape:
+    the capacity under the keys) lowers to the text of ``_minmax_merge``
+    itself."""
+    st, core, whole = _wide_pair("min")
+    d = _dealt([], [], [])
+    jaxpr = jax.make_jaxpr(core)(st, d).jaxpr
+    (switch,) = _switches(jaxpr)
+    branches = switch.params["branches"]
+    assert len(branches) == len(RUNGS)
+    for r, br in zip(RUNGS, branches):
+        assert sorted(_sorts(br.jaxpr)) == sorted(
+            [r, lw._block_slots(r) * 4 + r])
+    assert sorted(_sorts(jaxpr)) == sorted(
+        [CW] + [n for r in RUNGS for n in (r, lw._block_slots(r) * 4 + r)])
+
+    for k, c in ((KW, 4 * KW - 8), (4096, 512)):
+        st, as_core, as_merge = _wide_pair("max", k)
+        d = _dealt([], [], [], c)
+        text = as_core.lower(st, d).as_text()
+        assert text == as_merge.lower(st, d).as_text()
+        assert "stablehlo.case" not in text and "stablehlo.if" not in text
+        _, st = as_core(st, d)
+        assert int(st["counters"][3]) == c

@@ -192,6 +192,66 @@ def test_a_hub_over_its_buffer_stays_exact_under_inserts(candidates):
     assert bool(np.asarray(st["over_maybe_pos"])[hub])
 
 
+def test_a_hubs_improvement_takes_a_rung_of_its_own_inside_a_tick():
+    """One vertex fans out to 300 edges; a shortcut to it arrives and
+    every one of those candidates is retracted and bettered in one pass:
+    600 live rows, past the minimum's first two rungs (``n`` and ``4 n``
+    slots of the join's 2 048), so that pass merges 1 024 slots, the
+    pass after it (the fan's heads improved, their ~170 rows) 256, the
+    tick's other passes 64 — read from ``merged_slots`` — and the
+    distances are Bellman-Ford's after every tick, on the fused
+    program and on the host-driven loop alike, every tick quiesced, no
+    sticky error."""
+    from reflow_tpu.executors import lowerings as lw
+
+    n, hub, far, fan, arena = 64, 63, 20, 300, 1 << 10
+    assert lw._merge_rungs(2 * arena, n) == (64, 256, 1024, 2048)
+    rng = np.random.default_rng(5)
+    quantum = 1.0 / 256
+    chain = np.arange(far)
+    heads = np.arange(far + 1, hub - 4)       # 59 .. 62 are leaves
+    src = np.concatenate([chain, [far], np.full(fan, hub),
+                          np.repeat(heads, 2)])
+    dst = np.concatenate([chain + 1, [hub], rng.choice(heads, fan),
+                          rng.choice(heads, 2 * len(heads))])
+    w = (1 + rng.integers(0, 256, len(src))) * quantum
+    # a leaf edge, then two shortcuts to the hub, each better than the
+    # last, then a leaf edge again
+    late = [(5, 60, 3 * quantum), (10, hub, 2 * quantum),
+            (0, hub, quantum), (0, 61, 200 * quantum)]
+    seen = {}
+    for name, fused in (("fused", True), ("host", False)):
+        sg = sssp.build_graph(n, arena_capacity=arena, candidates=4)
+        sched = DirtyScheduler(sg.graph,
+                               get_executor("tpu", fixpoint=fused))
+        sched.push(sg.edges, sssp.edge_batch(src, dst, w))
+        sched.push(sg.seeds, sssp.seed_batch(0))
+        assert sched.tick().quiesced
+        es, ed, ew = src, dst, w
+        was = sched.executor.op_counters()["best"]["merged_slots"]
+        seen[name] = []
+        for a, b, ww in late:
+            es, ed, ew = (np.concatenate([x, [y]])
+                          for x, y in ((es, a), (ed, b), (ew, ww)))
+            sched.push(sg.edges, sssp.edge_batch([a], [b], [ww]))
+            r = sched.tick()
+            assert r.quiesced
+            sched.executor.check_errors()
+            got = {int(k): float(v)
+                   for k, v in sched.read_table(sg.best).items()}
+            assert got == sssp.reference_distances(n, es, ed, ew, 0)
+            now = sched.executor.op_counters()["best"]["merged_slots"]
+            seen[name].append((int(r.passes), now - was))
+            was = now
+        assert not bool(sched.executor.states[sg.best.id]["error"])
+        if fused:
+            assert sched.executor.op_counters()["dist"]["unquiesced"] == 0
+    # one merge a pass: a leaf's two passes take the first rung, a
+    # hub's tick 64 + 1 024 + 256 + 64
+    assert seen["fused"] == seen["host"] == [
+        (2, 128), (4, 1408), (4, 1408), (2, 128)]
+
+
 # -- (c) the counters ---------------------------------------------------------
 
 
