@@ -548,9 +548,58 @@ def join_phase(cfg: dict, dev) -> dict:
         f"(key 3 holds {int((rk == 3).sum())}), then {L} left rows: "
         f"{len(want)} late pairs, indexed == dense == NumPy; counters "
         f"{counters}")
+
+    # retract the first tick's right rows, compact and re-index the
+    # indexed arena as a program of its own (``join_reindex``: what the
+    # executor runs between two windows), then retract the left rows:
+    # the probe over the rebuilt index finds what the dense sweep finds
+    # in its uncompacted log, every pair of the rows that are left
+    from reflow_tpu.executors.lowerings import join_reindex
+
+    gone = DeviceDelta(jnp.asarray(rk[:C]), jnp.asarray(rv[:C]),
+                       -jnp.ones((C,), jnp.int32))
+    for ix in states:
+        _, states[ix] = step(states[ix], None, gone)
+    t0 = time.perf_counter()
+    states[True] = jax.jit(join_reindex, donate_argnums=0)(states[True])
+    rows_left = int(states[True]["rcount"])
+    reindex_s = time.perf_counter() - t0
+    require(rows_left == len(rk) - C,
+            f"reindex left {rows_left} rows of {len(rk)} less {C} retracted")
+    back = DeviceDelta(da.keys, da.values, -jnp.ones((L,), jnp.int32))
+    nets = {}
+    for ix in states:
+        out, states[ix] = step(states[ix], back, None)
+        require(not bool(states[ix]["error"]),
+                f"join ({'indexed' if ix else 'dense'}) latched its error "
+                f"on the retraction")
+        rows = np.concatenate([np.asarray(out.keys)[:, None],
+                               np.asarray(out.values)], axis=1)
+        uniq, inv = np.unique(rows, axis=0, return_inverse=True)
+        net = np.bincount(inv.ravel(), weights=np.asarray(out.weights),
+                          minlength=len(uniq)).astype(np.int64)
+        nets[ix] = np.concatenate([uniq, net[:, None]], axis=1)[net != 0]
+    want2 = np.stack([rk[C:], lval[rk[C:]], rv[C:]], axis=1)[held[rk[C:]]]
+    uniq, n = np.unique(want2, axis=0, return_counts=True)
+    want2 = np.concatenate([uniq, -n[:, None]], axis=1)
+    require(np.array_equal(nets[True], nets[False]),
+            "after join_reindex: indexed join != dense join on a left "
+            "retraction")
+    require(np.array_equal(nets[True], want2),
+            "after join_reindex: retracted pairs != NumPy")
+    counters = np.asarray(states[True]["counters"]).tolist()
+    require(counters[3] == 1 and counters[4] == 1 and counters[9] == C,
+            f"join counters {counters}: want 1 rebuild, 1 compaction and "
+            f"{C} retracted rows")
+    say(f"join: {C} right rows retracted, join_reindex in {reindex_s:.3f} s "
+        f"(first call: compiled in it) leaves {rows_left} rows; {L} left "
+        f"rows retracted: {len(want2)} distinct pairs taken back, "
+        f"indexed == dense == NumPy")
     return {"keys": K, "arena": R, "right_rows": int(len(rk)),
             "late_pairs": int(len(want)),
-            "late_ticks_s": round(late_s, 4)}
+            "late_ticks_s": round(late_s, 4),
+            "reindex_rows_left": rows_left,
+            "retracted_pairs": int(n.sum())}
 
 
 def _sssp_config():

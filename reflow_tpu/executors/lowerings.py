@@ -983,7 +983,7 @@ def _join_core_indexed(op: Join, K: int, R: int, state,
     err = state["error"]
     outs = []
     zero = jnp.zeros((), jnp.int32)
-    late = pairs = steps = zero
+    late = pairs = steps = retracted = zero
 
     if da is not None:
         with jax.named_scope("join.probe"):
@@ -1008,13 +1008,14 @@ def _join_core_indexed(op: Join, K: int, R: int, state,
             vals = merge_v(kb, st["lval"][kb], vb)
             outs.append(DeviceDelta(kb + key_offset, vals, w))
             pairs = jnp.sum((w != 0).astype(jnp.int32))
+            retracted = jnp.sum((wb < 0).astype(jnp.int32))
             st, ovf = index_append(st, kb, vb, wb)
             err = err | ovf
 
     st["error"] = err
     st["counters"] = (state["counters"]
                       + jnp.stack([pairs + late, late, zero, zero, zero,
-                                   steps, zero, zero, zero])
+                                   steps, zero, zero, zero, retracted])
                       ).at[2].set(st["rcount"])
     out = DeviceDelta(
         jnp.concatenate([o.keys for o in outs]),
@@ -1156,10 +1157,13 @@ def join_core(op: Join, K: int, R: int, odtype, state,
         left = (jnp.sum((da.weights != 0).astype(jnp.int32))
                 if da is not None else zero)
         sweeps = 0 if da is None else 1
+        retracted = (jnp.sum((db.weights < 0).astype(jnp.int32))
+                     if db is not None else zero)
         new_state["counters"] = (
             state["counters"]
             + jnp.stack([sum(n_live, zero), late, 0, 0, gen - state["gen"],
-                         0, sweeps, sweeps * 2 * R, left]).astype(jnp.int32)
+                         0, sweeps, sweeps * 2 * R, left,
+                         retracted]).astype(jnp.int32)
         ).at[2].set(rcount)
     return out, new_state
 
@@ -1207,7 +1211,10 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: a reader differences window by window, modulo 2^32), and
 #: ``left_rows``, the live rows of the left deltas folded into its
 #: table (retractions and inserts: under a loop, the frontier). An
-#: indexed join leaves the three at 0.
+#: indexed join leaves the three at 0. Both count ``retracted``, the
+#: rows appended to the arena with a negative weight (a right-side
+#: retraction is a row of the log until a compaction cancels it
+#: against its insert): what fills an arena whose live rows stay level.
 #:
 #: ``"loop"`` is no operator: the row fixpoint program
 #: (``fixpoint.FixpointProgram``) counts in the state of its region's
@@ -1218,7 +1225,7 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
                "join": ("pairs", "late_pairs", "arena_rows",
                         "index_rebuilds", "compactions", "probe_steps",
-                        "sweeps", "swept_rows", "left_rows"),
+                        "sweeps", "swept_rows", "left_rows", "retracted"),
                "reduce": ("touched", "evicted", "blocks", "merged_slots"),
                "loop": ("passes", "ticks", "unquiesced")}
 

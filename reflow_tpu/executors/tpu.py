@@ -341,7 +341,9 @@ class TpuExecutor(Executor):
         #: against the per-shard slice (worst-case key skew)
         self._arena_divisor = 1
         self._indexed_joins: set = set()
-        self._reindex = None
+        #: ``join_reindex`` compiled ahead of time, one executable a
+        #: join shape (``_reindex_program``)
+        self._reindex: Dict[tuple, object] = {}
         #: the fused delta-vector loop runs on both the single-device and
         #: the sharded executor (the sharded variant runs the loop inside
         #: one shard_map region — see linear_fixpoint.py)
@@ -1042,6 +1044,11 @@ class TpuExecutor(Executor):
                    tuple(sorted(caps.items()))) + (("token",) if tr else ())
             prog = self._cache.get(sig)
             if prog is None:
+                # with the window program, what makes room for it: a
+                # compaction inside a served window is then a dispatch
+                # and never a compile
+                for nid in sorted(self._indexed_joins):
+                    self._reindex_program(nid)
                 shared_sig = self._window_signature(plan, caps)
                 if shared_sig is not None:
                     shared_sig += sig[3:]
@@ -1391,6 +1398,37 @@ class TpuExecutor(Executor):
         return propagate_plan_caps(plan, ingress_caps, self._arena_divisor,
                                    self._indexed_joins)
 
+    def _reindex_program(self, nid: int):
+        """``join_reindex`` for the indexed join ``nid``, compiled ahead
+        of time for its state's shapes (and the device that holds it)
+        and kept by them: joins of one shape share a program. The
+        executor compiles every indexed join's when it builds its first
+        window program, so making room between two served windows
+        dispatches and never compiles."""
+        state = self._states[nid]
+        sig = tuple((name, x.shape, str(x.dtype), str(x.sharding))
+                    for name, x in sorted(state.items()))
+        prog = self._reindex.get(sig)
+        if prog is None:
+            prog = jax.jit(join_reindex, donate_argnums=0).lower(
+                state).compile()
+            self._reindex[sig] = prog
+        return prog
+
+    def _arena_rows(self, nid: int) -> int:
+        """An indexed arena's true row count, read from the device: it
+        waits for every window dispatched so far (``arena_rcount_read``
+        says for how long)."""
+        tr = _trace.ENABLED
+        t0 = time.perf_counter() if tr else 0.0
+        used = int(self._states[nid]["rcount"])
+        if tr:
+            _trace.evt("arena_rcount_read", t0, time.perf_counter() - t0,
+                       args=_trace.with_win(
+                           {"node": self.graph.nodes[nid].name,
+                            "rows": used}))
+        return used
+
     def _make_room(self, caps: Dict[int, int], ticks: int) -> None:
         """Before ``ticks`` ticks at the per-node capacities ``caps``
         (``_track_arena``'s): every indexed join's arena has room for
@@ -1401,7 +1439,9 @@ class TpuExecutor(Executor):
         of each arena's rows (every tick adds its right delta's whole
         capacity) and reads the true count from the device only when the
         bound reaches the end: one sync per ``arena_capacity`` rows of
-        capacity dispatched, and none in a tick."""
+        capacity dispatched, and none in a tick. Under tracing a
+        ``join_reindex`` span runs from the program's dispatch to the
+        count read behind it, which is when the device finished it."""
         for nid in self._indexed_joins:
             node = self.graph.nodes[nid]
             need = ticks * caps.get(node.inputs[1].id, 0)
@@ -1410,12 +1450,17 @@ class TpuExecutor(Executor):
             R = node.op.arena_capacity
             used = self._arena_used.get(nid)
             if used is None or used + need > R:
-                used = int(self._states[nid]["rcount"])
+                used = self._arena_rows(nid)
             if used + need > R:
-                if self._reindex is None:
-                    self._reindex = jax.jit(join_reindex, donate_argnums=0)
-                self._states[nid] = self._reindex(self._states[nid])
+                before = used
+                t0 = time.perf_counter()
+                self._states[nid] = self._reindex_program(nid)(
+                    self._states[nid])
                 used = int(self._states[nid]["rcount"])
+                _trace.evt("join_reindex", t0, time.perf_counter() - t0,
+                           args=_trace.with_win(
+                               {"node": node.name, "rows_before": before,
+                                "rows_after": used}))
             self._arena_used[nid] = used + need
 
     # -- trace & compile one pass program ----------------------------------

@@ -8,4 +8,8 @@ BASELINE.md), plus one beyond-spec demo:
 5. ``image_embed`` — ViT-B feature extract → incremental groupby-agg
 6. ``sssp``        — incremental single-source shortest paths (min-plus
                      Join + min-Reduce fixpoint; beyond the spec)
+
+and two public benchmarks of the incremental-view field: ``nexmark``
+(Q3 / Q4: two stream joins, max into mean) and ``tpch`` (Q3 under the
+refresh functions: a join that feeds a join, inserts and deletes).
 """

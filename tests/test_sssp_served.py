@@ -343,7 +343,7 @@ def test_counters_ride_the_traced_windows_token():
     last = spans[-1]["args"]["counters"]
     now = ex.op_counters()
     assert {k: list(v.values()) for k, v in now.items()} == last
-    assert last["dist"][1] == 4 and len(last["relax"]) == 9
+    assert last["dist"][1] == 4 and len(last["relax"]) == 10
 
 
 # -- (d) which engine ---------------------------------------------------------
