@@ -34,7 +34,10 @@ One process, one chip, four phases, every check fatal:
    scratch), then a few insert batches each a one-tick window
    (``tick_many``): every tick ``converged``, no sticky error, state
    resident, distances equal to Bellman-Ford's to the bit, the program's
-   own counters say the same.
+   own counters say the same; the loop's join took every pass's left
+   delta through its key-sorted view of the arena or, past the pair
+   budget, by the sweep (``probes`` + ``sweeps`` = the passes but each
+   tick's first).
 
 The default invocation needs a TPU and never finishes on anything else.
 ``--tiny`` is the small CPU form tier-1 drives; it is reached only by
@@ -676,6 +679,12 @@ def sssp_phase(cfg: dict, dev) -> dict:
             and counters["dist"]["ticks"] == 1 + c["ticks"]
             and counters["dist"]["passes"] == int(first.passes)
             + sum(passes), f"sssp: loop counters {counters['dist']}")
+    # every pass with a left delta went through the join's key-sorted
+    # view or swept, and a window's small frontiers took the view
+    relax = counters["relax"]
+    require(relax["sweeps"] + relax["probes"]
+            == counters["dist"]["passes"] - counters["dist"]["ticks"]
+            and relax["probes"] > 0, f"sssp: join counters {relax}")
     hi = half + c["ticks"] * batch
     want = sssp.reference_distances(
         n, np.concatenate([u[:hi], v[:hi]]),

@@ -29,10 +29,28 @@ each shard compacts its slice and its slot of ``rcount``).
 pre-dispatch capacity walk that rejects statically impossible ingress
 sizes and sizes the mega-tick ingress queue against the arenas.
 
+**Two indexes, by what the graph says of a join's traffic.** A
+unique-left join keeps, beside the log, what lets δA ⋈ B_old cost by the
+delta's matches and not by ``arena_capacity``; which of the two,
+``TpuExecutor.bind`` reads off the graph. Loop-free (NEXmark's and
+TPC-H's joins): the arena is appended to in every tick, tens of millions
+of rows, and probed once a tick, so the index must cost by the append —
+the chained index below, which never sorts the arena in a tick. Under a
+loop (SSSP's relaxation): the arena is appended to once a tick (phase A
+of the fixpoint program; ``fixpoint.analyze`` refuses a loop-carried
+right input) and probed by every pass of the loop, half a dozen times and
+more, each time by the frontier, a few hundred keys of tens of thousands
+— the key-sorted view further down, one sort of the arena a tick and a
+probe with no chain to walk, where a chained key would gain a segment in
+every tick and only a compaction, which an insert-only arena never
+needs, would shorten it. A sort a tick over NEXmark's or TPC-H's arenas
+would cost more than their whole tick. The sharded executor and a
+declared-linear left keep neither (the fused linear fixpoint has a CSR
+cache of its own, below).
+
 **The arena index** (``index_state`` / ``index_probe`` / ``index_append``
-/ ``reindex``). A unique-left join of a loop-free graph keeps, beside the
-log, what lets δA ⋈ B_old cost ``delta rows x matches`` and not
-``arena_capacity``: every tick's appends land key-SORTED, so the rows one
+/ ``reindex``), a loop-free join's: every tick's appends land
+key-SORTED, so the rows one
 tick gave one key are one contiguous *segment*, and the segments of a key
 are chained newest to oldest (``head[K]`` -> first row of the newest
 segment; at a segment's first row ``seg_len`` rows and ``seg_prev`` the
@@ -50,6 +68,26 @@ tick program's code and compile time, and a tick never needs it. The
 index is part of the join's state: it travels with the arena through
 donation, checkpoints and rebinds, only ``reindex`` compacts an indexed
 arena, and so the two are never out of step.
+
+**The key-sorted view** (``view_state`` / ``view_sort`` / ``view_count``
+/ ``view_probe``), a loop join's: ``view_order[R]``, the arena's rows by
+key (a key's rows in arena order, dead rows last), and ``view_deg[K]``,
+the live rows a key has; where a key's rows start is a running sum of
+``view_deg``. ``join_core`` rebuilds it in the pass that appends, which
+is a static fact of the traced pass as the product's presence is:
+``view_deg`` by the delta's rows (a scatter of its slots; a recount of
+the arena, one slot a row, only behind an in-program compaction) and
+``view_order`` by one stable sort of the arena's keys. A pass with a
+left delta lays the rows of the keys it holds into ``view_budget`` slots
+(``view_probe``) and pairs them with both halves of the delta; a pass
+whose keys hold more arena rows than that sweeps the arena as a join
+without a view does, chosen on the device, so no budget errs and nothing is dropped
+(``lowerings._view_product``). This is the CSR ``lowerings._keyed_product``
+builds on every call and ``linear_fixpoint``'s ``(gen, rcount)``-keyed
+cache keeps beside the executor's state for the fused linear loop, here
+as state of the join itself: it travels with the arena through
+donation, checkpoints and rebinds, and whoever appends re-sorts, so the
+two are never out of step and no validity key is needed.
 """
 
 from __future__ import annotations
@@ -62,7 +100,8 @@ import jax.numpy as jnp
 from reflow_tpu.graph import GraphError
 
 __all__ = ["compact_arena", "propagate_plan_caps", "index_state",
-           "index_probe", "index_append", "reindex"]
+           "index_probe", "index_append", "reindex", "view_state",
+           "view_budget", "view_sort", "view_count", "view_probe"]
 
 
 def propagate_plan_caps(plan, ingress_caps: Dict[int, int],
@@ -353,3 +392,74 @@ def reindex(state: dict) -> dict:
     st["deg"] = jnp.zeros((K,), jnp.int32).at[fkey].set(seg_len,
                                                         mode="drop")
     return st
+
+
+# -- the key-sorted view (see the module docstring) ------------------------
+
+def view_state(K: int, R: int) -> dict:
+    """The view leaves of a join's state over ``K`` keys and an arena of
+    ``R`` rows, for an empty arena (what ``view_sort`` and ``view_count``
+    give for one: every row dead, so every row in its own place)."""
+    return {
+        "view_order": jnp.arange(R, dtype=jnp.int32),
+        "view_deg": jnp.zeros((K,), jnp.int32),
+    }
+
+
+def view_budget(K: int, R: int) -> int:
+    """Arena rows a probe of the view lays out (slots: the rows of the
+    probed keys, each once) before a pass sweeps instead: the key
+    space, or the arena where that is smaller (which then always
+    fits). A probe of ``T`` slots emits up to ``2 T`` live rows (a key
+    both retracted and inserted pairs twice), so it does not bind the
+    min/max's rung (``_merge_rungs`` goes by the live rows it is
+    handed)."""
+    return min(K, R)
+
+
+def _view_key(rkeys: jax.Array, rw: jax.Array, K: int) -> jax.Array:
+    """The key the view sorts a row by: its own, clipped as a gather
+    clips it, and ``K`` for a dead row, which so sorts behind every
+    key."""
+    return jnp.where(rw != 0, jnp.clip(rkeys, 0, K - 1), K)
+
+
+def view_sort(rkeys: jax.Array, rw: jax.Array, K: int) -> jax.Array:
+    """``view_order``: the arena's rows by key, a key's rows in arena
+    order, dead rows last (one stable sort of ``R`` keys)."""
+    return jnp.argsort(_view_key(rkeys, rw, K), stable=True
+                       ).astype(jnp.int32)
+
+
+def view_count(rkeys: jax.Array, rw: jax.Array, K: int) -> jax.Array:
+    """``view_deg`` recounted over the whole arena: one scatter of ``R``
+    slots, for an arena a compaction has just rewritten (an append
+    counts its own rows in)."""
+    return jnp.zeros((K + 1,), jnp.int32).at[
+        _view_key(rkeys, rw, K)].add(1)[:K]
+
+
+def view_probe(state: dict, probed: jax.Array, T: int):
+    """Every live arena row whose key is ``probed`` (bool ``[K]``), laid
+    into ``T`` slots, key after key and a key's rows in arena order:
+    -> (key [T], arena row [T], valid [T]). Where there are more than
+    ``T`` the slots hold the first ``T`` of them: the caller counts
+    first and takes another way then (a loop join sweeps: nothing
+    latches). The slot assignment is ``_keyed_product``'s (each key's
+    first slot scattered, a running maximum fills the rest), over the
+    ``K`` keys and not over a delta's slots."""
+    deg, order = state["view_deg"], state["view_order"]
+    K, R = deg.shape[0], order.shape[0]
+    d = jnp.where(probed, deg, 0)
+    cum = jnp.cumsum(d)
+    start = cum - d
+    marks = jnp.full((T,), -1, jnp.int32).at[
+        jnp.where(d > 0, start, T)].max(
+            jnp.arange(K, dtype=jnp.int32), mode="drop")
+    key = jnp.maximum(jax.lax.cummax(marks), 0)
+    j = jnp.arange(T, dtype=jnp.int32)
+    # a key's rows start at (rows of the keys before it) in the view and
+    # at ``start`` in the slots: one table of the difference
+    shift = (jnp.cumsum(deg) - deg) - start
+    row = order[jnp.clip(shift[key] + j, 0, R - 1)]
+    return key, row, j < cum[-1]

@@ -21,7 +21,10 @@ Keyed-state representations:
   right side an append-log arena (``rkeys[R]``, ``rvals[R,*VB]``,
   ``rw[R]``, ``rcount``). δ(A⋈B) = δA⋈B + (A+δA)⋈δB, with δA split into
   its retract/insert halves scattered to dense temp tables so the arena-side
-  product is a pure gather (this is the SpMV shape the MXU/VPU wants).
+  product is a pure gather (this is the SpMV shape the MXU/VPU wants). Where
+  the executor's counters are kept the gather follows the delta and not the
+  arena: through a chained index (loop-free) or a key-sorted view (under a
+  loop), ``arena``'s module docstring.
 
 Non-linear reducers (min/max) lower to a bounded per-key candidate buffer
 (``minmax_core``) holding the R lex-best distinct value rows per key with
@@ -76,12 +79,13 @@ def reduce_state(op: Reduce, in_spec: Spec, out_spec: Spec) -> dict:
 
 
 def join_state(op: Join, left_spec: Spec, right_spec: Spec,
-               indexed: bool = False, counted: bool = False) -> dict:
-    """``indexed``: a unique-left join of a loop-free graph keeps
-    an arena index and its device counters (``arena.index_state``,
-    ``OP_COUNTERS``); ``counted``: a unique-left join that sweeps its
-    arena (under a loop) keeps the counters alone. The executor says
-    which joins those are."""
+               indexed: bool = False, viewed: bool = False) -> dict:
+    """``indexed``: a unique-left join of a loop-free graph keeps the
+    chained arena index (``arena.index_state``) and device counters
+    (``OP_COUNTERS``); ``viewed``: a unique-left join under a loop keeps
+    the key-sorted view of its arena (``arena.view_state``) and the
+    counters, ``probes`` among them. The executor says which joins
+    those are."""
     K = left_spec.key_space
     R = op.arena_capacity
     if not left_spec.unique:
@@ -106,13 +110,16 @@ def join_state(op: Join, left_spec: Spec, right_spec: Spec,
             "gen": jnp.zeros((), jnp.int32),
             "error": jnp.zeros((), jnp.bool_),
         }
-    from reflow_tpu.executors.arena import index_state
+    from reflow_tpu.executors.arena import index_state, view_state
 
     extra = {}
-    if indexed or counted:
-        extra = dict(index_state(K, R) if indexed else {},
-                     counters=jnp.zeros((len(OP_COUNTERS["join"]),),
-                                        jnp.int32))
+    if indexed:
+        # the names up to ``probes``: an indexed join has no view
+        extra = dict(index_state(K, R), counters=jnp.zeros(
+            (OP_COUNTERS["join"].index("probes"),), jnp.int32))
+    elif viewed:
+        extra = dict(view_state(K, R), counters=jnp.zeros(
+            (len(OP_COUNTERS["join"]),), jnp.int32))
     return {
         **extra,
         "lval": jnp.zeros((K,) + tuple(left_spec.value_shape),
@@ -400,9 +407,10 @@ def minmax_core(op: Reduce, K: int, out_vshape, odtype, state,
                 ) -> Tuple[DeviceDelta, dict]:
     """One tick of the buffered min/max (:func:`_minmax_merge`), over
     as many of the delta's slots as its live rows need. A delta's
-    capacity is its producer's worst case: a join that sweeps its arena
-    under a loop hands over ``2 x arena_capacity`` slots a pass for a
-    frontier's few thousand rows, a hub's fan-out for some hundred
+    capacity is its producer's worst case: a join under a loop hands
+    over ``2 x arena_capacity`` slots a pass (its sweep's; a probe of its
+    view fills the first of them) for a frontier's few thousand rows, a
+    hub's fan-out for some hundred
     thousand, and the merge's sorts, gathers and scatters cost by the
     slots, live or dead. So where the capacity is several times the key
     space (``_merge_rungs``) the live rows are moved to the front (one
@@ -857,6 +865,14 @@ def _append_arena(arena: dict, keys, vals, w, R) -> Tuple[dict, jax.Array]:
     return out, out["rcount"] > R
 
 
+def _cat_deltas(rows: Sequence[DeviceDelta]) -> DeviceDelta:
+    return DeviceDelta(
+        jnp.concatenate([o.keys for o in rows]),
+        jnp.concatenate([o.values for o in rows]),
+        jnp.concatenate([o.weights for o in rows]),
+    )
+
+
 def _join_core_multiset(op: Join, K: int, R: int, state,
                         da: Optional[DeviceDelta],
                         db: Optional[DeviceDelta], merge_v,
@@ -911,11 +927,7 @@ def _join_core_multiset(op: Join, K: int, R: int, state,
                          rw=rarena["rw"], rcount=rarena["rcount"],
                          gen=rarena["gen"])
 
-    out = DeviceDelta(
-        jnp.concatenate([o.keys for o in outs]),
-        jnp.concatenate([o.values for o in outs]),
-        jnp.concatenate([o.weights for o in outs]),
-    )
+    out = _cat_deltas(outs)
     new_state["error"] = err
     return out, new_state
 
@@ -1017,11 +1029,7 @@ def _join_core_indexed(op: Join, K: int, R: int, state,
                       + jnp.stack([pairs + late, late, zero, zero, zero,
                                    steps, zero, zero, zero, retracted])
                       ).at[2].set(st["rcount"])
-    out = DeviceDelta(
-        jnp.concatenate([o.keys for o in outs]),
-        jnp.concatenate([o.values for o in outs]),
-        jnp.concatenate([o.weights for o in outs]),
-    )
+    out = _cat_deltas(outs)
     return out, st
 
 
@@ -1034,6 +1042,56 @@ def join_reindex(state: dict) -> dict:
     st = reindex(state)
     st["counters"] = st["counters"].at[3].add(1).at[4].add(1)
     return st
+
+
+def _view_product(state: dict, halves, sweep, merge_v, key_offset
+                  ) -> Tuple[DeviceDelta, jax.Array, jax.Array]:
+    """δA ⋈ B_old of a unique-left join that keeps the key-sorted view
+    of its arena (``arena.view_*``; a join under a loop): -> (its rows,
+    how many are live, 1 if the view gave them and 0 if the sweep did).
+
+    ``halves`` are the left delta's retract and insert rows as dense
+    ``(value [K], weight [K])`` tables, ``sweep()`` the gather of both
+    by every arena row. Under a loop a pass's left delta is the
+    frontier — some hundred of the cell's 65 536 keys — so the rows are
+    taken through the view instead: the arena rows of the keys either
+    half holds, laid into ``view_budget`` slots (``view_probe``), each
+    paired with both halves exactly as the sweep pairs it (same tables,
+    same ``merge``, dead and negative-weight arena rows alike), behind
+    them weight 0 up to the sweep's ``2 R`` slots, so whoever reads the
+    rows sees one capacity. What the probe would lay out (the arena
+    rows of the keys either half holds, each once: a retracted and
+    re-inserted key shares its slots between the halves) decides on the
+    device (``lax.cond``): past the budget the pass sweeps as before.
+    Nothing is dropped, nothing latches, and a pass's rows are never
+    split between the two."""
+    from reflow_tpu.executors.arena import view_budget, view_probe
+
+    av, aw = state["rvals"], state["rw"]
+    K, R = state["view_deg"].shape[0], aw.shape[0]
+    T = view_budget(K, R)
+    held = halves[0][1] != 0
+    for _, dw in halves[1:]:
+        held = held | (dw != 0)
+    n_slots = jnp.sum(jnp.where(held, state["view_deg"], 0))
+
+    def probe():
+        with jax.named_scope("join.view_probe"):
+            k, row, valid = view_probe(state, held, T)
+            a_v, a_w = av[row], jnp.where(valid, aw[row], 0)
+            rows = [DeviceDelta(k + key_offset, merge_v(k, tab[k], a_v),
+                                dw[k] * a_w) for tab, dw in halves]
+            vals = rows[0].values
+            pad = 2 * (R - T)
+            rows.append(DeviceDelta(
+                jnp.zeros((pad,), rows[0].keys.dtype),
+                jnp.zeros((pad,) + vals.shape[1:], vals.dtype),
+                jnp.zeros((pad,), jnp.int32)))
+            return _cat_deltas(rows)
+
+    probed = n_slots <= T
+    out = jax.lax.cond(probed, probe, lambda: _cat_deltas(sweep()))
+    return out, out.nonzero(), probed.astype(jnp.int32)
 
 
 def join_core(op: Join, K: int, R: int, odtype, state,
@@ -1053,7 +1111,12 @@ def join_core(op: Join, K: int, R: int, odtype, state,
     path below, or, where the state carries an arena index (a loop-free
     join: ``join_state(indexed=True)``), :func:`_join_core_indexed`;
     multiset-left state (a second ``lkeys``/... append arena) takes
-    :func:`_join_core_multiset`.
+    :func:`_join_core_multiset`. On the path below a state that carries
+    the key-sorted view (a join under a loop: ``join_state(viewed=True)``)
+    takes δA ⋈ B_old through it where the arena rows of the pass's keys fit the budget
+    (:func:`_view_product`) and re-sorts it behind every append; a state
+    without one (the sharded executor's, a declared-linear left's) sweeps
+    the arena in every pass that has a left delta.
     """
 
     def merge_v(keys, va, vb):
@@ -1079,7 +1142,10 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 
     ak, av, aw = state["rkeys"], state["rvals"], state["rw"]
     lval, lw = state["lval"], state["lw"]
+    viewed = "view_order" in state
     outs = []
+    zero = jnp.zeros((), jnp.int32)
+    late = probed = zero
 
     if da is not None:
         # split δA into its retract / insert halves, scattered dense
@@ -1092,12 +1158,23 @@ def join_core(op: Join, K: int, R: int, odtype, state,
         dw_r = zero_w.at[ret_keys].set(wa, mode="drop")
         dval_i = zero_val.at[ins_keys].set(da.values, mode="drop")
         dw_i = zero_w.at[ins_keys].set(wa, mode="drop")
+        halves = ((dval_r, dw_r), (dval_i, dw_i))
 
-        # δA ⋈ B_old : pure gather over the arena (the SpMV)
-        for tab, dw in ((dval_r, dw_r), (dval_i, dw_i)):
-            w = dw[ak] * aw
-            vals = merge_v(ak, tab[ak], av)
-            outs.append(DeviceDelta(ak + key_offset, vals, w))
+        def sweep():
+            # δA ⋈ B_old : pure gather over the arena (the SpMV)
+            rows = []
+            for tab, dw in halves:
+                w = dw[ak] * aw
+                vals = merge_v(ak, tab[ak], av)
+                rows.append(DeviceDelta(ak + key_offset, vals, w))
+            return rows
+
+        if viewed:
+            out_a, late, probed = _view_product(state, halves, sweep,
+                                                merge_v, key_offset)
+            outs.append(out_a)
+        else:
+            outs += sweep()
 
         # fold δA into the left table
         lw = lw.at[da.keys].add(wa)
@@ -1105,12 +1182,15 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 
     rkeys, rvals, rw, rcount = ak, av, aw, state["rcount"]
     err = state.get("error", jnp.zeros((), jnp.bool_))
+    view = ({"view_order": state["view_order"],
+             "view_deg": state["view_deg"]} if viewed else {})
     if db is not None:
         # (A + δA) ⋈ δB
         kb, vb, wb = db.keys, db.values, db.weights
         w = lw[kb] * wb
         vals = merge_v(kb, lval[kb], vb)
-        outs.append(DeviceDelta(kb + key_offset, vals, w))
+        db_out = DeviceDelta(kb + key_offset, vals, w)
+        outs.append(db_out)
 
         # append δB to the arena (compacted: live rows first). The
         # high-water check is IN-PROGRAM: when the append would cross
@@ -1120,14 +1200,28 @@ def join_core(op: Join, K: int, R: int, odtype, state,
         # (SURVEY.md §7 hard part d). A genuine overflow (live rows +
         # appends > capacity even after compaction) drops the excess rows
         # and sets the sticky error flag, raised at the next sync point.
-        from reflow_tpu.executors.arena import compact_arena
+        from reflow_tpu.executors.arena import (compact_arena, view_count,
+                                                view_sort)
 
         liveb = wb != 0
         n_app = jnp.sum(liveb.astype(jnp.int32))
         arena = {"rkeys": ak, "rvals": av, "rw": aw,
                  "rcount": state["rcount"], "gen": state["gen"]}
+        compacted = compact_arena
+        if viewed:
+            # the view follows the arena: a compaction rewrites every
+            # row, so the rows a key has are recounted behind it; an
+            # append counts its own rows in (a scatter of the delta's
+            # slots, not the arena's) and the order is sorted anew,
+            # once a pass that appends (under a fixpoint program once a
+            # tick, for every pass of the loop to probe)
+            arena["view_deg"] = state["view_deg"]
+
+            def compacted(s):
+                s = compact_arena(s)
+                return dict(s, view_deg=view_count(s["rkeys"], s["rw"], K))
         arena = jax.lax.cond(arena["rcount"] + n_app > R,
-                             compact_arena, lambda s: s, arena)
+                             compacted, lambda s: s, arena)
         rank = jnp.cumsum(liveb.astype(jnp.int32)) - 1
         pos = jnp.where(liveb, arena["rcount"] + rank, R)
         rkeys = arena["rkeys"].at[pos].set(kb, mode="drop")
@@ -1136,34 +1230,40 @@ def join_core(op: Join, K: int, R: int, odtype, state,
         rcount = arena["rcount"] + n_app
         gen = arena["gen"]
         err = err | (rcount > R)
+        if viewed:
+            with jax.named_scope("join.view_sort"):
+                view = {
+                    "view_order": view_sort(rkeys, rw, K),
+                    "view_deg": arena["view_deg"].at[
+                        jnp.where(pos < R, jnp.clip(kb, 0, K - 1), K)
+                    ].add(1, mode="drop")}
     else:
         gen = state["gen"]
 
-    out = DeviceDelta(
-        jnp.concatenate([o.keys for o in outs]),
-        jnp.concatenate([o.values for o in outs]),
-        jnp.concatenate([o.weights for o in outs]),
-    )
+    out = _cat_deltas(outs)
     new_state = {"lval": lval, "lw": lw, "rkeys": rkeys, "rvals": rvals,
-                 "rw": rw, "rcount": rcount, "gen": gen, "error": err}
-    if "counters" in state:
-        # a swept join counts what it did (``OP_COUNTERS``): whether a
-        # pass sweeps is static (``da`` is there or it is not), and a
-        # sweep passes over the arena's whole capacity twice, once for
-        # the retracted and once for the inserted left rows
-        n_live = [jnp.sum((o.weights != 0).astype(jnp.int32)) for o in outs]
-        zero = jnp.zeros((), jnp.int32)
-        late = sum(n_live[:2], zero) if da is not None else zero
-        left = (jnp.sum((da.weights != 0).astype(jnp.int32))
-                if da is not None else zero)
-        sweeps = 0 if da is None else 1
+                 "rw": rw, "rcount": rcount, "gen": gen, "error": err,
+                 **view}
+    if viewed:
+        # a loop's join counts what it did (``OP_COUNTERS``): whether a
+        # pass has a left delta is static (``da`` is there or it is
+        # not), which way its product went is the device's. A sweep
+        # passes over the arena's whole capacity twice, once for the
+        # retracted and once for the inserted left rows; a probe lays
+        # each of the two into its budget of slots
+        from reflow_tpu.executors.arena import view_budget
+
+        n_right = db_out.nonzero() if db is not None else zero
+        left = da.nonzero() if da is not None else zero
+        swept = (0 if da is None else 1) - probed
         retracted = (jnp.sum((db.weights < 0).astype(jnp.int32))
                      if db is not None else zero)
         new_state["counters"] = (
             state["counters"]
-            + jnp.stack([sum(n_live, zero), late, 0, 0, gen - state["gen"],
-                         0, sweeps, sweeps * 2 * R, left,
-                         retracted]).astype(jnp.int32)
+            + jnp.stack([late + n_right, late, 0, 0, gen - state["gen"], 0,
+                         swept,
+                         swept * 2 * R + probed * 2 * view_budget(K, R),
+                         left, retracted, probed]).astype(jnp.int32)
         ).at[2].set(rcount)
     return out, new_state
 
@@ -1200,21 +1300,29 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 #: slots written are ``blocks`` x one size only where every tick took
 #: the same rung. Only nodes whose state has the leaf count.
 #:
-#: A unique-left join under a loop sweeps its arena and keeps no index
-#: (``join_state(counted=True)``): of the names above ``pairs`` (live
-#: rows it emitted, all three products), ``late_pairs`` (those of the
-#: sweep's two halves), ``arena_rows`` and ``compactions`` (in-program),
-#: and three of its own: ``sweeps``, the passes that swept the arena
-#: (the passes of a fixpoint in which the left side had a delta),
-#: ``swept_rows``, the arena slots those passes read: ``2 x
-#: arena_capacity`` a sweep, live or not (int32, wraps after 2^31 slots:
-#: a reader differences window by window, modulo 2^32), and
-#: ``left_rows``, the live rows of the left deltas folded into its
+#: A unique-left join under a loop keeps the key-sorted view of its
+#: arena and no chained index (``join_state(viewed=True)``): of the
+#: names above ``pairs`` (live rows it emitted, all three products),
+#: ``late_pairs`` (those of the left delta's product, whichever way it
+#: went), ``arena_rows`` and ``compactions`` (in-program), and four of
+#: its own. Every pass in which the left side has a delta (the passes
+#: of a fixpoint but a tick's first) is one of two: ``probes``, the
+#: passes whose keys' arena rows fit ``arena.view_budget`` slots and were
+#: enumerated
+#: through the view, and ``sweeps``, the passes past it, which gathered
+#: by every arena row as a join without a view does. ``swept_rows``,
+#: the slots those passes read: ``2 x arena_capacity`` a sweep, live
+#: or not, and twice the budget a probe (its slots, once for the
+#: retracted and once for the inserted left rows; int32, wraps after
+#: 2^31 slots: a reader differences window by window, modulo 2^32),
+#: and ``left_rows``, the live rows of the left deltas folded into its
 #: table (retractions and inserts: under a loop, the frontier). An
-#: indexed join leaves the three at 0. Both count ``retracted``, the
-#: rows appended to the arena with a negative weight (a right-side
-#: retraction is a row of the log until a compaction cancels it
-#: against its insert): what fills an arena whose live rows stay level.
+#: indexed join keeps the names up to ``retracted`` and leaves
+#: ``sweeps``, ``swept_rows`` and ``left_rows`` at 0. Both count
+#: ``retracted``, the rows appended to the arena with a negative weight
+#: (a right-side retraction is a row of the log until a compaction
+#: cancels it against its insert): what fills an arena whose live rows
+#: stay level.
 #:
 #: ``"loop"`` is no operator: the row fixpoint program
 #: (``fixpoint.FixpointProgram``) counts in the state of its region's
@@ -1225,7 +1333,8 @@ def join_core(op: Join, K: int, R: int, odtype, state,
 OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
                "join": ("pairs", "late_pairs", "arena_rows",
                         "index_rebuilds", "compactions", "probe_steps",
-                        "sweeps", "swept_rows", "left_rows", "retracted"),
+                        "sweeps", "swept_rows", "left_rows", "retracted",
+                        "probes"),
                "reduce": ("touched", "evicted", "blocks", "merged_slots"),
                "loop": ("passes", "ticks", "unquiesced")}
 

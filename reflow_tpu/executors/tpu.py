@@ -309,7 +309,8 @@ def _node_token(node: Node):
 
 class TpuExecutor(Executor):
     name = "tpu"
-    #: loop-free unique-left joins keep an arena index, and they and the
+    #: unique-left joins keep an index of their arena (loop-free the
+    #: chained one, under a loop the key-sorted view), and they and the
     #: min/max reduces count on the device (the sharded executor, whose
     #: per-shard scalars ride as mesh-length vectors, keeps neither)
     _index_joins = True
@@ -435,9 +436,12 @@ class TpuExecutor(Executor):
         out = []
         for n in (self.graph.nodes if self.graph else ()):
             kind = n.op.kind if n.kind == "op" else n.kind
-            if (kind in OP_COUNTERS
-                    and "counters" in (self.states.get(n.id) or ())):
-                out.append((n, OP_COUNTERS[kind]))
+            st = self.states.get(n.id) or ()
+            if kind in OP_COUNTERS and "counters" in st:
+                # a node keeps the first names of its kind, as many as
+                # its leaf is wide (an indexed join all but ``probes``:
+                # ROADMAP D23, one width for both)
+                out.append((n, OP_COUNTERS[kind][:st["counters"].shape[0]]))
         return out
 
     def counter_names(self) -> Dict[str, Tuple[str, ...]]:
@@ -607,18 +611,21 @@ class TpuExecutor(Executor):
                             f"{node}: default-merge device Join needs a "
                             f"spec with {flat} flat value elements "
                             f"(va ++ vb), got {node.spec.value_shape}")
-                # a unique-left join of a loop-free graph keeps an arena
-                # index: its δA product follows the delta. Under a loop
-                # the frontier is most of the key space and the dense
-                # sweep has no pair budget to overflow; there it counts
-                # its sweeps instead (a declared-linear left belongs to
-                # the fused linear fixpoint, which carries no counters)
-                counted = (self._index_joins and in_specs[0].unique
-                           and not op.linear_left)
-                indexed = counted and not graph.loops
+                # a unique-left join keeps one of two indexes of its
+                # arena, by what the graph says of its traffic (module
+                # docstring of ``arena``): loop-free, the chained index,
+                # appended to in every tick and probed once; under a
+                # loop, where the arena is appended to once a tick and
+                # probed by every pass's frontier, the key-sorted view
+                # (a declared-linear left belongs to the fused linear
+                # fixpoint, which keeps a CSR cache of its own). Both
+                # count on the device
+                keyed = (self._index_joins and in_specs[0].unique
+                         and not op.linear_left)
+                indexed = keyed and not graph.loops
                 self.states[node.id] = join_state(
                     op, in_specs[0], in_specs[1], indexed,
-                    counted and not indexed)
+                    keyed and not indexed)
                 if indexed:
                     self._indexed_joins.add(node.id)
             else:

@@ -19,9 +19,11 @@ served path. What a run counts on the device
 (``TpuExecutor.op_counters()``, gauges
 ``sched.sssp.<node>.<counter>``; ``lowerings.OP_COUNTERS``): the
 fixpoint program's ``dist.passes`` / ``ticks`` / ``unquiesced``, the
-swept join's ``relax.sweeps`` / ``swept_rows`` / ``pairs`` /
-``left_rows``, the minimum's ``best.touched`` / ``evicted`` /
-``blocks`` / ``merged_slots``.
+loop join's ``relax.probes`` (passes whose frontier's pairs it took
+through its key-sorted view of the arena) / ``sweeps`` (passes past the
+slot budget, a hub's, which read the whole arena) / ``swept_rows`` /
+``pairs`` / ``left_rows``, the minimum's ``best.touched`` / ``evicted``
+/ ``blocks`` / ``merged_slots``.
 
 Graph::
 

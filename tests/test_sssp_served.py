@@ -3,7 +3,8 @@ serves it (``benchmarks/configs/sssp-graph500.py``: a Kronecker dataset,
 half loaded and half streamed as insert batches, its plain reference and
 comparison): the served path against per-tick ``tick()`` and the
 reference, with the WAL re-read; a hub far over its candidate buffer;
-the device counters of the row fixpoint program, its swept join and its
+a hub whose improvement passes the loop join's pair budget inside one
+tick; the device counters of the row fixpoint program, its join and its
 minimum against the host loop and the CPU oracle; which fixpoint engine
 a graph lands on. Small seeded sizes, CPU."""
 
@@ -17,6 +18,7 @@ import pytest
 
 from reflow_tpu import DirtyScheduler
 from reflow_tpu.executors import CpuExecutor, get_executor
+from reflow_tpu.executors.arena import view_budget
 from reflow_tpu.net import LoopbackTransport
 from reflow_tpu.serve import (APPLIED, CoalesceWindow, IngestFrontend,
                               RemoteProducer, RpcIngestServer)
@@ -252,6 +254,63 @@ def test_a_hubs_improvement_takes_a_rung_of_its_own_inside_a_tick():
         (2, 128), (4, 1408), (4, 1408), (2, 128)]
 
 
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "host-loop"])
+def test_a_hubs_improvement_passes_the_joins_pair_budget_inside_a_tick(fused):
+    """The same growing graph, read at the join: its pair budget is the
+    key space, 64 pairs (``arena.view_budget``), a leaf's improvement
+    pairs with nothing and a hub's with its 300 edges twice over
+    (retracted and inserted). So a leaf's tick only probes the view, and
+    in a hub's tick the passes of the hub and of its fan's heads sweep
+    while the tick's last, back under the budget, probes again: both
+    forms inside one tick, on the fused program (one ``lax.cond`` a
+    pass) and on the host-driven loop alike, the distances
+    Bellman-Ford's after every tick, every tick quiesced, no sticky
+    error, nothing dropped."""
+    n, hub, far, fan, arena = 64, 63, 20, 300, 1 << 10
+    assert view_budget(n, arena) == 64
+    rng = np.random.default_rng(5)
+    quantum = 1.0 / 256
+    chain = np.arange(far)
+    heads = np.arange(far + 1, hub - 4)
+    src = np.concatenate([chain, [far], np.full(fan, hub),
+                          np.repeat(heads, 2)])
+    dst = np.concatenate([chain + 1, [hub], rng.choice(heads, fan),
+                          rng.choice(heads, 2 * len(heads))])
+    w = (1 + rng.integers(0, 256, len(src))) * quantum
+    late = [(5, 60, 3 * quantum), (10, hub, 2 * quantum),
+            (0, hub, quantum), (0, 61, 200 * quantum)]
+    sg = sssp.build_graph(n, arena_capacity=arena, candidates=4)
+    sched = DirtyScheduler(sg.graph, get_executor("tpu", fixpoint=fused))
+    sched.push(sg.edges, sssp.edge_batch(src, dst, w))
+    sched.push(sg.seeds, sssp.seed_batch(0))
+    assert sched.tick().quiesced
+    es, ed, ew = src, dst, w
+    was = sched.executor.op_counters()["relax"]
+    seen = []
+    for a, b, ww in late:
+        es, ed, ew = (np.concatenate([x, [y]])
+                      for x, y in ((es, a), (ed, b), (ew, ww)))
+        sched.push(sg.edges, sssp.edge_batch([a], [b], [ww]))
+        r = sched.tick()
+        assert r.quiesced
+        sched.executor.check_errors()
+        got = {int(k): float(v)
+               for k, v in sched.read_table(sg.best).items()}
+        assert got == sssp.reference_distances(n, es, ed, ew, 0)
+        now = sched.executor.op_counters()["relax"]
+        seen.append(tuple(now[c] - was[c] for c in ("sweeps", "probes")))
+        assert sum(seen[-1]) == int(r.passes) - 1
+        assert (now["swept_rows"] - was["swept_rows"]
+                == seen[-1][0] * 2 * arena + seen[-1][1] * 2 * 64)
+        was = now
+    relax = next(x for x in sg.graph.nodes if x.name == "relax")
+    assert not bool(sched.executor.states[relax.id]["error"])
+    if fused:
+        assert sched.executor.op_counters()["dist"]["unquiesced"] == 0
+    # (sweeps, probes) a tick: a leaf's, the hub's twice, a leaf's
+    assert seen == [(0, 1), (2, 1), (2, 1), (0, 1)]
+
+
 # -- (c) the counters ---------------------------------------------------------
 
 
@@ -264,10 +323,11 @@ def _feeds(cfg, seed):
 @pytest.mark.parametrize("seed", [2**31 + 7, 3])
 def test_counters_equal_the_host_loop_and_the_cpu_oracle(seed):
     """``passes`` is the host-driven loop's pass count on the same
-    feeds; ``swept_rows`` is ``sweeps x 2 x arena capacity`` and a pass
-    sweeps exactly when the loop has a delta, i.e. every pass but a
-    tick's first; ``pairs`` is the live rows the CPU oracle's join emits
-    on the same feeds."""
+    feeds; a pass takes its left delta's product through the view or by
+    the sweep exactly when the loop has a delta, i.e. every pass but a
+    tick's first, and ``swept_rows`` is ``2 x arena capacity`` a sweep
+    and twice the pair budget a probe; ``pairs`` is the live rows the
+    CPU oracle's join emits on the same feeds."""
     runs = {}
     for name, ex in (("fused", get_executor("tpu")),
                      ("host", get_executor("tpu", fixpoint=False)),
@@ -301,8 +361,12 @@ def test_counters_equal_the_host_loop_and_the_cpu_oracle(seed):
     assert c["dist"] == {"passes": sum(passes), "ticks": len(passes),
                          "unquiesced": 0}
     R = dep.relax.op.arena_capacity
-    assert c["relax"]["sweeps"] == sum(passes) - len(passes)
-    assert c["relax"]["swept_rows"] == c["relax"]["sweeps"] * 2 * R
+    T = view_budget(dep.relax.inputs[0].spec.key_space, R)
+    assert (c["relax"]["sweeps"] + c["relax"]["probes"]
+            == sum(passes) - len(passes))
+    assert c["relax"]["probes"] > 0
+    assert c["relax"]["swept_rows"] == (c["relax"]["sweeps"] * 2 * R
+                                        + c["relax"]["probes"] * 2 * T)
     assert c["relax"]["pairs"] == runs["oracle"][3] > 0
     # the host-driven loop runs the same join lowering pass by pass
     assert runs["host"][0].executor.op_counters()["relax"] == c["relax"]
@@ -343,7 +407,7 @@ def test_counters_ride_the_traced_windows_token():
     last = spans[-1]["args"]["counters"]
     now = ex.op_counters()
     assert {k: list(v.values()) for k, v in now.items()} == last
-    assert last["dist"][1] == 4 and len(last["relax"]) == 10
+    assert last["dist"][1] == 4 and len(last["relax"]) == 11
 
 
 # -- (d) which engine ---------------------------------------------------------

@@ -1,5 +1,6 @@
-"""The top-k kernels, and a reduce's block writes into its keyed tables,
-compiled for the chip, without the chip: the TPU's
+"""The top-k kernels, a reduce's block writes into its keyed tables and
+a loop join's probe of its key-sorted view, compiled for the chip,
+without the chip: the TPU's
 compiler is installed here and compiles for a v5e that is described and
 not attached, so what Mosaic would refuse on the chip (a misaligned
 slice, too much VMEM, an operand it cannot place) fails here, at the
@@ -137,3 +138,43 @@ def test_reduce_tables_ride_the_block_loop_in_place(one_chip, how, vshape,
                           text, re.M)
         updates.add(int(shape.group(1)))
     assert updates == {lw._block_slots(cap)}
+
+
+def test_a_loop_joins_pass_probes_its_view_through_near_memory(one_chip):
+    """A pass of the ``sssp-graph500`` cell's join with a left delta
+    (2^16 keys, a 2^21-row arena, the minimum's 2^17-slot delta) as the
+    v5e compiler leaves it: one ``conditional`` between the probe and
+    the sweep, the arena and the view donated and aliased through it,
+    and the probe's table of slot marks (``view_probe``: one scatter of
+    ``K`` slots) in near memory, ``S(1)``, like the four dense tables
+    the left delta is scattered into."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from reflow_tpu.executors import lowerings as lw
+    from reflow_tpu.executors.device_delta import DeviceDelta
+    from reflow_tpu.workloads import sssp
+
+    keys, rows, cap = 1 << 16, 1 << 21, 1 << 17
+    sg = sssp.build_graph(keys, arena_capacity=rows)
+    relax = next(n for n in sg.graph.nodes if n.name == "relax")
+    state = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: lw.join_state(
+            relax.op, relax.inputs[0].spec, relax.inputs[1].spec,
+            viewed=True)))
+    delta = DeviceDelta(_shape(one_chip, (cap,), jnp.int32),
+                        _shape(one_chip, (cap,), jnp.float32),
+                        _shape(one_chip, (cap,), jnp.int32))
+    comp = jax.jit(
+        lambda s, d: lw.join_core(relax.op, keys, rows, np.float32, s, d,
+                                  None, oshape=(2,)),
+        donate_argnums=0).lower(state, delta).compile()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert comp.memory_analysis().alias_size_in_bytes >= 0.999 * held
+    text = comp.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    marks = re.findall(r"= s32\[%d\]\{([^}]*)\} scatter\([^\n]*"
+                       r"view_probe/scatter-max" % keys, text)
+    assert marks and all("S(1)" in m for m in marks)
