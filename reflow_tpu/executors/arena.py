@@ -9,11 +9,16 @@ VERDICT item 7).
 ``compact_arena`` cancels matched pairs on device: rows are lex-sorted by
 (key, value bytes), equal (key, value) runs are weight-summed, and groups
 with net weight 0 vanish; survivors are repacked to the front with their
-net weight. Exactness contract: a retraction carries the SAME value bytes
-as the insert it cancels (true by construction for host-driven deltas —
-the retract batch replays the original row with weight -1; float values
-are compared bitwise, so NaNs and signed zeros cancel only their
-bit-identical twins).
+net weight. The columns move by two sorts and a scan: key, value bits
+and weights ride the lex sort as its operands, a run's net weight is a
+segmented running sum, and the survivors are packed by a second sort on
+their rank — nothing of the arena's length is gathered or scattered by
+index, which on a TPU costs several sorts' worth a column
+(``tests/test_arena_gc.py`` holds the jaxpr to it). Exactness contract:
+a retraction carries the SAME value bytes as the insert it cancels (true
+by construction for host-driven deltas — the retract batch replays the
+original row with weight -1; float values are compared bitwise, so NaNs
+and signed zeros cancel only their bit-identical twins).
 
 Compaction triggers IN-PROGRAM: ``join_core`` wraps this kernel in a
 ``lax.cond`` guarded by ``rcount + appends > capacity``, so the
@@ -176,61 +181,115 @@ def propagate_plan_caps(plan, ingress_caps: Dict[int, int],
     return outs_cap
 
 
+def _value_bits(vcols: jax.Array) -> jax.Array:
+    """``vcols [R, n]`` -> int32 ``[R, q]``: the columns the compaction
+    sorts and compares values by. Bitwise value identity at NATIVE width
+    (ADVICE r2: narrowing 64-bit payloads to 32 bits before the compare
+    can alias distinct values and corrupt non-matching rows): 64-bit
+    dtypes bitcast to two int32 columns, 32-bit to one, 16-bit through
+    int16; sub-4-byte ints widen losslessly."""
+    R = vcols.shape[0]
+    itemsize = jnp.dtype(vcols.dtype).itemsize
+    if itemsize >= 4:
+        return jax.lax.bitcast_convert_type(vcols, jnp.int32).reshape(R, -1)
+    if itemsize == 2:
+        return jax.lax.bitcast_convert_type(
+            vcols, jnp.int16).astype(jnp.int32)
+    if jnp.issubdtype(vcols.dtype, jnp.floating):
+        # 1-byte floats (f8 variants): widen losslessly, then bitcast —
+        # a numeric int cast would truncate distinct values to one bucket
+        return jax.lax.bitcast_convert_type(
+            vcols.astype(jnp.float32), jnp.int32)
+    return vcols.astype(jnp.int32)
+
+
+def _bits_value(bits: jax.Array, dtype, shape) -> jax.Array:
+    """``_value_bits``'s inverse: the values the bit columns came from,
+    in ``shape``, bit for bit: every widening there narrows back
+    losslessly (but an 8-bit float's NaN, whose payload XLA's conversions
+    do not keep in either direction: its sign and its being a NaN
+    stay)."""
+    R = bits.shape[0]
+    itemsize = jnp.dtype(dtype).itemsize
+    if itemsize >= 4:
+        words = itemsize // 4
+        vals = jax.lax.bitcast_convert_type(
+            bits.reshape((R, -1, words) if words > 1 else (R, -1)), dtype)
+    elif itemsize == 2:
+        vals = jax.lax.bitcast_convert_type(bits.astype(jnp.int16), dtype)
+    elif jnp.issubdtype(dtype, jnp.floating):
+        vals = jax.lax.bitcast_convert_type(bits, jnp.float32).astype(dtype)
+    else:
+        vals = bits.astype(dtype)
+    return vals.reshape(shape)
+
+
+def _run_sums(w: jax.Array, first: jax.Array) -> jax.Array:
+    """The running sum of ``w`` within each run of rows (``first`` marks
+    a run's first row): at a run's last row, the run's total. A scan by
+    doubling: after the step at distance ``d`` a row holds the sum of
+    the ``2 d`` rows up to it, or of its run up to it once that is
+    shorter (``reached``) — ``log2 R`` shifted adds, each one fused pass
+    over the column. int32 sums wrap as a scatter-add's would."""
+    def shifted(x, d, fill):
+        return jnp.concatenate([jnp.full((d,), fill, x.dtype), x[:-d]])
+
+    reached, d = first, 1
+    while d < w.shape[0]:
+        w = w + jnp.where(reached, 0, shifted(w, d, 0))
+        reached = reached | shifted(reached, d, True)
+        d *= 2
+    return w
+
+
 def compact_arena(state: dict) -> dict:
     """Pure kernel: (join state) -> (join state with arena compacted).
 
     Only the arena fields (rkeys/rvals/rw/rcount) change; the left table
     passes through untouched. Shapes are static; runs under jit or as a
     shard_map body.
+
+    How the columns move: they ride two sorts as operands, and nothing of
+    the arena's length is gathered or scattered by index. The first sort
+    orders the rows by (key, value bits) — dead rows behind every key —
+    and hands back the sorted key, bits and weights; a run of equal
+    (key, value bits) is one group, its net weight a running sum within
+    the run (``_run_sums``), read at the run's last row (any row of a
+    run carries the run's key and bits). The second sort packs the
+    survivors — groups of net weight other than 0 — to the front by
+    their rank, which is unique, so neither sort need be stable; every
+    other row rides it as zeros, which is what lies behind the
+    survivors. The values are their bits, bitcast back at native width.
     """
     rk, rv, rw = state["rkeys"], state["rvals"], state["rw"]
     R = rk.shape[0]
-    vcols = rv.reshape(R, -1)
-    # bitwise value identity at NATIVE width (ADVICE r2: narrowing 64-bit
-    # payloads to 32 bits before the compare can alias distinct values and
-    # corrupt non-matching rows): 64-bit dtypes bitcast to two int32
-    # columns, 32-bit to one, 16-bit through int16; sub-4-byte ints widen
-    # losslessly
-    itemsize = jnp.dtype(vcols.dtype).itemsize
-    if itemsize >= 4:
-        bits = jax.lax.bitcast_convert_type(vcols, jnp.int32).reshape(R, -1)
-    elif itemsize == 2:
-        bits = jax.lax.bitcast_convert_type(
-            vcols, jnp.int16).astype(jnp.int32).reshape(R, -1)
-    elif jnp.issubdtype(vcols.dtype, jnp.floating):
-        # 1-byte floats (f8 variants): widen losslessly, then bitcast —
-        # a numeric int cast would truncate distinct values to one bucket
-        bits = jax.lax.bitcast_convert_type(
-            vcols.astype(jnp.float32), jnp.int32).reshape(R, -1)
-    else:
-        bits = vcols.astype(jnp.int32)
-    live = rw != 0
-    skey = jnp.where(live, rk, jnp.iinfo(jnp.int32).max)
+    imax = jnp.iinfo(jnp.int32).max
+    bits = _value_bits(rv.reshape(R, -1))
+    cols = [bits[:, q] for q in range(bits.shape[1])]
 
-    # lex order: key primary, then value columns (np.lexsort: LAST key is
-    # primary)
-    order = jnp.lexsort(tuple(bits[:, q] for q in range(bits.shape[1] - 1,
-                                                        -1, -1)) + (skey,))
-    sk = skey[order]
-    sb = bits[order]
-    sv = rv[order]
-    sw = rw[order]
+    # lex order: key primary, then the value's bit columns
+    sk, *sb, sw = jax.lax.sort(
+        (jnp.where(rw != 0, rk, imax), *cols, rw),
+        num_keys=1 + len(cols), is_stable=False)
 
-    prev_same = jnp.concatenate([
-        jnp.zeros((1,), jnp.bool_),
-        (sk[1:] == sk[:-1]) & jnp.all(sb[1:] == sb[:-1], axis=-1),
-    ])
-    first = ~prev_same
-    gid = jnp.cumsum(first.astype(jnp.int32)) - 1
-    netw = jnp.zeros((R,), jnp.int32).at[gid].add(sw)
-    keep = first & (netw[gid] != 0) & (sk != jnp.iinfo(jnp.int32).max)
+    same = sk[1:] == sk[:-1]
+    for col in sb:
+        same = same & (col[1:] == col[:-1])
+    edge = jnp.ones((1,), jnp.bool_)
+    first = jnp.concatenate([edge, ~same])
+    last = jnp.concatenate([~same, edge])
+    netw = _run_sums(sw, first)
+    keep = last & (netw != 0) & (sk != imax)
 
-    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    tgt = jnp.where(keep, pos, R)
-    nk = jnp.zeros_like(rk).at[tgt].set(sk, mode="drop")
-    nv = jnp.zeros_like(rv).at[tgt].set(sv, mode="drop")
-    nw = jnp.zeros_like(rw).at[tgt].set(netw[gid], mode="drop")
-    ncount = jnp.sum(keep.astype(jnp.int32))
+    # the survivors' ranks rise with the sorted row: packing them is a
+    # sort by rank, everyone else behind
+    pos = jnp.cumsum(keep, dtype=jnp.int32) - 1
+    _, nk, *nbits, nw = jax.lax.sort(
+        (jnp.where(keep, pos, imax),
+         *(jnp.where(keep, col, 0) for col in (sk, *sb, netw))),
+        num_keys=1, is_stable=False)
+    nv = _bits_value(jnp.stack(nbits, axis=1), rv.dtype, rv.shape)
+    ncount = pos[-1] + 1
 
     out = dict(state)
     out.update(rkeys=nk, rvals=nv, rw=nw,
