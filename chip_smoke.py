@@ -487,7 +487,8 @@ def join_phase(cfg: dict, dev) -> dict:
 
     from reflow_tpu.delta import Spec
     from reflow_tpu.executors.device_delta import DeviceDelta
-    from reflow_tpu.executors.lowerings import join_core, join_state
+    from reflow_tpu.executors.join import (JOIN_COUNTERS, join_core,
+                                           join_reindex, join_state)
     from reflow_tpu.graph import FlowGraph
 
     c = cfg["join"]
@@ -502,9 +503,10 @@ def join_phase(cfg: dict, dev) -> dict:
     rng = np.random.default_rng(7)
     step = jax.jit(lambda st, da, db: join_core(
         j.op, K, R, np.int32, st, da, db, oshape=(2,)))
-    states = {ix: jax.device_put(join_state(j.op, left.spec, right.spec,
-                                            indexed=ix), dev)
-              for ix in (True, False)}
+    states = {ix: jax.device_put(
+        join_state(j.op, left.spec, right.spec,
+                   "indexed" if ix else "swept"), dev)
+        for ix in (True, False)}
     rk, rv = [], []
     for _ in range(c["ticks"]):
         keys = np.where(rng.random(C) < 0.25, 3,
@@ -543,8 +545,10 @@ def join_phase(cfg: dict, dev) -> dict:
     require(np.array_equal(outs[True], outs[False]),
             "indexed join != dense join on the same arena and delta")
     require(np.array_equal(outs[True], want), "join != NumPy")
-    counters = np.asarray(states[True]["counters"]).tolist()
-    require(counters[1] == len(want) and counters[2] == len(rk),
+    counters = dict(zip(JOIN_COUNTERS,
+                        np.asarray(states[True]["counters"]).tolist()))
+    require(counters["late_pairs"] == len(want)
+            and counters["arena_rows"] == len(rk),
             f"join counters {counters}: want {len(want)} late pairs and "
             f"{len(rk)} arena rows")
     say(f"join K {K} arena {R}: {len(rk)} right rows in {c['ticks']} ticks "
@@ -557,8 +561,6 @@ def join_phase(cfg: dict, dev) -> dict:
     # executor runs between two windows), then retract the left rows:
     # the probe over the rebuilt index finds what the dense sweep finds
     # in its uncompacted log, every pair of the rows that are left
-    from reflow_tpu.executors.lowerings import join_reindex
-
     gone = DeviceDelta(jnp.asarray(rk[:C]), jnp.asarray(rv[:C]),
                        -jnp.ones((C,), jnp.int32))
     for ix in states:
@@ -590,8 +592,10 @@ def join_phase(cfg: dict, dev) -> dict:
             "retraction")
     require(np.array_equal(nets[True], want2),
             "after join_reindex: retracted pairs != NumPy")
-    counters = np.asarray(states[True]["counters"]).tolist()
-    require(counters[3] == 1 and counters[4] == 1 and counters[9] == C,
+    counters = dict(zip(JOIN_COUNTERS,
+                        np.asarray(states[True]["counters"]).tolist()))
+    require(counters["index_rebuilds"] == 1 and counters["compactions"] == 1
+            and counters["retracted"] == C,
             f"join counters {counters}: want 1 rebuild, 1 compaction and "
             f"{C} retracted rows")
     say(f"join: {C} right rows retracted, join_reindex in {reindex_s:.3f} s "

@@ -20,41 +20,25 @@ by construction for host-driven deltas — the retract batch replays the
 original row with weight -1; float values are compared bitwise, so NaNs
 and signed zeros cancel only their bit-identical twins).
 
-Compaction triggers IN-PROGRAM: ``join_core`` wraps this kernel in a
-``lax.cond`` guarded by ``rcount + appends > capacity``, so the
-high-water decision is data-dependent on device and never reads a value
-back to the host (SURVEY.md §7 hard part d — streaming ticks stay
-pipelined). A genuine overflow (live + appends > capacity even after
-compaction) sets the join state's sticky ``error`` flag, raised at the
-next sync point. Sharded executors reach this through the same path:
-``join_core`` runs per shard under ``shard_map`` (rows never migrate;
-each shard compacts its slice and its slot of ``rcount``).
+Compaction triggers IN-PROGRAM: ``join._append_arena`` wraps this kernel
+in a ``lax.cond`` guarded by ``rcount + appends > capacity`` (no value is
+read back to the host); a genuine overflow (live + appends > capacity
+even after compaction) sets the join state's sticky ``error`` flag,
+raised at the next sync point. Sharded executors reach this through the
+same path: ``join_core`` runs per shard under ``shard_map`` (rows never
+migrate; each shard compacts its slice and its slot of ``rcount``).
 
 ``propagate_plan_caps`` is the host-side static counterpart: the
 pre-dispatch capacity walk that rejects statically impossible ingress
 sizes and sizes the mega-tick ingress queue against the arenas.
 
-**Two indexes, by what the graph says of a join's traffic.** A
-unique-left join keeps, beside the log, what lets δA ⋈ B_old cost by the
-delta's matches and not by ``arena_capacity``; which of the two,
-``TpuExecutor.bind`` reads off the graph. Loop-free (NEXmark's and
-TPC-H's joins): the arena is appended to in every tick, tens of millions
-of rows, and probed once a tick, so the index must cost by the append —
-the chained index below, which never sorts the arena in a tick. Under a
-loop (SSSP's relaxation): the arena is appended to once a tick (phase A
-of the fixpoint program; ``fixpoint.analyze`` refuses a loop-carried
-right input) and probed by every pass of the loop, half a dozen times and
-more, each time by the frontier, a few hundred keys of tens of thousands
-— the key-sorted view further down, one sort of the arena a tick and a
-probe with no chain to walk, where a chained key would gain a segment in
-every tick and only a compaction, which an insert-only arena never
-needs, would shorten it. A sort a tick over NEXmark's or TPC-H's arenas
-would cost more than their whole tick. The sharded executor and a
-declared-linear left keep neither (the fused linear fixpoint has a CSR
-cache of its own, below).
+Beside the log a unique-left join may keep one of two indexes, which let
+δA ⋈ B_old cost by the delta's matches and not by ``arena_capacity``.
+Which join keeps which, and why, is ``join.join_layout``'s to say; this
+module holds how each is stored, appended to and probed.
 
 **The arena index** (``index_state`` / ``index_probe`` / ``index_append``
-/ ``reindex``), a loop-free join's: every tick's appends land
+/ ``reindex``; the ``"indexed"`` layout's): every tick's appends land
 key-SORTED, so the rows one
 tick gave one key are one contiguous *segment*, and the segments of a key
 are chained newest to oldest (``head[K]`` -> first row of the newest
@@ -67,15 +51,15 @@ every probed key per step: as many steps as the most *ticks* any probed
 key was appended in, whatever the rows. A compaction re-sorts the log,
 after which every key is one segment again and the index is derived from
 the sorted log in one pass (``reindex``). That is a program of its own,
-which the executor runs between ticks when a window's appends might not
-fit (``TpuExecutor._make_room``): the sort of a whole arena is most of a
-tick program's code and compile time, and a tick never needs it. The
-index is part of the join's state: it travels with the arena through
-donation, checkpoints and rebinds, only ``reindex`` compacts an indexed
-arena, and so the two are never out of step.
+run between ticks when a window's appends might not fit
+(``join.ArenaRoom``). The index is part of the join's state: it travels
+with the arena through donation, checkpoints and rebinds, only
+``reindex`` compacts an indexed arena, and so the two are never out of
+step.
 
 **The key-sorted view** (``view_state`` / ``view_sort`` / ``view_count``
-/ ``view_probe``), a loop join's: ``view_order[R]``, the arena's rows by
+/ ``view_probe``; the ``"viewed"`` layout's): ``view_order[R]``, the
+arena's rows by
 key (a key's rows in arena order, dead rows last), and ``view_deg[K]``,
 the live rows a key has; where a key's rows start is a running sum of
 ``view_deg``. ``join_core`` rebuilds it in the pass that appends, which
@@ -86,13 +70,13 @@ the arena, one slot a row, only behind an in-program compaction) and
 left delta lays the rows of the keys it holds into ``view_budget`` slots
 (``view_probe``) and pairs them with both halves of the delta; a pass
 whose keys hold more arena rows than that sweeps the arena as a join
-without a view does, chosen on the device, so no budget errs and nothing is dropped
-(``lowerings._view_product``). This is the CSR ``lowerings._keyed_product``
-builds on every call and ``linear_fixpoint``'s ``(gen, rcount)``-keyed
-cache keeps beside the executor's state for the fused linear loop, here
-as state of the join itself: it travels with the arena through
-donation, checkpoints and rebinds, and whoever appends re-sorts, so the
-two are never out of step and no validity key is needed.
+without a view does, chosen on the device, so no budget errs and nothing
+is dropped (``join._view_product``). This is the CSR the multiset
+layout's ``join._keyed_product`` builds on every call (``view_sort``,
+``view_count``) and ``linear_fixpoint``'s ``(gen, rcount)``-keyed cache
+keeps beside the executor's state, here as state of the join itself:
+whoever appends re-sorts, so the two are never out of step and no
+validity key is needed.
 """
 
 from __future__ import annotations

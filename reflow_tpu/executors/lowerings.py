@@ -17,14 +17,9 @@ Keyed-state representations:
   ``wsum[K,*V]`` (Σ w·v), ``wcnt[K]`` (Σ w), ``emitted[K,*V]`` +
   ``emitted_has[K]`` (the last aggregate actually emitted downstream, for
   retract-correctness under ``tol`` — mirrors the host oracle exactly).
-- Join: left side a unique-keyed dense table (``lval[K,*VA]``, ``lw[K]``);
-  right side an append-log arena (``rkeys[R]``, ``rvals[R,*VB]``,
-  ``rw[R]``, ``rcount``). δ(A⋈B) = δA⋈B + (A+δA)⋈δB, with δA split into
-  its retract/insert halves scattered to dense temp tables so the arena-side
-  product is a pure gather (this is the SpMV shape the MXU/VPU wants). Where
-  the executor's counters are kept the gather follows the delta and not the
-  arena: through a chained index (loop-free) or a key-sorted view (under a
-  loop), ``arena``'s module docstring.
+- Join: a dense left table or a second arena, an append-log right arena
+  and, by the join's layout, an index over it — ``join`` has the state,
+  the layouts and the kernel; this module only sends ``join`` nodes there.
 
 Non-linear reducers (min/max) lower to a bounded per-key candidate buffer
 (``minmax_core``) holding the R lex-best distinct value rows per key with
@@ -44,12 +39,12 @@ import jax.numpy as jnp
 
 from reflow_tpu.delta import Spec
 from reflow_tpu.executors.device_delta import DeviceDelta
+from reflow_tpu.executors.join import JOIN_COUNTERS, lower_join
 from reflow_tpu.graph import Node
-from reflow_tpu.ops import Filter, GroupBy, Join, Map, Reduce, Union
+from reflow_tpu.ops import Filter, GroupBy, Map, Reduce, Union
 
-__all__ = ["lower_node", "reduce_state", "join_state", "join_core",
-           "knn_state", "minmax_core", "minmax_refresh_core",
-           "DEVICE_REDUCERS"]
+__all__ = ["lower_node", "reduce_state", "knn_state", "minmax_core",
+           "minmax_refresh_core", "DEVICE_REDUCERS", "OP_COUNTERS"]
 
 #: sum/count/mean lower to linear scatter-adds; min/max lower to the
 #: bounded candidate-buffer kernel (retraction-exact within the per-key
@@ -75,69 +70,6 @@ def reduce_state(op: Reduce, in_spec: Spec, out_spec: Spec) -> dict:
         "wcnt": jnp.zeros((K,), jnp.int32),
         "emitted": jnp.zeros((K,) + oshape, out_spec.value_dtype),
         "emitted_has": jnp.zeros((K,), jnp.bool_),
-    }
-
-
-def join_state(op: Join, left_spec: Spec, right_spec: Spec,
-               indexed: bool = False, viewed: bool = False) -> dict:
-    """``indexed``: a unique-left join of a loop-free graph keeps the
-    chained arena index (``arena.index_state``) and device counters
-    (``OP_COUNTERS``); ``viewed``: a unique-left join under a loop keeps
-    the key-sorted view of its arena (``arena.view_state``) and the
-    counters, ``probes`` among them. The executor says which joins
-    those are."""
-    K = left_spec.key_space
-    R = op.arena_capacity
-    if not left_spec.unique:
-        # MULTISET left (ROADMAP r4 #2 / VERDICT r4 #5): the left side is
-        # a second append arena mirroring the right side's log; both
-        # δ-products are key-matched delta×arena pair enumerations at a
-        # static budget (see _keyed_product). No dense lval/lw tables —
-        # a multiset has no per-key value to store densely.
-        La = op.left_arena_capacity or op.arena_capacity
-        return {
-            "lkeys": jnp.zeros((La,), jnp.int32),
-            "lvals": jnp.zeros((La,) + tuple(left_spec.value_shape),
-                               left_spec.value_dtype),
-            "lrw": jnp.zeros((La,), jnp.int32),
-            "lcount": jnp.zeros((), jnp.int32),
-            "lgen": jnp.zeros((), jnp.int32),
-            "rkeys": jnp.zeros((R,), jnp.int32),
-            "rvals": jnp.zeros((R,) + tuple(right_spec.value_shape),
-                               right_spec.value_dtype),
-            "rw": jnp.zeros((R,), jnp.int32),
-            "rcount": jnp.zeros((), jnp.int32),
-            "gen": jnp.zeros((), jnp.int32),
-            "error": jnp.zeros((), jnp.bool_),
-        }
-    from reflow_tpu.executors.arena import index_state, view_state
-
-    extra = {}
-    if indexed:
-        # the names up to ``probes``: an indexed join has no view
-        extra = dict(index_state(K, R), counters=jnp.zeros(
-            (OP_COUNTERS["join"].index("probes"),), jnp.int32))
-    elif viewed:
-        extra = dict(view_state(K, R), counters=jnp.zeros(
-            (len(OP_COUNTERS["join"]),), jnp.int32))
-    return {
-        **extra,
-        "lval": jnp.zeros((K,) + tuple(left_spec.value_shape),
-                          left_spec.value_dtype),
-        "lw": jnp.zeros((K,), jnp.int32),
-        "rkeys": jnp.zeros((R,), jnp.int32),
-        "rvals": jnp.zeros((R,) + tuple(right_spec.value_shape),
-                           right_spec.value_dtype),
-        "rw": jnp.zeros((R,), jnp.int32),
-        "rcount": jnp.zeros((), jnp.int32),
-        # arena generation: bumped by every compaction (which reorders
-        # rows). The linear fixpoint's persistent CSR cache keys its
-        # validity on (gen, rcount): a gen mismatch means the base
-        # ordering is gone and the CSR must rebuild.
-        "gen": jnp.zeros((), jnp.int32),
-        # sticky: set when an append overflows the arena even after the
-        # in-program compaction pass (checked loudly at the next sync)
-        "error": jnp.zeros((), jnp.bool_),
     }
 
 
@@ -833,508 +765,38 @@ def _lower_reduce(op: Reduce, node: Node, state, ins) -> Tuple[DeviceDelta, dict
     return out, new_state
 
 
-# -- Join ------------------------------------------------------------------
-
-def _lower_join(op: Join, node: Node, state, ins) -> Tuple[DeviceDelta, dict]:
-    da, db = ins
-    left_spec = node.inputs[0].spec
-    return join_core(op, left_spec.key_space, op.arena_capacity,
-                     node.spec.value_dtype, state, da, db,
-                     oshape=tuple(node.spec.value_shape))
-
-
-def _append_arena(arena: dict, keys, vals, w, R) -> Tuple[dict, jax.Array]:
-    """Append live delta rows to an append-log arena (compacted: live
-    rows first), compacting in-program when the append would cross
-    capacity. -> (arena', overflow). Shared by the right arena and the
-    multiset-left arena (the latter aliases its fields to the rkeys/...
-    names this kernel and ``compact_arena`` use)."""
-    from reflow_tpu.executors.arena import compact_arena
-
-    live = w != 0
-    n_app = jnp.sum(live.astype(jnp.int32))
-    arena = jax.lax.cond(arena["rcount"] + n_app > R,
-                         compact_arena, lambda s: s, arena)
-    rank = jnp.cumsum(live.astype(jnp.int32)) - 1
-    pos = jnp.where(live, arena["rcount"] + rank, R)
-    out = dict(arena)
-    out["rkeys"] = arena["rkeys"].at[pos].set(keys, mode="drop")
-    out["rvals"] = arena["rvals"].at[pos].set(vals, mode="drop")
-    out["rw"] = arena["rw"].at[pos].set(w, mode="drop")
-    out["rcount"] = arena["rcount"] + n_app
-    return out, out["rcount"] > R
-
-
-def _cat_deltas(rows: Sequence[DeviceDelta]) -> DeviceDelta:
-    return DeviceDelta(
-        jnp.concatenate([o.keys for o in rows]),
-        jnp.concatenate([o.values for o in rows]),
-        jnp.concatenate([o.weights for o in rows]),
-    )
-
-
-def _join_core_multiset(op: Join, K: int, R: int, state,
-                        da: Optional[DeviceDelta],
-                        db: Optional[DeviceDelta], merge_v,
-                        key_offset) -> Tuple[DeviceDelta, dict]:
-    """Two-arena join: both sides are append logs; both δ-products are
-    key-matched pair enumerations (δA against the old right arena, δB
-    against the post-fold left arena — the bilinear update δA⋈B +
-    (A+δA)⋈δB) at static budgets of ``product_slack x delta_capacity``
-    pair slots. Sticky error on budget or arena overflow."""
-    err = state["error"]
-    new_state = dict(state)
-    outs = []
-
-    if da is not None:
-        out_a, ovf = _keyed_product(
-            da.keys, da.values, da.weights,
-            state["rkeys"], state["rvals"], state["rw"],
-            K, op.product_slack * da.capacity,
-            lambda k, vd, va_: merge_v(k - key_offset, vd, va_),
-            key_offset)
-        err = err | ovf
-        outs.append(out_a)
-        larena = {"rkeys": state["lkeys"], "rvals": state["lvals"],
-                  "rw": state["lrw"], "rcount": state["lcount"],
-                  "gen": state["lgen"]}
-        La = state["lkeys"].shape[0]
-        larena, lovf = _append_arena(larena, da.keys, da.values,
-                                     da.weights, La)
-        err = err | lovf
-        new_state.update(lkeys=larena["rkeys"], lvals=larena["rvals"],
-                         lrw=larena["rw"], lcount=larena["rcount"],
-                         lgen=larena["gen"])
-
-    if db is not None:
-        # (A + δA) ⋈ δB : delta is the RIGHT side, arena the LEFT — swap
-        # the value argument order back to merge(k, va, vb)
-        out_b, ovf = _keyed_product(
-            db.keys, db.values, db.weights,
-            new_state["lkeys"], new_state["lvals"], new_state["lrw"],
-            K, op.product_slack * db.capacity,
-            lambda k, vd, va_: merge_v(k - key_offset, va_, vd),
-            key_offset)
-        err = err | ovf
-        outs.append(out_b)
-        rarena = {"rkeys": state["rkeys"], "rvals": state["rvals"],
-                  "rw": state["rw"], "rcount": state["rcount"],
-                  "gen": state["gen"]}
-        rarena, rovf = _append_arena(rarena, db.keys, db.values,
-                                     db.weights, R)
-        err = err | rovf
-        new_state.update(rkeys=rarena["rkeys"], rvals=rarena["rvals"],
-                         rw=rarena["rw"], rcount=rarena["rcount"],
-                         gen=rarena["gen"])
-
-    out = _cat_deltas(outs)
-    new_state["error"] = err
-    return out, new_state
-
-
-def _keyed_product(dk, dv, dw, ak, av, aw, K: int, T: int, emit,
-                   key_offset) -> Tuple[DeviceDelta, jax.Array]:
-    """Key-matched delta×arena pair enumeration at static budget ``T``.
-
-    For each live delta row i, pair it with every live arena row sharing
-    its key; pairs pack into ``T`` slots via the same scatter-of-starts +
-    cumsum slot assignment the fused fixpoint's budget tiers use
-    (linear_fixpoint.budget_tab — measured ~13x over searchsorted at 1M
-    slots). A true pair count beyond ``T`` returns overflow=True (the
-    caller sets the sticky error; never silent truncation).
-    ``emit(keys_global, v_delta, v_arena)`` -> merged values [T, ...].
-    """
-    C = dk.shape[0]
-    R = ak.shape[0]
-    # CSR over the arena by key (sorted view; dead rows to the sentinel)
-    skey = jnp.where(aw != 0, jnp.clip(ak, 0, K - 1), K)
-    order = jnp.argsort(skey)
-    deg = jnp.zeros((K + 1,), jnp.int32).at[skey].add(1, mode="drop")[:K]
-    starts = jnp.cumsum(deg) - deg
-    # per-delta-row segment geometry
-    k_c = jnp.clip(dk, 0, K - 1)
-    di = jnp.where(dw != 0, deg[k_c], 0)
-    cum = jnp.cumsum(di)
-    total = cum[-1]
-    seg0 = cum - di
-    overflow = total > T
-    # slot -> owning delta ROW INDEX: scatter each segment's row index at
-    # its start slot, forward-fill with a running max (row indices rise
-    # with slot position, so cummax is exactly last-segment-started; a
-    # segment-ORDINAL cumsum would be wrong whenever dead/unmatched delta
-    # rows interleave with live ones, e.g. after sharded _localize)
-    spos = jnp.where(di > 0, seg0, T)
-    marks = jnp.zeros((T,), jnp.int32).at[spos].max(
-        jnp.arange(C, dtype=jnp.int32), mode="drop")
-    owner = jnp.clip(jax.lax.cummax(marks), 0, C - 1)
-    j = jnp.arange(T, dtype=jnp.int32)
-    within = j - seg0[owner]
-    valid = (j < total) & (di[owner] > 0) & (within < di[owner])
-    srow = jnp.clip(starts[k_c[owner]] + within, 0, R - 1)
-    row = order[srow]
-    k = k_c[owner]
-    w = jnp.where(valid, dw[owner] * aw[row], 0)
-    vals = emit(k + key_offset, dv[owner], av[row])
-    return DeviceDelta(k + key_offset, vals, w), overflow
-
-
-def _join_core_indexed(op: Join, K: int, R: int, state,
-                       da: Optional[DeviceDelta], db: Optional[DeviceDelta],
-                       merge_v, key_offset) -> Tuple[DeviceDelta, dict]:
-    """Unique-left join over an indexed arena (``arena.index_*``): the
-    same bilinear update as the dense path, δA ⋈ B_old + (A+δA) ⋈ δB,
-    with the first product a key-matched pair enumeration at a static
-    budget of ``product_slack x delta capacity`` slots (sticky error
-    past it) and not a gather over the whole arena, so a tick's output
-    and cost follow the delta. Counts its work (``OP_COUNTERS``). Room
-    for the appends is made between ticks (:func:`join_reindex`): an
-    append past the arena's end latches the sticky error."""
-    from reflow_tpu.executors.arena import index_append, index_probe
-
-    st = dict(state)
-    err = state["error"]
-    outs = []
-    zero = jnp.zeros((), jnp.int32)
-    late = pairs = steps = retracted = zero
-
-    if da is not None:
-        with jax.named_scope("join.probe"):
-            wa = da.weights
-            own, row, valid, ovf, steps = index_probe(
-                st, da.keys, wa != 0, op.product_slack * da.capacity)
-            err = err | ovf
-            k = jnp.clip(da.keys, 0, K - 1)[own]
-            w = jnp.where(valid, wa[own] * st["rw"][row], 0)
-            vals = merge_v(k, da.values[own], st["rvals"][row])
-            outs.append(DeviceDelta(k + key_offset, vals, w))
-            late = jnp.sum((w != 0).astype(jnp.int32))
-            # fold δA into the left table
-            st["lw"] = st["lw"].at[da.keys].add(wa)
-            st["lval"] = st["lval"].at[
-                jnp.where(wa > 0, da.keys, K)].set(da.values, mode="drop")
-
-    if db is not None:
-        with jax.named_scope("join.append"):
-            kb, vb, wb = db.keys, db.values, db.weights
-            w = st["lw"][kb] * wb
-            vals = merge_v(kb, st["lval"][kb], vb)
-            outs.append(DeviceDelta(kb + key_offset, vals, w))
-            pairs = jnp.sum((w != 0).astype(jnp.int32))
-            retracted = jnp.sum((wb < 0).astype(jnp.int32))
-            st, ovf = index_append(st, kb, vb, wb)
-            err = err | ovf
-
-    st["error"] = err
-    st["counters"] = (state["counters"]
-                      + jnp.stack([pairs + late, late, zero, zero, zero,
-                                   steps, zero, zero, zero, retracted])
-                      ).at[2].set(st["rcount"])
-    out = _cat_deltas(outs)
-    return out, st
-
-
-def join_reindex(state: dict) -> dict:
-    """Compact an indexed join's arena and rebuild its index
-    (``arena.reindex``), counted: the program the executor runs between
-    ticks when the arena might not hold a window's appends."""
-    from reflow_tpu.executors.arena import reindex
-
-    st = reindex(state)
-    st["counters"] = st["counters"].at[3].add(1).at[4].add(1)
-    return st
-
-
-def _view_product(state: dict, halves, sweep, merge_v, key_offset
-                  ) -> Tuple[DeviceDelta, jax.Array, jax.Array]:
-    """δA ⋈ B_old of a unique-left join that keeps the key-sorted view
-    of its arena (``arena.view_*``; a join under a loop): -> (its rows,
-    how many are live, 1 if the view gave them and 0 if the sweep did).
-
-    ``halves`` are the left delta's retract and insert rows as dense
-    ``(value [K], weight [K])`` tables, ``sweep()`` the gather of both
-    by every arena row. Under a loop a pass's left delta is the
-    frontier — some hundred of the cell's 65 536 keys — so the rows are
-    taken through the view instead: the arena rows of the keys either
-    half holds, laid into ``view_budget`` slots (``view_probe``), each
-    paired with both halves exactly as the sweep pairs it (same tables,
-    same ``merge``, dead and negative-weight arena rows alike), behind
-    them weight 0 up to the sweep's ``2 R`` slots, so whoever reads the
-    rows sees one capacity. What the probe would lay out (the arena
-    rows of the keys either half holds, each once: a retracted and
-    re-inserted key shares its slots between the halves) decides on the
-    device (``lax.cond``): past the budget the pass sweeps as before.
-    Nothing is dropped, nothing latches, and a pass's rows are never
-    split between the two."""
-    from reflow_tpu.executors.arena import view_budget, view_probe
-
-    av, aw = state["rvals"], state["rw"]
-    K, R = state["view_deg"].shape[0], aw.shape[0]
-    T = view_budget(K, R)
-    held = halves[0][1] != 0
-    for _, dw in halves[1:]:
-        held = held | (dw != 0)
-    n_slots = jnp.sum(jnp.where(held, state["view_deg"], 0))
-
-    def probe():
-        with jax.named_scope("join.view_probe"):
-            k, row, valid = view_probe(state, held, T)
-            a_v, a_w = av[row], jnp.where(valid, aw[row], 0)
-            rows = [DeviceDelta(k + key_offset, merge_v(k, tab[k], a_v),
-                                dw[k] * a_w) for tab, dw in halves]
-            vals = rows[0].values
-            pad = 2 * (R - T)
-            rows.append(DeviceDelta(
-                jnp.zeros((pad,), rows[0].keys.dtype),
-                jnp.zeros((pad,) + vals.shape[1:], vals.dtype),
-                jnp.zeros((pad,), jnp.int32)))
-            return _cat_deltas(rows)
-
-    probed = n_slots <= T
-    out = jax.lax.cond(probed, probe, lambda: _cat_deltas(sweep()))
-    return out, out.nonzero(), probed.astype(jnp.int32)
-
-
-def join_core(op: Join, K: int, R: int, odtype, state,
-              da: Optional[DeviceDelta], db: Optional[DeviceDelta],
-              key_offset=0, oshape=None) -> Tuple[DeviceDelta, dict]:
-    """The join kernel over a (possibly per-shard) key range.
-
-    ``da``/``db`` carry keys LOCAL to this range ``[0, K)``;
-    ``key_offset`` maps them back to global ids on emitted rows and in the
-    arguments handed to ``merge`` (the sharded path passes the shard base;
-    single-device passes 0). A ``None`` side is *statically* absent: the
-    corresponding product, fold, and append are not traced at all — a tick
-    that only delivers right-side deltas (the steady churn shape) never
-    sweeps the arena, and a loop pass with no right deltas never appends.
-
-    Unique-left state (dense ``lval``/``lw`` tables) takes the table×arena
-    path below, or, where the state carries an arena index (a loop-free
-    join: ``join_state(indexed=True)``), :func:`_join_core_indexed`;
-    multiset-left state (a second ``lkeys``/... append arena) takes
-    :func:`_join_core_multiset`. On the path below a state that carries
-    the key-sorted view (a join under a loop: ``join_state(viewed=True)``)
-    takes δA ⋈ B_old through it where the arena rows of the pass's keys fit the budget
-    (:func:`_view_product`) and re-sorts it behind every append; a state
-    without one (the sharded executor's, a declared-linear left's) sweeps
-    the arena in every pass that has a left delta.
-    """
-
-    def merge_v(keys, va, vb):
-        if op.merge is None:
-            # default merge (multiset path): concatenate the flattened
-            # value pair — the device encoding of the host oracle's
-            # (va, vb) tuple (same flat components, same order)
-            n = va.shape[0]
-            out = jnp.concatenate(
-                [jnp.asarray(va, odtype).reshape(n, -1),
-                 jnp.asarray(vb, odtype).reshape(n, -1)], axis=-1)
-            return out.reshape((n,) + tuple(oshape))
-        out = op.merge(keys + key_offset, va, vb)
-        return jnp.asarray(out, odtype)
-
-    if "lkeys" in state:
-        return _join_core_multiset(op, K, R, state, da, db, merge_v,
-                                   key_offset)
-
-    if "head" in state:
-        return _join_core_indexed(op, K, R, state, da, db, merge_v,
-                                  key_offset)
-
-    ak, av, aw = state["rkeys"], state["rvals"], state["rw"]
-    lval, lw = state["lval"], state["lw"]
-    viewed = "view_order" in state
-    outs = []
-    zero = jnp.zeros((), jnp.int32)
-    late = probed = zero
-
-    if da is not None:
-        # split δA into its retract / insert halves, scattered dense
-        wa = da.weights
-        ret_keys = jnp.where(wa < 0, da.keys, K)
-        ins_keys = jnp.where(wa > 0, da.keys, K)
-        zero_val = jnp.zeros((K,) + da.values.shape[1:], da.values.dtype)
-        zero_w = jnp.zeros((K,), jnp.int32)
-        dval_r = zero_val.at[ret_keys].set(da.values, mode="drop")
-        dw_r = zero_w.at[ret_keys].set(wa, mode="drop")
-        dval_i = zero_val.at[ins_keys].set(da.values, mode="drop")
-        dw_i = zero_w.at[ins_keys].set(wa, mode="drop")
-        halves = ((dval_r, dw_r), (dval_i, dw_i))
-
-        def sweep():
-            # δA ⋈ B_old : pure gather over the arena (the SpMV)
-            rows = []
-            for tab, dw in halves:
-                w = dw[ak] * aw
-                vals = merge_v(ak, tab[ak], av)
-                rows.append(DeviceDelta(ak + key_offset, vals, w))
-            return rows
-
-        if viewed:
-            out_a, late, probed = _view_product(state, halves, sweep,
-                                                merge_v, key_offset)
-            outs.append(out_a)
-        else:
-            outs += sweep()
-
-        # fold δA into the left table
-        lw = lw.at[da.keys].add(wa)
-        lval = lval.at[ins_keys].set(da.values, mode="drop")
-
-    rkeys, rvals, rw, rcount = ak, av, aw, state["rcount"]
-    err = state.get("error", jnp.zeros((), jnp.bool_))
-    view = ({"view_order": state["view_order"],
-             "view_deg": state["view_deg"]} if viewed else {})
-    if db is not None:
-        # (A + δA) ⋈ δB
-        kb, vb, wb = db.keys, db.values, db.weights
-        w = lw[kb] * wb
-        vals = merge_v(kb, lval[kb], vb)
-        db_out = DeviceDelta(kb + key_offset, vals, w)
-        outs.append(db_out)
-
-        # append δB to the arena (compacted: live rows first). The
-        # high-water check is IN-PROGRAM: when the append would cross
-        # capacity, a lax.cond runs the compaction kernel (cancel matched
-        # insert/retract pairs) first — the decision never reads a device
-        # value back to the host, so streaming ticks stay pipelined
-        # (SURVEY.md §7 hard part d). A genuine overflow (live rows +
-        # appends > capacity even after compaction) drops the excess rows
-        # and sets the sticky error flag, raised at the next sync point.
-        from reflow_tpu.executors.arena import (compact_arena, view_count,
-                                                view_sort)
-
-        liveb = wb != 0
-        n_app = jnp.sum(liveb.astype(jnp.int32))
-        arena = {"rkeys": ak, "rvals": av, "rw": aw,
-                 "rcount": state["rcount"], "gen": state["gen"]}
-        compacted = compact_arena
-        if viewed:
-            # the view follows the arena: a compaction rewrites every
-            # row, so the rows a key has are recounted behind it; an
-            # append counts its own rows in (a scatter of the delta's
-            # slots, not the arena's) and the order is sorted anew,
-            # once a pass that appends (under a fixpoint program once a
-            # tick, for every pass of the loop to probe)
-            arena["view_deg"] = state["view_deg"]
-
-            def compacted(s):
-                s = compact_arena(s)
-                return dict(s, view_deg=view_count(s["rkeys"], s["rw"], K))
-        arena = jax.lax.cond(arena["rcount"] + n_app > R,
-                             compacted, lambda s: s, arena)
-        rank = jnp.cumsum(liveb.astype(jnp.int32)) - 1
-        pos = jnp.where(liveb, arena["rcount"] + rank, R)
-        rkeys = arena["rkeys"].at[pos].set(kb, mode="drop")
-        rvals = arena["rvals"].at[pos].set(vb, mode="drop")
-        rw = arena["rw"].at[pos].set(wb, mode="drop")
-        rcount = arena["rcount"] + n_app
-        gen = arena["gen"]
-        err = err | (rcount > R)
-        if viewed:
-            with jax.named_scope("join.view_sort"):
-                view = {
-                    "view_order": view_sort(rkeys, rw, K),
-                    "view_deg": arena["view_deg"].at[
-                        jnp.where(pos < R, jnp.clip(kb, 0, K - 1), K)
-                    ].add(1, mode="drop")}
-    else:
-        gen = state["gen"]
-
-    out = _cat_deltas(outs)
-    new_state = {"lval": lval, "lw": lw, "rkeys": rkeys, "rvals": rvals,
-                 "rw": rw, "rcount": rcount, "gen": gen, "error": err,
-                 **view}
-    if viewed:
-        # a loop's join counts what it did (``OP_COUNTERS``): whether a
-        # pass has a left delta is static (``da`` is there or it is
-        # not), which way its product went is the device's. A sweep
-        # passes over the arena's whole capacity twice, once for the
-        # retracted and once for the inserted left rows; a probe lays
-        # each of the two into its budget of slots
-        from reflow_tpu.executors.arena import view_budget
-
-        n_right = db_out.nonzero() if db is not None else zero
-        left = da.nonzero() if da is not None else zero
-        swept = (0 if da is None else 1) - probed
-        retracted = (jnp.sum((db.weights < 0).astype(jnp.int32))
-                     if db is not None else zero)
-        new_state["counters"] = (
-            state["counters"]
-            + jnp.stack([late + n_right, late, 0, 0, gen - state["gen"], 0,
-                         swept,
-                         swept * 2 * R + probed * 2 * view_budget(K, R),
-                         left, retracted, probed]).astype(jnp.int32)
-        ).at[2].set(rcount)
-    return out, new_state
-
-
 # -- KnnIndex (SURVEY.md §2 item 14: vmapped cosine + Pallas top-k) --------
 
 #: op kind -> what its nodes count on the device, in the order of their
-#: ``counters`` state leaf (int32 each). A KnnIndex: ticks that rescanned
-#: the corpus, ticks that took the incremental merge, delta rows folded
-#: into its two tables (wraps after 2^31 rows), and the chunk sweeps its
-#: rescans' folds ran (``kernels.topk.fold_topk``; an incremental tick
-#: adds none; wraps, so a reader takes differences modulo 2^32). They
-#: ride in the state, so they cost no program output and no host sync;
-#: the executor reads them when a snapshot is taken
-#: (``TpuExecutor.op_counters``). New names are appended: readers go by
-#: position.
+#: ``counters`` state leaf (int32 each, cumulative since bind). Only
+#: nodes whose state has the leaf count. The counters ride in the state,
+#: so they cost no program output and no host sync; the executor reads
+#: them when a snapshot is taken (``TpuExecutor.op_counters``). New names
+#: are appended: readers go by position.
 #:
-#: An indexed join (``join_state(indexed=True)``): live pairs emitted,
-#: those of them the δA product found (a left row that arrived after its
-#: matches), the arena's rows now (a level, not a sum), index rebuilds
-#: and, of those, compactions for room (every one, since the executor
-#: rebuilds only to make room: ``join_reindex``), and the trips of the
-#: probe's
-#: chain walk (each a pass over its pair slots). A min/max reduce: keys
-#: a tick's delta touched, distinct value rows pushed out of a
-#: candidate buffer, blocks of ``_block_slots(C)`` slots its keyed
-#: tables were written by (``_over_blocks``: slots written = blocks x
-#: that), and ``merged_slots``, the ``C`` slots of the delta each merge
-#: ran over (int32, wraps: a reader differences modulo 2^32, as
-#: ``swept_rows``'). ``C`` is the merged delta's: where the merge
-#: follows the live rows (``_merge_rungs``) the rung a tick took, so
-#: under a ladder ``merged_slots`` sums the rungs taken and ``blocks``
-#: counts trips of whichever rung ran, an eighth of that rung each: the
-#: slots written are ``blocks`` x one size only where every tick took
-#: the same rung. Only nodes whose state has the leaf count.
-#:
-#: A unique-left join under a loop keeps the key-sorted view of its
-#: arena and no chained index (``join_state(viewed=True)``): of the
-#: names above ``pairs`` (live rows it emitted, all three products),
-#: ``late_pairs`` (those of the left delta's product, whichever way it
-#: went), ``arena_rows`` and ``compactions`` (in-program), and four of
-#: its own. Every pass in which the left side has a delta (the passes
-#: of a fixpoint but a tick's first) is one of two: ``probes``, the
-#: passes whose keys' arena rows fit ``arena.view_budget`` slots and were
-#: enumerated
-#: through the view, and ``sweeps``, the passes past it, which gathered
-#: by every arena row as a join without a view does. ``swept_rows``,
-#: the slots those passes read: ``2 x arena_capacity`` a sweep, live
-#: or not, and twice the budget a probe (its slots, once for the
-#: retracted and once for the inserted left rows; int32, wraps after
-#: 2^31 slots: a reader differences window by window, modulo 2^32),
-#: and ``left_rows``, the live rows of the left deltas folded into its
-#: table (retractions and inserts: under a loop, the frontier). An
-#: indexed join keeps the names up to ``retracted`` and leaves
-#: ``sweeps``, ``swept_rows`` and ``left_rows`` at 0. Both count
-#: ``retracted``, the rows appended to the arena with a negative weight
-#: (a right-side retraction is a row of the log until a compaction
-#: cancels it against its insert): what fills an arena whose live rows
-#: stay level.
-#:
-#: ``"loop"`` is no operator: the row fixpoint program
-#: (``fixpoint.FixpointProgram``) counts in the state of its region's
-#: first loop node, where the executor gave it the leaf: ``passes`` as
-#: ``TickResult.passes`` has them (phase A, every trip of the
-#: ``while_loop``, the exit pass), ``ticks``, and ``unquiesced``, the
-#: ticks whose loop stopped at ``max_iters`` with its carry alive.
+#: - ``"knn"``: ticks that rescanned the corpus, ticks that took the
+#:   incremental merge, delta rows folded into its two tables (wraps
+#:   after 2^31 rows), and the chunk sweeps its rescans' folds ran
+#:   (``kernels.topk.fold_topk``; an incremental tick adds none; wraps,
+#:   so a reader takes differences modulo 2^32).
+#: - ``"join"``: ``join.JOIN_COUNTERS``, which says what each counts.
+#: - ``"reduce"`` (min/max): keys a tick's delta touched, distinct value
+#:   rows pushed out of a candidate buffer, blocks of ``_block_slots(C)``
+#:   slots its keyed tables were written by (``_over_blocks``: slots
+#:   written = blocks x that), and ``merged_slots``, the ``C`` slots of
+#:   the delta each merge ran over (wraps: a reader differences modulo
+#:   2^32). ``C`` is the merged delta's: where the merge follows the
+#:   live rows (``_merge_rungs``) the rung a tick took, so under a ladder
+#:   ``merged_slots`` sums the rungs taken and ``blocks`` counts trips of
+#:   whichever rung ran, an eighth of that rung each.
+#: - ``"loop"`` is no operator: the row fixpoint program
+#:   (``fixpoint.FixpointProgram``) counts in the state of its region's
+#:   first loop node, where the executor gave it the leaf: ``passes`` as
+#:   ``TickResult.passes`` has them (phase A, every trip of the
+#:   ``while_loop``, the exit pass), ``ticks``, and ``unquiesced``, the
+#:   ticks whose loop stopped at ``max_iters`` with its carry alive.
 OP_COUNTERS = {"knn": ("rescans", "incremental", "rows_folded", "sweeps"),
-               "join": ("pairs", "late_pairs", "arena_rows",
-                        "index_rebuilds", "compactions", "probe_steps",
-                        "sweeps", "swept_rows", "left_rows", "retracted",
-                        "probes"),
+               "join": JOIN_COUNTERS,
                "reduce": ("touched", "evicted", "blocks", "merged_slots"),
                "loop": ("passes", "ticks", "unquiesced")}
 
@@ -1516,7 +978,7 @@ _LOWERINGS = {
     "groupby": _lower_groupby,
     "union": _lower_union,
     "reduce": _lower_reduce,
-    "join": _lower_join,
+    "join": lower_join,
     "knn": _lower_knn,
 }
 

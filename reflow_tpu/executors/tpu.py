@@ -21,27 +21,28 @@ serving tier — trace their window program once (``megatick_cache_hits``).
 from __future__ import annotations
 
 import contextlib
-import os
 import queue as _queue
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from reflow_tpu.delta import DeltaBatch
+from reflow_tpu.executors.arena import propagate_plan_caps
 from reflow_tpu.executors.base import Executor
 from reflow_tpu.executors.device_delta import (DeviceDelta, bucket_capacity,
                                                check_weight_mass, to_device,
                                                to_host)
-from reflow_tpu.executors.lowerings import (DEVICE_REDUCERS, join_reindex,
-                                            join_state, lower_node,
-                                            reduce_state)
+from reflow_tpu.executors import join as _join
+from reflow_tpu.executors.lowerings import (DEVICE_REDUCERS, OP_COUNTERS,
+                                            knn_state, lower_node,
+                                            minmax_refresh_core, reduce_state)
 from reflow_tpu.graph import FlowGraph, GraphError, Node
 from reflow_tpu.obs import threads as _threads
 from reflow_tpu.obs import trace as _trace
-from reflow_tpu.utils.config import env_int
 from reflow_tpu.utils.runtime import named_lock
 
 __all__ = ["TpuExecutor", "StagedWindow"]
@@ -102,8 +103,6 @@ def _completion_token(states, counter_ids=()):
     int32 vector, the scalar's bits followed by those counters: the
     watcher, which waits on the token anyway, then reads what each
     window left them at, for no further output and no dispatch."""
-    import jax.numpy as jnp
-
     tok = jnp.zeros((), jnp.float32)
     for x in jax.tree.leaves(states):
         if getattr(x, "size", 0):
@@ -252,7 +251,6 @@ def _value_token(v):
         return v
     if isinstance(v, tuple):
         return tuple(_value_token(x) for x in v)
-    import numpy as np
 
     if isinstance(v, np.generic):
         return (str(v.dtype), v.item())
@@ -277,8 +275,6 @@ def _fn_token(fn):
 
 
 def _spec_token(spec):
-    import numpy as np
-
     return (tuple(spec.value_shape), str(np.dtype(spec.value_dtype)),
             int(spec.key_space), bool(spec.unique))
 
@@ -309,11 +305,6 @@ def _node_token(node: Node):
 
 class TpuExecutor(Executor):
     name = "tpu"
-    #: unique-left joins keep an index of their arena (loop-free the
-    #: chained one, under a loop the key-sorted view), and they and the
-    #: min/max reduces count on the device (the sharded executor, whose
-    #: per-shard scalars ride as mesh-length vectors, keeps neither)
-    _index_joins = True
 
     @property
     def states(self):
@@ -321,35 +312,36 @@ class TpuExecutor(Executor):
 
     @states.setter
     def states(self, value):
-        # whoever hands over states (bind, a restored checkpoint, a
-        # snapshot) may bring arenas of any fill: what the host knew of
-        # the indexed arenas' room (``_make_room``) is void
+        # new states may bring arenas of any fill
         self._states = value
-        self._arena_used: Dict[int, int] = {}
+        self._room.forget()
 
     def __init__(self, *, fixpoint: bool = True, linear_fixpoint: bool = True):
+        #: room for the indexed joins' appends, made between ticks
+        self._room = _join.ArenaRoom()
         super().__init__()
         self._cache: Dict[tuple, object] = {}
         #: lower whole ticks of iterative graphs to one lax.while_loop
         #: program (False forces the host-driven per-pass loop)
         self.fixpoint = fixpoint
         #: allow the fused delta-vector loop for declared-linear regions
-        #: (False forces the row-based while_loop program)
+        #: (False forces the row-based while_loop program); it runs on
+        #: both the single-device and the sharded executor (the sharded
+        #: variant runs the loop inside one shard_map region — see
+        #: linear_fixpoint.py)
         self.linear_fixpoint = linear_fixpoint
         self._fx_structure = None
         self._fx_unsupported = not fixpoint
         #: mesh size for sharded subclasses: arena overflow is bounded
         #: against the per-shard slice (worst-case key skew)
         self._arena_divisor = 1
-        self._indexed_joins: set = set()
-        #: ``join_reindex`` compiled ahead of time, one executable a
-        #: join shape (``_reindex_program``)
-        self._reindex: Dict[tuple, object] = {}
-        #: the fused delta-vector loop runs on both the single-device and
-        #: the sharded executor (the sharded variant runs the loop inside
-        #: one shard_map region — see linear_fixpoint.py)
-        self._linear_fixpoint = linear_fixpoint
+        #: the bound graph's declared-linear structure
+        #: (``analyze_linear``); None while the fused loop is not in use
         self._linear_structure = None
+        #: ``_build_fixpoint`` asked and the fused loop does not fit this
+        #: graph (no such structure, or shapes outside its fused-f32
+        #: representation): it does not ask again until the next graph
+        self._linear_unfit = False
         #: class name of the fixpoint program ``_build_fixpoint`` last
         #: built ("LinearFixpointProgram" / "FixpointProgram"; None
         #: before the first loop tick, or when neither fit and the
@@ -365,7 +357,7 @@ class TpuExecutor(Executor):
         #: mega-tick window path (run_window): per-source host batches
         #: above this row bound don't fit a reasonable queue slot — the
         #: scheduler falls back to the per-tick path instead
-        self.megatick_max_rows = env_int("REFLOW_MEGATICK_MAX_ROWS")
+        self.megatick_max_rows = 1 << 16
         #: windows dispatched through the device-resident ingress queue
         self.window_dispatches = 0
         #: tenant placement: the jax.Device this executor's state, ingress
@@ -431,17 +423,12 @@ class TpuExecutor(Executor):
         """The bound graph's nodes whose lowering keeps counters in its
         device state (a ``counters`` leaf), each with their names; a
         loop node's are the row fixpoint program's."""
-        from reflow_tpu.executors.lowerings import OP_COUNTERS
-
         out = []
         for n in (self.graph.nodes if self.graph else ()):
             kind = n.op.kind if n.kind == "op" else n.kind
             st = self.states.get(n.id) or ()
             if kind in OP_COUNTERS and "counters" in st:
-                # a node keeps the first names of its kind, as many as
-                # its leaf is wide (an indexed join all but ``probes``:
-                # ROADMAP D23, one width for both)
-                out.append((n, OP_COUNTERS[kind][:st["counters"].shape[0]]))
+                out.append((n, OP_COUNTERS[kind]))
         return out
 
     def counter_names(self) -> Dict[str, Tuple[str, ...]]:
@@ -488,17 +475,24 @@ class TpuExecutor(Executor):
 
     # -- bind: validate lowerability, build device state -------------------
 
+    def _indexes_joins(self) -> bool:
+        """Do unique-left joins keep an index of their arena
+        (``join.join_layout``), and do they and the row fixpoint's loop
+        count on the device? The sharded executor, whose per-shard
+        scalars ride as mesh-length vectors, overrides this: neither."""
+        return True
+
     def _row_fixpoint_loop(self, graph: FlowGraph) -> Optional[Node]:
         """The loop node in whose state the row fixpoint program counts
         (``OP_COUNTERS["loop"]``), or None: where the graph's ticks will
         not run on ``FixpointProgram`` as far as the graph alone says
         (no loop, fusion off, no on-device structure, or a region the
-        fused linear program takes), and on the sharded executor, which
-        keeps no counters."""
+        fused linear program takes), and where the executor keeps no
+        counters (``_indexes_joins``)."""
         from reflow_tpu.executors.fixpoint import analyze
         from reflow_tpu.executors.linear_fixpoint import analyze_linear
 
-        if not (self._index_joins and self.fixpoint and graph.loops):
+        if not (self._indexes_joins() and self.fixpoint and graph.loops):
             return None
         structure = analyze(graph)
         if structure is None or (
@@ -516,18 +510,15 @@ class TpuExecutor(Executor):
             self._fx_structure = None
             self._fx_unsupported = not self.fixpoint
             self._linear_structure = None
-            self._linear_fixpoint = self.linear_fixpoint
+            self._linear_unfit = False
         # state is reset below: any sorted-arena cache is now stale (the
         # (gen, rcount) predicate would also catch this via count > rcount,
         # but an explicit drop is cheaper than relying on it)
         self._csr_cache.clear()
         self.graph = graph
         self.states = {}
-        self._indexed_joins = set()
         counted_loop = self._row_fixpoint_loop(graph)
         if counted_loop is not None:
-            from reflow_tpu.executors.lowerings import OP_COUNTERS
-            import jax.numpy as jnp
             self.states[counted_loop.id] = {"counters": jnp.zeros(
                 (len(OP_COUNTERS["loop"]),), jnp.int32)}
         for loop in graph.loops:
@@ -537,8 +528,6 @@ class TpuExecutor(Executor):
                 # observables [K, P+1] (flattened dval columns + dw).
                 # SEMANTIC state — checkpointed with the state tree,
                 # unlike the derived CSR cache (docs/guide.md).
-                import jax.numpy as jnp
-                import numpy as np
                 K = loop.spec.key_space
                 if K <= 0:
                     raise GraphError(
@@ -560,7 +549,6 @@ class TpuExecutor(Executor):
                             f"{type(leaf).__name__}; close fn over static "
                             f"(shape-driving) config instead of passing it "
                             f"in params")
-                import jax.numpy as jnp
                 # deep-copy: tick programs DONATE state, and aliasing the
                 # caller's arrays would delete them out from under the
                 # user on the first tick
@@ -594,42 +582,28 @@ class TpuExecutor(Executor):
                     raise GraphError(
                         f"{node}: corpus key_space {D} must be a multiple "
                         f"of scan_chunk {op.scan_chunk}")
-                from reflow_tpu.executors.lowerings import knn_state
                 self.states[node.id] = knn_state(op, *in_specs)
             elif op.kind == "join":
                 if op.merge is None:
                     # the default merge lowers to the flattened
                     # concatenation of (va, vb) — the device encoding of
                     # the host oracle's tuple; the out Spec must size it
-                    import numpy as _np
-                    flat = int(_np.prod(in_specs[0].value_shape or (1,))
-                               ) + int(_np.prod(in_specs[1].value_shape
+                    flat = int(np.prod(in_specs[0].value_shape or (1,))
+                               ) + int(np.prod(in_specs[1].value_shape
                                                 or (1,)))
-                    got = int(_np.prod(node.spec.value_shape or (1,)))
+                    got = int(np.prod(node.spec.value_shape or (1,)))
                     if got != flat:
                         raise GraphError(
                             f"{node}: default-merge device Join needs a "
                             f"spec with {flat} flat value elements "
                             f"(va ++ vb), got {node.spec.value_shape}")
-                # a unique-left join keeps one of two indexes of its
-                # arena, by what the graph says of its traffic (module
-                # docstring of ``arena``): loop-free, the chained index,
-                # appended to in every tick and probed once; under a
-                # loop, where the arena is appended to once a tick and
-                # probed by every pass's frontier, the key-sorted view
-                # (a declared-linear left belongs to the fused linear
-                # fixpoint, which keeps a CSR cache of its own). Both
-                # count on the device
-                keyed = (self._index_joins and in_specs[0].unique
-                         and not op.linear_left)
-                indexed = keyed and not graph.loops
-                self.states[node.id] = join_state(
-                    op, in_specs[0], in_specs[1], indexed,
-                    keyed and not indexed)
-                if indexed:
-                    self._indexed_joins.add(node.id)
+                self.states[node.id] = _join.join_state(
+                    op, in_specs[0], in_specs[1], _join.join_layout(
+                        op, in_specs[0], looped=bool(graph.loops),
+                        index=self._indexes_joins()))
             else:
                 raise GraphError(f"{node}: no TPU lowering for {op.kind}")
+        self._room.bind(graph, self.states)
         if self.device is not None:
             # placed BEFORE bind: move the freshly-built state tree onto
             # the pinned device (the jnp.zeros above land on the default)
@@ -668,7 +642,7 @@ class TpuExecutor(Executor):
             self._cache[sig] = fn
 
         # fail loudly BEFORE truncation
-        self._make_room(self._track_arena(
+        self._room.make(self._states, self._track_arena(
             plan, {nid: d.capacity for nid, d in dev_ingress.items()}), 1)
         op_states = {nid: st for nid, st in self.states.items()}
         new_states, egress_dev = fn(op_states, dev_ingress)
@@ -1054,8 +1028,7 @@ class TpuExecutor(Executor):
                 # with the window program, what makes room for it: a
                 # compaction inside a served window is then a dispatch
                 # and never a compile
-                for nid in sorted(self._indexed_joins):
-                    self._reindex_program(nid)
+                self._room.compile(self._states)
                 shared_sig = self._window_signature(plan, caps)
                 if shared_sig is not None:
                     shared_sig += sig[3:]
@@ -1084,7 +1057,6 @@ class TpuExecutor(Executor):
                         # donated, and returning new zeros (not the dead
                         # input) lets XLA alias the donated memory while
                         # giving the ingress queue valid buffers to adopt
-                        import jax.numpy as jnp
                         out = (states,
                                jax.tree.map(jnp.zeros_like, ing_stack))
                         if with_token:
@@ -1098,7 +1070,7 @@ class TpuExecutor(Executor):
                             prog = _SHARED_WINDOW_PROGRAMS.setdefault(
                                 shared_sig, prog)
                 self._cache[sig] = prog
-            self._make_room(self._track_arena(plan, caps), K)
+            self._room.make(self._states, self._track_arena(plan, caps), K)
             kind = "window" if window else "pass_many"
             t_d0 = time.perf_counter() if tr else 0.0
             c_d0 = time.thread_time() if tr else 0.0
@@ -1177,15 +1149,11 @@ class TpuExecutor(Executor):
         transfer per ingress column instead of K separate uploads. The
         upload follows the executor's ingress placement (pinned device,
         or sharded capacity axis on the mesh subclass)."""
-        import numpy as _np
-
-        import jax.numpy as _jnp
-
         place = self._ingress_placement()
 
         def _up(x):
             if place is None:
-                return _jnp.asarray(x)
+                return jnp.asarray(x)
             if isinstance(place, tuple):
                 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1202,18 +1170,18 @@ class TpuExecutor(Executor):
             spec = self.graph.nodes[nid].spec
             cap = max(bucket_capacity(len(f[nid])) for f in feeds)
             caps[nid] = cap
-            keys = _np.zeros((K, cap), _np.int32)
-            weights = _np.zeros((K, cap), _np.int32)
-            values = _np.zeros((K, cap) + tuple(spec.value_shape),
+            keys = np.zeros((K, cap), np.int32)
+            weights = np.zeros((K, cap), np.int32)
+            values = np.zeros((K, cap) + tuple(spec.value_shape),
                                spec.value_dtype)
             for t, f in enumerate(feeds):
                 b = f[nid]
                 check_weight_mass(b)   # same host-boundary guard as to_device
                 n = len(b)
                 if n:
-                    keys[t, :n] = b.keys.astype(_np.int64)
+                    keys[t, :n] = b.keys.astype(np.int64)
                     weights[t, :n] = b.weights
-                    values[t, :n] = _np.asarray(b.values).reshape(
+                    values[t, :n] = np.asarray(b.values).reshape(
                         (n,) + tuple(spec.value_shape))
             stack[nid] = DeviceDelta(_up(keys), _up(values), _up(weights))
         return stack, caps
@@ -1227,12 +1195,11 @@ class TpuExecutor(Executor):
             LinearFixpointProgram, analyze_linear)
 
         prog = None
-        if self._linear_fixpoint:
+        if self.linear_fixpoint and not self._linear_unfit:
             if self._linear_structure is None:
                 self._linear_structure = analyze_linear(
                     self.graph, self._fx_structure)
-                if self._linear_structure is None:
-                    self._linear_fixpoint = False
+                self._linear_unfit = self._linear_structure is None
             if self._linear_structure is not None:
                 try:
                     prog = LinearFixpointProgram(
@@ -1242,8 +1209,8 @@ class TpuExecutor(Executor):
                 except ValueError:
                     # shapes don't fit the fused-f32 representation; use
                     # the row-based program below
-                    self._linear_fixpoint = False
                     self._linear_structure = None
+                    self._linear_unfit = True
         if prog is None:
             try:
                 prog = FixpointProgram(self, plan, caps, max_iters,
@@ -1265,8 +1232,6 @@ class TpuExecutor(Executor):
         Because params are program *arguments* (op state), this triggers
         no recompilation — the next tick simply runs with the new values.
         """
-        import jax.numpy as jnp
-
         if node.id not in self.states or "params" not in self.states[node.id]:
             raise GraphError(f"{node} holds no params state")
         fresh = {
@@ -1292,8 +1257,6 @@ class TpuExecutor(Executor):
         replay sets the sticky error instead). Call between ticks, from
         the same host thread that ticks (node validation lives in the
         scheduler wrapper — the one call site)."""
-        from reflow_tpu.executors.lowerings import minmax_refresh_core
-
         d = to_device(batch, node.inputs[0].spec, device=self.device)
         K = node.inputs[0].spec.key_space
         sig = ("mmrefresh", node.id, d.capacity)
@@ -1332,28 +1295,13 @@ class TpuExecutor(Executor):
                     "is invalid — re-run on the CPU executor or widen "
                     "the buffer")
         if node.kind == "op" and node.op.kind == "join":
-            return ("join sticky error: an arena overflowed (live rows + "
-                    "appends exceeded capacity even after compaction, "
-                    "in-program or, for an indexed arena, between "
-                    "windows — raise arena_capacity / "
-                    "left_arena_capacity); or a multiset-left or indexed "
-                    "delta-by-arena product exceeded its pair budget of "
-                    "product_slack x delta capacity (raise product_slack); or, "
-                    "under a sharded executor, sparse routing overflowed "
-                    "its per-destination budget (key skew — raise delta "
-                    "capacity or rebalance the key space); or a downstream "
-                    "GroupBy's stable_key=True declaration was violated "
-                    "(its key_fn read the loop value — the fused fixpoint's "
-                    "dense tier caught a precomputed/runtime destination "
-                    "mismatch); this tick's state is invalid")
+            return _join.ERROR_REASON
         return ("sticky device error flag set (sparse-route overflow: key "
                 "skew exceeded the ROUTE_SLACK per-destination budget); "
                 "this tick's state is invalid — raise the delta capacity "
                 "or rebalance the key space")
 
     def read_table(self, node: Node):
-        import numpy as np
-
         st = self.states.get(node.id)
         if st is None:
             raise KeyError(f"{node} holds no materialized state")
@@ -1368,7 +1316,7 @@ class TpuExecutor(Executor):
         if node.op.kind == "join":
             if "error" in st and bool(st["error"]):
                 raise RuntimeError(f"{node}: {self._error_reason(node)}")
-            if "lkeys" in st:
+            if _join.layout_of(st) == "multiset":
                 raise KeyError(
                     f"{node}: a multiset-left join has no unique left "
                     f"table to read; attach a sink to observe its output")
@@ -1384,91 +1332,15 @@ class TpuExecutor(Executor):
         raise KeyError(f"{node} ({node.op.kind}) has no table to read")
 
     def _track_arena(self, plan, ingress_caps: Dict[int, int]):
-        """Static per-tick capacity sanity for Join arenas.
-
-        The *dynamic* high-water check lives inside the compiled tick
-        program: a ``lax.cond`` runs the compaction kernel when an append
-        would cross capacity, and a genuine overflow sets the join state's
-        sticky ``error`` flag (raised at the next sync point). No device
-        value is ever read back here — streaming ticks stay pipelined.
-        This host check only rejects the statically impossible case: one
-        tick's right-delta capacity exceeding the whole (per-shard) arena.
-        ``ingress_caps`` maps seeded node ids (sources, loops, fixpoint
-        boundary producers) to their delta capacities. The propagation
-        itself lives in :func:`arena.propagate_plan_caps` so the
-        mega-tick ingress queue negotiates against the same rules.
-        Returns the per-node capacities it found (``_make_room`` reads
-        the indexed joins' right-delta capacities from them).
-        """
-        from reflow_tpu.executors.arena import propagate_plan_caps
-
+        """Static per-tick capacity sanity for Join arenas
+        (:func:`arena.propagate_plan_caps`; the dynamic high-water check
+        is the compiled program's): rejects one tick's right-delta
+        capacity exceeding the whole (per-shard) arena. ``ingress_caps``
+        maps seeded node ids (sources, loops, fixpoint boundary
+        producers) to their delta capacities. Returns the per-node
+        capacities it found, for ``ArenaRoom.make``."""
         return propagate_plan_caps(plan, ingress_caps, self._arena_divisor,
-                                   self._indexed_joins)
-
-    def _reindex_program(self, nid: int):
-        """``join_reindex`` for the indexed join ``nid``, compiled ahead
-        of time for its state's shapes (and the device that holds it)
-        and kept by them: joins of one shape share a program. The
-        executor compiles every indexed join's when it builds its first
-        window program, so making room between two served windows
-        dispatches and never compiles."""
-        state = self._states[nid]
-        sig = tuple((name, x.shape, str(x.dtype), str(x.sharding))
-                    for name, x in sorted(state.items()))
-        prog = self._reindex.get(sig)
-        if prog is None:
-            prog = jax.jit(join_reindex, donate_argnums=0).lower(
-                state).compile()
-            self._reindex[sig] = prog
-        return prog
-
-    def _arena_rows(self, nid: int) -> int:
-        """An indexed arena's true row count, read from the device: it
-        waits for every window dispatched so far (``arena_rcount_read``
-        says for how long)."""
-        tr = _trace.ENABLED
-        t0 = time.perf_counter() if tr else 0.0
-        used = int(self._states[nid]["rcount"])
-        if tr:
-            _trace.evt("arena_rcount_read", t0, time.perf_counter() - t0,
-                       args=_trace.with_win(
-                           {"node": self.graph.nodes[nid].name,
-                            "rows": used}))
-        return used
-
-    def _make_room(self, caps: Dict[int, int], ticks: int) -> None:
-        """Before ``ticks`` ticks at the per-node capacities ``caps``
-        (``_track_arena``'s): every indexed join's arena has room for
-        what they can append. The tick program only appends (an append
-        past the end latches the sticky error); compacting an arena and
-        rebuilding its index is ``join_reindex``, run from here when an
-        arena might not hold the appends. The host keeps an upper bound
-        of each arena's rows (every tick adds its right delta's whole
-        capacity) and reads the true count from the device only when the
-        bound reaches the end: one sync per ``arena_capacity`` rows of
-        capacity dispatched, and none in a tick. Under tracing a
-        ``join_reindex`` span runs from the program's dispatch to the
-        count read behind it, which is when the device finished it."""
-        for nid in self._indexed_joins:
-            node = self.graph.nodes[nid]
-            need = ticks * caps.get(node.inputs[1].id, 0)
-            if not need:
-                continue
-            R = node.op.arena_capacity
-            used = self._arena_used.get(nid)
-            if used is None or used + need > R:
-                used = self._arena_rows(nid)
-            if used + need > R:
-                before = used
-                t0 = time.perf_counter()
-                self._states[nid] = self._reindex_program(nid)(
-                    self._states[nid])
-                used = int(self._states[nid]["rcount"])
-                _trace.evt("join_reindex", t0, time.perf_counter() - t0,
-                           args=_trace.with_win(
-                               {"node": node.name, "rows_before": before,
-                                "rows_after": used}))
-            self._arena_used[nid] = used + need
+                                   self._room.joins)
 
     # -- trace & compile one pass program ----------------------------------
 

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from reflow_tpu.executors.device_delta import MIN_CAPACITY, DeviceDelta
+from reflow_tpu.executors.join import layout_of
 from reflow_tpu.executors.tpu import TpuExecutor
 from reflow_tpu.graph import FlowGraph, GraphError, Node
 from reflow_tpu.parallel.mesh import make_mesh, replicate
@@ -100,7 +101,8 @@ class ShardedTpuExecutor(TpuExecutor):
 
     # -- bind: divisibility validation + sharded state placement -----------
 
-    _index_joins = False
+    def _indexes_joins(self) -> bool:
+        return False
 
     def bind(self, graph: FlowGraph) -> None:
         super().bind(graph)
@@ -168,7 +170,7 @@ class ShardedTpuExecutor(TpuExecutor):
                 self.states[node.id]["rcount"] = jnp.zeros((n,), jnp.int32)
                 self.states[node.id]["gen"] = jnp.zeros((n,), jnp.int32)
                 self.states[node.id]["error"] = jnp.zeros((), jnp.bool_)
-                if "lkeys" in self.states[node.id]:
+                if layout_of(self.states[node.id]) == "multiset":
                     La = node.op.left_arena_capacity or node.op.arena_capacity
                     if La % n:
                         raise GraphError(

@@ -43,9 +43,10 @@ import jax
 import jax.numpy as jnp
 
 from reflow_tpu.executors.device_delta import DeviceDelta
+from reflow_tpu.executors.join import join_core, layout_of
 from reflow_tpu.executors.lowerings import (_LOWERINGS, LINEAR_DEVICE_REDUCERS,
                                             _agg_tables, _bcast_w, _differs,
-                                            _scatter_contribs, join_core)
+                                            _scatter_contribs)
 from reflow_tpu.graph import Node
 
 __all__ = ["lower_node_sharded", "route_rows", "deliver_to_owner",
@@ -335,7 +336,7 @@ def _lower_join_sharded(op, node: Node, state, ins, axis, n: int,
     core_state = dict(state)
     core_state["rcount"] = state["rcount"][0]
     core_state["gen"] = state["gen"][0]
-    multiset = "lkeys" in state
+    multiset = layout_of(state) == "multiset"
     if multiset:
         core_state["lcount"] = state["lcount"][0]
         core_state["lgen"] = state["lgen"][0]

@@ -26,7 +26,6 @@ import numpy as np
 
 from reflow_tpu.delta import DeltaBatch, lossy_value_cast
 from reflow_tpu.executors import CpuExecutor, Executor
-from reflow_tpu.utils.config import env_float
 from reflow_tpu.graph import FlowGraph, GraphError, Node
 from reflow_tpu.obs import trace as _trace
 from reflow_tpu.utils.faults import DeliveryError
@@ -230,7 +229,7 @@ class DirtyScheduler:
         #: max tolerated padding waste: the fraction of the window's
         #: (tick, source) slots that would be zero-row padding. Divergent
         #: per-tick dirty sets above this run the per-tick path instead
-        self.megatick_waste = env_float("REFLOW_MEGATICK_WASTE")
+        self.megatick_waste = 0.5
 
     # -- host boundary in --------------------------------------------------
 

@@ -131,14 +131,8 @@ declare("REFLOW_DEDUP_WINDOW", "int", 1 << 20,
         "idempotent-push dedup horizon (batch ids remembered)")
 declare("REFLOW_MESH_DEVICES", "int", None,
         "mesh size for the sharded executor (unset = all local devices)")
-declare("REFLOW_LINEAR_FIXPOINT", "flag", True,
-        "fused delta-vector loop on tpu/sharded executors (0 disables)")
 declare("REFLOW_WINDOW_DEPTH", "int", 2,
         "pipelined window depth (1 = serial stage->dispatch->retire)")
-declare("REFLOW_MEGATICK_WASTE", "float", 0.5,
-        "max padded-slot fraction before a fused window falls back")
-declare("REFLOW_MEGATICK_MAX_ROWS", "int", 1 << 16,
-        "max rows per fused mega-tick window before fallback")
 declare("REFLOW_LOCKCHECK", "flag", False,
         "wrap named locks with the runtime lock-order detector; a "
         "held-before cycle raises LockOrderError (docs/guide.md "
@@ -250,8 +244,6 @@ declare("REFLOW_PROC_READY_TIMEOUT_S", "float", 30.0,
 declare("REFLOW_PROC_REAP_TIMEOUT_S", "float", 10.0,
         "harness deadline for a stopping child to exit before it is "
         "SIGKILLed (a hung child can't wedge the suite)")
-declare("REFLOW_PROC_POLL_S", "float", 0.05,
-        "harness poll slice for child liveness / barrier probes")
 declare("REFLOW_PROC_PYTHON", "str", None,
         "interpreter used to spawn harness children "
         "(default sys.executable)")
@@ -313,8 +305,6 @@ class ReflowConfig:
     dedup_window: int = 1 << 20
     #: mesh size for the sharded executor (None = all local devices)
     mesh_devices: Optional[int] = None
-    #: disable the fused delta-vector loop (tpu/sharded executors)
-    linear_fixpoint: bool = True
 
     @staticmethod
     def from_env(env=None) -> "ReflowConfig":
@@ -323,7 +313,6 @@ class ReflowConfig:
             max_loop_iters=env_int("REFLOW_MAX_LOOP_ITERS", env=env),
             dedup_window=env_int("REFLOW_DEDUP_WINDOW", env=env),
             mesh_devices=env_int("REFLOW_MESH_DEVICES", env=env),
-            linear_fixpoint=env_flag("REFLOW_LINEAR_FIXPOINT", env=env),
         )
 
     def make_executor(self):
@@ -333,14 +322,8 @@ class ReflowConfig:
             from reflow_tpu.parallel import make_mesh
             from reflow_tpu.parallel.shard import ShardedTpuExecutor
 
-            mesh = make_mesh(self.mesh_devices)
-            ex = ShardedTpuExecutor(mesh)
-        else:
-            ex = get_executor(self.executor)
-        if hasattr(ex, "linear_fixpoint") and not self.linear_fixpoint:
-            ex.linear_fixpoint = False
-            ex._linear_fixpoint = False
-        return ex
+            return ShardedTpuExecutor(make_mesh(self.mesh_devices))
+        return get_executor(self.executor)
 
     def scheduler(self, graph):
         from reflow_tpu.scheduler import DirtyScheduler

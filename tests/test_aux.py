@@ -312,8 +312,7 @@ def test_config_from_env_and_scheduler():
     from reflow_tpu.utils.config import ReflowConfig
 
     cfg = ReflowConfig.from_env({"REFLOW_EXECUTOR": "tpu",
-                                 "REFLOW_MAX_LOOP_ITERS": "77",
-                                 "REFLOW_LINEAR_FIXPOINT": "0"})
+                                 "REFLOW_MAX_LOOP_ITERS": "77"})
     assert cfg.executor == "tpu" and cfg.max_loop_iters == 77
     g = FlowGraph()
     src = g.source("s", Spec((), np.float32, key_space=8))
@@ -321,7 +320,7 @@ def test_config_from_env_and_scheduler():
     sched = cfg.scheduler(g)
     assert sched.max_loop_iters == 77
     assert sched.executor.name == "tpu"
-    assert not sched.executor._linear_fixpoint
+    assert sched.executor.linear_fixpoint
     sched.push(src, DeltaBatch(np.array([2]), np.array([5.0], np.float32)))
     sched.tick()
     assert sched.view_dict("out") == {2: 5.0}

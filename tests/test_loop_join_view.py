@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from reflow_tpu import DirtyScheduler
 from reflow_tpu.delta import Spec
 from reflow_tpu.executors import arena, get_executor
+from reflow_tpu.executors import join as jn
 from reflow_tpu.executors import lowerings as lw
 from reflow_tpu.executors.device_delta import DeviceDelta
 from reflow_tpu.graph import FlowGraph
@@ -38,7 +39,7 @@ OP = _op()
 
 
 def _core(st, da, db, k=K, r=R, op=OP):
-    return lw.join_core(op, k, r, np.float32, st, da, db, oshape=(2,))
+    return jn.join_core(op, k, r, np.float32, st, da, db, oshape=(2,))
 
 
 CORE = jax.jit(_core, static_argnums=(3, 4, 5))
@@ -51,7 +52,8 @@ def _states(keys, vals, w, k=K, r=R, op=OP):
     right = Spec((2,), np.float32, key_space=k)
     out = []
     for viewed in (False, True):
-        st = lw.join_state(op, left, right, False, viewed)
+        st = jn.join_state(op, left, right,
+                           "viewed" if viewed else "swept")
         n = len(keys)
         st["rkeys"] = st["rkeys"].at[:n].set(jnp.asarray(keys, jnp.int32))
         st["rvals"] = st["rvals"].at[:n].set(jnp.asarray(vals, jnp.float32))
@@ -325,7 +327,7 @@ def test_the_two_forms_count_the_same_pairs(monkeypatch, fused):
     n, arena_rows = 256, 1 << 9
     for form in ("view", "sweep"):
         if form == "sweep":
-            monkeypatch.setattr(arena, "view_budget", lambda k, r: 0)
+            monkeypatch.setattr(jn, "view_budget", lambda k, r: 0)
         rng = np.random.default_rng(9)
         m = 240
         src, dst = rng.integers(0, n, m), rng.integers(0, n, m)
@@ -403,19 +405,21 @@ def _join_states(which):
 
 @pytest.mark.parametrize("which", ["loop-free", "linear-left", "sharded"])
 def test_only_a_loops_join_keeps_a_view(monkeypatch, which):
-    """A loop-free unique-left join keeps the chained index (and ten
-    counters, ``probes`` not among them), a declared-linear left and the
-    sharded executor's join the plain arena: none has a view leaf, none
-    of their lowerings touches ``arena.view_*`` (each raises here), and
-    none sorts anything but a compaction's rows."""
+    """A loop-free unique-left join keeps the chained index (and every
+    counter of ``OP_COUNTERS["join"]``, ``probes`` at 0), a
+    declared-linear left and the sharded executor's join the plain
+    arena: none has a view leaf, none of their lowerings touches
+    ``arena.view_*`` (each raises here), and none sorts anything but a
+    compaction's rows."""
     sched, j = _join_states(which)
     ex = sched.executor
     st = ex.states[j.id]
     assert not any(name.startswith("view_") for name in st)
-    assert ("head" in st) == (which == "loop-free")
+    assert jn.layout_of(st) == ("indexed" if which == "loop-free"
+                                else "swept")
     if which == "loop-free":
-        assert st["counters"].shape == (10,)
-        assert ex.counter_names()["j"] == lw.OP_COUNTERS["join"][:10]
+        assert st["counters"].shape == (len(lw.OP_COUNTERS["join"]),)
+        assert ex.counter_names()["j"] == lw.OP_COUNTERS["join"]
     else:
         assert "counters" not in st
 
@@ -425,6 +429,7 @@ def test_only_a_loops_join_keeps_a_view(monkeypatch, which):
     for name in ("view_state", "view_budget", "view_sort", "view_count",
                  "view_probe"):
         monkeypatch.setattr(arena, name, never)
+        monkeypatch.setattr(jn, name, never)
     if which == "sharded":
         g = ex.graph
         edges = next(n for n in g.nodes if n.name == "edges")
@@ -442,7 +447,7 @@ def test_only_a_loops_join_keeps_a_view(monkeypatch, which):
     db = DeviceDelta.empty(j.inputs[1].spec, 64)
 
     def core(s, a, b):
-        return lw.join_core(j.op, k, r, j.spec.value_dtype, s, a, b,
+        return jn.join_core(j.op, k, r, j.spec.value_dtype, s, a, b,
                             oshape=tuple(j.spec.value_shape))
 
     jaxpr = jax.make_jaxpr(core)(st, da, db).jaxpr
@@ -457,7 +462,7 @@ def test_a_loops_join_keeps_the_view_and_eleven_counters():
     relax = next(n for n in sg.graph.nodes if n.name == "relax")
     st = ex.states[relax.id]
     assert st["view_order"].shape == (128,) and st["view_deg"].shape == (32,)
-    assert "head" not in st
+    assert jn.layout_of(st) == "viewed"
     assert ex.counter_names()["relax"] == lw.OP_COUNTERS["join"]
     assert lw.OP_COUNTERS["join"][10] == "probes"
     assert [lw.OP_COUNTERS["join"].index(n) for n in (

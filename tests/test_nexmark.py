@@ -238,7 +238,7 @@ def _join_pair(K, R, slack=8):
     was (dense)."""
     import jax.numpy as jnp
 
-    from reflow_tpu.executors.lowerings import join_state
+    from reflow_tpu.executors.join import join_state
 
     g = FlowGraph("j")
     left = g.source("l", Spec((2,), np.int32, key_space=K, unique=True))
@@ -248,8 +248,8 @@ def _join_pair(K, R, slack=8):
                    [va, vb[:, None]], axis=-1),
                spec=Spec((3,), np.int32, key_space=K), arena_capacity=R,
                product_slack=slack)
-    states = [join_state(j.op, left.spec, right.spec, indexed=ix)
-              for ix in (True, False)]
+    states = [join_state(j.op, left.spec, right.spec, layout)
+              for layout in ("indexed", "swept")]
     return j, states
 
 
@@ -288,7 +288,7 @@ def test_indexed_product_equals_the_dense_one(seed):
     multiset."""
     import jax
 
-    from reflow_tpu.executors.lowerings import join_core, join_reindex
+    from reflow_tpu.executors.join import join_core, join_reindex
 
     K, R, C = 16, 192, 32
     j, (ist, dst) = _join_pair(K, R)
@@ -496,7 +496,7 @@ def test_a_blocks_dead_tail_does_not_leak_through_a_reindex(seed):
     import jax.numpy as jnp
 
     from reflow_tpu.executors.device_delta import DeviceDelta
-    from reflow_tpu.executors.lowerings import join_core, join_reindex
+    from reflow_tpu.executors.join import join_core, join_reindex
 
     K, R, C = 16, 192, 32
     j, (ist, dst) = _join_pair(K, R)
@@ -599,7 +599,7 @@ def test_pair_budget_overflow_still_raises_the_sticky_error():
     import jax.numpy as jnp
 
     from reflow_tpu.executors.device_delta import DeviceDelta
-    from reflow_tpu.executors.lowerings import join_core
+    from reflow_tpu.executors.join import join_core
 
     K, R, C = 8, 1024, 64
     j, (ist, _dst) = _join_pair(K, R, slack=1)
@@ -637,18 +637,18 @@ def test_executor_raises_on_the_sticky_error():
 def test_a_loop_region_keeps_the_dense_join():
     """PageRank's and SSSP's joins see most of the key space change in a
     pass: the executor indexes only joins no loop variable reaches."""
+    from reflow_tpu.executors.join import layout_of
     from reflow_tpu.workloads import pagerank, sssp
 
-    for build in (lambda: pagerank.build_graph(64).graph,
-                  lambda: sssp.build_graph(64).graph):
+    def layouts(graph):
         ex = get_executor("tpu")
-        ex.bind(build())
-        assert not ex._indexed_joins
-        assert all("head" not in st for st in ex.states.values()
-                   if isinstance(st, dict))
-    ex = get_executor("tpu")
-    ex.bind(_build(_cfg()).graph)
-    assert len(ex._indexed_joins) == 2
+        ex.bind(graph)
+        return sorted(layout_of(ex.states[n.id]) for n in graph.nodes
+                      if n.kind == "op" and n.op.kind == "join")
+
+    assert layouts(pagerank.build_graph(64).graph) == ["swept"]
+    assert layouts(sssp.build_graph(64).graph) == ["viewed"]
+    assert layouts(_build(_cfg()).graph) == ["indexed", "indexed"]
 
 
 @pytest.mark.parametrize("K", [512, 4096], ids=["dense", "sparse"])

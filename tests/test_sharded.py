@@ -169,7 +169,8 @@ def test_sharded_linear_fixpoint_engages(mesh):
         for _ in range(2):
             sched.push(pg.edges, web.churn(0.05))
             assert sched.tick().quiesced
-        assert ex._linear_fixpoint, f"{name}: fused loop fell back"
+        assert ex.fixpoint_engine == "LinearFixpointProgram", (
+            f"{name}: fused loop fell back")
         assert ex._linear_structure is not None
         results[name] = sched.read_table(pg.new_rank)
     assert set(results["sharded"]) == set(results["single"])

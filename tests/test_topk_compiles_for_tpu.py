@@ -152,7 +152,7 @@ def test_a_loop_joins_pass_probes_its_view_through_near_memory(one_chip):
     import jax.numpy as jnp
     import numpy as np
 
-    from reflow_tpu.executors import lowerings as lw
+    from reflow_tpu.executors import join as jn
     from reflow_tpu.executors.device_delta import DeviceDelta
     from reflow_tpu.workloads import sssp
 
@@ -161,14 +161,14 @@ def test_a_loop_joins_pass_probes_its_view_through_near_memory(one_chip):
     relax = next(n for n in sg.graph.nodes if n.name == "relax")
     state = jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype),
-        jax.eval_shape(lambda: lw.join_state(
+        jax.eval_shape(lambda: jn.join_state(
             relax.op, relax.inputs[0].spec, relax.inputs[1].spec,
-            viewed=True)))
+            "viewed")))
     delta = DeviceDelta(_shape(one_chip, (cap,), jnp.int32),
                         _shape(one_chip, (cap,), jnp.float32),
                         _shape(one_chip, (cap,), jnp.int32))
     comp = jax.jit(
-        lambda s, d: lw.join_core(relax.op, keys, rows, np.float32, s, d,
+        lambda s, d: jn.join_core(relax.op, keys, rows, np.float32, s, d,
                                   None, oshape=(2,)),
         donate_argnums=0).lower(state, delta).compile()
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))

@@ -22,12 +22,17 @@ fi
 python tools/reflow_lint.py \
   || { echo "TIER1: reflow-lint found violations"; exit 2; }
 
-rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
-  --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly \
-  2>&1 | tee /tmp/_t1.log
+# the driver's own command (/root/TESTS_LAST_RUN.json: six xdist workers
+# by file, 1 470 s), less its junit count and its
+# ALLOW_MULTIPLE_LIBTPU_LOAD (the compile-only tests load the TPU
+# compiler in a fixture of their one file: one worker, one load)
+log=$(mktemp "${TMPDIR:-/tmp}/tier1.XXXXXX.log")
+trap 'rm -f "$log"' EXIT
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
+  --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+  --dist loadfile -p no:randomly 2>&1 | tee "$log"
 rc=${PIPESTATUS[0]}
-dots=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
+dots=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$log" | tr -cd . | wc -c)
 echo DOTS_PASSED=$dots
 
 # the lockcheck re-run: the concurrent suites (serve/tier/failover:
